@@ -33,12 +33,21 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np
 
-V5E_PEAK_TFLOPS = 197.0    # bf16 dense peak
-MEASURED_MATMUL_TFLOPS = 148.7  # PERF.md: 8192^3 bf16 matmul on this chip
-
-
 def log(*a):
     print(*a, file=sys.stderr, flush=True)
+
+
+def peak_tflops():
+    """Published bf16 peak of the device the spans run on (telemetry's
+    table, keyed by ``device_kind``); an unknown kind is an error."""
+    import jax
+    from mxnet_tpu.observability import telemetry
+    dev = jax.devices()[0]
+    peak = telemetry.device_peak_flops(dev)
+    if peak is None:
+        raise RuntimeError("no published peak for device kind %r"
+                           % (dev.device_kind,))
+    return peak / 1e12
 
 
 class LMLoss:
@@ -186,9 +195,7 @@ def bench_bert(steps, repeat, batch=None, flash=None):
                 value=round(tok_s, 1), unit="tokens/s",
                 seq_per_sec=round(tok_s / seq, 1),
                 tflops=round(tflops, 1),
-                mfu_peak=round(tflops / V5E_PEAK_TFLOPS, 3),
-                mfu_matmul_ceiling=round(tflops / MEASURED_MATMUL_TFLOPS,
-                                         3))
+                mfu_peak=round(tflops / peak_tflops(), 3))
 
 
 def bench_bert_flash_delta(steps, repeat, batch=None):
@@ -258,9 +265,7 @@ def bench_translm(steps, repeat, batch=None):
                 % (batch, seq),
                 value=round(tok_s, 1), unit="tokens/s",
                 tflops=round(tflops, 1),
-                mfu_peak=round(tflops / V5E_PEAK_TFLOPS, 3),
-                mfu_matmul_ceiling=round(tflops / MEASURED_MATMUL_TFLOPS,
-                                         3))
+                mfu_peak=round(tflops / peak_tflops(), 3))
 
 
 def bench_lstm(steps, repeat, batch=None):
@@ -300,9 +305,7 @@ def bench_lstm(steps, repeat, batch=None):
     return dict(metric="lstm_lm_tokens_per_sec_b%d" % batch,
                 value=round(tok_s, 1), unit="tokens/s",
                 tflops=round(tflops, 1),
-                mfu_peak=round(tflops / V5E_PEAK_TFLOPS, 3),
-                mfu_matmul_ceiling=round(tflops / MEASURED_MATMUL_TFLOPS,
-                                         3))
+                mfu_peak=round(tflops / peak_tflops(), 3))
 
 
 def main():

@@ -8,8 +8,10 @@ path — fresh interpreter, import, engine build, HTTP listener, one real
 
 - ``cold``        — no caches: warm the ladder with real XLA compiles,
                     then serve (the pre-PR-10 restart).
-- ``pcache``      — ``MXNET_COMPILE_CACHE_DIR``: the ladder "compiles"
-                    are disk reads of a previous run's XLA output.
+- ``pcache``      — the persistent compile cache, placed with
+                    ``JAX_COMPILATION_CACHE_DIR``, holds a previous
+                    run's XLA output: the ladder "compiles" are disk
+                    reads.
 - ``aot_prewarm`` — AOT artifacts (``executables.mxa``) + background
                     trace-driven prewarm: the server accepts requests
                     immediately and **zero** XLA compiles happen —
@@ -47,19 +49,16 @@ QUICK_BUCKETS = (1, 2, 4)
 TARGET_ON_CHIP_S = 2.0
 
 
-def _child_env(cache_dir=None):
+def _spawn(mode, model_dir, buckets, cache_dir):
+    """One child restart with its persistent compile cache at
+    ``cache_dir`` (an empty directory = an uncached restart)."""
     env = dict(os.environ)
-    env["MXNET_COMPILE_CACHE_DIR"] = cache_dir or ""
-    return env
-
-
-def _spawn(mode, model_dir, buckets, cache_dir=None):
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     out = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child", mode,
          "--model-dir", model_dir,
          "--buckets", ",".join(str(b) for b in buckets)],
-        capture_output=True, text=True, env=_child_env(cache_dir),
-        timeout=1200)
+        capture_output=True, text=True, env=env, timeout=1200)
     if out.returncode != 0:
         raise RuntimeError("child %s failed (rc=%d):\n%s"
                            % (mode, out.returncode, out.stderr[-4000:]))
@@ -194,21 +193,25 @@ def main():
         model_dir = os.path.join(tmp, "v1")
         os.makedirs(model_dir)
         cache_dir = os.path.join(tmp, "pcache")
+
+        def empty(label):
+            return os.path.join(tmp, "empty-" + label)
+
         print("publishing model ...")
-        _spawn("prep", model_dir, buckets)
+        _spawn("prep", model_dir, buckets, empty("prep"))
 
         print("cold restart (the pre-PR-10 path) ...")
-        cold = _spawn("cold", model_dir, buckets)
+        cold = _spawn("cold", model_dir, buckets, empty("cold"))
 
         print("populating persistent compile cache ...")
-        _spawn("cold", model_dir, buckets, cache_dir=cache_dir)
+        _spawn("cold", model_dir, buckets, cache_dir)
         print("pcache restart ...")
-        pc = _spawn("pcache", model_dir, buckets, cache_dir=cache_dir)
+        pc = _spawn("pcache", model_dir, buckets, cache_dir)
 
         print("exporting AOT artifacts (the CI step) ...")
-        export = _spawn("export", model_dir, buckets)
+        export = _spawn("export", model_dir, buckets, empty("export"))
         print("aot+prewarm restart ...")
-        aot = _spawn("aot_prewarm", model_dir, buckets)
+        aot = _spawn("aot_prewarm", model_dir, buckets, empty("aot"))
 
     # the acceptance gate: a restart from shipped artifacts compiles NOTHING
     if aot["compiles"] != 0 or aot["global_compiles"] != 0:

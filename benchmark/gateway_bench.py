@@ -43,10 +43,8 @@ REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
+# this process routes and measures; it never touches jax — the replica
+# workers own the chips (one process per chip)
 from mxnet_tpu.serving import Gateway  # noqa: E402
 from mxnet_tpu.resilience.retry import RetryPolicy  # noqa: E402
 from serve_fleet import ProcessBackend  # noqa: E402
@@ -277,15 +275,14 @@ def main():
     ap.add_argument("--seconds", type=float, default=3.0)
     args = ap.parse_args()
 
-    import jax
-    platform = jax.devices()[0].platform
-    env = {"JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "")}
     backend = ProcessBackend()
     n_pool = 2 if args.quick else 4
     seconds = 1.0 if args.quick else args.seconds
 
     print("spawning %d replica workers..." % n_pool)
     pool = _spawn_workers(backend, n_pool, env=None)
+    with urllib.request.urlopen(pool[0][0] + "/metrics", timeout=10) as r:
+        platform = json.loads(r.read())["telemetry"]["devices"][0]["platform"]
     results = {"platform": platform,
                "worker": "tools/serve_fleet.py --worker (demo MLP %d)"
                          % D_IN}

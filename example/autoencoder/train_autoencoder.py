@@ -20,10 +20,6 @@ import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import autograd as ag, gluon, nd
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 
 def make_data(n=768, dim=64, rank=6, seed=0):
     """Low-rank structured data: the AE must discover the 6-d manifold."""

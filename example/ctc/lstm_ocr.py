@@ -19,10 +19,6 @@ import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import autograd as ag, gluon
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 NUM_CLASSES = 10  # digits; CTC blank is class NUM_CLASSES
 
 

@@ -17,10 +17,6 @@ import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import autograd as ag, gluon
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 
 def build_generator(ngf=16, nz=16):
     net = gluon.nn.Sequential()

@@ -22,10 +22,6 @@ import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import nd, sym
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 
 def mlp_symbol(classes=10):
     data = sym.var("data")

@@ -26,10 +26,6 @@ import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import nd, sym
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 
 class NumpySoftmax(mx.operator.CustomOp):
     """Softmax + cross-entropy gradient, all in numpy (reference

@@ -24,10 +24,6 @@ import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import autograd as ag, gluon, nd
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 
 def train_profiled(steps=30, outdir="/tmp/mxtpu_prof", log=print):
     net = gluon.nn.HybridSequential()

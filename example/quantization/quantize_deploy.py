@@ -26,10 +26,6 @@ import mxnet_tpu as mx
 from mxnet_tpu import autograd as ag, gluon, nd
 from mxnet_tpu.contrib.quantization import quantize_net
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 
 def build_cnn(classes=10):
     net = gluon.nn.HybridSequential()

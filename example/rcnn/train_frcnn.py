@@ -23,10 +23,6 @@ import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import autograd as ag, gluon
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 IMAGE = 32
 STRIDE = 4
 SCALES = (2, 3)

@@ -26,10 +26,6 @@ import mxnet_tpu as mx
 from mxnet_tpu import nd
 from mxnet_tpu.ndarray import sparse
 
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 
 def make_libsvm(path, n=512, feat=1000, active=12, seed=0):
     """Synthetic libsvm file: y in {0,1} from a sparse ground-truth w."""

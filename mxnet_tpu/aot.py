@@ -15,8 +15,8 @@ Container format (version 1)::
     header length     8-byte little-endian unsigned
     header JSON       {"format": 1, "fingerprint": {...}, "extra": {...},
                        "entries": [{"signature", "train", "flops",
-                                    "in_tree_size", "out_tree_size",
-                                    "blob_size"}, ...]}
+                                    "devices", "in_tree_size",
+                                    "out_tree_size", "blob_size"}, ...]}
     entry payloads    concatenated (in_tree pickle, out_tree pickle, blob)
                       in entry order
 
@@ -26,10 +26,13 @@ raises a typed :class:`ArtifactError` at *manifest verify* time, never as
 a confusing PJRT failure on the first live request.
 
 A serialized executable is machine code for one exact (backend, device
-kind, topology, jax/jaxlib version): :func:`fingerprint` records that
-tuple at export and :func:`fingerprint_matches` gates the load. A
-mismatch is never a crash — callers fall back to a normal compile (the
-persistent compile cache then usually still saves the XLA run).
+kind, device span, jax/jaxlib version): :func:`fingerprint` records that
+tuple at export and :func:`fingerprint_matches` gates the load. Each
+entry also records the ids of the devices its program was compiled for,
+and is loaded onto exactly those — a one-chip artifact loads on a
+four-chip host, onto the chip it names. A mismatch is never a crash —
+callers fall back to a normal compile (the persistent compile cache then
+usually still saves the XLA run).
 """
 from __future__ import annotations
 
@@ -80,32 +83,30 @@ def mesh_axes(mesh):
 def fingerprint(mesh=None):
     """The compatibility tuple a serialized executable is valid for:
     jax/jaxlib/mxnet_tpu versions + backend platform + device kind +
-    addressable-device count + (for sharded lanes) the mesh axis
-    names and sizes the program was compiled against. Computed at
+    the number of devices the programs span + (for sharded lanes) the
+    mesh axis names and sizes they were compiled against. Computed at
     export, compared at load.
 
-    ``mesh=None`` means a single-device program; an artifact exported
-    without a mesh can therefore never be silently installed into a
-    sharded lane (and vice versa) — :func:`fingerprint_matches` treats
-    ``mesh`` exactly like the topology keys."""
+    ``mesh=None`` means single-device programs (``n_devices`` 1,
+    whatever the host holds); an artifact exported without a mesh can
+    therefore never be silently installed into a sharded lane (and vice
+    versa) — :func:`fingerprint_matches` treats ``mesh`` exactly like
+    the topology keys. WHICH devices is per entry (``devices``)."""
+    import math
     import jax
     import jaxlib
     from . import __version__ as _mx_version
-    try:
-        devs = jax.local_devices()
-    except RuntimeError:
-        devs = []
-    accel = [d for d in devs if d.platform != "cpu"] or devs
+    dev = jax.local_devices()[0]
+    axes = mesh_axes(mesh)
     return {
         "format": 1,
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "mxnet_tpu": _mx_version,
-        "platform": accel[0].platform if accel else "unknown",
-        "device_kind": (getattr(accel[0], "device_kind", "") or ""
-                        ) if accel else "",
-        "n_devices": len(accel),
-        "mesh": mesh_axes(mesh),
+        "platform": dev.platform,
+        "device_kind": getattr(dev, "device_kind", "") or "",
+        "n_devices": math.prod(axes.values()) if axes else 1,
+        "mesh": axes,
     }
 
 
@@ -142,29 +143,39 @@ def fingerprint_diff(recorded, current=None):
 # ---------------------------------------------------------------------------
 
 def serialize_compiled(compiled):
-    """``jax.stages.Compiled`` → ``(blob, in_tree_bytes, out_tree_bytes)``.
-    Raises :class:`ArtifactError` when the backend's executables don't
-    support serialization (the caller skips AOT export, it doesn't
-    crash)."""
+    """``jax.stages.Compiled`` → ``(blob, in_tree_bytes, out_tree_bytes,
+    device_ids)``; ``device_ids`` are the devices the program was
+    compiled for, in assignment order. Raises :class:`ArtifactError`
+    when the backend's executables don't support serialization (the
+    caller skips AOT export, it doesn't crash)."""
     from jax.experimental import serialize_executable as _se
     try:
         blob, in_tree, out_tree = _se.serialize(compiled)
-        return blob, pickle.dumps(in_tree), pickle.dumps(out_tree)
+        devices = [int(d.id) for d in
+                   compiled.runtime_executable().local_devices()]
+        return blob, pickle.dumps(in_tree), pickle.dumps(out_tree), devices
     except Exception as exc:  # noqa: BLE001 — typed for callers
         raise ArtifactError(
             "backend cannot serialize compiled executable: %s: %s"
             % (type(exc).__name__, exc)) from exc
 
 
-def deserialize_compiled(blob, in_tree_bytes, out_tree_bytes):
+def deserialize_compiled(blob, in_tree_bytes, out_tree_bytes, device_ids):
     """Inverse of :func:`serialize_compiled`: bytes → a callable
-    ``jax.stages.Compiled`` loaded onto this process's backend. No XLA
-    compile happens here — PJRT deserializes machine code."""
+    ``jax.stages.Compiled`` loaded onto the devices it was compiled for
+    (``device_ids``; left to itself jax loads onto EVERY local device and
+    a one-chip program then refuses its one-shard arguments). A device
+    this host does not have is an :class:`ArtifactError`. No XLA compile
+    happens here — PJRT deserializes machine code."""
+    import jax
     from jax.experimental import serialize_executable as _se
     try:
+        by_id = {d.id: d for d in jax.local_devices()}
+        devices = [by_id[i] for i in device_ids]
         in_tree = pickle.loads(in_tree_bytes)
         out_tree = pickle.loads(out_tree_bytes)
-        return _se.deserialize_and_load(blob, in_tree, out_tree)
+        return _se.deserialize_and_load(blob, in_tree, out_tree,
+                                        execution_devices=devices)
     except Exception as exc:  # noqa: BLE001 — typed for callers
         raise ArtifactError(
             "cannot deserialize executable blob: %s: %s"
@@ -196,7 +207,7 @@ def write_artifact(path, records, extra=None, fp=None):
     half-artifact that passes a later existence check).
 
     ``records``: list of dicts with keys ``signature`` (cache-key tuple),
-    ``train``, ``flops``, ``blob``, ``in_tree``, ``out_tree``.
+    ``train``, ``flops``, ``devices``, ``blob``, ``in_tree``, ``out_tree``.
     ``extra`` lands in the header verbatim (the engine records its bucket
     ladder there). Returns the header dict."""
     if not records:
@@ -209,6 +220,7 @@ def write_artifact(path, records, extra=None, fp=None):
             "signature": _jsonable_signature(rec["signature"]),
             "train": bool(rec["train"]),
             "flops": float(rec.get("flops") or 0.0),
+            "devices": [int(i) for i in rec["devices"]],
             "in_tree_size": len(rec["in_tree"]),
             "out_tree_size": len(rec["out_tree"]),
             "blob_size": len(rec["blob"]),
@@ -293,8 +305,8 @@ def read_artifact_header(path):
 
 def read_artifact(path):
     """Read the full artifact: ``(header, records)`` where each record is
-    ``{"signature", "train", "flops", "blob", "in_tree", "out_tree"}``
-    ready for ``CachedOp.deserialize``. Raises :class:`ArtifactError` on
+    ``{"signature", "train", "flops", "devices", "blob", "in_tree",
+    "out_tree"}`` ready for ``CachedOp.deserialize``. Raises :class:`ArtifactError` on
     any structural problem."""
     header = read_artifact_header(path)
     records = []
@@ -313,6 +325,7 @@ def read_artifact(path):
                 "signature": signature_from_json(e["signature"]),
                 "train": bool(e["train"]),
                 "flops": float(e.get("flops") or 0.0),
+                "devices": [int(i) for i in e["devices"]],
                 "blob": blob, "in_tree": in_tree, "out_tree": out_tree,
             })
     return header, records

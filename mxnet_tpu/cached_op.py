@@ -275,6 +275,22 @@ class CachedOp:
         with self._dispatch_lock:
             self._shardings[sig] = tuple(shardings)
 
+    def signatures(self):
+        """Cache signatures of the resident executables, LRU order."""
+        with self._dispatch_lock:
+            return list(self._cache)
+
+    def lower(self, sig):
+        """Re-lower resident signature ``sig`` through the jax AOT API —
+        the program dispatch runs, against the committed input shardings
+        it ran with — as a ``jax.stages.Lowered``: ``.compile()`` it to
+        serialize the executable or to read its HLO. (The traced-dispatch
+        path's own executable isn't directly extractable.)"""
+        pure, _n_out_box, _aux_box = self._make_pure(sig[1])
+        return jax.jit(pure).lower(
+            jax.random.PRNGKey(0),
+            *self._specs_for(sig, self.input_shardings(sig)))
+
     def serialize(self):
         """Capture every resident executable's *program* as
         PJRT-serialized bytes: a list of records for
@@ -288,19 +304,15 @@ class CachedOp:
         restart after it compiles nothing. With the persistent compile
         cache enabled the re-compile here is itself a disk hit."""
         with self._dispatch_lock:
-            sigs = [(sig, entry[4], entry[6], self._shardings.get(sig))
+            sigs = [(sig, entry[4], entry[6])
                     for sig, entry in self._cache.items()]
         records = []
-        for sig, flops, nbytes, shardings in sigs:
-            train = sig[1]
-            pure, _n_out_box, _aux_box = self._make_pure(train)
-            compiled = jax.jit(pure).lower(
-                jax.random.PRNGKey(0),
-                *self._specs_for(sig, shardings)).compile()
-            blob, in_tree, out_tree = _aot.serialize_compiled(compiled)
-            records.append({"signature": sig, "train": train,
+        for sig, flops, nbytes in sigs:
+            blob, in_tree, out_tree, devices = \
+                _aot.serialize_compiled(self.lower(sig).compile())
+            records.append({"signature": sig, "train": sig[1],
                             "flops": flops, "bytes": nbytes,
-                            "blob": blob,
+                            "devices": devices, "blob": blob,
                             "in_tree": in_tree, "out_tree": out_tree})
         return records
 
@@ -325,7 +337,8 @@ class CachedOp:
             jax.eval_shape(jitted, jax.random.PRNGKey(0), *specs)
             n_out, multi = n_out_box[0]
             exe = _aot.deserialize_compiled(rec["blob"], rec["in_tree"],
-                                            rec["out_tree"])
+                                            rec["out_tree"],
+                                            rec["devices"])
             entry = (exe, n_out, multi, aux_handles_box[0],
                      float(rec.get("flops") or 0.0), True,
                      float(rec.get("bytes") or 0.0))

@@ -244,10 +244,6 @@ KNOBS = dict([
     _k("MXNET_ENGINE_BULK_SIZE", 15, int, "wired",
        "engine bulk-dispatch size set via the C API "
        "(MXEngineSetBulkSize parity; _c_api_impl.py)"),
-    _k("MXNET_COMPILE_CACHE_DIR", "", str, "wired",
-       "persistent XLA compilation cache directory (pcache.py, "
-       "initialized at import): recompiles of previously seen programs "
-       "become disk reads across process restarts; empty = off"),
     _k("MXNET_COMPILE_CACHE_MIN_COMPILE_SECS", 0.0, float, "wired",
        "only persist compiles at least this slow (0 = everything — "
        "jax's 1.0s default would skip the small serving-ladder rungs "

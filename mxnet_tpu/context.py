@@ -51,14 +51,19 @@ class Context:
         global list contains peers' devices, which cannot back an eager
         array here."""
         if self.device_type in ("cpu", "cpu_pinned", "cpu_shared"):
-            cpus = [d for d in jax.local_devices(backend="cpu")] \
+            pool = [d for d in jax.local_devices(backend="cpu")] \
                 if _has_cpu() else jax.local_devices()
-            return cpus[min(self.device_id, len(cpus) - 1)]
-        accel = self._accelerators()
-        if not accel:  # CPU-only process (tests): accelerator ctx falls back
-            local = jax.local_devices()
-            return local[min(self.device_id, len(local) - 1)]
-        return accel[min(self.device_id, len(accel) - 1)]
+        else:
+            # a process started on the CPU backend (JAX_PLATFORMS=cpu: the
+            # test suite, reference scripts that say mx.gpu()) has no
+            # accelerator; its accelerator contexts index the CPU devices
+            pool = self._accelerators() or jax.local_devices()
+        if not 0 <= self.device_id < len(pool):
+            # never clamp: tpu(3) on a one-chip host silently landing on
+            # chip 0 would put a whole multi-chip job on the first device
+            raise ValueError("%s: this process has %d such device(s)"
+                             % (self, len(pool)))
+        return pool[self.device_id]
 
     def __hash__(self):
         return hash((self.device_type, self.device_id))
@@ -152,10 +157,10 @@ def current_context() -> Context:
 # Persistent XLA compile cache (ROADMAP item 4): initialized ONCE at
 # import — this module is the first device-touching import every
 # ``import mxnet_tpu`` performs, so the cache directory is configured
-# before any program can compile. With ``MXNET_COMPILE_CACHE_DIR`` set,
-# a restarted process re-reads previously compiled programs off disk
-# instead of paying XLA again; unset, this only registers the (zeroed)
-# ``cachedop.pcache.*`` telemetry. Never raises (see pcache.py).
+# before any program can compile: a restarted process re-reads
+# previously compiled programs off disk instead of paying XLA again.
+# The cache is where ``JAX_COMPILATION_CACHE_DIR`` says, else the fixed
+# ``<checkout>/.jax_cache``. Never raises (see pcache.py).
 from . import pcache as _pcache  # noqa: E402  (import-time init by design)
 
 _pcache.init_from_env()

@@ -21,7 +21,8 @@ from collections import OrderedDict
 
 from ..context import current_context
 from ..ndarray.ndarray import NDArray
-from .parameter import Parameter, ParameterDict, DeferredInitializationError
+from .parameter import (DeferredInitializationError, Parameter,
+                        ParameterDict, swapped_in)
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock"]
 
@@ -424,19 +425,11 @@ class HybridBlock(Block):
         def fn(*vals):
             n_in = n_in_box["n"]
             inputs, pvals = vals[:n_in], vals[n_in:]
-            saved = []
-            try:
-                for p, v in zip(params, pvals):
-                    for i, d in enumerate(p._data):
-                        saved.append((p, i, d._data))
-                        d._data = v._data
+            with swapped_in(params, pvals):
                 args_re, _ = _regroup(list(inputs), self._in_fmt)
                 if not isinstance(args_re, list):
                     args_re = [args_re]
                 out = self._eager_forward(*args_re)
-            finally:
-                for p, i, old in reversed(saved):
-                    p._data[i]._data = old
             flat_out, self._out_fmt = _flatten(out)
             return flat_out if len(flat_out) > 1 else flat_out[0]
 

@@ -9,6 +9,7 @@ is kept for MXNet compatibility and single-host multi-device eager use.
 """
 from __future__ import annotations
 
+import contextlib
 from collections import OrderedDict
 
 import numpy as _np
@@ -20,7 +21,30 @@ from ..ndarray import ndarray as _nd
 from ..ndarray.ndarray import NDArray
 
 __all__ = ["DeferredInitializationError", "Parameter", "Constant",
-           "ParameterDict", "tensor_types"]
+           "ParameterDict", "tensor_types", "swapped_in"]
+
+
+@contextlib.contextmanager
+def swapped_in(params, values):
+    """While the ``with`` block runs, every context copy of ``params[i]``
+    reads as ``values[i]`` (raw arrays or NDArrays — tracers, while a
+    program that takes the parameters as ARGUMENTS is being traced).
+    A parameter a traced program merely closes over is baked into the
+    executable as a constant: one copy of the weights per program, in
+    host memory while it compiles, in the serialized artifact and in
+    device memory. Not re-entrant across threads: the handles are shared
+    state, as in the reference's CachedOp parameter binding."""
+    saved = []
+    try:
+        for p, v in zip(params, values):
+            v = v._data if isinstance(v, NDArray) else v
+            for d in p._data:
+                saved.append((d, d._data))
+                d._data = v
+        yield
+    finally:
+        for d, old in reversed(saved):
+            d._data = old
 
 tensor_types = (NDArray,)
 

@@ -331,10 +331,7 @@ def _cross_process_allreduce(x):
     (`src/kvstore/kvstore_dist_server.h:337`) with one collective."""
     from jax.experimental import multihost_utils
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     nproc = jax.process_count()
     key = ("mesh", nproc)

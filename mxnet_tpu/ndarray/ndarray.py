@@ -92,7 +92,9 @@ class NDArray:
             d = next(iter(dev))
             if d.platform == "cpu":
                 return Context("cpu", d.id)
-            return Context("tpu", 0)
+            # the id Context.jax_device resolves back to this device
+            accel = [x for x in jax.local_devices() if x.platform != "cpu"]
+            return Context("tpu", accel.index(d))
         return current_context()
 
     context = ctx

@@ -252,7 +252,8 @@ def _render_pcache(w):
     st = _pcache.stats()
     w.gauge("mxtpu_pcache_enabled",
             "1 while the persistent XLA compile cache is wired to a "
-            "directory (MXNET_COMPILE_CACHE_DIR)", st["enabled"])
+            "directory (JAX_COMPILATION_CACHE_DIR, else "
+            "<checkout>/.jax_cache)", st["enabled"])
     for key, help_text in (
             ("disk_hits", "compiles served from the persistent cache "
                           "(disk read instead of an XLA run)"),

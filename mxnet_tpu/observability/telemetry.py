@@ -156,6 +156,14 @@ def _accel_devices():
     return accel or devs
 
 
+def device_peak_flops(device):
+    """Published peak FLOP/s (bf16) of one jax device by its
+    ``device_kind``, or ``None`` for a kind that is not in the table —
+    never a default."""
+    kind = (getattr(device, "device_kind", "") or "").lower()
+    return next((p for sub, p in _PEAK_FLOPS_BY_KIND if sub in kind), None)
+
+
 def peak_flops():
     """Aggregate peak FLOP/s across this process's devices, or ``None``
     when unknown (CPU-only and no ``MXNET_TELEMETRY_PEAK_FLOPS``
@@ -166,13 +174,7 @@ def peak_flops():
         return None
     if override > 0:
         return override * len(devices)
-    total = 0.0
-    for d in devices:
-        kind = (getattr(d, "device_kind", "") or "").lower()
-        per_dev = next((p for sub, p in _PEAK_FLOPS_BY_KIND
-                        if sub in kind), 0.0)
-        total += per_dev
-    return total or None
+    return sum(device_peak_flops(d) or 0.0 for d in devices) or None
 
 
 def mfu_percent():

@@ -139,6 +139,17 @@ def _wrap_host(np_arrays):
             for a in np_arrays]
 
 
+def _settled(results):
+    """Wait for an eagerly dispatched host callback before the caller
+    dispatches anything else (no-op on tracers, i.e. under jit). The
+    user's op works on NDArrays, so the callback itself dispatches jax
+    work; a device runs its computations in order, so if the main thread
+    has meanwhile queued more work behind the computation that hosts the
+    callback, the callback's own dispatch queues behind that work and
+    the two wait on each other for good."""
+    return jax.block_until_ready(results)
+
+
 def _zeros_nd(specs):
     from .ndarray.ndarray import NDArray
     return [NDArray(jnp.zeros(s, d)) for s, d in specs]
@@ -220,8 +231,8 @@ def _custom_callable(op_type, prop_kwargs, is_train):
         specs = (jax.ShapeDtypeStruct((), _np.int64),) + tuple(
             jax.ShapeDtypeStruct(s, t)
             for s, t in zip(out_shapes, out_types))
-        res = jax.pure_callback(host_forward, specs, *tensor_vals,
-                                vmap_method="sequential")
+        res = _settled(jax.pure_callback(host_forward, specs, *tensor_vals,
+                                         vmap_method="sequential"))
         return res[0], tuple(res[1:])
 
     @jax.custom_vjp
@@ -237,9 +248,9 @@ def _custom_callable(op_type, prop_kwargs, is_train):
         call_id, tensor_vals, outs = res
         in_specs = tuple(jax.ShapeDtypeStruct(v.shape, v.dtype)
                          for v in tensor_vals[:n_args])
-        grads = jax.pure_callback(host_backward, in_specs, call_id, *gouts,
-                                  *tensor_vals, *outs,
-                                  vmap_method="sequential")
+        grads = _settled(jax.pure_callback(
+            host_backward, in_specs, call_id, *gouts, *tensor_vals, *outs,
+            vmap_method="sequential"))
         if not isinstance(grads, tuple):
             grads = (grads,)
         # aux states receive no gradient

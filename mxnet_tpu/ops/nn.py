@@ -12,6 +12,7 @@ re-layouts internally for the TPU).
 """
 from __future__ import annotations
 
+import math as _math
 import os as _os
 
 import numpy as _np
@@ -756,6 +757,62 @@ def _reduce_key_mask(mask, batch, key_len):
     return None, False
 
 
+def _sharded_flash(kernel, heads_dim, mesh, batch_axes, query, key, value,
+                   kv_mask, seed, causal, drop, interpret=False):
+    """``kernel`` under ``jax.shard_map`` over ``mesh``: GSPMD cannot
+    partition a Mosaic kernel, so each device runs it on its own shard —
+    the batch dim split over ``batch_axes``, the heads dim over ``tp``,
+    each only where the dim divides (what does not divide is computed
+    replicated). Every shard folds its global batch/head offset into the
+    dropout seed operand, so the keep-mask is the unsharded call's."""
+    from jax.sharding import PartitionSpec as P
+    B, H = query.shape[0], query.shape[heads_dim]
+    b_axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)
+    n_b = _math.prod(mesh.shape[a] for a in b_axes)
+    if not b_axes or B % n_b:
+        b_axes, n_b = None, 1
+    tp = mesh.shape.get("tp", 1)
+    h_axis, n_h = ("tp", tp) if tp > 1 and H % tp == 0 else (None, 1)
+    spec = [b_axes, None, None, None]
+    spec[heads_dim] = h_axis
+    spec = P(*spec)
+
+    def shard(q, k, v, kv_mask, seed):
+        if seed is not None:
+            b_off = lax.axis_index(b_axes) * (B // n_b) if b_axes else 0
+            h_off = lax.axis_index(h_axis) * (H // n_h) if h_axis else 0
+            seed = jnp.stack([seed, jnp.int32(b_off * H + h_off),
+                              jnp.int32(H)])
+        return kernel(q, k, v, kv_mask, seed, causal, drop, interpret)
+
+    return jax.shard_map(
+        shard, mesh=mesh,
+        in_specs=(spec, spec, spec, P(b_axes, None), P()),
+        out_specs=spec, check_vma=False)(query, key, value, kv_mask, seed)
+
+
+def _flash_call(kernel, heads_dim, query, key, value, kv_mask, rng_key,
+                causal, drop):
+    """Run a flash kernel on the devices the enclosing program spans:
+    the bare call on one device, :func:`_sharded_flash` when the trainer
+    or serving lane tracing this op made a larger mesh visible
+    (``parallel.mesh.mesh_scope``)."""
+    from ..parallel.mesh import current_scope
+    seed = None
+    if drop > 0.0:
+        seed = jax.random.randint(rng_key, (), -2**31, 2**31 - 1,
+                                  dtype=jnp.int32)
+    scope = current_scope()
+    if scope is None or scope[0].size == 1:
+        return kernel(query, key, value, kv_mask, seed, causal, drop)
+    return _sharded_flash(kernel, heads_dim, scope[0], scope[1], query,
+                          key, value, kv_mask, seed, causal, drop)
+
+
+def _on_accelerator():
+    return any(d.platform != "cpu" for d in jax.devices())
+
+
 @register("_contrib_dot_product_attention",
           state_binders={"rng_key": _bind_key, "train": _bind_train})
 def dot_product_attention(query, key, value, mask=None, dropout=0.0,
@@ -769,7 +826,6 @@ def dot_product_attention(query, key, value, mask=None, dropout=0.0,
     per-key mask) and train-time attention dropout (in-kernel counter RNG,
     fwd/bwd consistent). Full (B,H,Q,K) masks and cross-attention take the
     XLA softmax path below."""
-    import os
     if layout == "BSHD" and getattr(query, "ndim", 0) == 4:
         # (B, S, H, D) — the transformer's natural layout straight out of
         # the qkv projection. The head-fused kernel consumes it with NO
@@ -785,19 +841,9 @@ def dot_product_attention(query, key, value, mask=None, dropout=0.0,
                 and (drop == 0.0 or rng_key is not None)
                 and flash_attention_bshd_usable(query.shape,
                                                 query.shape[-1])
-                and _flash_enabled()):
-            try:
-                on_tpu = any(d.platform not in ("cpu",)
-                             for d in jax.devices())
-            except RuntimeError:
-                on_tpu = False
-            if on_tpu:
-                seed = None
-                if drop > 0.0:
-                    seed = jax.random.randint(
-                        rng_key, (), -2**31, 2**31 - 1, dtype=jnp.int32)
-                return flash_attention_bshd(query, key, value, kv_mask,
-                                            seed, causal, drop)
+                and _flash_enabled() and _on_accelerator()):
+            return _flash_call(flash_attention_bshd, 2, query, key, value,
+                               kv_mask, rng_key, causal, drop)
         # fallback: run the BHSD path and restore the layout; XLA fuses
         # these transposes into the surrounding einsums. (.fn: the module
         # name is the registered Op wrapper, whose __call__ re-wraps)
@@ -820,19 +866,10 @@ def dot_product_attention(query, key, value, mask=None, dropout=0.0,
         if (mask_ok and key.shape == query.shape
                 and value.shape == query.shape
                 and (drop == 0.0 or rng_key is not None)
-                and flash_attention_usable(query.shape, causal)):
-            try:
-                on_tpu = any(d.platform not in ("cpu",)
-                             for d in jax.devices())
-            except RuntimeError:
-                on_tpu = False
-            if on_tpu:
-                seed = None
-                if drop > 0.0:
-                    seed = jax.random.randint(
-                        rng_key, (), -2**31, 2**31 - 1, dtype=jnp.int32)
-                return flash_attention(query, key, value, kv_mask, seed,
-                                       causal, drop)
+                and flash_attention_usable(query.shape, causal)
+                and _on_accelerator()):
+            return _flash_call(flash_attention, 1, query, key, value,
+                               kv_mask, rng_key, causal, drop)
     d = query.shape[-1]
     scores = jnp.einsum("...qd,...kd->...qk", query, key)
     if scaled:

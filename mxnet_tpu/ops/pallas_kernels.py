@@ -33,13 +33,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
-__all__ = ["flash_attention", "flash_attention_bshd", "pallas_available",
+__all__ = ["flash_attention", "flash_attention_bshd",
            "flash_attention_usable", "flash_attention_bshd_usable"]
 
 import os as _os
@@ -116,14 +112,8 @@ def _pick_blocks_bshd(S, causal, HD, itemsize):
 NEG_INF = -1e30
 
 
-def pallas_available():
-    return _HAS_PALLAS
-
-
 def flash_attention_usable(q_shape, causal=False):
     """Whether the pallas path supports this problem size."""
-    if not _HAS_PALLAS:
-        return False
     B, H, S, D = q_shape
     return S % BLOCK_Q == 0 and S >= BLOCK_Q and D <= 256
 
@@ -143,6 +133,21 @@ def _lowbias32(x):
     x = x * _U32(0x846CA68B)
     x = x ^ (x >> _U32(16))
     return x
+
+
+def _seed_parts(seed_ref, b, h):
+    """``(seed, bh)`` from the (1, 3) seed operand ``[seed, bh_base,
+    bh_stride]`` (see :func:`_seed_operand`): ``bh`` is the GLOBAL
+    batch*heads index of this program's local ``(b, h)``."""
+    return seed_ref[0, 0], b * seed_ref[0, 2] + h + seed_ref[0, 1]
+
+
+def _flat_seed_parts(seed_ref, num_heads):
+    """:func:`_seed_parts` for the BHSD kernels, whose grid dim 0 is the
+    flat local ``b * num_heads + h``."""
+    H = jnp.int32(num_heads)
+    pid = pl.program_id(0)
+    return _seed_parts(seed_ref, jax.lax.div(pid, H), jax.lax.rem(pid, H))
 
 
 def _keep_bits(seed, bh, q0, k0, blk_q, blk_k, keep_prob):
@@ -238,13 +243,12 @@ def _bwd_tile_ds(q, k, v, do, lse, delta, mask_row, causal, dropout,
 
 def _attn_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
                      lse_ref, *, scale, causal, blk_q, blk_k, seq_len,
-                     dropout, has_mask):
+                     dropout, has_mask, num_heads):
     """One (batch*head, q-block) program: stream K/V blocks with online
     softmax accumulation in fp32. Also writes the per-row logsumexp the
     backward kernels recompute probability tiles from."""
-    bh = pl.program_id(0)
     qi = pl.program_id(1)
-    seed = seed_ref[0, 0]
+    seed, bh = _flat_seed_parts(seed_ref, num_heads)
     q = q_ref[0]                                  # (blk_q, D), raw dtype
 
     n_kb = seq_len // blk_k
@@ -311,12 +315,12 @@ def _recompute_tile(q, k, lse, seed, bh, q0, k0, mask_row, causal,
 
 def _attn_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                         delta_ref, mask_ref, dq_ref, *, scale, causal,
-                        blk_q, blk_k, seq_len, dropout, has_mask):
+                        blk_q, blk_k, seq_len, dropout, has_mask,
+                        num_heads):
     """grad wrt Q: one (batch*head, q-block) program streaming K blocks.
     dS = P o (dP - delta); dQ = dS K * scale (flash-attention-2 eq. 4)."""
-    bh = pl.program_id(0)
     qi = pl.program_id(1)
-    seed = seed_ref[0, 0]
+    seed, bh = _flat_seed_parts(seed_ref, num_heads)
     q = q_ref[0]
     do = do_ref[0]                               # (blk_q, D)
     lse = lse_ref[0, 0, :]                       # (blk_q,)
@@ -347,12 +351,12 @@ def _attn_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _attn_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                          delta_ref, mask_ref, dk_ref, dv_ref, *, scale,
-                         causal, blk_q, blk_k, seq_len, dropout, has_mask):
+                         causal, blk_q, blk_k, seq_len, dropout, has_mask,
+                         num_heads):
     """grads wrt K and V: one (batch*head, k-block) program streaming Q
     blocks. dV = Pdrop^T dO; dK = dS^T Q * scale."""
-    bh = pl.program_id(0)
     ki = pl.program_id(1)
-    seed = seed_ref[0, 0]
+    seed, bh = _flat_seed_parts(seed_ref, num_heads)
     k = k_ref[0]                                 # (blk_k, D)
     v = v_ref[0]
     mask_row = None
@@ -398,6 +402,22 @@ def _attn_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 # ----------------------------------------------------------- pallas plumbing
 
+def _seed_operand(seed, num_heads):
+    """The kernels' (1, 3) int32 seed operand ``[seed, bh_base,
+    bh_stride]``. Dropout keep-bits are keyed on the global index
+    ``bh = b * bh_stride + h + bh_base`` of a program's local ``(b, h)``.
+    A scalar ``seed`` means the call holds the whole batch and all heads
+    (``bh_base = 0``, ``bh_stride = num_heads``); a ``shard_map`` shard
+    passes the 3-vector with its own offsets, so a sharded call draws the
+    mask the unsharded call would."""
+    if seed is None:
+        return jnp.zeros((1, 3), jnp.int32)
+    seed = jnp.asarray(seed, jnp.int32)
+    if seed.ndim == 0:
+        seed = jnp.stack([seed, jnp.int32(0), jnp.int32(num_heads)])
+    return seed.reshape(1, 3)
+
+
 def _prep(q, k, v, kv_mask, seed):
     B, H, S, D = q.shape
     qr = q.reshape(B * H, S, D)
@@ -407,11 +427,7 @@ def _prep(q, k, v, kv_mask, seed):
         mr = jnp.ones((B, 1, S), jnp.int32)  # dummy operand, loads elided
     else:
         mr = kv_mask.astype(jnp.int32).reshape(B, 1, S)
-    if seed is None:
-        sr = jnp.zeros((1, 1), jnp.int32)
-    else:
-        sr = jnp.asarray(seed, jnp.int32).reshape(1, 1)
-    return qr, kr, vr, mr, sr
+    return qr, kr, vr, mr, _seed_operand(seed, H)
 
 
 def _flash_fwd_impl(q, k, v, kv_mask, seed, causal, dropout, interpret):
@@ -425,14 +441,14 @@ def _flash_fwd_impl(q, k, v, kv_mask, seed, causal, dropout, interpret):
     kernel = functools.partial(
         _attn_fwd_kernel, scale=scale, causal=causal, blk_q=blk_q,
         blk_k=blk_k, seq_len=S, dropout=float(dropout),
-        has_mask=kv_mask is not None)
+        has_mask=kv_mask is not None, num_heads=H)
     call = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
                    jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i: (0, 0)),          # seed
+            pl.BlockSpec((1, 3), lambda b, i: (0, 0)),          # seed
             pl.BlockSpec((1, blk_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0)),
@@ -464,8 +480,8 @@ def _flash_bwd_impl(q, k, v, kv_mask, seed, o, lse, g, causal, dropout,
                     axis=-1)[:, None, :]
     common = dict(scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
                   seq_len=S, dropout=float(dropout),
-                  has_mask=kv_mask is not None)
-    seed_spec = pl.BlockSpec((1, 1), lambda b, i: (0, 0))
+                  has_mask=kv_mask is not None, num_heads=H)
+    seed_spec = pl.BlockSpec((1, 3), lambda b, i: (0, 0))
     mask_spec = pl.BlockSpec((1, 1, S), lambda b, i, H=H: (b // H, 0, 0))
     full_spec = pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0))
     row_full = pl.BlockSpec((1, 1, S), lambda b, i: (b, 0, 0))
@@ -536,7 +552,8 @@ def flash_attention(q, k, v, kv_mask=None, seed=None, causal=False,
     """Blockwise exact attention, (B, H, S, D) layout.
 
     kv_mask: optional (B, S) key keep-mask (nonzero = attend).
-    seed:    int32 scalar for attention dropout (required if dropout > 0).
+    seed:    int32 scalar for attention dropout (required if dropout > 0),
+             or the 3-vector of :func:`_seed_operand` from a shard.
     dropout: STATIC attention-probability dropout rate (traced under jit
              per distinct value; rates are fixed hyperparameters).
     """
@@ -572,8 +589,6 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # Requires H*D % 128 == 0.
 
 def flash_attention_bshd_usable(q_shape, head_dim):
-    if not _HAS_PALLAS:
-        return False
     B, S, HD = q_shape[0], q_shape[1], int(np.prod(q_shape[2:]))
     # Each program holds two FULL (S, H*D) operands in VMEM (K+V in the
     # forward; Q+dO in the dkdv backward) plus block-sized tiles and fp32
@@ -591,15 +606,14 @@ def _bshd_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
                      dropout, has_mask, num_heads, head_dim):
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    seed = seed_ref[0, 0]
     n_kb = seq_len // blk_k
     H, D = num_heads, head_dim
 
     for h in range(H):                            # static unroll
         q = q_ref[0, :, h * D:(h + 1) * D]
-        bh = b * jnp.int32(H) + jnp.int32(h)
+        seed, bh = _seed_parts(seed_ref, b, jnp.int32(h))
 
-        def body(kb, carry, h=h, q=q, bh=bh):
+        def body(kb, carry, h=h, q=q, seed=seed, bh=bh):
             k = k_ref[0, pl.ds(kb * blk_k, blk_k), h * D:(h + 1) * D]
             v = v_ref[0, pl.ds(kb * blk_k, blk_k), h * D:(h + 1) * D]
             mrow = mask_ref[0, 0:1, pl.ds(kb * blk_k, blk_k)] \
@@ -632,7 +646,6 @@ def _bshd_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                         num_heads, head_dim):
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    seed = seed_ref[0, 0]
     H, D = num_heads, head_dim
 
     for h in range(H):
@@ -640,9 +653,10 @@ def _bshd_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         do = do_ref[0, :, h * D:(h + 1) * D]
         lse = lse_ref[0, 0, :, h]
         delta = delta_ref[0, 0, :, h]
-        bh = b * jnp.int32(H) + jnp.int32(h)
+        seed, bh = _seed_parts(seed_ref, b, jnp.int32(h))
 
-        def body(kb, dq_acc, h=h, q=q, do=do, lse=lse, delta=delta, bh=bh):
+        def body(kb, dq_acc, h=h, q=q, do=do, lse=lse, delta=delta,
+                 seed=seed, bh=bh):
             k = k_ref[0, pl.ds(kb * blk_k, blk_k), h * D:(h + 1) * D]
             v = v_ref[0, pl.ds(kb * blk_k, blk_k), h * D:(h + 1) * D]
             mask_row = None
@@ -671,7 +685,6 @@ def _bshd_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                          num_heads, head_dim):
     b = pl.program_id(0)
     ki = pl.program_id(1)
-    seed = seed_ref[0, 0]
     H, D = num_heads, head_dim
     mask_row = None
     if has_mask:
@@ -681,9 +694,9 @@ def _bshd_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     for h in range(H):
         k = k_ref[0, :, h * D:(h + 1) * D]
         v = v_ref[0, :, h * D:(h + 1) * D]
-        bh = b * jnp.int32(H) + jnp.int32(h)
+        seed, bh = _seed_parts(seed_ref, b, jnp.int32(h))
 
-        def body(qj, carry, h=h, k=k, v=v, bh=bh):
+        def body(qj, carry, h=h, k=k, v=v, seed=seed, bh=bh):
             dk_acc, dv_acc = carry
             if causal:
                 qb = qj + ki * (blk_k // blk_q)
@@ -726,11 +739,7 @@ def _bshd_prep(q, k, v, kv_mask, seed):
         mr = jnp.ones((B, 1, S), jnp.int32)
     else:
         mr = kv_mask.astype(jnp.int32).reshape(B, 1, S)
-    if seed is None:
-        sr = jnp.zeros((1, 1), jnp.int32)
-    else:
-        sr = jnp.asarray(seed, jnp.int32).reshape(1, 1)
-    return qf, kf, vf, mr, sr
+    return qf, kf, vf, mr, _seed_operand(seed, H)
 
 
 def _bshd_fwd_impl(q, k, v, kv_mask, seed, causal, dropout, interpret):
@@ -751,7 +760,7 @@ def _bshd_fwd_impl(q, k, v, kv_mask, seed, causal, dropout, interpret):
                                         jnp.float32)),
         grid=(B, n_q),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i: (0, 0)),
+            pl.BlockSpec((1, 3), lambda b, i: (0, 0)),
             pl.BlockSpec((1, blk_q, HD), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, S, HD), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, S, HD), lambda b, i: (b, 0, 0)),
@@ -783,7 +792,7 @@ def _bshd_bwd_impl(q, k, v, kv_mask, seed, o, lse, g, causal, dropout,
     common = dict(scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
                   seq_len=S, dropout=float(dropout),
                   has_mask=kv_mask is not None, num_heads=H, head_dim=D)
-    seed_spec = pl.BlockSpec((1, 1), lambda b, i: (0, 0))
+    seed_spec = pl.BlockSpec((1, 3), lambda b, i: (0, 0))
     mask_spec = pl.BlockSpec((1, 1, S), lambda b, i: (b, 0, 0))
     full_spec = pl.BlockSpec((1, S, HD), lambda b, i: (b, 0, 0))
     blkq_spec = pl.BlockSpec((1, blk_q, HD), lambda b, i: (b, i, 0))

@@ -8,7 +8,7 @@ bootstrap helpers.
 """
 from jax.sharding import PartitionSpec, NamedSharding, Mesh  # re-export
 
-from .mesh import (MeshConfig, make_mesh, current_mesh, set_mesh,
+from .mesh import (MeshConfig, make_mesh, current_scope, mesh_scope,
                    replicated, batch_sharding)
 from .functional import functionalize, functional_optimizer, shard_params
 from .trainer import ShardedTrainer

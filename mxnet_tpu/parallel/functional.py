@@ -30,6 +30,7 @@ def functionalize(block, train=True):
     """Return (pure_fn, params). ``pure_fn(rng_key, param_vals, *inputs)``
     → (outputs_tuple, aux_vals_tuple); aux_vals align with ``aux_handles``
     attribute set on the function (BatchNorm moving stats etc.)."""
+    from ..gluon.parameter import swapped_in
     from ..ndarray.ndarray import NDArray
     params = list(block.collect_params().values())
 
@@ -39,16 +40,10 @@ def functionalize(block, train=True):
         prev_rec = _tape.set_recording(False)
         prev_train = _tape.set_training(train)
         sink = _tape.push_aux_sink()
-        saved = []
         try:
-            for p, v in zip(params, param_vals):
-                for i, d in enumerate(p._data):
-                    saved.append((p, i, d._data))
-                    d._data = v
-            out = block(*nds)
+            with swapped_in(params, param_vals):
+                out = block(*nds)
         finally:
-            for p, i, old in reversed(saved):
-                p._data[i]._data = old
             _tape.pop_aux_sink()
             _tape.set_training(prev_train)
             _tape.set_recording(prev_rec)

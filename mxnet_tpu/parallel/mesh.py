@@ -10,17 +10,19 @@ annotate shardings, let XLA insert collectives).
 """
 from __future__ import annotations
 
+import contextlib
 import math
-from typing import Optional
+import threading
 
 import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-__all__ = ["MeshConfig", "make_mesh", "current_mesh", "set_mesh",
+__all__ = ["MeshConfig", "make_mesh", "current_scope", "mesh_scope",
            "replicated", "batch_sharding", "PartitionSpec", "NamedSharding"]
 
-_CURRENT = [None]
+# per thread: trainers and serving lanes trace their programs concurrently
+_SCOPE = threading.local()
 
 AXES = ("dp", "pp", "ep", "tp", "sp")
 
@@ -36,18 +38,25 @@ class MeshConfig:
         fixed = self.pp * self.ep * self.tp * self.sp
         dp = self.dp
         if dp == -1:
-            assert n_devices % fixed == 0, \
-                "device count %d not divisible by pp*ep*tp*sp=%d" \
-                % (n_devices, fixed)
+            if n_devices % fixed:
+                raise ValueError(
+                    "device count %d not divisible by pp*ep*tp*sp=%d"
+                    % (n_devices, fixed))
             dp = n_devices // fixed
-        assert dp * fixed == n_devices, \
-            "mesh %s does not cover %d devices" % (
-                (dp, self.pp, self.ep, self.tp, self.sp), n_devices)
+        if dp * fixed != n_devices:
+            raise ValueError(
+                "mesh %s does not cover %d devices" % (
+                    (dp, self.pp, self.ep, self.tp, self.sp), n_devices))
         return (dp, self.pp, self.ep, self.tp, self.sp)
 
 
 def make_mesh(dp=-1, pp=1, ep=1, tp=1, sp=1, devices=None):
-    """Create a Mesh over the given (default: all) devices.
+    """Create a Mesh over ``devices``.
+
+    Without ``devices`` the mesh takes what its axis sizes ask for from
+    the front of ``jax.devices()``: all of them when ``dp=-1``, the first
+    ``dp*pp*ep*tp*sp`` otherwise (``make_mesh(dp=1)`` is one chip on any
+    host). An explicit ``devices`` list must be covered exactly.
 
     Axis order is (dp, pp, ep, tp, sp): tp/sp innermost so tensor/
     sequence collectives ride the fastest ICI links (scaling-book layout
@@ -57,19 +66,39 @@ def make_mesh(dp=-1, pp=1, ep=1, tp=1, sp=1, devices=None):
     """
     if devices is None:
         devices = jax.devices()
+        if dp != -1:
+            want = math.prod((dp, pp, ep, tp, sp))
+            if want > len(devices):
+                raise ValueError("mesh %s needs %d devices, host has %d"
+                                 % ((dp, pp, ep, tp, sp), want,
+                                    len(devices)))
+            devices = devices[:want]
     shape = MeshConfig(dp, pp, ep, tp, sp).resolve(len(devices))
     arr = np.array(devices).reshape(shape)
     mesh = Mesh(arr, AXES)
     return mesh
 
 
-def set_mesh(mesh):
-    _CURRENT[0] = mesh
-    return mesh
+@contextlib.contextmanager
+def mesh_scope(mesh, batch_axes=()):
+    """Make ``mesh`` and the axes the batch dim is sharded over visible
+    to the ops traced inside the ``with`` block. ShardedTrainer and the
+    sharded serving lanes enter it around their traced programs; ops
+    that GSPMD cannot partition (the Pallas attention kernels) read it
+    through :func:`current_scope` and wrap themselves in ``shard_map``."""
+    prev = getattr(_SCOPE, "value", None)
+    _SCOPE.value = (mesh, tuple(batch_axes))
+    try:
+        yield mesh
+    finally:
+        _SCOPE.value = prev
 
 
-def current_mesh() -> Optional[Mesh]:
-    return _CURRENT[0]
+def current_scope():
+    """``(mesh, batch_axes)`` of the innermost :func:`mesh_scope` on
+    this thread, or ``None``."""
+    return getattr(_SCOPE, "value", None)
+
 
 
 def replicated(mesh):

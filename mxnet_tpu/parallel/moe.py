@@ -21,19 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 moves shard_map to the top level
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
-
 __all__ = ["moe_ffn", "moe_ffn_sharded", "init_moe_params"]
 
 
@@ -125,9 +112,9 @@ def moe_ffn_sharded(x, gate_w, w1, w2, mesh, capacity_factor=1.25,
         y = jnp.einsum("tec,ecd->td", combine, out)
         return y, lax.pmean(aux, axis)
 
-    fn = shard_map(local, mesh,
-                   in_specs=(P(axis), P(), P(axis), P(axis)),
-                   out_specs=(P(axis), P()))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axis), P(), P(axis), P(axis)),
+                       out_specs=(P(axis), P()))
     lead = x.shape[:-1]
     y, aux = fn(x.reshape(-1, x.shape[-1]), gate_w, w1, w2)
     # a dead ep peer wedges the all_to_all exchange silently — bound the

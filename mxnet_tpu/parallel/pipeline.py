@@ -14,13 +14,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_apply", "pipeline_spmd"]
 
@@ -45,9 +40,7 @@ def pipeline_apply(fn, local_params, batch, n_micro, axis_name="pp"):
     # mark loop carries as device-varying over the pp axis (their values
     # diverge per rank inside the loop)
     def _vary(x):
-        if hasattr(lax, "pvary"):
-            return lax.pvary(x, axis_name)
-        return x * (1 + 0 * idx)
+        return lax.pcast(x, axis_name, to="varying")
 
     state = _vary(jnp.zeros_like(micro[0]))
     outputs = _vary(jnp.zeros_like(micro))

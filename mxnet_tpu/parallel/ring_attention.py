@@ -17,10 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = ["ring_attention", "ring_attention_sharded"]

@@ -5,10 +5,18 @@ content-addressed persistent compilation cache — every compiled module is
 keyed by a hash of its HLO + compile options + backend and written under
 a directory, so a process restart that compiles a previously seen
 program reads machine code off disk instead of running XLA for seconds.
-It is off by default; this module wires it to the ``MXNET_*`` knob
-surface and makes its effectiveness *observable*:
+This module decides where it lives and makes its effectiveness
+*observable*:
 
-- ``MXNET_COMPILE_CACHE_DIR``       — enable, rooted here ("" = off)
+- where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself puts the cache
+  there and this module sets no directory (whoever placed the cache from
+  outside — a deployment, a benchmark driver — owns its location);
+- where it is not, the cache is ``<checkout>/.jax_cache``: one fixed,
+  git-ignored path. The path is part of every entry's key, so a
+  directory built from a temp name, a pid or a time would never hit.
+
+Tunables (``config.py``):
+
 - ``MXNET_COMPILE_CACHE_MIN_COMPILE_SECS`` — only persist compiles at
   least this slow (0 = everything; jax's default 1.0 would skip exactly
   the small serving-ladder rungs restarts stall on)
@@ -30,7 +38,8 @@ import threading
 import time
 import warnings
 
-__all__ = ["init", "init_from_env", "enabled", "cache_dir", "stats",
+__all__ = ["init", "init_from_env", "default_dir", "enabled", "cache_dir",
+           "stats",
            "reset_stats", "note_aot_load", "note_aot_fallback",
            "sweep_ttl"]
 
@@ -70,12 +79,9 @@ def _on_jax_event(event, **kwargs):
 def _register_listener():
     if _state["listener_registered"]:
         return
-    try:
-        from jax._src import monitoring as _monitoring
-        _monitoring.register_event_listener(_on_jax_event)
-        _state["listener_registered"] = True
-    except Exception:  # noqa: BLE001 — private API moved: counters stay 0
-        pass
+    import jax.monitoring
+    jax.monitoring.register_event_listener(_on_jax_event)
+    _state["listener_registered"] = True
 
 
 def _register_rows():
@@ -131,53 +137,69 @@ def sweep_ttl(directory, ttl_days):
     return evicted
 
 
+def default_dir():
+    """``<checkout>/.jax_cache`` — the fixed in-tree cache location used
+    when nothing outside placed the cache."""
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+
+
 def init(cache_dir=None, min_entry_bytes=None, min_compile_secs=None,
          ttl_days=None, force=False):
-    """Point jax's persistent compilation cache at ``cache_dir`` (default
-    ``MXNET_COMPILE_CACHE_DIR``) and hook the hit/miss telemetry.
-    Idempotent unless ``force``; a falsy directory leaves the cache off
-    but still registers the counters (rows read 0, scrapes stay shaped).
-    Returns the active cache directory or ``None``."""
+    """Place jax's persistent compilation cache and hook the hit/miss
+    telemetry. The directory is, in order: ``cache_dir`` when given (a
+    test's own ``tmp_path``, which also switches the cache on; ``""``
+    leaves it without a directory, i.e. off);
+    ``JAX_COMPILATION_CACHE_DIR`` when set — jax reads that itself and
+    no directory is set here; else :func:`default_dir`. jax's own
+    on/off flag (``JAX_ENABLE_COMPILATION_CACHE``, on by default) is
+    left as the process was started. Nothing is created on disk here
+    (jax makes the directory on first write). Idempotent unless
+    ``force``. Returns the active cache directory or ``None``."""
     if _state["initialized"] and not force:
         return _state["dir"] if _state["enabled"] else None
     _state["initialized"] = True
     _register_listener()
     _register_rows()
-    directory = cache_dir if cache_dir is not None \
-        else _cfg("MXNET_COMPILE_CACHE_DIR")
-    if not directory:
-        _state["enabled"] = False
-        _state["dir"] = None
+    import jax
+    placed_outside = cache_dir is None and \
+        bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    if placed_outside:
+        directory = jax.config.jax_compilation_cache_dir
+    else:
+        directory = default_dir() if cache_dir is None else cache_dir
+        directory = directory and os.path.abspath(
+            os.path.expanduser(str(directory)))
+        jax.config.update("jax_compilation_cache_dir", directory or None)
+        if cache_dir:
+            jax.config.update("jax_enable_compilation_cache", True)
+        # jax binds its cache object to the directory on first use
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
+    _state["enabled"] = bool(directory) and \
+        bool(jax.config.jax_enable_compilation_cache)
+    _state["dir"] = directory or None
+    if not _state["enabled"]:
         return None
-    directory = os.path.abspath(os.path.expanduser(str(directory)))
-    os.makedirs(directory, exist_ok=True)
     ttl = float(ttl_days if ttl_days is not None
                 else _cfg("MXNET_COMPILE_CACHE_TTL_DAYS"))
     sweep_ttl(directory, ttl)
-    import jax
-    jax.config.update("jax_compilation_cache_dir", directory)
-    jax.config.update("jax_enable_compilation_cache", True)
-    min_secs = float(min_compile_secs if min_compile_secs is not None
-                     else _cfg("MXNET_COMPILE_CACHE_MIN_COMPILE_SECS"))
-    min_bytes = int(min_entry_bytes if min_entry_bytes is not None
-                    else _cfg("MXNET_COMPILE_CACHE_MIN_ENTRY_BYTES"))
-    # knob names moved across jax versions; set what this one has
-    for opt, value in (
-            ("jax_persistent_cache_min_compile_time_secs", min_secs),
-            ("jax_persistent_cache_min_entry_size_bytes", min_bytes)):
-        try:
-            jax.config.update(opt, value)
-        except (AttributeError, KeyError):
-            pass
-    _state["enabled"] = True
-    _state["dir"] = directory
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(min_compile_secs if min_compile_secs is not None
+              else _cfg("MXNET_COMPILE_CACHE_MIN_COMPILE_SECS")))
+    jax.config.update(
+        "jax_persistent_cache_min_entry_size_bytes",
+        int(min_entry_bytes if min_entry_bytes is not None
+            else _cfg("MXNET_COMPILE_CACHE_MIN_ENTRY_BYTES")))
     return directory
 
 
 def init_from_env():
     """Import-time entry point (``mxnet_tpu.context``): never raises — a
-    bad cache dir must not take the whole import down, it just warns and
-    leaves compiles uncached."""
+    cache that cannot be set up must not take the whole import down, it
+    just warns and leaves compiles uncached."""
     try:
         return init()
     except Exception as exc:  # noqa: BLE001 — import path must survive

@@ -502,8 +502,9 @@ class GuardedStep:
                       if self._dynamic else mean_loss)
             return scaled, (mean_loss, aux)
 
-        (_, (loss_val, aux)), grads = jax.value_and_grad(
-            lfn, has_aux=True)([param_vals[i] for i in trainable])
+        with tr._mesh_scope():
+            (_, (loss_val, aux)), grads = jax.value_and_grad(
+                lfn, has_aux=True)([param_vals[i] for i in trainable])
         if self._dynamic:
             inv = jnp.float32(1.0) / scale  # exact for power-of-2 scales
             grads = [g * inv.astype(g.dtype) for g in grads]

@@ -27,7 +27,7 @@ def _detect():
         "INT64_TENSOR_SIZE": True,
         "DIST_KVSTORE": True,       # XLA collectives (SURVEY §5.8)
         "RING_ATTENTION": True,
-        "PALLAS": _has_pallas(),
+        "PALLAS": True,
         "CUDA": False, "CUDNN": False, "NCCL": False, "TENSORRT": False,
         "MKLDNN": False, "OPENCV": _has("PIL"),
         "OPENMP": True, "SSE": False, "F16C": False,
@@ -39,14 +39,6 @@ def _detect():
 def _has(mod):
     try:
         __import__(mod)
-        return True
-    except ImportError:
-        return False
-
-
-def _has_pallas():
-    try:
-        from jax.experimental import pallas  # noqa: F401
         return True
     except ImportError:
         return False

@@ -31,6 +31,7 @@ import numpy as _np
 
 from ... import config as _config
 from ...cached_op import CachedOp
+from ...gluon.parameter import swapped_in
 from ...observability import tracer as _trace
 from ..batcher import ServingError
 from .kvcache import SlotKVCache
@@ -94,6 +95,7 @@ class DecodeEngine:
                  chunk=None, prefix_cache=None, name="generation"):
         import jax
         self._model = model
+        self._params = list(model.collect_params().values())
         self._name = name
         if cache is None:
             num_slots = int(num_slots or _config.get("MXNET_GEN_SLOTS"))
@@ -213,26 +215,43 @@ class DecodeEngine:
         return _np.asarray(self._fold(self._base_key, c))
 
     # ---- traced programs --------------------------------------------------
-    def _prefill_fn(self, tokens, length, slot, k_arena, v_arena):
+    def param_args(self):
+        """The model's parameter handles: the trailing ARGUMENTS of every
+        program that runs the model. Closed over instead, the weights
+        would be baked into each executable as constants (see
+        :func:`~mxnet_tpu.gluon.parameter.swapped_in`) — at 768 wide that
+        is 0.5 GB per program, and compiling the ladder runs the host out
+        of memory."""
+        return [p.data() for p in self._params]
+
+    def bound_params(self, pvals):
+        """Context manager: the model reads ``pvals`` (the traced
+        :meth:`param_args`) as its parameters."""
+        return swapped_in(self._params, pvals)
+
+    def _prefill_fn(self, tokens, length, slot, k_arena, v_arena, *pvals):
         from ... import ndarray as nd
-        logits, cache = self._model.prefill(tokens, length)
+        with self.bound_params(pvals):
+            logits, cache = self._model.prefill(tokens, length)
         k_blk = nd.stack(*[k for k, _ in cache], axis=0)  # (L,1,rung,H,D)
         v_blk = nd.stack(*[v for _, v in cache], axis=0)
         k_arena = nd.arena_update(k_arena, k_blk, slot, axis=1)
         v_arena = nd.arena_update(v_arena, v_blk, slot, axis=1)
         return logits, k_arena, v_arena
 
-    def _decode_fn(self, tokens, lengths, temps, key, k_arena, v_arena):
+    def _decode_fn(self, tokens, lengths, temps, key, k_arena, v_arena,
+                   *pvals):
         from ... import ndarray as nd
         cache = [(k_arena[layer], v_arena[layer])
                  for layer in range(self.cache.num_layers)]
-        logits, new_cache = self._model.step(tokens, cache, lengths)
+        with self.bound_params(pvals):
+            logits, new_cache = self._model.step(tokens, cache, lengths)
         k_arena = nd.stack(*[k for k, _ in new_cache], axis=0)
         v_arena = nd.stack(*[v for _, v in new_cache], axis=0)
         toks = nd.generation_sample(logits, key, temps, k=self._top_k)
         return toks, k_arena, v_arena
 
-    def _chunk_fn(self, tokens, start, slot, k_arena, v_arena):
+    def _chunk_fn(self, tokens, start, slot, k_arena, v_arena, *pvals):
         """Chunk prefill for ONE slot: pull the slot's K/V rows out of
         the arena (traced slot index — one program per chunk width serves
         every slot), append the chunk via the model's ``prefill_chunk``,
@@ -243,7 +262,9 @@ class DecodeEngine:
         v_slot = nd.arena_slice(v_arena, slot, axis=1)
         cache = [(k_slot[layer], v_slot[layer])
                  for layer in range(self.cache.num_layers)]
-        logits, new_cache = self._model.prefill_chunk(tokens, cache, start)
+        with self.bound_params(pvals):
+            logits, new_cache = self._model.prefill_chunk(tokens, cache,
+                                                          start)
         k_blk = nd.stack(*[k for k, _ in new_cache], axis=0)
         v_blk = nd.stack(*[v for _, v in new_cache], axis=0)
         k_arena = nd.arena_update(k_arena, k_blk, slot, axis=1)
@@ -284,7 +305,8 @@ class DecodeEngine:
             logits, k_arena, v_arena = self._prefill_op(
                 nd.array(padded), nd.array(_np.array([n], _np.int32)),
                 nd.array(_np.int32(slot)),
-                self.cache.k_arena, self.cache.v_arena)
+                self.cache.k_arena, self.cache.v_arena,
+                *self.param_args())
             self.cache.commit(k_arena, v_arena)
             self.cache.set_length(slot, n)
             return self._sample_first(logits[0], temperature)
@@ -340,7 +362,8 @@ class DecodeEngine:
                     nd.array(padded),
                     nd.array(_np.array([pos], _np.int32)),
                     nd.array(_np.int32(slot)),
-                    self.cache.k_arena, self.cache.v_arena)
+                    self.cache.k_arena, self.cache.v_arena,
+                    *self.param_args())
                 self.cache.commit(k_arena, v_arena)
                 self.cache.set_length(slot, end)
             pos = end
@@ -478,11 +501,18 @@ class DecodeEngine:
             toks, k_arena, v_arena = self._decode_op(
                 nd.array(tokens), nd.array(lengths), nd.array(temps),
                 nd.array(self._next_key()),
-                self.cache.k_arena, self.cache.v_arena)
+                self.cache.k_arena, self.cache.v_arena,
+                *self.param_args())
             self.cache.commit(k_arena, v_arena)
             return toks.asnumpy().reshape(-1)
 
     # ---- stats ------------------------------------------------------------
+    def lower_decode(self):
+        """The fused decode step, once it has run, re-lowered as a
+        ``jax.stages.Lowered`` (:meth:`CachedOp.lower`) — ``.compile()``
+        it to read what the compiler made of the step."""
+        return self._decode_op.lower(self._decode_op.signatures()[0])
+
     def compile_stats(self):
         """CachedOp cache stats for every program family — the
         membership-churn-compiles-nothing acceptance check reads

@@ -95,7 +95,7 @@ class SpeculativeDecoder:
         self._c = {"rounds": 0, "drafted": 0, "accepted": 0, "syncs": 0}
 
     # ---- traced verify program --------------------------------------------
-    def _verify_fn(self, tokens, lengths, k_arena, v_arena):
+    def _verify_fn(self, tokens, lengths, k_arena, v_arena, *pvals):
         """ONE fused verify: append ``(num_slots, k+1)`` tokens to every
         slot at its committed length and return the target's greedy
         choice at each position (plus the updated arenas — rejected
@@ -103,8 +103,9 @@ class SpeculativeDecoder:
         from ... import ndarray as nd
         cache = [(k_arena[layer], v_arena[layer])
                  for layer in range(self.engine.cache.num_layers)]
-        logits, new_cache = self.engine._model.prefill_chunk(
-            tokens, cache, lengths)
+        with self.engine.bound_params(pvals):
+            logits, new_cache = self.engine._model.prefill_chunk(
+                tokens, cache, lengths)
         k_arena = nd.stack(*[k for k, _ in new_cache], axis=0)
         v_arena = nd.stack(*[v for _, v in new_cache], axis=0)
         return nd.sample_greedy(logits), k_arena, v_arena
@@ -176,7 +177,7 @@ class SpeculativeDecoder:
                          k=self.k):
             greedy, k_arena, v_arena = self._verify_op(
                 nd.array(tokens_mat), nd.array(lengths),
-                eng.cache.k_arena, eng.cache.v_arena)
+                eng.cache.k_arena, eng.cache.v_arena, *eng.param_args())
             eng.cache.commit(k_arena, v_arena)
             g = greedy.asnumpy()
         out = {}

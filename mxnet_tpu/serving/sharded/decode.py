@@ -42,15 +42,17 @@ from .placement import (MeshCommittedOp, arena_sharding, arena_spec,
 
 __all__ = ["ShardedDecodeEngine", "ShardedSlotKVCache"]
 
-# which positional args of each program family are the K/V arenas (the
-# only mesh-sharded inputs; everything else dispatches replicated)
+# which positional args of each program family are the K/V arenas; the
+# families that run the model take its parameters after these (placed by
+# the plan), everything else dispatches replicated
 _ARENA_ARGS = {
-    "decode": (4, 5),          # tokens, lengths, temps, key, K, V
-    "prefill": (3, 4),         # tokens, length, slot, K, V
-    "chunk": (3, 4),           # tokens, start, slot, K, V
+    "decode": (4, 5),          # tokens, lengths, temps, key, K, V, *params
+    "prefill": (3, 4),         # tokens, length, slot, K, V, *params
+    "chunk": (3, 4),           # tokens, start, slot, K, V, *params
     "prefix_insert": (3, 4),   # k_slab, v_slab, slot, K, V
     "prefix_extract": (0, 1),  # K, V, slot
 }
+_MODEL_FAMILIES = ("decode", "prefill", "chunk")
 
 
 class ShardedSlotKVCache(SlotKVCache):
@@ -157,14 +159,16 @@ class ShardedDecodeEngine(DecodeEngine):
     def _sample_first(self, logits_row, temperature):
         # the fused sampler runs EAGERLY on one logits row; a
         # mesh-committed row can't mix with the host-side temps/key
-        # (committed to the default device), so gather it first — one
-        # (V,) vector, the same bytes asnumpy() would move anyway
+        # (nd.array commits them to the current context's device), so
+        # bring it there first — one (V,) vector, the same bytes
+        # asnumpy() would move anyway
         import jax
+        from ...context import current_context
         from ...ndarray.ndarray import NDArray
+        dev = current_context().jax_device
         data = logits_row._data
-        s = getattr(data, "sharding", None)
-        if getattr(getattr(s, "mesh", None), "size", 1) > 1:
-            logits_row = NDArray(jax.device_put(data, jax.devices()[0]))
+        if data.sharding.device_set != {dev}:
+            logits_row = NDArray(jax.device_put(data, dev))
         return super()._sample_first(logits_row, temperature)
 
     # ---- introspection ----------------------------------------------------
@@ -195,14 +199,18 @@ class ShardedDecodeEngine(DecodeEngine):
 
     def _family_shardings(self, family, sig):
         """Committed input shardings for one artifact record: arenas on
-        the canonical arena sharding, everything else replicated — the
-        exact placement :class:`MeshCommittedOp` dispatches under."""
+        the canonical arena sharding, the model's parameters (trailing
+        arguments of the families that run it) as the plan placed them,
+        everything else replicated — the exact placement
+        :class:`MeshCommittedOp` dispatches under."""
         from jax.sharding import NamedSharding, PartitionSpec
         repl = NamedSharding(self._mesh, PartitionSpec())
         arena_pos = _ARENA_ARGS.get(family, ())
         shapes, _train = sig
+        params = tuple(self._param_shardings[p.name] for p in self._params) \
+            if family in _MODEL_FAMILIES else ()
         return tuple(self.cache.arena_sharding if i in arena_pos else repl
-                     for i in range(len(shapes)))
+                     for i in range(len(shapes) - len(params))) + params
 
     # ---- AOT: sharded executables in the .mxa container -------------------
     def export_artifacts(self, directory):

@@ -108,17 +108,31 @@ class MeshCommittedOp(CachedOp):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
         self._mesh = mesh
+        self._batch_axes = axes = tuple(batch_axes or ())
         self._replicated = NamedSharding(mesh, PartitionSpec())
         self._batch = None
         self._batch_n = 1
-        if batch_axes:
-            axes = tuple(batch_axes)
+        if axes:
             self._batch = NamedSharding(mesh, PartitionSpec(axes))
             n = 1
             for ax in axes:
                 n *= int(mesh.shape[ax])
             self._batch_n = n
         self._device_put = jax.device_put
+
+    def _make_pure(self, train):
+        """Every trace of this op's body (dispatch, AOT export, AOT load)
+        runs inside the lane's :func:`~mxnet_tpu.parallel.mesh.mesh_scope`
+        — a full prefill's flash kernel must see the mesh to shard
+        itself."""
+        from ...parallel.mesh import mesh_scope
+        pure, n_out_box, aux_handles_box = super()._make_pure(train)
+
+        def scoped(rng_key, *vals):
+            with mesh_scope(self._mesh, self._batch_axes):
+                return pure(rng_key, *vals)
+
+        return scoped, n_out_box, aux_handles_box
 
     def _commit(self, a):
         from ...ndarray.ndarray import NDArray

@@ -10,10 +10,9 @@ Exit code 0 on success in every process.
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

@@ -39,11 +39,9 @@ import os
 import shutil
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 

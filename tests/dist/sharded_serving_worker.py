@@ -22,7 +22,7 @@ import json
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
@@ -34,8 +34,6 @@ sys.path.insert(0, REPO)
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 
 import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import nd  # noqa: E402

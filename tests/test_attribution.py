@@ -664,8 +664,41 @@ def test_bench_sections_isolate_crashes():
     # declared section list covers the subsystems
     names = [n for n, _ in bench.SECTIONS]
     assert names == ["resnet50_train", "serving_probe", "elastic3d",
-                     "sharded_serving", "roofline_attribution",
-                     "bench_gate"]
+                     "roofline_attribution"]
+
+
+def test_bench_refuses_other_platforms_and_fails_on_failed_section(
+        monkeypatch, capsys):
+    """bench.py measures the chip: off-TPU it exits non-zero before any
+    section, and a round with a FAILED section prints its JSON line and
+    still exits non-zero."""
+    import jax
+    spec = importlib.util.spec_from_file_location(
+        "bench_mod2", os.path.join(REPO, "bench.py"))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    assert jax.devices()[0].platform == "cpu"
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code not in (0, None)
+
+    class _Tpu:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    def boom(ctx):
+        raise RuntimeError("section exploded")
+
+    monkeypatch.setattr(bench, "_require_tpu", lambda: [_Tpu()])
+    monkeypatch.setattr(bench, "SECTIONS", (("boom", boom),))
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code == 1
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["failed_sections"] == ["boom"]
+    assert bench._peak_tflops(_Tpu()) == 197.0
+    _Tpu.device_kind = "TPU v99"
+    with pytest.raises(RuntimeError, match="no published peak"):
+        bench._peak_tflops(_Tpu())
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,111 @@
+"""The attention kernels of the main path, compiled for the real chip.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a chip
+that is described, not attached (``jax.experimental.topologies``), so
+these tests see what interpret mode cannot: Mosaic's tiling and VMEM
+limits at the real widths, and that a kernel inside a multi-chip program
+is partitioned by ``shard_map`` (GSPMD refuses a bare Mosaic call).
+Nothing runs — results are ``tests/test_pallas.py``'s job (interpret
+mode) and ``chip_smoke.py``'s (on the chip).
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load libtpu, and every xdist worker imports
+this file. All such compiles live in this one file for the same reason.
+"""
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from mxnet_tpu.ops import nn as nn_ops
+from mxnet_tpu.ops.pallas_kernels import flash_attention, flash_attention_bshd
+from mxnet_tpu.parallel.mesh import AXES, mesh_scope
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep it off around these."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _fwd_bwd(attend):
+    """Sum-of-squares loss through ``attend``: forward + dq + dkdv."""
+    def loss(q, k, v, kv_mask, seed):
+        return jnp.sum(attend(q, k, v, kv_mask, seed).astype(jnp.float32)
+                       ** 2)
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+
+
+def _specs(qkv_shape, batch, seq, sharding, mask_sharding, scalar_sharding):
+    qkv = jax.ShapeDtypeStruct(qkv_shape, jnp.bfloat16, sharding=sharding)
+    mask = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                sharding=mask_sharding)
+    seed = jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar_sharding)
+    return qkv, qkv, qkv, mask, seed
+
+
+@pytest.mark.parametrize("batch,seq", [(16, 512), (64, 128)],
+                         ids=["bert_s512_b16", "bert_s128_b64"])
+def test_bshd_fwd_bwd_compiles_one_chip(topo, batch, seq):
+    """BERT-base widths (12 heads x 64), padding mask, dropout 0.1."""
+    one = SingleDeviceSharding(topo.devices[0])
+    fn = _fwd_bwd(lambda q, k, v, m, s: flash_attention_bshd(
+        q, k, v, m, s, False, 0.1))
+    text = fn.lower(*_specs((batch, seq, 12, 64), batch, seq, one, one,
+                            one)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkdv
+
+
+def test_bhsd_causal_fwd_bwd_compiles_one_chip(topo):
+    """TransformerLM s512 b32, 8 heads x 64, causal, no mask/dropout."""
+    one = SingleDeviceSharding(topo.devices[0])
+    fn = _fwd_bwd(lambda q, k, v, m, s: flash_attention(
+        q, k, v, None, None, True, 0.0))
+    text = fn.lower(*_specs((32, 8, 512, 64), 32, 512, one, one,
+                            one)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_bshd_dp_sharded_compiles_four_chips(topo, monkeypatch):
+    """The dispatcher inside a dp=4 program: BERT-base s128, global batch
+    64 sharded over four chips, mask + dropout. A bare Mosaic call here
+    raises 'Mosaic kernels cannot be automatically partitioned'; the
+    dispatcher must wrap it in shard_map over the mesh it observes, and
+    the batch-sharded q/k/v must reach the kernel without an all-gather."""
+    assert len(topo.devices) == 4
+    mesh = Mesh(np.array(topo.devices).reshape((4, 1, 1, 1, 1)), AXES)
+    # the process's own backend is the CPU; the program is for the chip
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    key = jax.random.PRNGKey(0)
+
+    def attend(q, k, v, kv_mask, seed):
+        with mesh_scope(mesh, ("dp",)):
+            return nn_ops.dot_product_attention.fn(
+                q, k, v, mask=kv_mask, dropout=0.1, layout="BSHD",
+                rng_key=jax.random.fold_in(key, seed), train=True)
+
+    batch = NamedSharding(mesh, P("dp"))
+    text = _fwd_bwd(attend).lower(*_specs(
+        (64, 128, 12, 64), 64, 128, batch, batch,
+        NamedSharding(mesh, P()))).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "all-gather" not in text

@@ -70,8 +70,8 @@ def _exported_dir(tmp_path, buckets=(1, 2)):
 
 def _fake_records():
     return [{"signature": ((((2, 3), "float32"),), False), "train": False,
-             "flops": 12.0, "blob": b"B" * 40, "in_tree": b"I" * 7,
-             "out_tree": b"O" * 9}]
+             "flops": 12.0, "devices": [0], "blob": b"B" * 40,
+             "in_tree": b"I" * 7, "out_tree": b"O" * 9}]
 
 
 def test_artifact_roundtrip_and_header_validation(tmp_path):
@@ -139,6 +139,25 @@ def test_cachedop_serialize_deserialize_zero_compile():
     assert cache_stats()["misses"] == 0      # no process-wide compile either
     np.testing.assert_array_equal(out, ref)
     assert pcache.stats()["aot_loads"] == 1
+
+
+def test_aot_entry_loads_onto_the_devices_it_was_compiled_for():
+    """A one-device program on a many-device host (the suite's 8 virtual
+    devices stand in for a four-chip host): the record names its device,
+    the load goes there and nowhere else, and a device this host lacks
+    is a typed ArtifactError, not a dispatch-time surprise."""
+    import jax
+    op = CachedOp(_linear, name="cs.dev")
+    op(nd.array(np.ones((2, D_IN), "float32")))
+    (rec,) = op.serialize()
+    assert rec["devices"] == [jax.devices()[0].id]
+    assert aot.fingerprint()["n_devices"] == 1 < len(jax.devices())
+    exe = aot.deserialize_compiled(rec["blob"], rec["in_tree"],
+                                   rec["out_tree"], rec["devices"])
+    assert exe.runtime_executable().local_devices() == [jax.devices()[0]]
+    with pytest.raises(aot.ArtifactError):
+        aot.deserialize_compiled(rec["blob"], rec["in_tree"],
+                                 rec["out_tree"], [10 ** 6])
 
 
 def test_cachedop_aot_entry_recompiles_under_recording():
@@ -516,17 +535,58 @@ def test_pcache_ttl_sweep(tmp_path):
     assert pcache.sweep_ttl(str(tmp_path), ttl_days=0) == 0   # 0 = keep
 
 
-def test_pcache_init_from_env_never_raises(monkeypatch, tmp_path):
-    bad = tmp_path / "file"
-    bad.write_text("not a directory")
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(bad / "sub"))
-    monkeypatch.setitem(pcache._state, "initialized", False)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert pcache.init_from_env() is None
-    assert any("persistent compile cache init failed" in str(x.message)
-               for x in w)
-    assert not pcache.enabled()
+@pytest.fixture
+def _pcache_restored():
+    """Leave jax's persistent cache as conftest started the suite: off."""
+    import jax
+    yield
+    pcache.init(cache_dir="", force=True)
+    jax.config.update("jax_enable_compilation_cache", False)
+
+
+@pytest.mark.parametrize("placed_outside", [True, False],
+                         ids=["JAX_COMPILATION_CACHE_DIR", "in_checkout"])
+def test_pcache_init_directory(monkeypatch, tmp_path, placed_outside,
+                               _pcache_restored):
+    """Where JAX_COMPILATION_CACHE_DIR is set the cache is there and
+    pcache sets no directory itself (jax owns it); where it is not, the
+    cache is the one fixed in-checkout path, never a temp/pid/time name.
+    Init creates nothing on disk and leaves jax's on/off flag alone."""
+    import jax
+    real_update = jax.config.update
+    real_update("jax_enable_compilation_cache", True)  # a normal process
+    updates = []
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, value: (updates.append(name),
+                             real_update(name, value))[1])
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if placed_outside:
+        outside = str(tmp_path / "placed")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        # what jax does with the variable when it starts
+        real_update("jax_compilation_cache_dir", outside)
+        assert pcache.init(force=True) == outside
+        assert "jax_compilation_cache_dir" not in updates
+        assert not os.path.exists(outside)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        expected = os.path.join(repo, ".jax_cache")
+        assert pcache.default_dir() == expected
+        assert pcache.init(force=True) == expected
+        assert jax.config.jax_compilation_cache_dir == expected
+    assert "jax_enable_compilation_cache" not in updates
+    assert pcache.enabled()
+    assert pcache.stats()["dir"] == pcache.cache_dir()
+    # started with jax's switch off (the test suite): placed, not enabled
+    real_update("jax_enable_compilation_cache", False)
+    assert pcache.init(force=True) is None and not pcache.enabled()
+    # a test's own directory switches it on; "" takes the directory away
+    own = str(tmp_path / "own")
+    assert pcache.init(cache_dir=own, force=True) == own
+    assert pcache.enabled() and jax.config.jax_enable_compilation_cache
+    assert pcache.init(cache_dir="", force=True) is None
+    assert jax.config.jax_compilation_cache_dir is None
 
 
 # ---------------------------------------------------------------------------

@@ -725,3 +725,40 @@ def test_scheduler_ttft_improves_over_sequential_queueing(shared_eng):
         assert m.snapshot()["ok"] == 2
     finally:
         sched.close()
+
+
+def test_model_programs_take_the_weights_as_arguments():
+    """The engine's programs must not close over the model's parameters:
+    jit bakes a closed-over array into the executable as a constant, so
+    every program would carry its own copy of the weights (host memory at
+    compile time, the serialized artifact, device memory). Found on the
+    chip at 768 wide: 1.5 GB per executable, host out of memory while the
+    prefill ladder compiled. Seen here as executable size against
+    parameter bytes."""
+    from mxnet_tpu.models import TransformerLM
+    net = TransformerLM(4096, units=256, num_layers=2, num_heads=4,
+                        max_len=64)
+    net.initialize(mx.init.Xavier())
+    param_bytes = sum(int(np.prod(p.shape)) * 4
+                      for p in net.collect_params().values())
+    eng = DecodeEngine(net, num_slots=2, max_seq=32, ladder=(8,),
+                       prefix_cache=False)
+    try:
+        slot = eng.cache.acquire()
+        eng.prefill(slot, [1, 2, 3])
+        eng.decode_step(np.zeros(2, "int32"), np.zeros(2, "float32"))
+        for op in (eng._prefill_op, eng._decode_op):
+            (rec,) = op.serialize()
+            assert len(rec["blob"]) < param_bytes // 8, \
+                (len(rec["blob"]), param_bytes)
+        # and a weight update reaches the compiled program, no recompile
+        before = eng.decode_step(np.zeros(2, "int32"),
+                                 np.zeros(2, "float32"))
+        head = net.head.weight.data()
+        head._data = head._data[::-1]
+        after = eng.decode_step(np.zeros(2, "int32"),
+                                np.zeros(2, "float32"))
+        assert eng.compile_stats()["decode"]["misses"] == 1
+        assert before[slot] != after[slot]
+    finally:
+        eng.close()

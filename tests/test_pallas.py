@@ -304,3 +304,74 @@ def test_bshd_usability_gate_and_fallback():
                                         _to_bhsd(v), False))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-3, rtol=2e-3)
+
+
+# ------------------------------------------- shard_map dispatch (multi-chip)
+
+@pytest.mark.parametrize("mesh_axes,batch,heads", [
+    ({"dp": 4}, 4, 2),             # batch over dp
+    ({"dp": 2, "tp": 2}, 4, 4),    # batch over dp, heads over tp
+    ({"dp": 4}, 3, 2),             # batch does not divide: replicated
+])
+def test_sharded_flash_draws_the_unsharded_dropout_mask(mesh_axes, batch,
+                                                        heads):
+    """Under shard_map every shard sees LOCAL batch/head indices; the
+    dispatcher folds the shard's global offset into the seed operand.
+    Identical rows in every batch entry must therefore still get
+    different keep-masks on different shards, and the sharded call must
+    reproduce the unsharded output and gradients exactly."""
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import nn as nn_ops
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_bshd
+    mesh = parallel.make_mesh(**mesh_axes)
+    S, D = 128, 64
+    q, k, v = (jnp.repeat(_rand((1, S, heads, D), 110 + i), batch, 0)
+               for i in range(3))
+    seed = jnp.asarray(7, jnp.int32)
+
+    def sharded(q, k, v):
+        return nn_ops._sharded_flash(flash_attention_bshd, 2, mesh, ("dp",),
+                                     q, k, v, None, seed, False, 0.5,
+                                     interpret=True)
+
+    def unsharded(q, k, v):
+        return flash_attention_bshd(q, k, v, None, seed, False, 0.5, True)
+
+    out = np.asarray(jax.jit(sharded)(q, k, v))
+    for b in range(1, batch):      # same inputs, different shard (or row)
+        assert not np.allclose(out[0], out[b])
+    np.testing.assert_allclose(out, np.asarray(unsharded(q, k, v)),
+                               atol=1e-5, rtol=1e-5)
+    grads = jax.jit(jax.grad(lambda *a: (sharded(*a) ** 2).sum(),
+                             argnums=(0, 1, 2)))(q, k, v)
+    ref = jax.grad(lambda *a: (unsharded(*a) ** 2).sum(),
+                   argnums=(0, 1, 2))(q, k, v)
+    for a, b, n in zip(grads, ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4, err_msg="d%s" % n)
+
+
+def test_dispatch_shards_flash_under_a_visible_mesh(monkeypatch):
+    """The dispatcher takes the shard_map route exactly when the tracing
+    trainer/lane made a mesh of more than one device visible."""
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import nn as nn_ops
+    calls = []
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    monkeypatch.setattr(
+        nn_ops, "_sharded_flash",
+        lambda kernel, heads_dim, mesh, axes, q, *a, **kw:
+        calls.append((mesh.size, axes)) or q)
+    monkeypatch.setattr(nn_ops, "_flash_enabled", lambda: True)
+    from mxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "flash_attention_bshd",
+                        lambda q, *a, **kw: calls.append("bare") or q)
+    q = _rand((4, 128, 2, 64), 120)
+    attend = lambda: nn_ops.dot_product_attention.fn(q, q, q, layout="BSHD")
+    attend()
+    with parallel.mesh_scope(parallel.make_mesh(dp=1), ("dp",)):
+        attend()
+    with parallel.mesh_scope(parallel.make_mesh(dp=2, tp=2), ("dp",)):
+        attend()
+    assert calls == ["bare", "bare", (4, ("dp",))]
+    assert parallel.current_scope() is None

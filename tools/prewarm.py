@@ -111,8 +111,7 @@ def check(model_dir, mesh=None):
                       error="manifest has no executables section — "
                             "artifacts were not exported for this version")
         return 2, report
-    current = aot.fingerprint()
-    current["mesh"] = aot.mesh_axes(mesh)
+    current = aot.fingerprint(mesh)
     recorded = exe.get("fingerprint")
     # the --mesh expectation is operator shorthand: a sharded lane always
     # forms the full named mesh, so axes the spec omits materialize at
@@ -134,7 +133,9 @@ def check(model_dir, mesh=None):
     report["fingerprint"] = {"recorded": recorded, "current": current}
     if not aot.fingerprint_matches(recorded, current):
         diff = aot.fingerprint_diff(recorded, current)
-        mesh_drift = all(d.startswith("mesh:") for d in diff)
+        # the device span follows from the mesh
+        mesh_drift = all(d.startswith(("mesh:", "n_devices:"))
+                         for d in diff)
         report.update(
             status="mesh-drift" if mesh_drift else "stale",
             error="artifact fingerprint does not match this process: %s "

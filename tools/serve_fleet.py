@@ -38,12 +38,14 @@ absorbs it (see docs/resilience.md).
 from __future__ import annotations
 
 import argparse
+import itertools
 import os
 import shlex
 import signal
 import socket
 import subprocess
 import sys
+import threading
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -67,6 +69,14 @@ class ProcessBackend:
     duck-type: ``spawn() -> (url, meta)``, ``restart(replica)``,
     ``stop(replica)``.
 
+    A chip belongs to one process, so each replica is handed its own:
+    the lowest chip index no live replica of this backend holds, through
+    libtpu's own variables (the child then sees exactly that chip; a CPU
+    child ignores them). This process never touches jax — a parent that
+    had would hold every chip itself. An index past the host's last chip
+    makes that child fail at start-up, which is the truth: there is no
+    chip for it.
+
     Each worker runs in its own process group so a kill takes its whole
     tree, ``launch.py`` style. Restarts land on a FRESH port (no
     TIME_WAIT races); the gateway learns the new URL from
@@ -78,6 +88,15 @@ class ProcessBackend:
         self.host = host
         self.stop_grace_s = float(stop_grace_s)
         self.extra_env = dict(extra_env or {})
+        self._chips = set()           # chip indices live replicas hold
+        self._chips_lock = threading.Lock()
+
+    def _claim_chip(self):
+        with self._chips_lock:
+            chip = next(i for i in itertools.count()
+                        if i not in self._chips)
+            self._chips.add(chip)
+        return chip
 
     def _command(self, port):
         if self.worker_cmd:
@@ -87,17 +106,30 @@ class ProcessBackend:
 
     def spawn(self, port=None, env=None):
         port = port or _free_port(self.host)
+        chip = self._claim_chip()
         penv = dict(os.environ)
+        penv.update(TPU_VISIBLE_CHIPS=str(chip),
+                    TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                    TPU_PROCESS_BOUNDS="1,1,1")
         penv.update(self.extra_env)
         penv.update(env or {})
         proc = subprocess.Popen(self._command(port), env=penv,
                                 start_new_session=True)
         url = "http://%s:%d" % (self.host, port)
-        return url, {"proc": proc, "port": port}
+        return url, {"proc": proc, "port": port, "chip": chip}
 
     def _terminate(self, meta):
         proc = (meta or {}).get("proc")
-        if proc is None or proc.poll() is not None:
+        if proc is None:
+            return
+        try:
+            self._stop_process(proc)
+        finally:
+            with self._chips_lock:
+                self._chips.discard(meta.get("chip"))
+
+    def _stop_process(self, proc):
+        if proc.poll() is not None:
             return
         try:
             os.killpg(os.getpgid(proc.pid), signal.SIGTERM)
@@ -134,9 +166,6 @@ class ProcessBackend:
 def run_worker(args):
     """One replica process: demo MLP behind a full ``ModelServer``,
     draining (not dropping) on SIGTERM."""
-    if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from mxnet_tpu import nd
@@ -257,6 +286,7 @@ def run_supervisor(args):
                 gateway.log_event("replica_exited", replica=rep.id,
                                   rc=rc, respawn=n)
                 gateway.remove_replica(rep.id)
+                backend.stop(rep)   # already dead: hands its chip back
                 if max_restarts and n > max_restarts:
                     gateway.log_event("replica_evicted", replica=rep.id,
                                       rc=rc)
