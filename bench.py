@@ -67,7 +67,7 @@ def _peak_tflops(device):
     ``device_kind``); a kind that is not in the table is an error."""
     from mxnet_tpu.observability import telemetry
 
-    peak = telemetry.device_peak_flops(device)
+    peak = telemetry.device_peaks(device)[0]
     if peak is None:
         raise RuntimeError("no published peak for device kind %r"
                            % (device.device_kind,))

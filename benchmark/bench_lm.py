@@ -43,7 +43,7 @@ def peak_tflops():
     import jax
     from mxnet_tpu.observability import telemetry
     dev = jax.devices()[0]
-    peak = telemetry.device_peak_flops(dev)
+    peak = telemetry.device_peaks(dev)[0]
     if peak is None:
         raise RuntimeError("no published peak for device kind %r"
                            % (dev.device_kind,))

@@ -13,13 +13,22 @@ import numpy as _np
 
 __all__ = [
     "MXNetError", "string_types", "numeric_types", "integer_types",
-    "dtype_np", "dtype_name", "_as_list",
+    "dtype_np", "dtype_name", "_as_list", "PROGRAM_SCOPES",
 ]
 
 
 class MXNetError(RuntimeError):
     """Error raised by the framework (parity with mxnet.base.MXNetError)."""
 
+
+# ``jax.named_scope`` names the program itself writes into a traced
+# program's op metadata, beside each Gluon block's own name (see
+# docs/observability.md): ``attention`` around the whole attention op
+# (ops/nn.py), ``optimizer`` around the trainer's update loop
+# (parallel/trainer.py). A block of one of these names enters the scope
+# under its name plus "_", so a trace reader that meets the bare word knows
+# the program wrote it.
+PROGRAM_SCOPES = ("attention", "optimizer")
 
 string_types = (str,)
 numeric_types = (float, int, _np.generic)

@@ -24,6 +24,7 @@ instead).
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -210,6 +211,9 @@ class CachedOp:
             # aux writes (e.g. BatchNorm moving stats) ride as extra outputs
             return tuple(o._data for o in outs_t) + tuple(v for _, v in sink)
 
+        # the program is jit_<op name> in the device trace and the HLO
+        pure.__name__ = pure.__qualname__ = \
+            re.sub(r"[^0-9A-Za-z_.]", "_", self._name) or "CachedOp"
         return pure, n_out_box, aux_handles_box
 
     def _compile(self, args):

@@ -19,6 +19,10 @@ import re
 import threading
 from collections import OrderedDict
 
+import jax
+from jax.core import Tracer as _Tracer
+
+from ..base import PROGRAM_SCOPES
 from ..context import current_context
 from ..ndarray.ndarray import NDArray
 from .parameter import (DeferredInitializationError, Parameter,
@@ -276,6 +280,20 @@ class Block:
         return block_summary(self, *inputs)
 
     def __call__(self, *args):
+        # while JAX traces the call, its ops carry the block's name in
+        # their metadata (the reference profiler showed operators under the
+        # symbol's name). An eager call compiles op by op and keeps no
+        # scope, so it does not pay for one.
+        if self._name and any(
+                isinstance(getattr(a, "_data", None), _Tracer)
+                for a in args):
+            name = self._name
+            with jax.named_scope(name + "_" if name in PROGRAM_SCOPES
+                                 else name):
+                return self._call(*args)
+        return self._call(*args)
+
+    def _call(self, *args):
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
         out = self.forward(*args)

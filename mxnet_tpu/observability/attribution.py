@@ -55,6 +55,9 @@ import threading
 import time
 from collections import deque
 
+from . import telemetry as _telemetry
+from .telemetry import _cfg
+
 __all__ = ["RooflineRegistry", "roofline", "record_dispatch",
            "attribution_enabled", "peak_bytes_per_s", "ridge_point",
            "classify", "snapshot", "reset", "roofline_gauge",
@@ -63,28 +66,9 @@ __all__ = ["RooflineRegistry", "roofline", "record_dispatch",
            "capture_profile", "CaptureBusy", "configure"]
 
 
-def _cfg(name):
-    from .. import config as _config
-    return _config.get(name)
-
-
 # ---------------------------------------------------------------------------
 # roofline parameters
 # ---------------------------------------------------------------------------
-
-# Peak HBM bandwidth per jax device (bytes/s), by ``device_kind``
-# substring — companion of telemetry._PEAK_FLOPS_BY_KIND (same matching
-# rule: first match wins, most specific first; v2/v3 entries are
-# per-core like their FLOPs entries). Published per-chip numbers.
-_HBM_BYTES_S_BY_KIND = (
-    ("v6", 1640e9),        # Trillium
-    ("v5 lite", 819e9),    # v5e
-    ("v5e", 819e9),
-    ("v5", 2765e9),        # v5p
-    ("v4", 1228e9),
-    ("v3", 450e9),         # per core (900 GB/s per 2-core chip)
-    ("v2", 350e9),         # per core (700 GB/s per 2-core chip)
-)
 
 # Ridge point used when neither peak FLOP/s nor HBM bandwidth is known
 # (the CPU oracle): v5e-like, 197 TFLOP/s / 819 GB/s ~= 240 FLOP/byte.
@@ -101,23 +85,17 @@ UNKNOWN = "unknown"
 def peak_bytes_per_s():
     """Aggregate peak HBM bytes/s across this process's accelerator
     devices (``MXNET_PROF_HBM_GBPS`` override, else the device-kind
-    table), or ``None`` when unknown — the ridge then falls back to
+    table of ``telemetry``), or ``None`` when unknown — the ridge then falls back to
     ``MXNET_PROF_RIDGE`` / the built-in default instead of fabricating
     a bandwidth."""
-    from . import telemetry as _telemetry
     override = float(_cfg("MXNET_PROF_HBM_GBPS") or 0.0) * 1e9
     devices = _telemetry._accel_devices()
     if not devices:
         return None
     if override > 0:
         return override * len(devices)
-    total = 0.0
-    for d in devices:
-        kind = (getattr(d, "device_kind", "") or "").lower()
-        per_dev = next((b for sub, b in _HBM_BYTES_S_BY_KIND
-                        if sub in kind), 0.0)
-        total += per_dev
-    return total or None
+    return sum(_telemetry.device_peaks(d)[1] or 0.0
+               for d in devices) or None
 
 
 def _ridge_from(peak, bw):
@@ -133,7 +111,6 @@ def ridge_point():
     """The arithmetic-intensity ridge (FLOP/byte) separating HBM-bound
     from compute-bound: ``peak FLOP/s / peak bytes/s`` when both are
     known, else ``MXNET_PROF_RIDGE``, else the built-in default."""
-    from . import telemetry as _telemetry
     return _ridge_from(_telemetry.peak_flops(), peak_bytes_per_s())
 
 
@@ -160,7 +137,6 @@ def classify(flops_per_call, bytes_per_call, wall_s_per_call,
     achieved = (flops_per_call / wall_s_per_call
                 if wall_s_per_call > 0 else 0.0)
     if peak is None or bw is None:
-        from . import telemetry as _telemetry
         peak = _telemetry.peak_flops() if peak is None else peak
         bw = peak_bytes_per_s() if bw is None else bw
     ridge = ridge_point() if ridge is None else ridge
@@ -232,7 +208,6 @@ class RooflineRegistry:
         """
         with self._lock:
             rows = {k: list(v) for k, v in self._rows.items()}
-        from . import telemetry as _telemetry
         peak = _telemetry.peak_flops()
         bw = peak_bytes_per_s()
         ridge = _ridge_from(peak, bw)
@@ -271,7 +246,6 @@ class RooflineRegistry:
         aggregate."""
         with self._lock:
             rows = {k: list(v) for k, v in self._rows.items()}
-        from . import telemetry as _telemetry
         peak = _telemetry.peak_flops()
         bw = peak_bytes_per_s()
         ridge = _ridge_from(peak, bw)
@@ -358,7 +332,6 @@ def reset():
 def roofline_gauge():
     """JSON gauge (the ``/metrics`` ``"roofline"`` section): the ranked
     per-executable table plus the parameters it was derived under."""
-    from . import telemetry as _telemetry
     return {"rows": snapshot(),
             "peak_flops": _telemetry.peak_flops(),
             "peak_bytes_s": peak_bytes_per_s(),

@@ -330,23 +330,23 @@ def _render_trace(w):
 
 def _render_telemetry(w):
     mems = _telemetry.device_memory()
-    w.family("mxtpu_device_hbm_bytes_in_use", "gauge",
-             "device allocator bytes in use")
-    w.family("mxtpu_device_hbm_bytes_limit", "gauge",
-             "device allocator capacity (0 = unknown)")
-    w.family("mxtpu_device_hbm_peak_bytes", "gauge",
-             "peak bytes in use observed by this process")
+    gauges = (
+        ("bytes_in_use", "bytes_in_use", "device allocator bytes in use"),
+        ("bytes_limit", "bytes_limit",
+         "device allocator capacity (0 = unknown)"),
+        ("peak_bytes", "peak_bytes_in_use",
+         "peak bytes in use observed by this process"),
+        ("bytes_reserved", "bytes_reserved",
+         "bytes the runtime holds for compiled programs' temporaries"))
+    for fam, _key, text in gauges:
+        w.family("mxtpu_device_hbm_" + fam, "gauge", text)
     for m in mems:
         if not m["available"]:
             continue
         labels = {"device": m["device"], "platform": m["platform"],
                   "kind": m["kind"]}
-        w.sample("mxtpu_device_hbm_bytes_in_use", m["bytes_in_use"],
-                 labels=labels)
-        w.sample("mxtpu_device_hbm_bytes_limit", m["bytes_limit"],
-                 labels=labels)
-        w.sample("mxtpu_device_hbm_peak_bytes", m["peak_bytes_in_use"],
-                 labels=labels)
+        for fam, key, _text in gauges:
+            w.sample("mxtpu_device_hbm_" + fam, m[key], labels=labels)
     headroom = _telemetry.memory_headroom(mems)
     if headroom is not None:
         w.gauge("mxtpu_device_memory_headroom_ratio",

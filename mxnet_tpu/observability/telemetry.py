@@ -130,19 +130,20 @@ def flops_rate():
     return flops_meter.rate()
 
 
-# Peak dense-matmul throughput per jax device (FLOP/s, bf16), by
-# ``device_kind`` substring — first match wins, most specific first.
-# These are published per-chip numbers; v2/v3 expose each CORE as a jax
-# device, so their entries are per-core. Override with
-# MXNET_TELEMETRY_PEAK_FLOPS when the table is wrong for your topology.
-_PEAK_FLOPS_BY_KIND = (
-    ("v6", 918e12),        # Trillium
-    ("v5 lite", 197e12),   # v5e
-    ("v5e", 197e12),
-    ("v5", 459e12),        # v5p
-    ("v4", 275e12),
-    ("v3", 61.5e12),       # per core (123 TFLOP/s per 2-core chip)
-    ("v2", 23e12),         # per core (46 TFLOP/s per 2-core chip)
+# The program's one table of published peaks per jax device, by
+# ``device_kind`` substring — first match wins, most specific first:
+# dense-matmul throughput (FLOP/s, bf16) and HBM bandwidth (bytes/s).
+# v2/v3 expose each CORE as a jax device, so their entries are per-core.
+# Override with MXNET_TELEMETRY_PEAK_FLOPS / MXNET_PROF_HBM_GBPS when the
+# table is wrong for your topology.
+_PEAKS_BY_KIND = (
+    ("v6", 918e12, 1640e9),        # Trillium
+    ("v5 lite", 197e12, 819e9),    # v5e
+    ("v5e", 197e12, 819e9),
+    ("v5", 459e12, 2765e9),        # v5p
+    ("v4", 275e12, 1228e9),
+    ("v3", 61.5e12, 450e9),        # per core (123 TFLOP/s, 900 GB/s a chip)
+    ("v2", 23e12, 350e9),          # per core (46 TFLOP/s, 700 GB/s a chip)
 )
 
 
@@ -156,12 +157,13 @@ def _accel_devices():
     return accel or devs
 
 
-def device_peak_flops(device):
-    """Published peak FLOP/s (bf16) of one jax device by its
-    ``device_kind``, or ``None`` for a kind that is not in the table —
-    never a default."""
+def device_peaks(device):
+    """Published ``(FLOP/s in bf16, HBM bytes/s)`` of one jax device by
+    its ``device_kind``, or ``(None, None)`` for a kind that is not in the
+    table — never a default."""
     kind = (getattr(device, "device_kind", "") or "").lower()
-    return next((p for sub, p in _PEAK_FLOPS_BY_KIND if sub in kind), None)
+    return next(((f, b) for sub, f, b in _PEAKS_BY_KIND if sub in kind),
+                (None, None))
 
 
 def peak_flops():
@@ -174,7 +176,7 @@ def peak_flops():
         return None
     if override > 0:
         return override * len(devices)
-    return sum(device_peak_flops(d) or 0.0 for d in devices) or None
+    return sum(device_peaks(d)[0] or 0.0 for d in devices) or None
 
 
 def mfu_percent():
@@ -224,18 +226,23 @@ def memory_probe_errors():
 
 def device_memory():
     """Per-device HBM accounting: ``[{device, platform, kind,
-    bytes_in_use, bytes_limit, peak_bytes_in_use, available}]``.
+    bytes_in_use, bytes_limit, peak_bytes_in_use, bytes_reserved,
+    peak_bytes_reserved, available}]``.
     Devices whose runtime exposes no allocator stats (CPU backend)
     report ``available: False`` — absence of data, not zero usage.
     The peak is the max in-use THIS process has observed across probes
     (monotone per process lifetime), seeded from PJRT's own
-    ``peak_bytes_in_use`` when present."""
+    ``peak_bytes_in_use`` when present. ``bytes_reserved`` is what the
+    runtime holds apart for compiled programs' temporaries, which
+    ``bytes_in_use`` (live arrays) leaves out: a training step can fill
+    the chip there. 0 where the backend does not report it."""
     out = []
     for i, d in enumerate(_accel_devices()):
         rec = {"device": i, "platform": getattr(d, "platform", "?"),
                "kind": getattr(d, "device_kind", "") or "",
                "available": False, "bytes_in_use": 0, "bytes_limit": 0,
-               "peak_bytes_in_use": 0}
+               "peak_bytes_in_use": 0, "bytes_reserved": 0,
+               "peak_bytes_reserved": 0}
         try:
             stats = d.memory_stats()
         except Exception as exc:  # noqa: BLE001 — counted, not swallowed
@@ -252,18 +259,23 @@ def device_memory():
             prev = _mem_peak.get(i, 0)
             peak = max(peak, prev, in_use)
             _mem_peak[i] = peak
+        reserved = int(stats.get("bytes_reserved", 0))
         rec.update(available=True, bytes_in_use=in_use,
-                   bytes_limit=limit, peak_bytes_in_use=peak)
+                   bytes_limit=limit, peak_bytes_in_use=peak,
+                   bytes_reserved=reserved,
+                   peak_bytes_reserved=max(
+                       reserved, int(stats.get("peak_bytes_reserved", 0))))
         out.append(rec)
     return out
 
 
 def memory_headroom(mems=None):
     """Worst-case free-HBM fraction across devices with a known limit
-    (``min (limit - in_use) / limit``), or ``None`` when no device
-    reports a limit."""
+    (``min (limit - in_use - reserved) / limit``), or ``None`` when no
+    device reports a limit."""
     mems = device_memory() if mems is None else mems
-    fracs = [(m["bytes_limit"] - m["bytes_in_use"]) / m["bytes_limit"]
+    fracs = [(m["bytes_limit"] - m["bytes_in_use"]
+              - m.get("bytes_reserved", 0)) / m["bytes_limit"]
              for m in mems if m["available"] and m["bytes_limit"] > 0]
     return min(fracs) if fracs else None
 

@@ -19,6 +19,14 @@ asserts < 2%). When enabled, a span costs two clock reads, an id, and a
 deque append; there is no lock on the record path (the only lock guards
 the per-phase aggregate histogram, taken once per completed span).
 
+Bridge to the profiler: a live span also holds a
+``jax.profiler.TraceAnnotation`` of its name and attributes, so any jax
+profile taken while the tracer is enabled shows the program's spans on
+the profiler's clock, beside the device's operations (a ``step_num``
+attribute marks a step, as ``StepTraceAnnotation`` would). With no profile
+running that costs under a microsecond; :meth:`Tracer.complete` records
+after the fact and has nothing to bridge.
+
 Knobs: ``MXNET_TRACE_ENABLE`` (record from import), ``MXNET_TRACE_BUFFER``
 (ring capacity in events, default 65536).
 """
@@ -31,6 +39,8 @@ import threading
 import time
 import warnings
 from collections import deque
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 __all__ = ["Tracer", "SpanContext", "tracer", "span", "instant", "counter",
            "complete", "attach", "current", "enable", "disable", "enabled",
@@ -95,7 +105,7 @@ class _Span:
     span stack; ``__exit__`` records one "X" event."""
 
     __slots__ = ("_tr", "name", "_attrs", "_parent", "_t0", "ctx",
-                 "_pushed", "_cancelled")
+                 "_pushed", "_cancelled", "_ann")
 
     def __init__(self, tr, name, parent, attrs):
         self._tr = tr
@@ -106,10 +116,13 @@ class _Span:
         self.ctx = None
         self._pushed = False
         self._cancelled = False
+        self._ann = None
 
     def set(self, **attrs):
         """Attach attributes after entry (e.g. a count known only later)."""
         self._attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def cancel(self):
@@ -131,12 +144,20 @@ class _Span:
                                else sid, sid)
         stack.append(self.ctx)
         self._pushed = True
+        attrs = self._attrs
+        self._ann = _TraceAnnotation(
+            self.name, **(dict(attrs, _r=1) if "step_num" in attrs
+                          else attrs))
+        self._ann.__enter__()
         self._t0 = now()
         return self
 
     def __exit__(self, *exc):
         t1 = now()
         tr = self._tr
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         if self._pushed:
             stack = tr._stack()
             if stack and stack[-1] is self.ctx:

@@ -825,7 +825,16 @@ def dot_product_attention(query, key, value, mask=None, dropout=0.0,
     including BERT's padding keep-mask ((B,1,1,T) or (B,T), reduced to a
     per-key mask) and train-time attention dropout (in-kernel counter RNG,
     fwd/bwd consistent). Full (B,H,Q,K) masks and cross-attention take the
-    XLA softmax path below."""
+    XLA softmax path. Whatever implements it, every op it traces (mask
+    reduction, kernels, transposes, the kernels' backward rules) carries
+    the ``attention`` scope in its metadata."""
+    with jax.named_scope("attention"):
+        return _attention(query, key, value, mask, dropout, scaled, causal,
+                          layout, rng_key, train)
+
+
+def _attention(query, key, value, mask, dropout, scaled, causal, layout,
+               rng_key, train):
     if layout == "BSHD" and getattr(query, "ndim", 0) == 4:
         # (B, S, H, D) — the transformer's natural layout straight out of
         # the qkv projection. The head-fused kernel consumes it with NO
@@ -845,14 +854,12 @@ def dot_product_attention(query, key, value, mask=None, dropout=0.0,
             return _flash_call(flash_attention_bshd, 2, query, key, value,
                                kv_mask, rng_key, causal, drop)
         # fallback: run the BHSD path and restore the layout; XLA fuses
-        # these transposes into the surrounding einsums. (.fn: the module
-        # name is the registered Op wrapper, whose __call__ re-wraps)
-        out = dot_product_attention.fn(
+        # these transposes into the surrounding einsums
+        out = _attention(
             jnp.transpose(query, (0, 2, 1, 3)),
             jnp.transpose(key, (0, 2, 1, 3)),
             jnp.transpose(value, (0, 2, 1, 3)),
-            mask=mask, dropout=dropout, scaled=scaled, causal=causal,
-            layout="BHSD", rng_key=rng_key, train=train)
+            mask, dropout, scaled, causal, "BHSD", rng_key, train)
         return jnp.transpose(out, (0, 2, 1, 3))
 
     if query.ndim == 4 and scaled and _flash_enabled():

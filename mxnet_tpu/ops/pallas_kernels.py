@@ -457,6 +457,7 @@ def _flash_fwd_impl(q, k, v, kv_mask, seed, causal, dropout, interpret):
         out_specs=(pl.BlockSpec((1, blk_q, D), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, 1, blk_q), lambda b, i: (b, 0, i))),
         interpret=interpret,
+        name="flash_bhsd_fwd",
     )
     # trace with x64 off: this framework enables jax_enable_x64 globally
     # (int64 index parity), but Mosaic's grid machinery then emits i64
@@ -502,6 +503,7 @@ def _flash_bwd_impl(q, k, v, kv_mask, seed, o, lse, g, causal, dropout,
         ],
         out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i: (b, i, 0)),
         interpret=interpret,
+        name="flash_bhsd_dq",
     )
     dkv_call = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel, **common),
@@ -521,6 +523,7 @@ def _flash_bwd_impl(q, k, v, kv_mask, seed, o, lse, g, causal, dropout,
         out_specs=(pl.BlockSpec((1, blk_k, D), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, blk_k, D), lambda b, i: (b, i, 0))),
         interpret=interpret,
+        name="flash_bhsd_dkv",
     )
     with jax.enable_x64(False):
         dq = dq_call(sr, qr, kr, vr, gr, lse, delta, mr)
@@ -770,6 +773,7 @@ def _bshd_fwd_impl(q, k, v, kv_mask, seed, causal, dropout, interpret):
                    pl.BlockSpec((1, 1, blk_q, H),
                                 lambda b, i: (b, i, 0, 0))),
         interpret=interpret,
+        name="flash_bshd_fwd",
     )
     with jax.enable_x64(False):
         out, lse = call(sr, qf, kf, vf, mr)
@@ -809,6 +813,7 @@ def _bshd_bwd_impl(q, k, v, kv_mask, seed, o, lse, g, causal, dropout,
                   lse_blk, lse_blk, mask_spec],
         out_specs=blkq_spec,
         interpret=interpret,
+        name="flash_bshd_dq",
     )
     dkv_call = pl.pallas_call(
         functools.partial(_bshd_bwd_dkv_kernel, **common),
@@ -819,6 +824,7 @@ def _bshd_bwd_impl(q, k, v, kv_mask, seed, o, lse, g, causal, dropout,
                   lse_full, lse_full, mask_spec],
         out_specs=(blkk_spec, blkk_spec),
         interpret=interpret,
+        name="flash_bshd_dkv",
     )
     with jax.enable_x64(False):
         dq = dq_call(sr, qf, kf, vf, gf, lse, delta, mr)

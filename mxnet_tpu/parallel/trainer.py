@@ -185,11 +185,12 @@ class ShardedTrainer:
                 lfn, has_aux=True)([param_vals[i] for i in trainable])
         new_vals = list(param_vals)
         new_states = list(states)
-        for i, g in zip(trainable, grads):
-            w = param_vals[i]
-            w2, s2 = update(w, g.astype(w.dtype), states[i], t, lr)
-            new_vals[i] = w2
-            new_states[i] = s2
+        with jax.named_scope("optimizer"):
+            for i, g in zip(trainable, grads):
+                w = param_vals[i]
+                w2, s2 = update(w, g.astype(w.dtype), states[i], t, lr)
+                new_vals[i] = w2
+                new_states[i] = s2
         # aux state (running mean/var) becomes the carried value of its
         # parameter slot — grad_req='null' params are never touched by the
         # optimizer (a wd>0 zero-grad "update" would decay running stats)
@@ -232,7 +233,8 @@ class ShardedTrainer:
         a tuple means multi-input; lists are rejected as ambiguous. Each
         input is batch-sharded over the dp axes. Returns the (replicated)
         scalar loss as a host float-convertible array."""
-        with _trace.span("trainer.step", t=self._t + 1):
+        with _trace.span("trainer.step", t=self._t + 1,
+                         step_num=self._t + 1):
             return self._step_impl(data, label, lr)
 
     def _step_impl(self, data, label, lr):

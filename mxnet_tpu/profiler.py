@@ -291,20 +291,17 @@ class Domain:
 class _Scoped:
     """User-scoped span: lands in the aggregate table AND, while tracing
     is enabled, in the exported timeline as a span of its own (wired into
-    the trace ring, not just the table)."""
+    the trace ring, whose bridge also writes it into a running jax
+    profile)."""
 
     def __init__(self, domain, name):
         self.domain = domain
         self.name = name
         self._t0 = None
-        self._ann = None
         self._span = None
 
     def start(self):
-        import jax
         self._t0 = time.time()
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
         self._span = _trace.span(self.name,
                                  domain=getattr(self.domain, "name", None),
                                  kind=type(self).__name__)
@@ -314,9 +311,6 @@ class _Scoped:
         if self._span is not None:
             self._span.__exit__(None, None, None)
             self._span = None
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
         if self._t0 is not None:
             entry = _state["aggregate"][self.name]
             entry[0] += 1

@@ -63,6 +63,7 @@ class GenerationRequest:
         self.slot = None
         self.admitted_t = None
         self.first_token_t = None
+        self.token_times = []         # scheduler's clock at each emit
         self.done_t = None
         self.prefix_skipped = 0       # prompt tokens served from the cache
         self._pending = None          # last sampled, not yet cache-written
@@ -120,8 +121,9 @@ class GenerationRequest:
         if self._done.is_set():
             return  # failed externally (close timeout): consumer is gone
         self.tokens_out.append(int(tok))
+        self.token_times.append(time.monotonic())
         if self.first_token_t is None:
-            self.first_token_t = time.monotonic()
+            self.first_token_t = self.token_times[0]
         self._pending = int(tok)
         self._q.put(("token", int(tok)))
 
@@ -335,7 +337,15 @@ class GenerationScheduler:
 
     def _iterate(self):
         """One scheduling iteration. Returns False when the worker should
-        exit (closed and nothing left to do)."""
+        exit (closed and nothing left to do). An iteration that does work
+        is one ``generation.iteration`` span whose children are the
+        device calls (``generation.prefill``, ``generation.step``) and
+        ``generation.emit``: its self time is what the host did between
+        them. The idle wait records none."""
+        with _trace.span("generation.iteration") as span:
+            return self._iterate_in(span)
+
+    def _iterate_in(self, span):
         admits, expired, cancelled = [], [], []
         with self._not_empty:
             self._drop_expired_locked(expired, cancelled)
@@ -353,10 +363,12 @@ class GenerationScheduler:
                     and not self._prefilling and not to_fail
                     and not cancelled)
             if idle:
+                span.cancel()
                 if self._closing:
                     return False
                 self._not_empty.wait(0.05)
                 return True
+            span.set(admits=len(admits), live=len(self._live))
         for req in expired:
             if self.metrics is not None:
                 self.metrics.record_expired()
@@ -457,39 +469,42 @@ class GenerationScheduler:
             return
         req.slot = slot
         req.admitted_t = time.monotonic()
+        # the prefill spans belong to the iteration; this instant keeps the
+        # request's own trace linked to them, by slot
+        _trace.instant("generation.admit", parent=req.ctx,
+                       request_id=req.request_id, slot=int(slot))
         try:
-            with _trace.attach(req.ctx):
-                req._prefill_t0 = time.monotonic()
-                n = int(req.prompt.size)
-                skipped = self.engine.prefix_admit(slot, req.prompt)
-                if skipped:
-                    req.prefix_skipped = skipped
-                    with self._lock:
-                        self._c["prefix_hits"] += 1
-                        self._c["prefix_tokens_saved"] += skipped
-                elif self._lane == "decode" and self.engine.prefix \
-                        is not None and n > self.engine.prefix.block:
-                    # a decode lane expects its prefill to have been done
-                    # by a prefill lane; a miss is a routing signal, not
-                    # an error — the remainder prefills locally
-                    with self._lock:
-                        self._c["decode_lane_misses"] += 1
-                chunk = self.engine.chunk
-                remaining = n - skipped
-                if chunk and remaining > chunk:
-                    # long prompt: rung-sized chunks interleave with the
-                    # decode iterations (_advance_prefills)
-                    req._prefill_pos = skipped
-                    with self._lock:
-                        self._prefilling[slot] = req
-                    return
-                if skipped or chunk:
-                    _, tok = self.engine.prefill_chunks(
-                        slot, req.prompt, skipped,
-                        temperature=req.temperature)
-                else:
-                    tok = self.engine.prefill(slot, req.prompt,
-                                              temperature=req.temperature)
+            req._prefill_t0 = time.monotonic()
+            n = int(req.prompt.size)
+            skipped = self.engine.prefix_admit(slot, req.prompt)
+            if skipped:
+                req.prefix_skipped = skipped
+                with self._lock:
+                    self._c["prefix_hits"] += 1
+                    self._c["prefix_tokens_saved"] += skipped
+            elif self._lane == "decode" and self.engine.prefix \
+                    is not None and n > self.engine.prefix.block:
+                # a decode lane expects its prefill to have been done
+                # by a prefill lane; a miss is a routing signal, not
+                # an error — the remainder prefills locally
+                with self._lock:
+                    self._c["decode_lane_misses"] += 1
+            chunk = self.engine.chunk
+            remaining = n - skipped
+            if chunk and remaining > chunk:
+                # long prompt: rung-sized chunks interleave with the
+                # decode iterations (_advance_prefills)
+                req._prefill_pos = skipped
+                with self._lock:
+                    self._prefilling[slot] = req
+                return
+            if skipped or chunk:
+                _, tok = self.engine.prefill_chunks(
+                    slot, req.prompt, skipped,
+                    temperature=req.temperature)
+            else:
+                tok = self.engine.prefill(slot, req.prompt,
+                                          temperature=req.temperature)
         except Exception as exc:  # noqa: BLE001 — this request only
             self.engine.cache.release(slot)
             req.slot = None
@@ -512,10 +527,9 @@ class GenerationScheduler:
                 self._retire_cancelled(req, slot)
                 continue
             try:
-                with _trace.attach(req.ctx):
-                    pos, tok = self.engine.prefill_chunks(
-                        slot, req.prompt, req._prefill_pos,
-                        temperature=req.temperature, max_chunks=1)
+                pos, tok = self.engine.prefill_chunks(
+                    slot, req.prompt, req._prefill_pos,
+                    temperature=req.temperature, max_chunks=1)
                 req._prefill_pos = pos
                 if self.metrics is not None:
                     self.metrics.record_prefill_chunk()
@@ -648,9 +662,10 @@ class GenerationScheduler:
         self.engine.cache.advance(list(live.keys()))
         if self.metrics is not None:
             self.metrics.record_step(len(live), time.monotonic() - t0)
-        for slot, req in live.items():
-            req._emit(int(next_toks[slot]))
-            self._retire_if_finished(req)
+        with _trace.span("generation.emit", slots=len(live)):
+            for slot, req in live.items():
+                req._emit(int(next_toks[slot]))
+                self._retire_if_finished(req)
 
     def _step_spec(self, live):
         """One draft-then-verify iteration: up to ``k+1`` tokens per live
@@ -681,22 +696,23 @@ class GenerationScheduler:
             return
         elapsed = time.monotonic() - t0
         emitted = 0
-        for slot, req in live.items():
-            toks = result[slot]
-            # trim to budget, then to (and including) the first EOS:
-            # only the kept tokens' cache writes are committed
-            n_allow = min(len(toks),
-                          req.max_new_tokens - len(req.tokens_out))
-            if req.eos_id is not None:
-                for j in range(n_allow):
-                    if toks[j] == req.eos_id:
-                        n_allow = j + 1
-                        break
-            self._spec.commit(slot, n_allow)
-            emitted += n_allow
-            for tok in toks[:n_allow]:
-                req._emit(tok)
-            self._retire_if_finished(req)
+        with _trace.span("generation.emit", slots=len(live)):
+            for slot, req in live.items():
+                toks = result[slot]
+                # trim to budget, then to (and including) the first EOS:
+                # only the kept tokens' cache writes are committed
+                n_allow = min(len(toks),
+                              req.max_new_tokens - len(req.tokens_out))
+                if req.eos_id is not None:
+                    for j in range(n_allow):
+                        if toks[j] == req.eos_id:
+                            n_allow = j + 1
+                            break
+                self._spec.commit(slot, n_allow)
+                emitted += n_allow
+                for tok in toks[:n_allow]:
+                    req._emit(tok)
+                self._retire_if_finished(req)
         if self.metrics is not None:
             self.metrics.record_spec_round(
                 len(live), self._spec.k * len(live), emitted, elapsed)

@@ -12,6 +12,8 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may load libtpu, and every xdist worker imports
 this file. All such compiles live in this one file for the same reason.
 """
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -73,6 +75,10 @@ def test_bshd_fwd_bwd_compiles_one_chip(topo, batch, seq):
     text = fn.lower(*_specs((batch, seq, 12, 64), batch, seq, one, one,
                             one)).compile().as_text()
     assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkdv
+    # each kernel's custom call carries the name the program gave it
+    for kernel in ("flash_bshd_fwd", "flash_bshd_dq", "flash_bshd_dkv"):
+        assert re.search(r'op_name="[^"]*\b%s\b[^"]*/pallas_call"' % kernel,
+                         text), kernel
 
 
 def test_bhsd_causal_fwd_bwd_compiles_one_chip(topo):
@@ -83,6 +89,9 @@ def test_bhsd_causal_fwd_bwd_compiles_one_chip(topo):
     text = fn.lower(*_specs((32, 8, 512, 64), 32, 512, one, one,
                             one)).compile().as_text()
     assert text.count("tpu_custom_call") >= 3
+    for kernel in ("flash_bhsd_fwd", "flash_bhsd_dq", "flash_bhsd_dkv"):
+        assert re.search(r'op_name="[^"]*\b%s\b[^"]*/pallas_call"' % kernel,
+                         text), kernel
 
 
 def test_bshd_dp_sharded_compiles_four_chips(topo, monkeypatch):
@@ -109,3 +118,6 @@ def test_bshd_dp_sharded_compiles_four_chips(topo, monkeypatch):
         NamedSharding(mesh, P()))).compile().as_text()
     assert text.count("tpu_custom_call") >= 3
     assert "all-gather" not in text
+    # through the dispatcher the kernels lie under its scope, backward too
+    assert re.search(r'op_name="[^"]*\battention\b[^"]*\bflash_bshd_dkv\b',
+                     text)

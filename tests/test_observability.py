@@ -490,6 +490,141 @@ def test_trainer_step_span():
     assert [e[9]["t"] for e in steps] == [1, 2]
 
 
+# ---------------------------------------------------------------------------
+# the bridge into the profiler's own trace
+# ---------------------------------------------------------------------------
+
+def _host_events(trace_dir):
+    """``{name: [stats dict]}`` over the host planes of the one profile
+    written under ``trace_dir``."""
+    import glob
+    import jax
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                found.setdefault(ev.name, []).append(dict(ev.stats))
+    return found
+
+
+def _profiled(trace_dir, body):
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(trace_dir)
+
+
+def _some_spans():
+    with tr.span("trainer.step", t=3, step_num=3):
+        with tr.span("bridge.inner", op="m") as inner:
+            inner.set(late=5)
+    with tr.span("bridge.cancelled") as gone:
+        gone.cancel()
+
+
+def test_enabled_spans_are_in_the_profilers_host_plane(tmp_path):
+    tr.enable()
+    host = _profiled(tmp_path, _some_spans)
+    recorded = {e[1] for e in tr.events() if e[0] == "X"}
+    assert recorded == {"trainer.step", "bridge.inner"}
+    assert recorded <= set(host)               # every program span is there
+    step, = host["trainer.step"]
+    assert step["t"] == 3 and step["step_num"] == 3 and step["_r"] == 1
+    inner, = host["bridge.inner"]
+    assert inner["op"] == "m" and inner["late"] == 5
+    assert "_r" not in inner
+    # the tracer's own record keeps its layout and attributes
+    rec, = [e for e in tr.events() if e[1] == "trainer.step"]
+    assert len(rec) == 10 and rec[9] == {"t": 3, "step_num": 3}
+
+
+def test_disabled_tracer_writes_no_annotation(tmp_path, monkeypatch):
+    made = []
+    real = tr._TraceAnnotation
+    monkeypatch.setattr(tr, "_TraceAnnotation",
+                        lambda *a, **kw: made.append(a) or real(*a, **kw))
+    assert not tr.enabled()
+    assert tr.span("x") is tr._NULL_SPAN and tr.tracer.span("x") is tr._NULL_SPAN
+    host = _profiled(tmp_path, _some_spans)
+    assert made == [] and tr.events() == []
+    assert not {"trainer.step", "bridge.inner", "bridge.cancelled"} & set(host)
+    tr.enable()
+    _some_spans()
+    assert [a[0] for a in made] == ["trainer.step", "bridge.inner",
+                                    "bridge.cancelled"]
+
+
+def test_scoped_profiler_object_is_in_the_profile_once(tmp_path):
+    """``_Scoped`` leaves the annotation to the tracer's bridge: a Task of
+    a running session shows once in the profiler's trace, not twice."""
+    profiler.set_config(filename=str(tmp_path / "profile.json"))
+    profiler.set_state("run")
+    try:
+        if not profiler._state["jax_running"]:
+            pytest.skip("no jax profile could be started here")
+        with profiler.Domain("d").new_task("user.task"):
+            pass
+    finally:
+        profiler.set_state("stop")
+    assert len(_host_events(tmp_path)["user.task"]) == 1
+
+
+# ---------------------------------------------------------------------------
+# the scheduler's host time
+# ---------------------------------------------------------------------------
+
+def test_generation_iteration_spans_and_token_times():
+    from mxnet_tpu.models import transformer_lm_tiny
+    from mxnet_tpu.serving.generation import (DecodeEngine,
+                                              GenerationScheduler)
+    np.random.seed(0)
+    net = transformer_lm_tiny(vocab_size=64)
+    net.initialize(mx.init.Xavier())
+    net(nd.array(np.zeros((1, 8), "int32")))
+    eng = DecodeEngine(net, num_slots=2, max_seq=32, ladder=(8,))
+    sched = GenerationScheduler(eng)
+    try:
+        tr.enable()
+        time.sleep(0.2)                        # idle iterations only
+        assert [e for e in tr.events()
+                if e[1] == "generation.iteration"] == []
+        req = sched.submit(np.arange(1, 6), max_new_tokens=4)
+        tokens = req.result(timeout=120)
+        time.sleep(0.15)                       # and idle again
+    finally:
+        tr.disable()
+        sched.close()
+        eng.close()
+    assert len(tokens) == 4 and len(req.token_times) == 4
+    assert req.token_times[0] == req.first_token_t
+    assert req.token_times == sorted(req.token_times)
+    assert req.admitted_t <= req.token_times[0] <= req.done_t
+    spans = [e for e in tr.events() if e[0] == "X"]
+    iterations = {e[6]: e for e in spans if e[1] == "generation.iteration"}
+    # one prefill iteration (which also takes the first decode step) and
+    # two more steps; the idle waits before and after recorded none
+    assert len(iterations) == 3
+    assert all(set(e[9]) == {"admits", "live"} for e in iterations.values())
+    assert sorted(e[9]["admits"] for e in iterations.values()) == [0, 0, 1]
+    for name in ("generation.prefill", "generation.step", "generation.emit"):
+        children = [e for e in spans if e[1] == name]
+        assert children, name
+        assert all(e[7] in iterations for e in children), name
+    assert len([e for e in spans if e[1] == "generation.step"]) == 3
+    # the request's own trace keeps its link to the slot it was given
+    admit, = [e for e in tr.events() if e[1] == "generation.admit"]
+    assert admit[9]["slot"] in (0, 1)
+
+
 def test_retry_attempts_become_instants():
     from mxnet_tpu.resilience.retry import RetryPolicy
     tr.enable()
@@ -581,6 +716,33 @@ def test_trace_summary_on_synthetic_trace(tmp_path):
     assert "trainer.chunk" in text
     # the CLI entry point round-trips
     assert ts.main([path, "--top", "2"]) == 0
+
+
+def test_trace_summary_shows_the_schedulers_self_time(tmp_path):
+    ts = _load_trace_summary()
+    tr.enable()
+    base = tr.now()
+    for i, (step_ms, emit_ms) in enumerate([(40, 5), (30, 2)]):
+        t0 = base + 0.1 * i
+        it = tr.complete("generation.iteration", t0, t0 + 0.060,
+                         admits=0, live=2)
+        tr.complete("generation.step", t0 + 0.002,
+                    t0 + 0.002 + step_ms / 1e3, parent=it, slots=2)
+        tr.complete("generation.emit", t0 + 0.050,
+                    t0 + 0.050 + emit_ms / 1e3, parent=it, slots=2)
+    path = str(tmp_path / "sched.json")
+    obs_export.dump_chrome_trace(path, tr.events())
+    events, kept = ts.load_trace(path)
+    summary = ts.summarize(events, kept=kept)
+    cp = summary["critical_path"]
+    assert cp["scheduler_iterations"] == 2
+    # 120 ms of iterations less 70 ms of steps and 7 ms of emits
+    assert cp["scheduler_self_ms"] == pytest.approx(43.0, rel=0.01)
+    assert cp["scheduler_emit_ms"] == pytest.approx(7.0, rel=0.01)
+    text = ts.format_summary(summary)
+    assert "scheduler host (self)" in text and "generation.emit" in text
+    # a training trace has no such line
+    assert "scheduler host" not in ts.format_summary(ts.summarize([]))
 
 
 # ---------------------------------------------------------------------------

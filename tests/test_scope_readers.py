@@ -1,0 +1,316 @@
+"""The names the program writes on its traced programs, and the benchmark's
+readers of them (``chipbench/scopes.py``, ``chipbench/layer_metrics/``).
+
+Two halves. The program's half: a step traced through ``ShardedTrainer``
+carries ``attention`` (forward and transposed), ``optimizer`` and every
+block's name in its op metadata, whatever implements the attention; a
+``CachedOp``'s module is named after the op. The readers' half: each new
+per-layer reader on a synthetic ``obs`` (a ten-instruction ``step_text``
+and a ``by_name`` made by hand), including where it must return ``None``.
+Nothing here gives a device time: the seconds are made up.
+"""
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import mxnet_tpu as mx  # noqa: E402
+from mxnet_tpu import gluon, nd, parallel  # noqa: E402
+from mxnet_tpu.base import PROGRAM_SCOPES  # noqa: E402
+from mxnet_tpu.cached_op import CachedOp  # noqa: E402
+from mxnet_tpu.models.bert import bert_tiny  # noqa: E402
+from mxnet_tpu.ops import nn as nn_ops  # noqa: E402
+from mxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
+
+from chipbench import scopes  # noqa: E402
+from chipbench.run import load_reader  # noqa: E402
+
+METRIC_DIR = os.path.join(ROOT, "chipbench", "layer_metrics")
+
+
+# ---------------------------------------------------------------------------
+# the program's half
+# ---------------------------------------------------------------------------
+
+class _Step(gluon.HybridBlock):
+    """A scalar out of BERT: the trainer's loss is the identity."""
+
+    def __init__(self, bert):
+        super().__init__()
+        with self.name_scope():
+            self.bert = bert
+
+    def hybrid_forward(self, F, tokens, segments, valid):
+        _, _, mlm, nsp = self.bert(tokens, segments, valid)
+        return F.mean(mlm) + F.mean(nsp)
+
+
+def _step_locations():
+    """``op_name`` of every operation of a tiny BERT step as lowered."""
+    net = bert_tiny(vocab_size=100, max_length=128)
+    net.initialize(mx.init.Normal(0.02))
+    trainer = parallel.ShardedTrainer(
+        _Step(net), lambda out, _label: out, "adam",
+        {"learning_rate": 1e-4}, mesh=parallel.make_mesh(dp=1))
+    rows, seq = 2, 128
+    data = (nd.array(np.random.randint(0, 100, (rows, seq)).astype("float32")),
+            nd.array(np.zeros((rows, seq), "float32")),
+            nd.array(np.full((rows,), 100, "float32")))
+    text = trainer.lower_step(
+        data, nd.array(np.zeros((rows,), "float32"))).as_text(debug_info=True)
+    return set(re.findall(r'"(jit\(step\)/[^"]*)"', text))
+
+
+@pytest.mark.parametrize("path", ["xla", "flash_interpret"])
+def test_traced_step_carries_the_programs_scopes(path, monkeypatch):
+    if path == "flash_interpret":
+        kernel = pk.flash_attention_bshd
+        monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+        monkeypatch.setattr(
+            pk, "flash_attention_bshd",
+            lambda q, k, v, m, s, c, d: kernel(q, k, v, m, s, c, d, True))
+    names = _step_locations()
+    layers = {}
+    for name in names:
+        layers.setdefault(scopes.layer_of(name), []).append(name)
+    attention = layers[scopes.ATTENTION]
+    assert any("/jvp(" in n and "/attention/" in n for n in attention)
+    assert any("/transpose(jvp(" in n and "/attention/" in n
+               for n in attention)
+    assert any(n.startswith("jit(step)/optimizer/")
+               for n in layers[scopes.OPTIMIZER])
+    # a block's own name, nested under its parents'
+    assert any(re.search(r"bertmodel\d+_encoder_layer1_ffn",
+                         "/".join(scopes.scope_path(n)))
+               for n in layers[scopes.BLOCKS])
+    kernels = {k for n in attention
+               for k in ("flash_bshd_fwd", "flash_bshd_dq", "flash_bshd_dkv")
+               if "/attention/%s/" % k in n}
+    assert kernels == ({"flash_bshd_fwd", "flash_bshd_dq", "flash_bshd_dkv"}
+                       if path == "flash_interpret" else set())
+    # the backward rule of the kernels' custom_vjp keeps the call site's scope
+    if path == "flash_interpret":
+        assert any("transpose(jvp(" in n and "/attention/flash_bshd_dkv/" in n
+                   for n in attention)
+
+
+def test_eager_block_call_enters_no_scope_and_reserved_names_are_kept(
+        monkeypatch):
+    import jax
+    entered = []
+    real = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: entered.append(name) or real(name))
+    dense = gluon.nn.Dense(4, in_units=4, prefix="attention_")
+    dense.initialize()
+    assert dense.name in PROGRAM_SCOPES
+    x = nd.ones((2, 4))
+    dense(x)                                    # eager: op by op, no scope
+    assert entered == []
+    jax.jit(lambda v: dense(nd.NDArray(v))._data)(x._data)
+    assert entered == ["attention_"]            # never the dispatcher's word
+
+
+@pytest.mark.parametrize("name,module", [
+    ("lm0.decode", "jit_lm0.decode"),
+    ("my net/prefix insert", "jit_my_net_prefix_insert"),
+])
+def test_cached_op_module_is_named_after_the_op(name, module):
+    op = CachedOp(lambda a: a * 2, name=name)
+    op(nd.ones((3,)))
+    sig, = op.signatures()
+    text = op.lower(sig).as_text()
+    assert "module @%s " % module in text
+    assert "jit_pure" not in text
+
+
+# ---------------------------------------------------------------------------
+# the readers' half: a step text of ten instructions
+# ---------------------------------------------------------------------------
+
+def _line(name, shape, rest, op_name=None):
+    meta = ', metadata={op_name="%s" source_file="x.py"}' % op_name \
+        if op_name else ""
+    return "  %%%s = %s %s%s" % (name, shape, rest, meta)
+
+
+NET = "jit(step)/jvp(net0)/net0_layer0"
+NET_T = "jit(step)/transpose(jvp(net0))/net0_layer0"
+STEP_TEXT = "\n".join([
+    "HloModule jit_step, is_scheduled=true",
+    # a forward matmul that holds a scalar of the optimizer's: no work
+    "%fused_computation.1 (a.1: bf16[8,128]) -> bf16[8,128] {",
+    _line("dot.1", "bf16[8,128]{1,0}", "dot(%a.1, %a.1)",
+          NET + "/net0_layer0_ffn/dot_general"),
+    _line("power.1", "f32[]", "power(%c.1, %c.2)", "jit(step)/optimizer/pow"),
+    "}",
+    # a weight-gradient matmul with the update fused in: the matmul names it
+    "%fused_computation.5 (a.5: bf16[8,128]) -> (bf16[8,128], bf16[8,128]) {",
+    _line("dot.5", "bf16[8,128]{1,0}", "dot(%a.5, %a.5)",
+          NET_T + "/net0_layer0_ffn/dot_general"),
+    _line("multiply.5", "bf16[8,128]{1,0}", "multiply(%dot.5, %dot.5)",
+          "jit(step)/optimizer/mul"),
+    "  ROOT %tuple.5 = (bf16[8,128], bf16[8,128]) tuple(%dot.5, %multiply.5)",
+    "}",
+    "ENTRY %main.1 (p0: bf16[8,128]) -> bf16[8,128] {",
+    _line("p0", "bf16[8,128]{1,0}", "parameter(0)", "params[0]"),
+    _line("fusion.1", "bf16[8,128]{1,0}",
+          "fusion(%p0), kind=kOutput, calls=%fused_computation.1",
+          NET + "/net0_layer0_ffn/dot_general"),
+    _line("divide_subtract_fusion.5", "(bf16[8,128], bf16[8,128])",
+          "fusion(%fusion.1), kind=kOutput, calls=%fused_computation.5",
+          NET_T + "/net0_layer0_ffn/dot_general"),
+    _line("copy.3", "bf16[8,128]{1,0}", "copy(%fusion.1)",
+          NET + "/attention/reshape;" + NET + "/squeeze"),
+    _line("fusion.2", "bf16[8,128]{1,0}", "fusion(%copy.3), kind=kLoop",
+          NET + "/attention/jit(_where)/select_n"),
+    _line("fusion.3", "bf16[8,128]{1,0}", "fusion(%fusion.2), kind=kLoop",
+          NET_T + "/attention/mul"),
+    _line("pad_add_fusion.1", "bf16[8,128]{1,0}",
+          "fusion(%fusion.3), kind=kLoop", NET_T + "/add_any"),
+    _line("divide_subtract_fusion.4", "bf16[8,128]{1,0}",
+          "fusion(%pad_add_fusion.1), kind=kOutput",
+          "jit(step)/optimizer/sub"),
+    _line("copy-start.9", "(bf16[8,128]{1,0}, u32[])", "copy-start(%p0)"),
+    _line("convert.7", "f32[]", "convert(%p0)", "lr"),
+    "  ROOT %tuple.1 = (bf16[8,128]) tuple(%divide_subtract_fusion.4)",
+    "}"])
+# seconds over two traced steps, made up: 10 busy in all
+BY_NAME = {"fusion.1": 3.0, "divide_subtract_fusion.5": 1.0, "copy.3": 0.5, "fusion.2": 1.5, "fusion.3": 1.0,
+           "pad_add_fusion.1": 0.5, "divide_subtract_fusion.4": 2.0,
+           "copy-start.9": 0.3, "convert.7": 0.2}
+CFG = {"hidden_size": 128, "num_hidden_layers": 1}
+PEAKS = {"flops_per_s": 1e9, "hbm_bytes_per_s": 1e9}
+
+
+def _obs(**changes):
+    events = [(name, float(i), float(i) + 0.1)
+              for i in range(2) for name in BY_NAME]
+    obs = {"kind": "train", "step_text": STEP_TEXT, "cfg": CFG,
+           "peaks": PEAKS, "batch": 8, "seq": 128, "chips": 1, "spans": [],
+           "trace": {"by_name": dict(BY_NAME), "busy_s": 10.0,
+                     "window_s": 10.0, "events": events,
+                     "custom_calls": set()}}
+    obs.update(changes)
+    return obs
+
+
+def test_op_names_and_layers_of_the_synthetic_step():
+    names = scopes.op_names(STEP_TEXT)
+    assert "copy-start.9" not in names and names["convert.7"] == "lr"
+    layers = {n: scopes.layer_of(names.get(n)) for n in BY_NAME}
+    assert layers == {
+        "fusion.1": "blocks", "divide_subtract_fusion.5": "blocks",
+        "copy.3": "attention", "fusion.2": "attention",
+        "fusion.3": "attention", "pad_add_fusion.1": "blocks",
+        "divide_subtract_fusion.4": "optimizer", "copy-start.9": "unscoped",
+        "convert.7": "unscoped"}
+    assert scopes.seconds_by_layer(_obs()) == {
+        "attention": 3.0, "optimizer": 2.0, "blocks": 4.5, "unscoped": 0.5}
+    assert scopes.steps_traced(_obs(), "attention") == 2.0
+    assert scopes.layers_held(STEP_TEXT) == {
+        "fusion.1": {"blocks"},
+        "divide_subtract_fusion.5": {"blocks", "optimizer"}}
+
+
+@pytest.mark.parametrize("op_name,path", [
+    ("jit(step)/transpose(jvp(a))/b/attention/mul", ["a", "b", "attention"]),
+    ("jit(step)/jvp(a)/attention/flash_bshd_fwd/pallas_call",
+     ["a", "attention", "flash_bshd_fwd"]),
+    ("jit(step)/jvp(a)/b/jit(_where)/select_n", ["a", "b"]),
+    ("jit(step)/jvp()/mul", []),
+    ("jit(step)/while/body/mul", []),
+    ("jit(step)/jvp(my_attention)/mul", ["my_attention"]),
+    ("jit(step)/jvp(a)/...qd,...kd->...qk/dot_general", ["a"]),
+    ("params[5]", []),
+    ("", []),
+])
+def test_scope_path(op_name, path):
+    assert scopes.scope_path(op_name) == path
+
+
+@pytest.mark.parametrize("metric,expected", [
+    ("attention_scope_pct.train", 30.0),
+    ("optimizer_scope_pct.train", 20.0),
+    ("unscoped_device_pct.train", 5.0),
+    # the update that stands alone (20) and the matmul it rides in (10)
+    ("optimizer_fused_pct.train", 30.0),
+])
+def test_scope_share_readers(metric, expected):
+    read = load_reader(metric, METRIC_DIR)
+    assert read(_obs()) == pytest.approx(expected)
+    assert read(_obs(trace=None)) is None            # an untraced rehearsal
+    assert read(_obs(step_text=None)) is None
+    assert read(_obs(kind="serve")) is None
+    # a program from before the scopes: nothing can be put down to a layer
+    bare = STEP_TEXT.replace("/attention/", "/").replace(
+        "/optimizer/", "/jvp(...qd,...kd->...qk)/")
+    assert read(_obs(step_text=bare)) is None
+
+
+def test_attention_roofline_reads_plain_xla_ops_under_the_scope():
+    """No kernel, no ``tpu_custom_call`` and no kernel's name anywhere in
+    the text: the roofline is of the work, not of what implements it."""
+    assert "custom_call" not in STEP_TEXT and "flash" not in STEP_TEXT
+    read = load_reader("attention_roofline_pct.train", METRIC_DIR)
+    # one layer, 8 rows of 128: forward 4*8*128*128*128 FLOPs over 1e9/s,
+    # backward twice that; bytes are far below; two steps; 3.0 s under scope
+    least = 3 * 4.0 * 8 * 128 * 128 * 128 / 1e9
+    assert read(_obs()) == pytest.approx(100.0 * least * 2 / 3.0)
+    assert read(_obs(peaks=None)) is None
+    assert read(_obs(trace=None)) is None
+    assert read(_obs(kind="serve")) is None
+    no_attention = STEP_TEXT.replace("/attention/", "/elsewhere/")
+    assert read(_obs(step_text=no_attention)) is None
+
+
+class _Chip:
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("stats,expected", [
+    ({"bytes_in_use": 2059794432, "peak_bytes_in_use": 2326938624,
+      "bytes_reserved": 13573783552, "peak_bytes_reserved": 13573783552,
+      "bytes_limit": 16900000000}, 94.09),
+    ({"bytes_in_use": 50, "peak_bytes_in_use": 60, "bytes_limit": 100}, None),
+    (None, None),
+])
+def test_device_memory_held_reader(stats, expected, monkeypatch):
+    from mxnet_tpu.observability import telemetry
+    monkeypatch.setattr(telemetry, "_accel_devices", lambda: [_Chip(stats)])
+    monkeypatch.setattr(telemetry, "_mem_peak", {})
+    read = load_reader("device_memory_held_pct.train", METRIC_DIR)
+    got = read(_obs())
+    assert got is None if expected is None \
+        else got == pytest.approx(expected, abs=0.01)
+    assert read(_obs(kind="serve")) is None
+
+
+def test_scheduler_self_time_reader():
+    read = load_reader("scheduler_self_ms_p50.serve", METRIC_DIR)
+    spans = [
+        ("generation.iteration", 0.000, 0.100, {"admits": 1, "live": 2}),
+        ("generation.prefill", 0.010, 0.040, {}),
+        ("generation.step", 0.045, 0.090, {}),
+        ("generation.emit", 0.091, 0.096, {}),
+        ("generation.iteration", 0.100, 0.160, {"admits": 0, "live": 3}),
+        ("generation.step", 0.102, 0.150, {}),
+        ("generation.iteration", 0.160, 0.200, {"admits": 0, "live": 3}),
+        ("generation.step", 0.170, 0.199, {}),
+    ]
+    # self times 20, 12 and 11 ms: the median
+    assert read({"kind": "serve", "spans": spans}) == pytest.approx(12.0)
+    assert read({"kind": "serve", "spans": spans[1:4]}) is None
+    assert read({"kind": "train", "spans": spans}) is None

@@ -678,6 +678,44 @@ def test_memory_health_degrades_before_oom(monkeypatch):
     assert telemetry.memory_health()["status"] == "ok"
 
 
+class _FullChip:
+    """A v5e chip as PR 24 read it while BERT-base b96 trained: 2.06 GB of
+    live arrays, 13.57 GB reserved for the step's temporaries."""
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+    def memory_stats(self):
+        return {"bytes_in_use": 2059794432, "peak_bytes_in_use": 2326938624,
+                "bytes_reserved": 13573783552,
+                "peak_bytes_reserved": 13573783552,
+                "bytes_limit": 16900000000}
+
+
+def test_headroom_counts_memory_reserved_for_programs(monkeypatch):
+    monkeypatch.setattr(telemetry, "_accel_devices", lambda: [_FullChip()])
+    mem, = telemetry.device_memory()
+    assert mem["bytes_reserved"] == mem["peak_bytes_reserved"] == 13573783552
+    in_use_only = (mem["bytes_limit"] - mem["bytes_in_use"]) \
+        / mem["bytes_limit"]
+    assert in_use_only == pytest.approx(0.878, abs=1e-3)   # what it read
+    assert telemetry.memory_headroom() < 0.10
+    assert telemetry.memory_headroom() == pytest.approx(0.0749, abs=1e-3)
+    monkeypatch.setenv("MXNET_TELEMETRY_HEADROOM_MIN", "0.10")
+    assert telemetry.memory_health()["reason"] == "memory_headroom"
+    text = prom.render_process()
+    assert "mxtpu_device_hbm_bytes_reserved{" in text
+
+
+def test_device_memory_without_reserved_stats_reads_zero(monkeypatch):
+    class _Plain(_FullChip):
+        def memory_stats(self):
+            return {"bytes_in_use": 50, "bytes_limit": 100}
+    monkeypatch.setattr(telemetry, "_accel_devices", lambda: [_Plain()])
+    mem, = telemetry.device_memory()
+    assert mem["bytes_reserved"] == mem["peak_bytes_reserved"] == 0
+    assert telemetry.memory_headroom() == pytest.approx(0.5)
+
+
 def test_server_healthz_degrades_on_low_headroom(monkeypatch):
     with ModelServer(_times(1), port=0, buckets=(1,), jit=False) as srv:
         assert srv.health()["status"] == "ok"

@@ -9,9 +9,12 @@ timeline exists to surface:
   (``serving.queue_wait``) vs. XLA compiles (``cachedop.compile``), and
   the staging **overlap efficiency** — the fraction of training time NOT
   spent stalled on input staging (1.0 = perfect overlap, the
-  ``step_stream`` design target). Category sums use **exclusive (self)
-  time** — a span's duration minus its direct children's overlap — so a
-  parent is never double-counted over the children nested inside it;
+  ``step_stream`` design target), and for a generation scheduler the
+  host's own time per iteration (``generation.iteration`` less its
+  children: the device calls and ``generation.emit``). Category sums use
+  **exclusive (self) time** — a span's duration minus its direct
+  children's overlap — so a parent is never double-counted over the
+  children nested inside it;
 - a per-span-name aggregate table (count / total / self / mean / max);
 - the **top-N slowest spans**, each with its request id when it carries
   one — the p99 outlier, decomposed.
@@ -37,6 +40,8 @@ STAGE_WAIT_NAMES = ("datafeed.consumer_wait",)
 QUEUE_WAIT_NAMES = ("serving.queue_wait",)
 COMPILE_NAMES = ("cachedop.compile",)
 SERVING_ROOT = "serving.http"
+SCHEDULER_ITERATION = "generation.iteration"
+SCHEDULER_EMIT = "generation.emit"
 
 
 class TraceLoadError(Exception):
@@ -165,6 +170,9 @@ def summarize(events, top=10, kept=None):
         if SERVING_ROOT in by_name else 0.0
     serving_self_ms = by_name[SERVING_ROOT][3] / 1e3 \
         if SERVING_ROOT in by_name else 0.0
+    # the scheduler's own host time: what ran between the device calls
+    iterations = by_name[SCHEDULER_ITERATION][0] \
+        if SCHEDULER_ITERATION in by_name else 0
 
     wall_ms = 0.0
     if spans:
@@ -230,6 +238,9 @@ def summarize(events, top=10, kept=None):
             "compile_ms": compile_ms,
             "serving_ms": serving_ms,
             "serving_self_ms": serving_self_ms,
+            "scheduler_iterations": iterations,
+            "scheduler_self_ms": total_ms((SCHEDULER_ITERATION,)),
+            "scheduler_emit_ms": total_ms((SCHEDULER_EMIT,)),
             "basis": "exclusive",
         },
         "overlap_efficiency": overlap_efficiency,
@@ -261,6 +272,11 @@ def format_summary(summary):
     lines.append("  %-28s %12.2f ms  (self %.2f ms)"
                  % ("serving requests (http)", cp["serving_ms"],
                     cp.get("serving_self_ms", cp["serving_ms"])))
+    if cp.get("scheduler_iterations"):
+        lines.append("  %-28s %12.2f ms  (%d iterations; emit %.2f ms)"
+                     % ("scheduler host (self)", cp["scheduler_self_ms"],
+                        cp["scheduler_iterations"],
+                        cp["scheduler_emit_ms"]))
     lines.append("  (categories are EXCLUSIVE time: children are not "
                  "re-counted into parents)")
     if summary["overlap_efficiency"] is not None:
