@@ -17,7 +17,8 @@ non-zero exit and no result line. Phases (full width, random weights from
   mask, dropout as shipped; the compiled step must contain the Pallas
   attention kernels. Then ``dot_product_attention`` at (16, 512, 12, 64)
   with a padding mask: the flash kernels against the XLA path of the same
-  op, on the chip.
+  op, on the chip, and the packed entry (value and packed gradient)
+  against the separate-operand kernels, bit for bit.
 - ``serve_lm``     — ``transformer_lm_base`` behind ``DecodeEngine`` ->
   ``GenerationScheduler`` -> ``ModelServer`` in this process: four
   concurrent HTTP ``POST /generate`` streams + ``GET /healthz``; served
@@ -289,6 +290,33 @@ def check_flash_against_xla(seed):
     np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
     log("check flash_vs_xla", shape=[B, S, H, D], max_abs_err=err,
         ref_abs_max=float(np.abs(ref).max()), flash_s=round(flash_s, 3))
+
+    # the packed entry (what the models call): the same kernels reading q,
+    # k, v as column blocks of one (B, S, 3*H*D) array and writing ONE
+    # packed gradient in place, against the separate-operand call above
+    def loss(attend_fn):
+        return lambda *a: jnp.sum(attend_fn(*a).astype(jnp.float32) ** 2)
+
+    qkv = jnp.concatenate([a.reshape(B, S, H * D) for a in (q, k, v)], -1)
+    packed = jax.jit(jax.value_and_grad(loss(
+        lambda a: nn_ops.packed_self_attention.fn(a, mask=mask,
+                                                  num_heads=H))))
+    text = packed.lower(qkv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3, "packed entry fell back"
+    value, d_qkv = packed(qkv)
+    want, grads = jax.jit(jax.value_and_grad(
+        loss(lambda q, k, v: attend(q, k, v, mask)), argnums=(0, 1, 2)))(
+            q, k, v)
+    want_d = np.concatenate([host(g).reshape(B, S, H * D) for g in grads],
+                            -1).astype(np.float32)
+    d_qkv = host(d_qkv).astype(np.float32)
+    # the two sums run over differently shaped arrays: same value up to
+    # the order of the additions
+    np.testing.assert_allclose(float(value), float(want), rtol=1e-5)
+    np.testing.assert_array_equal(d_qkv, want_d)
+    assert np.isfinite(d_qkv).all() and np.abs(d_qkv).max() > 0
+    log("check packed_vs_split", loss=float(value),
+        grad_abs_max=float(np.abs(d_qkv).max()), equal=True)
 
 
 # ------------------------------------------------------------------- serve_lm

@@ -26,6 +26,7 @@ FP32_OPS = ["BatchNorm", "LayerNorm", "InstanceNorm", "GroupNorm", "softmax",
             "mean", "sum", "erfinv", "_ctc_loss"]
 LP16_OPS = ["FullyConnected", "Convolution", "Deconvolution", "dot",
             "batch_dot", "matmul", "_contrib_dot_product_attention",
+            "_contrib_packed_self_attention",
             "_rnn_scan_layer"]
 
 

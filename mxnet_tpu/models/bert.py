@@ -5,9 +5,11 @@ GluonNLP on the same Gluon substrate).
 Mesh-first like models/transformer.py: parameter names carry qkv/proj/
 ffn_up/ffn_down markers so the Megatron tensor-parallel rules
 (`mxnet_tpu.parallel` + `models.transformer.tp_rules`) apply unchanged;
-attention routes through `_contrib_dot_product_attention` (flash kernel /
-ring attention capable). Padding is handled with a boolean keep-mask
-broadcast to (B, 1, 1, T) — XLA fuses it into the softmax."""
+attention routes through `_contrib_packed_self_attention` (the flash
+kernels on the unsplit QKV projection where they run, the XLA softmax
+elsewhere). Padding is handled with a boolean keep-mask broadcast to
+(B, 1, 1, T) — reduced to a per-key mask for the kernels, fused into the
+softmax by XLA otherwise."""
 from __future__ import annotations
 
 import math
@@ -40,18 +42,11 @@ class _MaskedAttention(MultiHeadAttention):
                          **kwargs)
 
     def hybrid_forward(self, F, x, mask=None):
-        # natural (B, T, H, D) layout end to end (see MultiHeadAttention)
-        B, T, C = x.shape
-        H = self._num_heads
-        qkv = self.qkv(x)
-        qkv = qkv.reshape((B, T, 3, H, C // H))
-        q = qkv[:, :, 0]
-        k = qkv[:, :, 1]
-        v = qkv[:, :, 2]
-        out = F._contrib_dot_product_attention(
-            q, k, v, mask=mask, dropout=self._dropout, causal=False,
-            layout="BSHD")
-        return self.proj(out.reshape((B, T, C)))
+        # packed projection in, (B, T, C) out (see MultiHeadAttention)
+        out = F._contrib_packed_self_attention(
+            self.qkv(x), mask=mask, num_heads=self._num_heads,
+            dropout=self._dropout, causal=False)
+        return self.proj(out)
 
 
 class _BERTLayer(HybridBlock):

@@ -7,8 +7,10 @@ parameter names carry `qkv`/`proj`/`ffn_up`/`ffn_down` markers so
 tensor-parallel PartitionSpec rules (mxnet_tpu.parallel.shard_params) apply
 by regex — the Megatron split: qkv/ffn_up column-sharded on 'tp', proj/
 ffn_down row-sharded — and attention routes through the
-`_contrib_dot_product_attention` op (swappable for the pallas flash kernel
-/ ring attention under sequence parallelism).
+`_contrib_packed_self_attention` op (full-sequence self-attention on the
+unsplit QKV projection) and `_contrib_dot_product_attention` (the decode
+steps, q_len != kv_len): pallas flash kernels where they run, XLA
+elsewhere.
 """
 from __future__ import annotations
 
@@ -38,22 +40,24 @@ class MultiHeadAttention(HybridBlock):
                                  in_units=units, prefix="proj_")
 
     def hybrid_forward(self, F, x):
-        # x: (B, T, C). q/k/v stay in the natural (B, T, H, D) layout —
-        # the head-fused BSHD flash kernel consumes it directly, so no
-        # physical transpose brackets the attention (XPlane study: the
-        # BHSD shuffles cost ~12% of a BERT-base s128 training span)
-        B, T, C = x.shape
-        q, k, v = self._split_qkv(x)
-        out = F._contrib_dot_product_attention(
-            q, k, v, dropout=self._dropout, causal=self._causal,
-            layout="BSHD")
-        return self.proj(out.reshape((B, T, C)))
+        # x: (B, T, C). The packed (B, T, 3C) projection goes to the
+        # attention op unsplit and its (B, T, C) result to the output
+        # projection: where the flash kernels run they read q, k, v as
+        # column blocks of the one array (no 4-D view, no relayout copy);
+        # elsewhere the op splits it into (B, T, H, D) views itself
+        out = F._contrib_packed_self_attention(
+            self.qkv(x), num_heads=self._num_heads, dropout=self._dropout,
+            causal=self._causal)
+        return self.proj(out)
 
     def _split_qkv(self, x):
-        B, T, C = x.shape
+        return self._heads(self.qkv(x))
+
+    def _heads(self, qkv):
+        """The packed (B, T, 3C) projection as q, k, v (B, T, H, D)."""
+        B, T, C3 = qkv.shape
         H = self._num_heads
-        qkv = self.qkv(x)  # (B, T, 3C)
-        qkv = qkv.reshape((B, T, 3, H, C // H))
+        qkv = qkv.reshape((B, T, 3, H, C3 // (3 * H)))
         return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
     # ---- incremental decode (KV-cache) path -------------------------------
@@ -65,12 +69,14 @@ class MultiHeadAttention(HybridBlock):
         ``(out (B, T, C), k (B, T, H, D), v (B, T, H, D))`` — the K/V the
         generation prefill copies into its cache arena."""
         from .. import ndarray as nd
-        B, T, C = x.shape
-        q, k, v = self._split_qkv(x)
-        out = nd._contrib_dot_product_attention(
-            q, k, v, mask=kv_mask, dropout=self._dropout,
-            causal=self._causal, layout="BSHD")
-        return self.proj(out.reshape((B, T, C))), k, v
+        qkv = self.qkv(x)  # (B, T, 3C)
+        out = nd._contrib_packed_self_attention(
+            qkv, mask=kv_mask, num_heads=self._num_heads,
+            dropout=self._dropout, causal=self._causal)
+        # k and v are sliced for the arena only; the attention read them
+        # in place
+        _, k, v = self._heads(qkv)
+        return self.proj(out), k, v
 
     def step(self, x, k_cache, v_cache, positions):
         """One incremental-decode step against cached K/V.

@@ -757,38 +757,58 @@ def _reduce_key_mask(mask, batch, key_len):
     return None, False
 
 
-def _sharded_flash(kernel, heads_dim, mesh, batch_axes, query, key, value,
-                   kv_mask, seed, causal, drop, interpret=False):
-    """``kernel`` under ``jax.shard_map`` over ``mesh``: GSPMD cannot
-    partition a Mosaic kernel, so each device runs it on its own shard —
-    the batch dim split over ``batch_axes``, the heads dim over ``tp``,
-    each only where the dim divides (what does not divide is computed
-    replicated). Every shard folds its global batch/head offset into the
-    dropout seed operand, so the keep-mask is the unsharded call's."""
+def _shard_flash(call, operands, num_heads, heads_dim, mesh, batch_axes,
+                 kv_mask, seed):
+    """``call(operands, kv_mask, seed)`` under ``jax.shard_map`` over
+    ``mesh``: GSPMD cannot partition a Mosaic kernel, so each device runs
+    it on its own shard — the batch dim split over ``batch_axes``, the
+    heads dim (``heads_dim``; ``None`` = the operands have none to split,
+    the packed projection) over ``tp``, each only where the dim divides
+    (what does not divide is computed replicated). Every shard folds its
+    global batch/head offset into the dropout seed operand, so the
+    keep-mask is the unsharded call's."""
     from jax.sharding import PartitionSpec as P
-    B, H = query.shape[0], query.shape[heads_dim]
+    B, H = operands[0].shape[0], num_heads
     b_axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)
     n_b = _math.prod(mesh.shape[a] for a in b_axes)
     if not b_axes or B % n_b:
         b_axes, n_b = None, 1
     tp = mesh.shape.get("tp", 1)
-    h_axis, n_h = ("tp", tp) if tp > 1 and H % tp == 0 else (None, 1)
-    spec = [b_axes, None, None, None]
-    spec[heads_dim] = h_axis
+    h_axis, n_h = ("tp", tp) if heads_dim is not None and tp > 1 \
+        and H % tp == 0 else (None, 1)
+    spec = [b_axes] + [None] * (operands[0].ndim - 1)
+    if heads_dim is not None:
+        spec[heads_dim] = h_axis
     spec = P(*spec)
 
-    def shard(q, k, v, kv_mask, seed):
+    def shard(kv_mask, seed, *operands):
         if seed is not None:
             b_off = lax.axis_index(b_axes) * (B // n_b) if b_axes else 0
             h_off = lax.axis_index(h_axis) * (H // n_h) if h_axis else 0
             seed = jnp.stack([seed, jnp.int32(b_off * H + h_off),
                               jnp.int32(H)])
-        return kernel(q, k, v, kv_mask, seed, causal, drop, interpret)
+        return call(operands, kv_mask, seed)
 
     return jax.shard_map(
         shard, mesh=mesh,
-        in_specs=(spec, spec, spec, P(b_axes, None), P()),
-        out_specs=spec, check_vma=False)(query, key, value, kv_mask, seed)
+        in_specs=(P(b_axes, None), P()) + (spec,) * len(operands),
+        out_specs=spec, check_vma=False)(kv_mask, seed, *operands)
+
+
+def _sharded_flash(kernel, heads_dim, mesh, batch_axes, query, key, value,
+                   kv_mask, seed, causal, drop, interpret=False):
+    """A q/k/v ``kernel`` on its shard of ``mesh`` (:func:`_shard_flash`)."""
+    return _shard_flash(
+        lambda qkv, m, s: kernel(*qkv, m, s, causal, drop, interpret),
+        (query, key, value), query.shape[heads_dim], heads_dim, mesh,
+        batch_axes, kv_mask, seed)
+
+
+def _flash_seed(drop, rng_key):
+    if drop > 0.0:
+        return jax.random.randint(rng_key, (), -2**31, 2**31 - 1,
+                                  dtype=jnp.int32)
+    return None
 
 
 def _flash_call(kernel, heads_dim, query, key, value, kv_mask, rng_key,
@@ -798,15 +818,42 @@ def _flash_call(kernel, heads_dim, query, key, value, kv_mask, rng_key,
     or serving lane tracing this op made a larger mesh visible
     (``parallel.mesh.mesh_scope``)."""
     from ..parallel.mesh import current_scope
-    seed = None
-    if drop > 0.0:
-        seed = jax.random.randint(rng_key, (), -2**31, 2**31 - 1,
-                                  dtype=jnp.int32)
+    seed = _flash_seed(drop, rng_key)
     scope = current_scope()
     if scope is None or scope[0].size == 1:
         return kernel(query, key, value, kv_mask, seed, causal, drop)
     return _sharded_flash(kernel, heads_dim, scope[0], scope[1], query,
                           key, value, kv_mask, seed, causal, drop)
+
+
+def _packed_flash_call(qkv, num_heads, kv_mask, rng_key, causal, drop):
+    """:func:`_flash_call` for the packed projection: the batch over the
+    visible mesh's batch axes, nothing over ``tp`` (the caller sends a
+    ``tp`` > 1 mesh down the split path, whose heads dim can shard)."""
+    from ..parallel.mesh import current_scope
+    from .pallas_kernels import flash_attention_packed
+    seed = _flash_seed(drop, rng_key)
+    scope = current_scope()
+    if scope is None or scope[0].size == 1:
+        return flash_attention_packed(qkv, num_heads, kv_mask, seed, causal,
+                                      drop)
+    return _shard_flash(
+        lambda ops, m, s: flash_attention_packed(ops[0], num_heads, m, s,
+                                                 causal, drop),
+        (qkv,), num_heads, None, scope[0], scope[1], kv_mask, seed)
+
+
+# Which implementation each attention call was traced into, counted where
+# the dispatcher decides (once a trace, not once a step): "packed" = the
+# flash kernels on the unsplit QKV projection, "flash" = the flash kernels
+# on separate q/k/v, "xla" = the composed softmax.
+_DISPATCHED = {"packed": 0, "flash": 0, "xla": 0}
+
+
+def attention_dispatch_stats():
+    """Snapshot of the dispatcher's path counts since the process
+    started: ``{"packed", "flash", "xla"}``."""
+    return dict(_DISPATCHED)
 
 
 def _on_accelerator():
@@ -833,26 +880,79 @@ def dot_product_attention(query, key, value, mask=None, dropout=0.0,
                           layout, rng_key, train)
 
 
+@register("_contrib_packed_self_attention",
+          state_binders={"rng_key": _bind_key, "train": _bind_train})
+def packed_self_attention(qkv, mask=None, num_heads=1, dropout=0.0,
+                          scaled=True, causal=False, rng_key=None,
+                          train=False):
+    """Self-attention straight from the packed QKV projection: ``qkv`` is
+    (B, S, 3*H*D) as the Dense produced it ([q | k | v] along the last
+    axis), the result (B, S, H*D) as the output projection reads it; mask,
+    dropout and causal as :func:`dot_product_attention`. Where the
+    head-fused flash kernels run (accelerator present, S a multiple of 128,
+    H*D of 128, mask reducible to (B, S), no ``tp`` > 1 in the visible
+    mesh) they read q, k and v as column blocks of the one array and the
+    backward returns one packed gradient: no split, no 4-D view, no
+    relayout copy around the kernels. Everywhere else the projection is
+    split into (B, S, H, D) views and attended as
+    ``dot_product_attention(..., layout="BSHD")`` does, bit for bit."""
+    with jax.named_scope("attention"):
+        B, S, C3 = qkv.shape
+        H, D = int(num_heads), C3 // (3 * int(num_heads))
+        kv_mask, mask_ok = _reduce_key_mask(mask, B, S)
+        if mask_ok and _packed_flash_usable((B, S, H, D), dropout, scaled,
+                                            rng_key, train):
+            _DISPATCHED["packed"] += 1
+            return _packed_flash_call(qkv, H, kv_mask, rng_key, causal,
+                                      float(dropout) if train else 0.0)
+        split = qkv.reshape(B, S, 3, H, D)
+        out = _attention(split[:, :, 0], split[:, :, 1], split[:, :, 2],
+                         mask, dropout, scaled, causal, "BSHD", rng_key,
+                         train)
+        return out.reshape(B, S, H * D)
+
+
+def _bshd_flash_usable(q_shape, dropout, scaled, rng_key, train):
+    """Whether the head-fused flash kernels take a self-attention whose q,
+    k and v are each ``q_shape`` (B, S, H, D) and whose mask, if any,
+    reduces to (B, S): decided from shapes, the platform and the knob
+    alone."""
+    from .pallas_kernels import flash_attention_bshd_usable
+    drop = float(dropout) if train else 0.0
+    return (scaled and (drop == 0.0 or rng_key is not None)
+            and flash_attention_bshd_usable(q_shape, q_shape[-1])
+            and _flash_enabled() and _on_accelerator())
+
+
+def _packed_flash_usable(q_shape, dropout, scaled, rng_key, train):
+    """The packed form runs wherever the separate-operand kernels would,
+    except under tensor parallelism: there the heads dim shards over
+    ``tp``, which one (B, S, 3*H*D) operand cannot express."""
+    from ..parallel.mesh import current_scope
+    scope = current_scope()
+    if scope is not None and scope[0].shape.get("tp", 1) > 1:
+        return False
+    return _bshd_flash_usable(q_shape, dropout, scaled, rng_key, train)
+
+
 def _attention(query, key, value, mask, dropout, scaled, causal, layout,
                rng_key, train):
     if layout == "BSHD" and getattr(query, "ndim", 0) == 4:
-        # (B, S, H, D) — the transformer's natural layout straight out of
-        # the qkv projection. The head-fused kernel consumes it with NO
-        # physical transpose (the BHSD kernels force one on each side:
-        # ~12% of a BERT-base s128 span per the XPlane study in PERF.md).
-        from .pallas_kernels import (flash_attention_bshd,
-                                     flash_attention_bshd_usable)
+        # (B, S, H, D) views of the qkv projection: the head-fused kernels
+        # read them as (B, S, H*D) with no head transpose (the BHSD kernels
+        # force one on each side); the 4-D views themselves still cost a
+        # relayout each way on the chip, which the packed entry spares
         kv_mask, mask_ok = _reduce_key_mask(mask, query.shape[0],
                                             key.shape[1])
-        drop = float(dropout) if train else 0.0
-        if (scaled and mask_ok and key.shape == query.shape
+        if (mask_ok and key.shape == query.shape
                 and value.shape == query.shape
-                and (drop == 0.0 or rng_key is not None)
-                and flash_attention_bshd_usable(query.shape,
-                                                query.shape[-1])
-                and _flash_enabled() and _on_accelerator()):
+                and _bshd_flash_usable(query.shape, dropout, scaled,
+                                       rng_key, train)):
+            from .pallas_kernels import flash_attention_bshd
+            _DISPATCHED["flash"] += 1
             return _flash_call(flash_attention_bshd, 2, query, key, value,
-                               kv_mask, rng_key, causal, drop)
+                               kv_mask, rng_key, causal,
+                               float(dropout) if train else 0.0)
         # fallback: run the BHSD path and restore the layout; XLA fuses
         # these transposes into the surrounding einsums
         out = _attention(
@@ -875,8 +975,10 @@ def _attention(query, key, value, mask, dropout, scaled, causal, layout,
                 and (drop == 0.0 or rng_key is not None)
                 and flash_attention_usable(query.shape, causal)
                 and _on_accelerator()):
+            _DISPATCHED["flash"] += 1
             return _flash_call(flash_attention, 1, query, key, value,
                                kv_mask, rng_key, causal, drop)
+    _DISPATCHED["xla"] += 1
     d = query.shape[-1]
     scores = jnp.einsum("...qd,...kd->...qk", query, key)
     if scaled:

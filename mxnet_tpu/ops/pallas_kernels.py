@@ -36,7 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 __all__ = ["flash_attention", "flash_attention_bshd",
-           "flash_attention_usable", "flash_attention_bshd_usable"]
+           "flash_attention_packed", "flash_attention_usable",
+           "flash_attention_bshd_usable"]
 
 import os as _os
 
@@ -582,14 +583,18 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
 # ===================================================================== BSHD
-# Head-fused kernels operating directly on (B, S, H, D) tensors viewed as
-# (B, S, H*D): the transformer's natural layout straight out of the qkv
-# projection. Eliminates the (B,T,H,D)->(B,H,T,D) physical transposes the
-# BHSD kernels force around every attention (XPlane: ~12% of a BERT-base
-# s128 training span). Mosaic's tiling rule forbids per-head blocks
+# Head-fused kernels operating on (B, S, H*D) rows: the transformer's
+# natural layout straight out of the qkv projection. Eliminates the
+# (B,T,H,D)->(B,H,T,D) physical transposes the BHSD kernels force around
+# every attention. Mosaic's tiling rule forbids per-head blocks
 # ((..,1,D) over (..,H,D)), so each program loads full (blk, H*D) rows —
 # every byte of which it needs — and statically unrolls the head loop.
-# Requires H*D % 128 == 0.
+# Requires H*D % 128 == 0. The operands are three (B, S, H*D) arrays
+# (`flash_attention_bshd`: (B, S, H, D) views, whose reshapes cost a
+# relayout copy each way on the chip) or column blocks 0, 1, 2 of the one
+# packed (B, S, 3*H*D) projection (`flash_attention_packed`: no view, no
+# copy, one packed gradient written in place): same kernels, the block
+# specs' column index is the only difference.
 
 def flash_attention_bshd_usable(q_shape, head_dim):
     B, S, HD = q_shape[0], q_shape[1], int(np.prod(q_shape[2:]))
@@ -643,9 +648,9 @@ def _bshd_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
         lse_ref[0, 0, :, h] = m_i + jnp.log(l_safe)
 
 
-def _bshd_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, mask_ref, dq_ref, *, scale, causal,
-                        blk_q, blk_k, seq_len, dropout, has_mask,
+def _bshd_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
+                        lse_ref, mask_ref, dq_ref, delta_ref, *, scale,
+                        causal, blk_q, blk_k, seq_len, dropout, has_mask,
                         num_heads, head_dim):
     b = pl.program_id(0)
     qi = pl.program_id(1)
@@ -655,7 +660,12 @@ def _bshd_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         q = q_ref[0, :, h * D:(h + 1) * D]
         do = do_ref[0, :, h * D:(h + 1) * D]
         lse = lse_ref[0, 0, :, h]
-        delta = delta_ref[0, 0, :, h]
+        # delta = rowsum_d(dO o O): this program holds both blocks, so the
+        # product never goes to HBM; written out for the dkdv kernel
+        delta = jnp.sum(do.astype(jnp.float32)
+                        * o_ref[0, :, h * D:(h + 1) * D].astype(jnp.float32),
+                        axis=-1)
+        delta_ref[0, 0, :, h] = delta
         seed, bh = _seed_parts(seed_ref, b, jnp.int32(h))
 
         def body(kb, dq_acc, h=h, q=q, do=do, lse=lse, delta=delta,
@@ -733,128 +743,212 @@ def _bshd_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0, :, h * D:(h + 1) * D] = dv.astype(dv_ref.dtype)
 
 
-def _bshd_prep(q, k, v, kv_mask, seed):
-    B, S, H, D = q.shape
-    qf = q.reshape(B, S, H * D)
-    kf = k.reshape(B, S, H * D)
-    vf = v.reshape(B, S, H * D)
+def _bshd_mask(kv_mask, B, S):
     if kv_mask is None:
-        mr = jnp.ones((B, 1, S), jnp.int32)
-    else:
-        mr = kv_mask.astype(jnp.int32).reshape(B, 1, S)
-    return qf, kf, vf, mr, _seed_operand(seed, H)
+        return jnp.ones((B, 1, S), jnp.int32)   # dummy operand, loads elided
+    return kv_mask.astype(jnp.int32).reshape(B, 1, S)
 
 
-def _bshd_fwd_impl(q, k, v, kv_mask, seed, causal, dropout, interpret):
-    B, S, H, D = q.shape
-    HD = H * D
-    scale = float(1.0 / np.sqrt(D))
-    blk_q, blk_k = _pick_blocks_bshd(S, causal, HD, q.dtype.itemsize)
-    qf, kf, vf, mr, sr = _bshd_prep(q, k, v, kv_mask, seed)
+def _bshd_specs(B, S, HD, H, blk_q, blk_k, packed):
+    """Block specs of the head-fused kernels' operands. q, k and v are
+    read at a COLUMN BLOCK (in units of H*D) of the array each comes in:
+    0, 0, 0 of three (B, S, H*D) arrays, or 0, 1, 2 of the one ``packed``
+    (B, S, 3*H*D) projection — all that differs between the two forms on
+    the way in."""
+    cq, ck, cv = (0, 1, 2) if packed else (0, 0, 0)
     n_q = S // blk_q
+
+    def rows(blk, col):
+        return pl.BlockSpec((1, blk, HD), lambda b, i: (b, i, col))
+
+    def full(col):
+        return pl.BlockSpec((1, S, HD), lambda b, i: (b, 0, col))
+
+    return dict(
+        seed=pl.BlockSpec((1, 3), lambda b, i: (0, 0)),
+        mask=pl.BlockSpec((1, 1, S), lambda b, i: (b, 0, 0)),
+        q_blk=rows(blk_q, cq), q_full=full(cq),
+        k_blk=rows(blk_k, ck), k_full=full(ck),
+        v_blk=rows(blk_k, cv), v_full=full(cv),
+        blkq=rows(blk_q, 0), blkk=rows(blk_k, 0), full=full(0),
+        lse_blk=pl.BlockSpec((1, 1, blk_q, H), lambda b, i: (b, i, 0, 0)),
+        lse_full=pl.BlockSpec((1, n_q, blk_q, H),
+                              lambda b, i: (b, 0, 0, 0)))
+
+
+def _bshd_fwd_impl(qf, kf, vf, packed, num_heads, kv_mask, seed, causal,
+                   dropout, interpret):
+    """Forward over 3-D operands: three (B, S, H*D) arrays, or the one
+    ``packed`` (B, S, 3*H*D) projection given three times. Returns ``(out
+    (B, S, H*D), lse (B, n_q, blk_q, H))``."""
+    B, S = qf.shape[:2]
+    H = num_heads
+    HD = qf.shape[2] // (3 if packed else 1)
+    D = HD // H
+    scale = float(1.0 / np.sqrt(D))
+    blk_q, blk_k = _pick_blocks_bshd(S, causal, HD, qf.dtype.itemsize)
+    n_q = S // blk_q
+    sp = _bshd_specs(B, S, HD, H, blk_q, blk_k, packed)
     kernel = functools.partial(
         _bshd_fwd_kernel, scale=scale, causal=causal, blk_q=blk_q,
         blk_k=blk_k, seq_len=S, dropout=float(dropout),
         has_mask=kv_mask is not None, num_heads=H, head_dim=D)
     call = pl.pallas_call(
         kernel,
-        out_shape=(jax.ShapeDtypeStruct((B, S, HD), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((B, S, HD), qf.dtype),
                    jax.ShapeDtypeStruct((B, n_q, blk_q, H),
                                         jnp.float32)),
         grid=(B, n_q),
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda b, i: (0, 0)),
-            pl.BlockSpec((1, blk_q, HD), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, S, HD), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, S, HD), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, S), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, blk_q, HD), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, 1, blk_q, H),
-                                lambda b, i: (b, i, 0, 0))),
+        in_specs=[sp["seed"], sp["q_blk"], sp["k_full"], sp["v_full"],
+                  sp["mask"]],
+        out_specs=(sp["blkq"], sp["lse_blk"]),
         interpret=interpret,
         name="flash_bshd_fwd",
     )
+    # x64 off: under the package's jax_enable_x64 an index map's Python
+    # ints become i64 and Mosaic cannot legalize the map's return
     with jax.enable_x64(False):
-        out, lse = call(sr, qf, kf, vf, mr)
-    return out.reshape(B, S, H, D), lse
+        return call(_seed_operand(seed, H), qf, kf, vf,
+                    _bshd_mask(kv_mask, B, S))
 
 
-def _bshd_bwd_impl(q, k, v, kv_mask, seed, o, lse, g, causal, dropout,
-                   interpret):
-    B, S, H, D = q.shape
-    HD = H * D
+def _bshd_bwd_dkv_packed_kernel(*refs, num_heads, head_dim, **kw):
+    """The dkdv kernel writing dk and dv as the two halves of ONE
+    (blk_k, 2*H*D) output block: columns [H*D, 3*H*D) of the packed
+    gradient, whose buffer (the dq call's output, aliased) it never
+    reads."""
+    *ins, _packed_grad, dkv_ref = refs
+    hd = num_heads * head_dim
+    _bshd_bwd_dkv_kernel(*ins, dkv_ref.at[:, :, :hd], dkv_ref.at[:, :, hd:],
+                         num_heads=num_heads, head_dim=head_dim, **kw)
+
+
+def _bshd_bwd_impl(qf, kf, vf, packed, num_heads, kv_mask, seed, o, lse, g,
+                   causal, dropout, interpret):
+    """Backward over the forward's 3-D operands; ``o`` and ``g`` are
+    (B, S, H*D). Returns 3-D ``(dq, dk, dv)`` for separate operands and
+    ONE (B, S, 3*H*D) gradient for the packed projection: both calls
+    write their column blocks of the one buffer (dq: block 0; dkdv: blocks
+    1 and 2 of the buffer it takes over from the dq call), so no
+    concatenate, pad or copy assembles it afterwards."""
+    B, S = qf.shape[:2]
+    H = num_heads
+    HD = o.shape[2]
+    D = HD // H
     scale = float(1.0 / np.sqrt(D))
-    blk_q, blk_k = _pick_blocks_bshd(S, causal, HD, q.dtype.itemsize)
-    qf, kf, vf, mr, sr = _bshd_prep(q, k, v, kv_mask, seed)
-    gf = g.reshape(B, S, HD)
-    # delta = rowsum_d(dO o O) per head: (B, nQ, blk_q, H)
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                          # (B, S, H)
+    blk_q, blk_k = _pick_blocks_bshd(S, causal, HD, qf.dtype.itemsize)
     n_q = S // blk_q
-    delta = delta.reshape(B, n_q, blk_q, H)
     common = dict(scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
                   seq_len=S, dropout=float(dropout),
                   has_mask=kv_mask is not None, num_heads=H, head_dim=D)
-    seed_spec = pl.BlockSpec((1, 3), lambda b, i: (0, 0))
-    mask_spec = pl.BlockSpec((1, 1, S), lambda b, i: (b, 0, 0))
-    full_spec = pl.BlockSpec((1, S, HD), lambda b, i: (b, 0, 0))
-    blkq_spec = pl.BlockSpec((1, blk_q, HD), lambda b, i: (b, i, 0))
-    blkk_spec = pl.BlockSpec((1, blk_k, HD), lambda b, i: (b, i, 0))
-    lse_blk = pl.BlockSpec((1, 1, blk_q, H), lambda b, i: (b, i, 0, 0))
-    lse_full = pl.BlockSpec((1, n_q, blk_q, H),
-                            lambda b, i: (b, 0, 0, 0))
+    sp = _bshd_specs(B, S, HD, H, blk_q, blk_k, packed)
+    grad = jax.ShapeDtypeStruct((B, S, (3 if packed else 1) * HD), qf.dtype)
 
+    # the dq kernel also computes delta = rowsum_d(dO o O) per head, in the
+    # LSE's (B, n_q, blk_q, H) layout, which the dkdv kernel then reads
     dq_call = pl.pallas_call(
         functools.partial(_bshd_bwd_dq_kernel, **common),
-        out_shape=jax.ShapeDtypeStruct((B, S, HD), q.dtype),
+        out_shape=(grad, jax.ShapeDtypeStruct((B, n_q, blk_q, H),
+                                              jnp.float32)),
         grid=(B, n_q),
-        in_specs=[seed_spec, blkq_spec, full_spec, full_spec, blkq_spec,
-                  lse_blk, lse_blk, mask_spec],
-        out_specs=blkq_spec,
+        in_specs=[sp["seed"], sp["q_blk"], sp["k_full"], sp["v_full"],
+                  sp["blkq"], sp["blkq"], sp["lse_blk"], sp["mask"]],
+        out_specs=(sp["blkq"], sp["lse_blk"]),
         interpret=interpret,
         name="flash_bshd_dq",
     )
+    # dkdv: two (B, S, H*D) outputs, or — packed — the two halves of ONE
+    # element-indexed block (Mosaic wants every dim so indexed, or none):
+    # rows i*blk_k on, 2*H*D columns from column H*D on, of the dq call's
+    # buffer, taken over through the alias and never read
+    dkv = dict(kernel=_bshd_bwd_dkv_kernel, out_shape=(grad, grad),
+               out_specs=(sp["blkk"], sp["blkk"]), in_specs=[], aliases={})
+    if packed:
+        dkv = dict(
+            kernel=_bshd_bwd_dkv_packed_kernel, out_shape=grad,
+            out_specs=pl.BlockSpec(
+                (pl.Element(1), pl.Element(blk_k), pl.Element(2 * HD)),
+                lambda b, i: (b, i * blk_k, HD)),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)], aliases={8: 0})
     dkv_call = pl.pallas_call(
-        functools.partial(_bshd_bwd_dkv_kernel, **common),
-        out_shape=(jax.ShapeDtypeStruct((B, S, HD), k.dtype),
-                   jax.ShapeDtypeStruct((B, S, HD), v.dtype)),
+        functools.partial(dkv["kernel"], **common),
+        out_shape=dkv["out_shape"],
         grid=(B, S // blk_k),
-        in_specs=[seed_spec, full_spec, blkk_spec, blkk_spec, full_spec,
-                  lse_full, lse_full, mask_spec],
-        out_specs=(blkk_spec, blkk_spec),
+        in_specs=[sp["seed"], sp["q_full"], sp["k_blk"], sp["v_blk"],
+                  sp["full"], sp["lse_full"], sp["lse_full"], sp["mask"]]
+        + dkv["in_specs"],
+        out_specs=dkv["out_specs"],
+        input_output_aliases=dkv["aliases"],
         interpret=interpret,
         name="flash_bshd_dkv",
     )
+    sr, mr = _seed_operand(seed, H), _bshd_mask(kv_mask, B, S)
     with jax.enable_x64(False):
-        dq = dq_call(sr, qf, kf, vf, gf, lse, delta, mr)
-        dk, dv = dkv_call(sr, qf, kf, vf, gf, lse, delta, mr)
-    return (dq.reshape(B, S, H, D), dk.reshape(B, S, H, D),
-            dv.reshape(B, S, H, D))
+        dq, delta = dq_call(sr, qf, kf, vf, g, o, lse, mr)
+        if packed:
+            return dkv_call(sr, qf, kf, vf, g, lse, delta, mr, dq)
+        dk, dv = dkv_call(sr, qf, kf, vf, g, lse, delta, mr)
+    return dq, dk, dv
+
+
+def _flat3(x):
+    B, S, H, D = x.shape
+    return x.reshape(B, S, H * D)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def flash_attention_bshd(q, k, v, kv_mask=None, seed=None, causal=False,
                          dropout=0.0, interpret=False):
-    """Blockwise exact attention in (B, S, H, D) layout — no physical
-    transpose between the qkv projection and the kernel. Same mask/
-    dropout semantics as `flash_attention`."""
-    out, _ = _bshd_fwd_impl(q, k, v, kv_mask, seed, causal, dropout,
-                            interpret)
-    return out
+    """Blockwise exact attention over (B, S, H, D) operands, head-fused:
+    no (B,S,H,D)->(B,H,S,D) transpose between the qkv projection and the
+    kernel. Same mask/dropout semantics as `flash_attention`. A caller
+    that holds the packed projection uses :func:`flash_attention_packed`,
+    which also spares the reshapes' relayouts."""
+    return _fab_fwd(q, k, v, kv_mask, seed, causal, dropout, interpret)[0]
 
 
 def _fab_fwd(q, k, v, kv_mask, seed, causal, dropout, interpret):
-    out, lse = _bshd_fwd_impl(q, k, v, kv_mask, seed, causal, dropout,
+    out, lse = _bshd_fwd_impl(_flat3(q), _flat3(k), _flat3(v), False,
+                              q.shape[2], kv_mask, seed, causal, dropout,
                               interpret)
-    return out, (q, k, v, kv_mask, seed, out, lse)
+    return out.reshape(q.shape), (q, k, v, kv_mask, seed, out, lse)
 
 
 def _fab_bwd(causal, dropout, interpret, res, g):
     q, k, v, kv_mask, seed, o, lse = res
-    dq, dk, dv = _bshd_bwd_impl(q, k, v, kv_mask, seed, o, lse, g,
-                                causal, dropout, interpret)
-    return dq, dk, dv, None, None
+    grads = _bshd_bwd_impl(_flat3(q), _flat3(k), _flat3(v), False,
+                           q.shape[2], kv_mask, seed, o, lse, _flat3(g),
+                           causal, dropout, interpret)
+    return tuple(d.reshape(q.shape) for d in grads) + (None, None)
 
 
 flash_attention_bshd.defvjp(_fab_fwd, _fab_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 4, 5, 6))
+def flash_attention_packed(qkv, num_heads, kv_mask=None, seed=None,
+                           causal=False, dropout=0.0, interpret=False):
+    """Self-attention straight from the packed projection: ``qkv`` is
+    (B, S, 3*H*D) as the QKV Dense produced it ([q | k | v] along the last
+    axis), the result (B, S, H*D) as the output projection reads it. The
+    same three kernels as :func:`flash_attention_bshd`, handed the one
+    array three times at column blocks 0, 1, 2; nothing has more than three
+    dimensions, and the backward returns ONE (B, S, 3*H*D) gradient."""
+    return _fap_fwd(qkv, num_heads, kv_mask, seed, causal, dropout,
+                    interpret)[0]
+
+
+def _fap_fwd(qkv, num_heads, kv_mask, seed, causal, dropout, interpret):
+    out, lse = _bshd_fwd_impl(qkv, qkv, qkv, True, num_heads, kv_mask,
+                              seed, causal, dropout, interpret)
+    return out, (qkv, kv_mask, seed, out, lse)
+
+
+def _fap_bwd(num_heads, causal, dropout, interpret, res, g):
+    qkv, kv_mask, seed, o, lse = res
+    d_qkv = _bshd_bwd_impl(qkv, qkv, qkv, True, num_heads, kv_mask,
+                           seed, o, lse, g, causal, dropout, interpret)
+    return d_qkv, None, None
+
+
+flash_attention_packed.defvjp(_fap_fwd, _fap_bwd)

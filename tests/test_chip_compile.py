@@ -121,3 +121,79 @@ def test_bshd_dp_sharded_compiles_four_chips(topo, monkeypatch):
     # through the dispatcher the kernels lie under its scope, backward too
     assert re.search(r'op_name="[^"]*\battention\b[^"]*\bflash_bshd_dkv\b',
                      text)
+
+
+# ------------------------------------------- the packed entry, one block
+
+_COPY_OF_QKV = re.compile(
+    r"= bf16\[96,512,(768|2304)\]\S* copy\(|"
+    r"= bf16\[24,512,(768|2304)\]\S* copy\(")
+
+
+def _attention_block(mesh_scope_args=None):
+    """One BERT-base attention block as the model runs it: QKV Dense (no
+    bias) -> the packed entry under a padding mask -> output projection;
+    sum-of-squares loss, gradients of the input and both weights."""
+    def loss(x, w_qkv, w_proj, mask):
+        qkv = jnp.einsum("bsc,oc->bso", x, w_qkv)
+        out = nn_ops.packed_self_attention.fn(
+            qkv, mask=mask[:, None, None, :], num_heads=12, dropout=0.0,
+            causal=False)
+        y = jnp.einsum("bsc,oc->bso", out, w_proj)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    def scoped(*args):
+        if mesh_scope_args is None:
+            return loss(*args)
+        with mesh_scope(*mesh_scope_args):
+            return loss(*args)
+    return jax.jit(jax.value_and_grad(scoped, argnums=(0, 1, 2)))
+
+
+def _block_specs(batch, rows, replicated):
+    bf16 = jnp.bfloat16
+    return (jax.ShapeDtypeStruct((batch, 512, 768), bf16, sharding=rows),
+            jax.ShapeDtypeStruct((2304, 768), bf16, sharding=replicated),
+            jax.ShapeDtypeStruct((768, 768), bf16, sharding=replicated),
+            jax.ShapeDtypeStruct((batch, 512), jnp.int32, sharding=rows))
+
+
+def _assert_packed_block(text):
+    """Three Mosaic calls under the ``attention`` scope, and none of the
+    layout passes the split form paid: no ``pad_add`` fusion (the gradient
+    of the QKV split) and no bf16 copy of a q/k/v- or qkv-sized tensor."""
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("flash_bshd_fwd", "flash_bshd_dq", "flash_bshd_dkv"):
+        assert re.search(r'op_name="[^"]*\battention\b[^"]*\b%s\b[^"]*'
+                         r'/pallas_call"' % kernel, text), kernel
+    assert "pad_add" not in text
+    assert not _COPY_OF_QKV.search(text), _COPY_OF_QKV.search(text).group(0)
+
+
+def test_packed_attention_block_compiles_without_layout_copies(topo,
+                                                               monkeypatch):
+    """The benchmark cell's block, b96 x s512, for one described chip."""
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    before = nn_ops.attention_dispatch_stats()
+    text = _attention_block().lower(
+        *_block_specs(96, one, one)).compile().as_text()
+    _assert_packed_block(text)
+    after = nn_ops.attention_dispatch_stats()
+    assert after["packed"] == before["packed"] + 1
+    assert (after["flash"], after["xla"]) == (before["flash"],
+                                              before["xla"])
+
+
+def test_packed_attention_block_dp_sharded_compiles_four_chips(topo,
+                                                               monkeypatch):
+    """The same block inside a dp=4 program (global batch 96, 24 rows a
+    chip): the packed kernels under ``shard_map`` over the batch, the
+    sharded projection reaching them without an all-gather."""
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    mesh = Mesh(np.array(topo.devices).reshape((4, 1, 1, 1, 1)), AXES)
+    text = _attention_block((mesh, ("dp",))).lower(*_block_specs(
+        96, NamedSharding(mesh, P("dp")),
+        NamedSharding(mesh, P()))).compile().as_text()
+    _assert_packed_block(text)
+    assert "all-gather" not in text

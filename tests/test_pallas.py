@@ -375,3 +375,161 @@ def test_dispatch_shards_flash_under_a_visible_mesh(monkeypatch):
         attend()
     assert calls == ["bare", "bare", (4, ("dp",))]
     assert parallel.current_scope() is None
+
+
+# --------------------------------------- the packed QKV projection (PR 26)
+
+def _split_heads(qkv, heads):
+    """(B, S, 3*H*D) -> q, k, v as the (B, S, H, D) views the blocks used
+    to slice."""
+    B, S, C3 = qkv.shape
+    split = qkv.reshape(B, S, 3, heads, C3 // (3 * heads))
+    return split[:, :, 0], split[:, :, 1], split[:, :, 2]
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["nodrop", "drop0.1"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "masked"])
+def test_packed_kernels_equal_the_split_kernels_bit_for_bit(masked, causal,
+                                                            dropout):
+    """The same three kernels read q, k, v as column blocks of the packed
+    projection: output and the ONE packed gradient equal the separate-
+    operand call's output and concatenate([dq, dk, dv]) exactly."""
+    from mxnet_tpu.ops.pallas_kernels import (flash_attention_bshd,
+                                              flash_attention_packed)
+    B, S, H, D = 2, 256, 4, 32
+    qkv = _rand((B, S, 3 * H * D), 130).astype(jnp.bfloat16)
+    kv_mask = None
+    if masked:
+        lens = np.array([160, S])
+        kv_mask = jnp.asarray((np.arange(S)[None, :] < lens[:, None])
+                              .astype("int32"))
+    seed = jnp.asarray(23, jnp.int32) if dropout else None
+    g = _rand((B, S, H * D), 131).astype(jnp.bfloat16)
+
+    out, vjp = jax.vjp(lambda a: flash_attention_packed(
+        a, H, kv_mask, seed, causal, dropout, True), qkv)
+    (d_qkv,) = vjp(g)
+    assert out.shape == (B, S, H * D) and d_qkv.shape == qkv.shape
+
+    out_s, vjp_s = jax.vjp(lambda q, k, v: flash_attention_bshd(
+        q, k, v, kv_mask, seed, causal, dropout, True),
+        *_split_heads(qkv, H))
+    dq, dk, dv = vjp_s(g.reshape(B, S, H, D))
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(out_s.reshape(B, S, H * D), np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(d_qkv, np.float32),
+        np.asarray(jnp.concatenate(
+            [d.reshape(B, S, H * D) for d in (dq, dk, dv)], -1),
+            np.float32))
+
+
+def test_packed_entry_paths_and_fallbacks(monkeypatch):
+    """The packed entry takes the packed kernels where the head-fused
+    kernels run and no ``tp`` > 1 mesh is visible, and is otherwise today's
+    split + ``dot_product_attention(layout="BSHD")`` bit for bit: on the
+    CPU backend, at an unaligned length, under tensor parallelism. The
+    dispatch counter names the path each call took."""
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import nn as nn_ops
+    from mxnet_tpu.ops import pallas_kernels as pk
+    B, H, D = 2, 2, 64
+    real_bshd, real_packed = pk.flash_attention_bshd, \
+        pk.flash_attention_packed
+
+    def took(fn):
+        before = nn_ops.attention_dispatch_stats()
+        out = fn()
+        after = nn_ops.attention_dispatch_stats()
+        return out, {k: after[k] - before[k] for k in after
+                     if after[k] != before[k]}
+
+    def entry(qkv, mask=None):
+        return nn_ops.packed_self_attention.fn(qkv, mask=mask, num_heads=H)
+
+    def todays(qkv, mask=None):
+        S = qkv.shape[1]
+        out = nn_ops.dot_product_attention.fn(
+            *_split_heads(qkv, H), mask=mask, layout="BSHD")
+        return out.reshape(B, S, H * D)
+
+    qkv = _rand((B, 256, 3 * H * D), 140)
+    mask = jnp.asarray((np.arange(256)[None, :] < np.array([[160], [256]]))
+                       .astype("int32"))[:, None, None, :]
+
+    # the CPU backend: no kernel, the composed softmax
+    out, path = took(lambda: entry(qkv, mask))
+    assert path == {"xla": 1}
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(todays(qkv, mask)))
+
+    # from here on a chip is "present"; the kernels run interpreted
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    monkeypatch.setattr(nn_ops, "_flash_enabled", lambda: True)
+    monkeypatch.setattr(
+        pk, "flash_attention_bshd",
+        lambda q, k, v, m, s, c, d, interpret=False:
+        real_bshd(q, k, v, m, s, c, d, True))
+    monkeypatch.setattr(
+        pk, "flash_attention_packed",
+        lambda a, h, m, s, c, d, interpret=False:
+        real_packed(a, h, m, s, c, d, True))
+
+    out, path = took(lambda: entry(qkv, mask))
+    assert path == {"packed": 1}
+    ref, path = took(lambda: todays(qkv, mask))
+    assert path == {"flash": 1}
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+    # S = 200: no 128-multiple, neither kernel family takes it
+    odd = _rand((B, 200, 3 * H * D), 141)
+    out, path = took(lambda: entry(odd))
+    assert path == {"xla": 1}
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(todays(odd)))
+
+    # a visible mesh with tp = 2: the heads shard, so the split kernels
+    with parallel.mesh_scope(parallel.make_mesh(dp=2, tp=2), ("dp",)):
+        out, path = took(lambda: jax.jit(lambda a, m: entry(a, m))(qkv, mask))
+        assert path == {"flash": 1}
+        ref = jax.jit(todays)(qkv, mask)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+    # dp alone: the packed kernels under shard_map over the batch (a
+    # fresh function: jit's cache does not see the mesh scope)
+    with parallel.mesh_scope(parallel.make_mesh(dp=2), ("dp",)):
+        out, path = took(lambda: jax.jit(lambda a, m: entry(a, m))(qkv, mask))
+    assert path == {"packed": 1}
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_sharded_packed_flash_draws_the_unsharded_dropout_mask():
+    """The packed call under shard_map over dp folds each shard's batch
+    offset into the seed operand like the q/k/v call: output and packed
+    gradient equal the unsharded call's."""
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import nn as nn_ops
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_packed
+    mesh = parallel.make_mesh(dp=4)
+    batch, H, S, D = 4, 2, 128, 64
+    qkv = jnp.repeat(_rand((1, S, 3 * H * D), 150), batch, 0)
+    seed = jnp.asarray(7, jnp.int32)
+
+    def sharded(a):
+        return nn_ops._shard_flash(
+            lambda ops, m, s: flash_attention_packed(ops[0], H, m, s, False,
+                                                     0.5, True),
+            (a,), H, None, mesh, ("dp",), None, seed)
+
+    def unsharded(a):
+        return flash_attention_packed(a, H, None, seed, False, 0.5, True)
+
+    out = np.asarray(jax.jit(sharded)(qkv))
+    for b in range(1, batch):
+        assert not np.allclose(out[0], out[b])
+    np.testing.assert_array_equal(out, np.asarray(unsharded(qkv)))
+    grad = jax.jit(jax.grad(lambda a: (sharded(a) ** 2).sum()))(qkv)
+    ref = jax.grad(lambda a: (unsharded(a) ** 2).sum())(qkv)
+    np.testing.assert_allclose(np.asarray(grad), np.asarray(ref),
+                               atol=1e-4, rtol=1e-4)

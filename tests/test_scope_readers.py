@@ -67,14 +67,20 @@ def _step_locations():
     return set(re.findall(r'"(jit\(step\)/[^"]*)"', text))
 
 
+def _interpret_packed_kernels(monkeypatch):
+    """A chip is "present" and the packed kernels run interpreted: the path
+    BERT's and TransformerLM's blocks take on the chip."""
+    kernel = pk.flash_attention_packed
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    monkeypatch.setattr(
+        pk, "flash_attention_packed",
+        lambda qkv, h, m, s, c, d: kernel(qkv, h, m, s, c, d, True))
+
+
 @pytest.mark.parametrize("path", ["xla", "flash_interpret"])
 def test_traced_step_carries_the_programs_scopes(path, monkeypatch):
     if path == "flash_interpret":
-        kernel = pk.flash_attention_bshd
-        monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
-        monkeypatch.setattr(
-            pk, "flash_attention_bshd",
-            lambda q, k, v, m, s, c, d: kernel(q, k, v, m, s, c, d, True))
+        _interpret_packed_kernels(monkeypatch)
     names = _step_locations()
     layers = {}
     for name in names:
@@ -314,3 +320,86 @@ def test_scheduler_self_time_reader():
     assert read({"kind": "serve", "spans": spans}) == pytest.approx(12.0)
     assert read({"kind": "serve", "spans": spans[1:4]}) is None
     assert read({"kind": "train", "spans": spans}) is None
+
+
+@pytest.mark.parametrize("path,expected", [
+    ("flash_interpret", 100.0), ("xla", 0.0), ("no_counter", None),
+    ("nothing_traced", None)])
+def test_attention_packed_reader_on_a_tiny_traced_step(path, expected,
+                                                       monkeypatch):
+    """``attention_packed_pct.train`` over the dispatcher's own counter:
+    every attention of a tiny BERT step traced through the packed kernels
+    reads 100, through the composed softmax 0; a program without the
+    counter (the parent), or one that traced no attention, reads None."""
+    monkeypatch.setattr(nn_ops, "_DISPATCHED",
+                        dict.fromkeys(nn_ops._DISPATCHED, 0))
+    if path == "flash_interpret":
+        _interpret_packed_kernels(monkeypatch)
+    if path != "nothing_traced":
+        _step_locations()
+        counts = nn_ops.attention_dispatch_stats()
+        assert sum(counts.values()) >= 2        # bert_tiny: two layers
+    if path == "no_counter":
+        monkeypatch.delattr(nn_ops, "attention_dispatch_stats")
+    read = load_reader("attention_packed_pct.train", METRIC_DIR)
+    assert read(_obs()) == expected
+    assert read(_obs(kind="serve")) is None
+
+
+@pytest.mark.parametrize("path", ["xla", "packed_interpret"])
+def test_prefill_through_the_packed_entry_equals_the_split(path,
+                                                           monkeypatch):
+    """``TransformerLM.prefill`` (``forward_kv`` in every block) hands the
+    packed projection to the attention and slices k and v for the arena
+    only: the same next tokens, the same k and v as the split it replaced.
+    On the CPU the packed entry IS that split (bit for bit); with the
+    packed kernels interpreted the k/v are still the projection's own
+    slices and the tokens agree."""
+    from mxnet_tpu.models.transformer import (MultiHeadAttention,
+                                              TransformerLM)
+    # heads x head_dim = 128: the narrowest the head-fused kernels take
+    net = TransformerLM(50, units=128, num_layers=2, num_heads=2,
+                        max_len=256)
+    net.initialize(mx.init.Normal(0.5))
+    rows, seq = 2, 128
+    tokens = nd.array(np.random.RandomState(7).randint(0, 50, (rows, seq)),
+                      dtype="int32")
+    lengths = nd.array(np.array([100, 128]), dtype="int32")
+
+    def split_forward_kv(self, x, kv_mask=None):
+        B, T, C = x.shape
+        q, k, v = self._split_qkv(x)
+        out = nd._contrib_dot_product_attention(
+            q, k, v, mask=kv_mask, dropout=self._dropout,
+            causal=self._causal, layout="BSHD")
+        return self.proj(out.reshape((B, T, C))), k, v
+
+    packed = MultiHeadAttention.forward_kv
+    monkeypatch.setattr(MultiHeadAttention, "forward_kv", split_forward_kv)
+    want_logits, want_cache = net.prefill(tokens, lengths)
+    monkeypatch.setattr(MultiHeadAttention, "forward_kv", packed)
+    before = nn_ops.attention_dispatch_stats()
+    if path == "packed_interpret":
+        _interpret_packed_kernels(monkeypatch)
+    logits, cache = net.prefill(tokens, lengths)
+    took = {k: v - before[k]
+            for k, v in nn_ops.attention_dispatch_stats().items()}
+    assert took == {"packed": net.num_layers if path != "xla" else 0,
+                    "flash": 0, "xla": net.num_layers if path == "xla" else 0}
+
+    np.testing.assert_array_equal(logits.asnumpy().argmax(-1),
+                                  want_logits.asnumpy().argmax(-1))
+    if path == "xla":
+        np.testing.assert_array_equal(logits.asnumpy(),
+                                      want_logits.asnumpy())
+    for layer, ((k, v), (want_k, want_v)) in enumerate(
+            zip(cache, want_cache)):
+        assert k.shape == (rows, seq, net.num_heads, net.head_dim)
+        # past the first layer k/v inherit the attention's rounding
+        exact = path == "xla" or layer == 0
+        for got, want in ((k, want_k), (v, want_v)):
+            if exact:
+                np.testing.assert_array_equal(got.asnumpy(), want.asnumpy())
+            else:
+                np.testing.assert_allclose(got.asnumpy(), want.asnumpy(),
+                                           atol=2e-3, rtol=2e-3)
