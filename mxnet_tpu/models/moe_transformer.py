@@ -1,6 +1,13 @@
 """Stage-stacked Mixture-of-Experts transformer LM — the planner's
 flagship workload (ROADMAP item 2: a model that does not fit one chip).
 
+What this is now: the Switch top-1 oracle the planner's and the elastic
+tests place and re-place over dp x pp x ep meshes (LayerNorm, learned
+positions, a capacity, over-capacity tokens dropped). It is not the path
+of any published expert model: a decoder with latent attention and a
+dropless layer over the experts a chip holds is ``models/mla_moe.py`` on
+``parallel.moe.held_experts_ffn``.
+
 Design is mesh-first for the :mod:`~mxnet_tpu.parallel.planner` naming
 convention: every per-layer parameter is ONE tensor with a leading
 ``n_stages`` axis (``stack_*`` -> ``PartitionSpec('pp')``), and the

@@ -846,13 +846,15 @@ def _packed_flash_call(qkv, num_heads, kv_mask, rng_key, causal, drop):
 # Which implementation each attention call was traced into, counted where
 # the dispatcher decides (once a trace, not once a step): "packed" = the
 # flash kernels on the unsplit QKV projection, "flash" = the flash kernels
-# on separate q/k/v, "xla" = the composed softmax.
-_DISPATCHED = {"packed": 0, "flash": 0, "xla": 0}
+# on separate q/k/v, "latent" = the latent-attention flash kernels (score
+# of two dot products, keys wider than values), "xla" = the composed
+# softmax.
+_DISPATCHED = {"packed": 0, "flash": 0, "latent": 0, "xla": 0}
 
 
 def attention_dispatch_stats():
     """Snapshot of the dispatcher's path counts since the process
-    started: ``{"packed", "flash", "xla"}``."""
+    started: ``{"packed", "flash", "latent", "xla"}``."""
     return dict(_DISPATCHED)
 
 
@@ -998,3 +1000,205 @@ def _attention(query, key, value, mask, dropout, scaled, causal, layout,
         keep = jax.random.bernoulli(rng_key, 1.0 - dropout, w.shape)
         w = jnp.where(keep, w / (1.0 - dropout), 0.0)
     return jnp.einsum("...qk,...kd->...qd", w, value)
+
+
+# ------------------------------------------------- latent attention (MLA)
+
+
+@register("RMSNorm", aliases=("rms_norm",))
+def RMSNorm(data, gamma, eps=1e-5):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, computed in
+    float32 whatever the storage type (Zhang & Sennrich 2019)."""
+    x = data.astype(jnp.float32)
+    y = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+def _rotary_tables(seq, dim, theta):
+    """cos and sin of position x frequency, (seq, dim / 2) float32, worked
+    out in float64 on the host (the length is static): frequency i is
+    ``theta ** (-2 i / dim)``."""
+    inv = 1.0 / (float(theta) ** (_np.arange(0, dim, 2, dtype=_np.float64)
+                                  / dim))
+    angle = _np.arange(seq, dtype=_np.float64)[:, None] * inv[None, :]
+    return (jnp.asarray(_np.cos(angle), jnp.float32),
+            jnp.asarray(_np.sin(angle), jnp.float32))
+
+
+@register("_contrib_rotary_embedding")
+def rotary_embedding(data, theta=10000.0):
+    """Rotary position embedding (Su et al. 2021) of ``data (B, S, ..., D)``
+    by the position along axis 1, pairing element i with element i + D/2
+    (the "halves" convention), in float32."""
+    seq, dim = data.shape[1], data.shape[-1]
+    cos, sin = _rotary_tables(seq, dim, theta)
+    shape = (1, seq) + (1,) * (data.ndim - 3) + (dim // 2,)
+    cos, sin = cos.reshape(shape), sin.reshape(shape)
+    x = data.astype(jnp.float32)
+    a, b = x[..., :dim // 2], x[..., dim // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(data.dtype)
+
+
+def _latent_flash_call(q_nope, q_rope, kv, k_rope, num_heads, causal):
+    """The latent flash kernels on the devices the enclosing program spans:
+    the bare call on one device, each device's share of the batch under
+    ``shard_map`` where a larger mesh is visible."""
+    from jax.sharding import PartitionSpec as P
+    from ..parallel.mesh import current_scope
+    from .pallas_kernels import flash_attention_latent
+
+    def call(*operands):
+        return flash_attention_latent(*operands, num_heads, causal)
+
+    scope = current_scope()
+    if scope is None or scope[0].size == 1:
+        return call(q_nope, q_rope, kv, k_rope)
+    mesh, batch_axes = scope
+    axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)
+    if not axes or q_nope.shape[0] % _math.prod(mesh.shape[a] for a in axes):
+        axes = None
+    return jax.shard_map(call, mesh=mesh, in_specs=(P(axes),) * 4,
+                         out_specs=P(axes), check_vma=False)(
+        q_nope, q_rope, kv, k_rope)
+
+
+@register("_contrib_latent_attention")
+def latent_attention(q_nope, q_rope, kv, k_rope, num_heads=1, causal=True):
+    """Multi-head latent attention in its training form (DeepSeek-V2): the
+    score of head h is ``(q_nope_h . k_nope_h + q_rope_h . k_rope) /
+    sqrt(nope + rope)`` with ONE rotary key a position shared by the heads,
+    and the keys (nope + rope wide) are wider than the values.
+
+    ``q_nope (B, S, H*nope)``; ``q_rope (B, S, H, rope)`` and ``k_rope
+    (B, S, rope)``, both already rotated; ``kv (B, S, H*(nope + v))`` as the
+    up-projection of the latent made it, ``[k_nope_h | v_h]`` head by head.
+    Returns ``(B, S, H*v)`` as the output projection reads it. Where the
+    latent flash kernels run (accelerator present, S a multiple of 128,
+    nope == v a multiple of 128) nothing is padded, split or broadcast in
+    memory; everywhere else the plain XLA form computes the same. Either way
+    every op carries the ``attention`` scope."""
+    with jax.named_scope("attention"):
+        B, S, _ = q_nope.shape
+        H = int(num_heads)
+        dn, dr = q_nope.shape[-1] // H, q_rope.shape[-1]
+        dv = kv.shape[-1] // H - dn
+        from .pallas_kernels import flash_attention_latent_usable
+        if (flash_attention_latent_usable(S, dn, dr, dv) and _flash_enabled()
+                and _on_accelerator()):
+            _DISPATCHED["latent"] += 1
+            return _latent_flash_call(q_nope, q_rope, kv, k_rope, H, causal)
+        _DISPATCHED["xla"] += 1
+        kv4 = kv.reshape(B, S, H, dn + dv)
+        q = jnp.concatenate([q_nope.reshape(B, S, H, dn), q_rope], axis=-1)
+        k = jnp.concatenate(
+            [kv4[..., :dn],
+             jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))], axis=-1)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32)
+        scores = scores / _np.float32(_np.sqrt(dn + dr))
+        if causal:
+            scores = jnp.where(jnp.tril(jnp.ones((S, S), dtype=bool)), scores,
+                               -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(kv.dtype)
+        out = jnp.einsum("bhqk,bkhd->bqhd", probs, kv4[..., dn:])
+        return out.reshape(B, S, H * dv)
+
+
+@register("_contrib_held_experts_ffn", n_out=2)
+def held_experts_ffn_op(data, router_weight, router_bias, gate_weight,
+                        up_weight, down_weight, first=0, top_k=1, scale=1.0,
+                        normalize=True):
+    """The routed part of a dropless expert layer over the experts held
+    here (``parallel.moe.held_experts_ffn``) for ``data (..., d)``:
+    ``(result, rows routed to each held expert)``."""
+    from ..parallel.moe import held_experts_ffn
+    lead = data.shape[:-1]
+    y, rows = held_experts_ffn(
+        data.reshape(-1, data.shape[-1]), router_weight, router_bias,
+        gate_weight, up_weight, down_weight, first=int(first),
+        top_k=int(top_k), scale=float(scale), normalize=bool(normalize))
+    return y.reshape(lead + (data.shape[-1],)), rows
+
+
+@register("_contrib_gated_ffn")
+def gated_ffn_op(data, gate_weight, up_weight, down_weight, scope=None):
+    """``W_down(silu(W_gate x) * W_up x)``, matrices stored (out, in);
+    ``scope`` names a program scope to enter (``moe_shared``)."""
+    import contextlib
+    from ..parallel.moe import gated_ffn
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        return gated_ffn(data, gate_weight, up_weight, down_weight)
+
+
+# --------------------------------------------- chunked cross-entropy head
+
+def _ce_chunks(tokens, chunk):
+    chunk = min(int(chunk), tokens)
+    return chunk if tokens % chunk == 0 else tokens
+
+
+@_partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chunked_ce(hidden, weight, labels, chunk):
+    return _chunked_ce_fwd(hidden, weight, labels, chunk)[0]
+
+
+def _chunk_logits(h, weight):
+    return lax.dot_general(h, weight, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _chunked_ce_fwd(hidden, weight, labels, chunk):
+    T = hidden.shape[0]
+    valid = labels >= 0
+    count = jnp.maximum(jnp.sum(valid), 1).astype(jnp.float32)
+
+    def one(_, args):
+        h, lab = args
+        logits = _chunk_logits(h, weight)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logits, jnp.maximum(lab, 0)[:, None], axis=-1)[:, 0]
+        return None, (lse, jnp.where(lab >= 0, lse - picked, 0.0))
+
+    _, (lse, nll) = lax.scan(one, None, (
+        hidden.reshape(T // chunk, chunk, -1), labels.reshape(-1, chunk)))
+    return jnp.sum(nll) / count, (hidden, weight, labels, lse.reshape(T),
+                                  count)
+
+
+def _chunked_ce_bwd(chunk, res, g):
+    hidden, weight, labels, lse, count = res
+    T = hidden.shape[0]
+    ids = jnp.arange(weight.shape[0], dtype=labels.dtype)
+
+    def one(dw, args):
+        h, lab, l = args
+        probs = jnp.exp(_chunk_logits(h, weight) - l[:, None])
+        d = jnp.where((lab >= 0)[:, None],
+                      probs - (ids[None, :] == lab[:, None]), 0.0)
+        d = (d * (g / count)).astype(hidden.dtype)
+        dw = dw + lax.dot_general(d, h, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        return dw, jnp.matmul(d, weight)
+
+    dw, dh = lax.scan(one, jnp.zeros(weight.shape, jnp.float32), (
+        hidden.reshape(T // chunk, chunk, -1), labels.reshape(-1, chunk),
+        lse.reshape(-1, chunk)))
+    return dh.reshape(hidden.shape), dw.astype(weight.dtype), None
+
+
+_chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
+
+
+@register("_contrib_chunked_softmax_cross_entropy")
+def chunked_softmax_cross_entropy(hidden, weight, labels, chunk=2048):
+    """Mean cross-entropy of ``hidden (..., d) @ weight (V, d).T`` against
+    ``labels (...)`` over the positions whose label is not negative, a
+    ``chunk`` of positions at a time: no (positions, V) array is live whole,
+    forward or backward (the backward pass recomputes each chunk's logits
+    from the saved log-sum-exp and adds the head's gradient up in
+    float32)."""
+    flat = hidden.reshape(-1, hidden.shape[-1])
+    lab = labels.reshape(-1).astype(jnp.int32)
+    return _chunked_ce(flat, weight, lab, _ce_chunks(flat.shape[0], chunk))

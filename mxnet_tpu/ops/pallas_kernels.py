@@ -36,8 +36,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 __all__ = ["flash_attention", "flash_attention_bshd",
-           "flash_attention_packed", "flash_attention_usable",
-           "flash_attention_bshd_usable"]
+           "flash_attention_packed", "flash_attention_latent",
+           "flash_attention_usable", "flash_attention_bshd_usable",
+           "flash_attention_latent_usable"]
 
 import os as _os
 
@@ -183,15 +184,29 @@ def _tile_dead(causal, q0, k0, blk_q, blk_k, mask_row):
     return dead
 
 
+def _scores(q, k, extra):
+    """``q k^T`` in float32, plus ``q2 k2^T`` where ``extra = (q2, k2)``."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if extra is not None:
+        q2, k2 = extra
+        s = s + jax.lax.dot_general(q2, k2.astype(q2.dtype),
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+    return s
+
+
 def _fwd_tile_update(q, k, v, carry, dead, seed, bh, q0, k0, blk_q, blk_k,
-                     dropout, scale):
+                     dropout, scale, extra=None):
     """One online-softmax accumulation step over a (q-block, k-block)
     tile — the single implementation both the BHSD and the head-fused
     BSHD forward kernels run. Masked positions contribute EXACTLY zero
     (not exp(-1e30 - m)): fully-masked rows keep l = 0 and the epsilon
     guard at the end returns 0 output instead of garbage. The normalizer
     l accumulates PRE-dropout probabilities (dropout rescales P, never
-    the softmax denominator)."""
+    the softmax denominator). ``extra = (q2, k2)`` adds a second dot
+    product to the score (latent attention's rotary part: other widths,
+    and a key that is not this head's alone)."""
     acc, m_i, l_i = carry
     # matmuls run in the OPERAND dtype (bf16 inputs ride the fast MXU
     # path, 3x the f32 rate) with f32 accumulation; all softmax math
@@ -200,9 +215,7 @@ def _fwd_tile_update(q, k, v, carry, dead, seed, bh, q0, k0, blk_q, blk_k,
     # operand dtypes).
     k = k.astype(q.dtype)
     v = v.astype(q.dtype)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * jnp.float32(scale)
+    s = _scores(q, k, extra) * jnp.float32(scale)
     if dead is not None:
         s = jnp.where(dead, jnp.float32(NEG_INF), s)
     m_new = jnp.maximum(m_i, jnp.max(s, axis=-1))
@@ -221,14 +234,14 @@ def _fwd_tile_update(q, k, v, carry, dead, seed, bh, q0, k0, blk_q, blk_k,
 
 
 def _bwd_tile_ds(q, k, v, do, lse, delta, mask_row, causal, dropout,
-                 scale, seed, bh, q0, k0, blk_q, blk_k):
+                 scale, seed, bh, q0, k0, blk_q, blk_k, extra=None):
     """Recompute dS = P o (dP - delta) for one tile (and Pdrop for dV) —
     the single implementation all four backward kernels run."""
     k = k.astype(q.dtype)
     v = v.astype(q.dtype)
     do = do.astype(q.dtype)
     p, pd, keep = _recompute_tile(q, k, lse, seed, bh, q0, k0, mask_row,
-                                  causal, dropout, scale, blk_q, blk_k)
+                                  causal, dropout, scale, blk_q, blk_k, extra)
     dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
     if dropout > 0.0:
@@ -288,12 +301,10 @@ def _attn_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
 # ------------------------------------------------------------ backward tiles
 
 def _recompute_tile(q, k, lse, seed, bh, q0, k0, mask_row, causal,
-                    dropout, scale, blk_q, blk_k):
+                    dropout, scale, blk_q, blk_k, extra=None):
     """Recompute (P, Pdrop, keep, dead) for one (q-block, k-block) tile
     from the saved logsumexp. Shared by the dq and dkdv kernels."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * jnp.float32(scale)
+    s = _scores(q, k, extra) * jnp.float32(scale)
     dead = None
     if causal:
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
@@ -952,3 +963,311 @@ def _fap_bwd(num_heads, causal, dropout, interpret, res, g):
 
 
 flash_attention_packed.defvjp(_fap_fwd, _fap_bwd)
+
+
+# =================================================================== latent
+# Multi-head latent attention in its training form: the score of head h is
+# q_nope_h . k_nope_h + q_rope_h . k_rope, with ONE rotary key a position
+# shared by the heads, and the keys (nope + rope) are wider than the values.
+# The operands stay as the projections made them: q_nope (B, S, H*nope) and
+# the latent's up-projection kv (B, S, H*(nope + v)), [k_nope_h | v_h] head
+# by head, are read as column blocks; q_rope is (B, H, S, rope) (a block's
+# last dim must be a multiple of 128 or the whole dim, and the rotary op
+# writes a new array anyway); k_rope (B, S, rope) is read by every head
+# from the one array. Nothing is padded or broadcast in memory. The key
+# blocks are a GRID axis (the innermost, sequential one), so VMEM holds one
+# block of each operand and accumulators in scratch, whatever the sequence
+# length; a causal tile wholly above the diagonal is skipped and its block
+# index clamped, so nothing is fetched for it. The tile bodies are the ones
+# the BHSD and BSHD kernels run (`_fwd_tile_update`, `_bwd_tile_ds`,
+# `_tile_dead`), given the rotary pair as their second dot product.
+
+# measured on the chip at 2 x 8192 x 16 heads (PR 27), forward + backward:
+# 256/256 70.7 ms, 512/512 38.2, 1024/512 36.4, 256/1024 37.0, 256/2048
+# 35.4, 512/1024 32.6, 1024/1024 30.8 (512/2048: dkv refused, VMEM)
+_PREF_LATENT_Q = _PREF_LATENT_K = 1024
+
+
+def flash_attention_latent_usable(seq, nope, rope, v_dim):
+    """Whether the latent kernels take this problem."""
+    return (seq % 128 == 0 and nope % 128 == 0 and v_dim % 128 == 0
+            and rope % 8 == 0 and rope <= 128)
+
+
+def _pick_blocks_latent(seq):
+    """(blk_q, blk_k): the largest multiples of 128 that divide ``seq``, up
+    to the preferred sizes. They need not divide each other: a tile is
+    skipped by its positions, not by block arithmetic."""
+    def pick(pref):
+        b = max(128, min(pref // 128 * 128, seq))
+        while seq % b:
+            b -= 128
+        return b
+    return pick(_PREF_LATENT_Q), pick(_PREF_LATENT_K)
+
+
+def _latent_live(causal, q0, k0, blk_q):
+    """Whether tile (q0, k0) has any position at or below the diagonal."""
+    return (k0 <= q0 + (blk_q - 1)) if causal else True
+
+
+def _latent_fwd_kernel(qn_ref, qr_ref, kv_ref, kr_ref, o_ref, lse_ref,
+                       acc_ref, m_ref, l_ref, *, scale, causal, blk_q, blk_k,
+                       nope):
+    """One (batch, head, q-block, k-block) program: one online-softmax
+    step into the scratch accumulators; the last k-block writes the output
+    and the log-sum-exp."""
+    kj = pl.program_id(3)
+    q0, k0 = pl.program_id(2) * blk_q, kj * blk_k
+
+    @pl.when(kj == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    @pl.when(_latent_live(causal, q0, k0, blk_q))
+    def _():
+        dead = _tile_dead(causal, q0, k0, blk_q, blk_k, None)
+        carry = (acc_ref[...], m_ref[0, :], l_ref[0, :])
+        _, (acc, m_i, l_i) = _fwd_tile_update(
+            qn_ref[0], kv_ref[0, :, :nope], kv_ref[0, :, nope:], carry, dead,
+            None, None, q0, k0, blk_q, blk_k, 0.0, scale,
+            extra=(qr_ref[0, 0], kr_ref[0]))
+        acc_ref[...] = acc
+        m_ref[0, :] = m_i
+        l_ref[0, :] = l_i
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _():
+        l_safe = jnp.maximum(l_ref[0, :], jnp.float32(1e-20))
+        o_ref[0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        lse_ref[0, 0, :] = m_ref[0, :] + jnp.log(l_safe)
+
+
+def _latent_dq_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref,
+                      delta_ref, dqn_ref, dqr_ref, dqn_acc, dqr_acc, *, scale,
+                      causal, blk_q, blk_k, nope):
+    """grad wrt both parts of Q: one (batch, head, q-block, k-block)
+    program; dQ = dS K * scale, part by part."""
+    kj = pl.program_id(3)
+    q0, k0 = pl.program_id(2) * blk_q, kj * blk_k
+
+    @pl.when(kj == 0)
+    def _():
+        dqn_acc[...] = jnp.zeros(dqn_acc.shape, jnp.float32)
+        dqr_acc[...] = jnp.zeros(dqr_acc.shape, jnp.float32)
+
+    @pl.when(_latent_live(causal, q0, k0, blk_q))
+    def _():
+        kn, kr = kv_ref[0, :, :nope], kr_ref[0]
+        ds, _ = _bwd_tile_ds(
+            qn_ref[0], kn, kv_ref[0, :, nope:], do_ref[0], lse_ref[0, 0, :],
+            delta_ref[0, 0, :], None, causal, 0.0, scale, None, None, q0, k0,
+            blk_q, blk_k, extra=(qr_ref[0, 0], kr))
+        dqn_acc[...] += jax.lax.dot_general(
+            ds.astype(kn.dtype), kn, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dqr_acc[...] += jax.lax.dot_general(
+            ds.astype(kr.dtype), kr, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _():
+        dqn_ref[0] = (dqn_acc[...] * jnp.float32(scale)).astype(dqn_ref.dtype)
+        dqr_ref[0, 0] = (dqr_acc[...] * jnp.float32(scale)).astype(
+            dqr_ref.dtype)
+
+
+def _latent_dkv_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref,
+                       delta_ref, dkv_ref, dkr_ref, dkn_acc, dv_acc, dkr_acc,
+                       *, scale, causal, blk_q, blk_k, nope):
+    """grads wrt K's own part, V and this head's share of the shared rotary
+    key: one (batch, head, k-block, q-block) program. dV = P^T dO; dK = dS^T
+    Q * scale, part by part. The heads' shares of dk_rope are summed
+    outside (float32)."""
+    qi = pl.program_id(3)
+    k0, q0 = pl.program_id(2) * blk_k, qi * blk_q
+
+    @pl.when(qi == 0)
+    def _():
+        dkn_acc[...] = jnp.zeros(dkn_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+        dkr_acc[...] = jnp.zeros(dkr_acc.shape, jnp.float32)
+
+    @pl.when(_latent_live(causal, q0, k0, blk_q))
+    def _():
+        qn, qr, do = qn_ref[0], qr_ref[0, 0], do_ref[0]
+        ds, pd = _bwd_tile_ds(
+            qn, kv_ref[0, :, :nope], kv_ref[0, :, nope:], do,
+            lse_ref[0, 0, :], delta_ref[0, 0, :], None, causal, 0.0, scale,
+            None, None, q0, k0, blk_q, blk_k, extra=(qr, kr_ref[0]))
+        over_q = (((0,), (0,)), ((), ()))
+        dv_acc[...] += jax.lax.dot_general(
+            pd.astype(do.dtype), do, over_q,
+            preferred_element_type=jnp.float32)
+        dkn_acc[...] += jax.lax.dot_general(
+            ds.astype(qn.dtype), qn, over_q,
+            preferred_element_type=jnp.float32)
+        dkr_acc[...] += jax.lax.dot_general(
+            ds.astype(qr.dtype), qr, over_q,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(qi == pl.num_programs(3) - 1)
+    def _():
+        dkv_ref[0, :, :nope] = (dkn_acc[...] * jnp.float32(scale)).astype(
+            dkv_ref.dtype)
+        dkv_ref[0, :, nope:] = dv_acc[...].astype(dkv_ref.dtype)
+        dkr_ref[0, 0] = dkr_acc[...] * jnp.float32(scale)
+
+
+def _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, q_inner):
+    """Block specs of the latent kernels' operands on a (batch, head, outer
+    block, inner block) grid; ``q_inner`` says which of the two block axes
+    the innermost grid axis walks (the dkv kernel's). A causal tile that is
+    skipped takes the block index of the nearest live one, so its operands
+    are not fetched again."""
+    if q_inner:
+        def qk(j, i):
+            return (jnp.maximum(i, (j * blk_k) // blk_q) if causal else i), j
+    else:
+        def qk(i, j):
+            return i, (jnp.minimum(j, (i * blk_q + blk_q - 1) // blk_k)
+                       if causal else j)
+
+    def q_side(spec):
+        return lambda b, h, x, y: spec(b, h, qk(x, y)[0])
+
+    def k_side(spec):
+        return lambda b, h, x, y: spec(b, h, qk(x, y)[1])
+
+    return {
+        "q_nope": pl.BlockSpec((1, blk_q, nope),
+                               q_side(lambda b, h, i: (b, i, h))),
+        "q_rope": pl.BlockSpec((1, 1, blk_q, rope),
+                               q_side(lambda b, h, i: (b, h, i, 0))),
+        "out": pl.BlockSpec((1, blk_q, v_dim),
+                            q_side(lambda b, h, i: (b, i, h))),
+        "row": pl.BlockSpec((1, 1, blk_q),
+                            q_side(lambda b, h, i: (b * H + h, 0, i))),
+        "kv": pl.BlockSpec((1, blk_k, nope + v_dim),
+                           k_side(lambda b, h, j: (b, j, h))),
+        "k_rope": pl.BlockSpec((1, blk_k, rope),
+                               k_side(lambda b, h, j: (b, j, 0))),
+        "dk_rope": pl.BlockSpec((1, 1, blk_k, rope),
+                                k_side(lambda b, h, j: (b, h, j, 0))),
+    }
+
+
+def _latent_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
+                 interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        kernel, out_shape=out_shape, grid=grid, in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=interpret, name=name)
+
+
+def _latent_dims(q_nope, q_rope, kv, num_heads, blocks):
+    B, S, _ = q_nope.shape
+    nope, rope = q_nope.shape[-1] // num_heads, q_rope.shape[-1]
+    v_dim = kv.shape[-1] // num_heads - nope
+    blk_q, blk_k = blocks or _pick_blocks_latent(S)
+    return B, S, nope, rope, v_dim, blk_q, blk_k
+
+
+def _latent_fwd_impl(q_nope, q_rope, kv, k_rope, num_heads, causal, blocks,
+                     interpret):
+    H = num_heads
+    B, S, nope, rope, v_dim, blk_q, blk_k = _latent_dims(
+        q_nope, q_rope, kv, H, blocks)
+    spec = _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, False)
+    kernel = functools.partial(
+        _latent_fwd_kernel, scale=float(1.0 / np.sqrt(nope + rope)),
+        causal=causal, blk_q=blk_q, blk_k=blk_k, nope=nope)
+    call = _latent_call(
+        kernel, "flash_latent_fwd", (B, H, S // blk_q, S // blk_k),
+        [spec["q_nope"], spec["q_rope"], spec["kv"], spec["k_rope"]],
+        (spec["out"], spec["row"]),
+        (jax.ShapeDtypeStruct((B, S, H * v_dim), q_nope.dtype),
+         jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)),
+        [(blk_q, v_dim), (1, blk_q), (1, blk_q)], interpret)
+    with jax.enable_x64(False):
+        return call(q_nope, q_rope, kv, k_rope)
+
+
+def _latent_bwd_impl(q_nope, q_rope, kv, k_rope, o, lse, g, num_heads,
+                     causal, blocks, interpret):
+    H = num_heads
+    B, S, nope, rope, v_dim, blk_q, blk_k = _latent_dims(
+        q_nope, q_rope, kv, H, blocks)
+    # delta_i = rowsum(dO o O) per head: one fused XLA elementwise + reduce
+    delta = jnp.sum((g.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(B, S, H, v_dim), axis=-1)
+    delta = jnp.transpose(delta, (0, 2, 1)).reshape(B * H, 1, S)
+    common = dict(scale=float(1.0 / np.sqrt(nope + rope)), causal=causal,
+                  blk_q=blk_q, blk_k=blk_k, nope=nope)
+    by_k = _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, False)
+    by_q = _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, True)
+
+    def operands(s):
+        return [s["q_nope"], s["q_rope"], s["kv"], s["k_rope"], s["out"],
+                s["row"], s["row"]]
+
+    dq_call = _latent_call(
+        functools.partial(_latent_dq_kernel, **common), "flash_latent_dq",
+        (B, H, S // blk_q, S // blk_k), operands(by_k),
+        (by_k["q_nope"], by_k["q_rope"]),
+        (jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
+         jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype)),
+        [(blk_q, nope), (blk_q, rope)], interpret)
+    dkv_call = _latent_call(
+        functools.partial(_latent_dkv_kernel, **common), "flash_latent_dkv",
+        (B, H, S // blk_k, S // blk_q), operands(by_q),
+        (by_q["kv"], by_q["dk_rope"]),
+        (jax.ShapeDtypeStruct(kv.shape, kv.dtype),
+         jax.ShapeDtypeStruct((B, H, S, rope), jnp.float32)),
+        [(blk_k, nope), (blk_k, v_dim), (blk_k, rope)], interpret)
+    with jax.enable_x64(False):
+        dqn, dqr = dq_call(q_nope, q_rope, kv, k_rope, g, lse, delta)
+        dkv, dkr = dkv_call(q_nope, q_rope, kv, k_rope, g, lse, delta)
+    return dqn, dqr, dkv, jnp.sum(dkr, axis=1).astype(k_rope.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_latent(q_nope, q_rope, kv, k_rope, num_heads, causal, blocks,
+                  interpret):
+    return _latent_fwd_impl(q_nope, q_rope, kv, k_rope, num_heads, causal,
+                            blocks, interpret)[0]
+
+
+def _fl_fwd(q_nope, q_rope, kv, k_rope, num_heads, causal, blocks, interpret):
+    out, lse = _latent_fwd_impl(q_nope, q_rope, kv, k_rope, num_heads,
+                                causal, blocks, interpret)
+    return out, (q_nope, q_rope, kv, k_rope, out, lse)
+
+
+def _fl_bwd(num_heads, causal, blocks, interpret, res, g):
+    q_nope, q_rope, kv, k_rope, out, lse = res
+    return _latent_bwd_impl(q_nope, q_rope, kv, k_rope, out, lse, g,
+                            num_heads, causal, blocks, interpret)
+
+
+_flash_latent.defvjp(_fl_fwd, _fl_bwd)
+
+
+def flash_attention_latent(q_nope, q_rope, kv, k_rope, num_heads,
+                           causal=True, blocks=None, interpret=False):
+    """Blockwise exact latent attention. ``q_nope (B, S, H*nope)``;
+    ``q_rope (B, S, H, rope)`` and ``k_rope (B, S, rope)``, both already
+    rotated; ``kv (B, S, H*(nope + v))``, ``[k_nope_h | v_h]`` head by
+    head. Returns ``(B, S, H*v)``. ``blocks = (blk_q, blk_k)`` overrides
+    the block sizes (multiples of 128 that divide S; they need not be
+    equal)."""
+    out = _flash_latent(q_nope, jnp.transpose(q_rope, (0, 2, 1, 3)), kv,
+                        k_rope, int(num_heads), bool(causal), blocks,
+                        interpret)
+    return out

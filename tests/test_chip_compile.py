@@ -197,3 +197,50 @@ def test_packed_attention_block_dp_sharded_compiles_four_chips(topo,
         NamedSharding(mesh, P()))).compile().as_text()
     _assert_packed_block(text)
     assert "all-gather" not in text
+
+
+# ---- the latent-attention kernels at the benchmark's widths -----------------
+
+def _latent_fwd_bwd():
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_latent
+
+    def loss(q_nope, q_rope, kv, k_rope):
+        return jnp.sum(flash_attention_latent(
+            q_nope, q_rope, kv, k_rope, 16, True).astype(jnp.float32) ** 2)
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))
+
+
+@pytest.mark.parametrize("seq", [8192, 32768])
+def test_latent_kernels_compile_at_published_widths(topo, seq):
+    """16 heads of 128 + 64 against 128, bfloat16: forward, dq and dkv for
+    one described chip. The key blocks are a grid axis, so what fits at
+    8,192 positions (the cell's) fits at 32,768: VMEM does not grow with
+    the sequence (the per-head BHSD kernels are refused at 8,192 x 192)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    rows = 2 if seq == 8192 else 1
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    text = _latent_fwd_bwd().lower(
+        spec(rows, seq, 16 * 128), spec(rows, seq, 16, 64),
+        spec(rows, seq, 16 * 256), spec(rows, seq, 64)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("flash_latent_fwd", "flash_latent_dq", "flash_latent_dkv"):
+        assert kernel in text, kernel
+
+
+def test_bhsd_kernels_are_refused_at_the_latent_cells_length(topo):
+    """Why the latent kernels exist: the per-head kernels keep K and V of a
+    head whole in VMEM, and at 8,192 x 192 the chip's compiler refuses
+    them."""
+    one = SingleDeviceSharding(topo.devices[0])
+    shape = jax.ShapeDtypeStruct((32, 1, 8192, 192), jnp.bfloat16,
+                                 sharding=one)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, None, True, 0.0)
+                       .astype(jnp.float32))
+    with pytest.raises(Exception, match="vmem"):
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shape, shape, shape).compile()

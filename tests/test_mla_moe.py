@@ -1,0 +1,440 @@
+"""The latent-attention decoder with held experts (``models/mla_moe.py``)
+against its plain reference (``chipbench/configs/mla_moe_ref.py``, which
+imports nothing of the program), at a small size on the CPU: forward, loss
+and every gradient; the latent flash kernels in interpret mode against the
+XLA form; the held-expert layer's shares adding up to the uncut layer;
+droplessness; the chunked loss; the trainer's step with its counter."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from chipbench.configs import mla_moe, mla_moe_ref as ref
+from mxnet_tpu import parallel
+from mxnet_tpu.ops import nn as nn_ops, pallas_kernels as pk
+from mxnet_tpu.parallel import moe
+from mxnet_tpu.parallel.functional import functionalize
+
+TINY = dict(
+    vocab_size=64, hidden_size=64, intermediate_size=160,
+    moe_intermediate_size=48, num_hidden_layers=3, num_attention_heads=4,
+    n_shared_experts=2, n_routed_experts=4, router_width=16,
+    experts_held_first=4, routed_scaling_factor=2.446, kv_lora_rank=32,
+    qk_rope_head_dim=8, v_head_dim=16, qk_nope_head_dim=24,
+    num_experts_per_tok=4, first_k_dense_replace=1, norm_topk_prob=True,
+    rms_norm_eps=1e-5, rope_theta=800000, loss_chunk=16,
+    param_dtype="float32",
+    optimizer={"name": "adam", "learning_rate": 1e-4, "beta1": 0.9,
+               "beta2": 0.999, "epsilon": 1e-8})
+
+
+def _tokens(rows=2, seq=32, seed=0):
+    return np.random.default_rng(seed).integers(0, TINY["vocab_size"],
+                                                (rows, seq))
+
+
+@pytest.fixture(scope="module")
+def model_and_reference():
+    """Loss and gradients of the program (through ``functionalize``, as the
+    trainer calls it) and of the reference, on the same seeded weights with
+    a non-zero router bias."""
+    net, names = mla_moe.build_net(TINY, 7, "float32")
+    pure, params = functionalize(net, train=True)
+    values = [p.data()._data for p in params]
+    toks = _tokens()
+    tokens, labels = mla_moe.as_program_batch(toks)
+
+    def loss(v):
+        outs, aux = pure(jax.random.PRNGKey(0), v, jnp.asarray(tokens),
+                         jnp.asarray(labels))
+        return outs[0], aux
+
+    (value, aux), grads = jax.value_and_grad(loss, has_aux=True)(values)
+    weights = ref.make_params(TINY, 7, "float32")
+    assert float(jnp.abs(weights["layer1_moe_router_bias"]).max()) > 0
+    trained = {k: v for k, v in weights.items() if ref.takes_gradient(k)}
+    fixed = {k: v for k, v in weights.items() if not ref.takes_gradient(k)}
+    targets = float(toks.shape[0] * (toks.shape[1] - 1))
+    ref_value, ref_grads = jax.value_and_grad(lambda t: sum(
+        ref.row_loss(dict(t, **fixed), jnp.asarray(row), TINY, targets)
+        for row in toks))(trained)
+    short = {full: s for s, full in names.items()}
+    return {"loss": float(value), "aux": aux, "ref_loss": float(ref_value),
+            "grads": {short[p.name]: g for p, g in zip(params, grads)},
+            "ref_grads": ref_grads, "weights": weights, "tokens": toks}
+
+
+def test_loss_matches_the_reference(model_and_reference):
+    m = model_and_reference
+    assert m["loss"] == pytest.approx(m["ref_loss"], rel=1e-6)
+    assert m["loss"] == pytest.approx(np.log(64), rel=0.05)
+
+
+@pytest.mark.parametrize("leaf", sorted(
+    k for k in ref.param_spec(TINY) if ref.takes_gradient(k)))
+def test_gradient_matches_the_reference(model_and_reference, leaf):
+    got = model_and_reference["grads"][leaf]
+    want = model_and_reference["ref_grads"][leaf]
+    assert float(jnp.linalg.norm(want)) > 0
+    assert float(jnp.linalg.norm(got - want)) <= \
+        2e-5 * float(jnp.linalg.norm(want))
+
+
+def test_router_bias_takes_no_gradient_and_counter_counts(model_and_reference):
+    m = model_and_reference
+    for leaf, g in m["grads"].items():
+        if not ref.takes_gradient(leaf):
+            assert float(jnp.abs(g).max()) == 0.0
+    # the auxiliary output: rows routed to each held expert, a layer
+    tokens = m["tokens"].size
+    for rows in m["aux"]:
+        assert rows.shape == (4,) and 0 < float(rows.sum()) <= tokens * 4
+
+
+# ---- the latent flash kernels, interpret mode, against the XLA form --------
+
+def _latent_operands(batch, seq, heads=2, nope=128, rope=64, v_dim=128):
+    ks = jax.random.split(jax.random.PRNGKey(seq), 5)
+    shapes = [(batch, seq, heads * nope), (batch, seq, heads, rope),
+              (batch, seq, heads * (nope + v_dim)), (batch, seq, rope),
+              (batch, seq, heads * v_dim)]
+    return [jax.random.normal(k, s, jnp.float32) for k, s in zip(ks, shapes)]
+
+
+@pytest.fixture(scope="module")
+def latent_cases():
+    out = {}
+    for seq, blocks in ((256, (128, 128)), (384, (128, 128)),
+                        (256, (256, 128)), (256, (128, 256))):
+        *ops, w = _latent_operands(2, seq)
+
+        def flash(*a):
+            return jnp.sum(pk.flash_attention_latent(
+                *a, 2, True, blocks, True) * w)
+
+        def plain(*a):
+            return jnp.sum(nn_ops.latent_attention.fn(
+                *a, num_heads=2, causal=True) * w)
+
+        got = (pk.flash_attention_latent(*ops, 2, True, blocks, True),) \
+            + jax.grad(flash, argnums=(0, 1, 2, 3))(*ops)
+        want = (nn_ops.latent_attention.fn(*ops, num_heads=2, causal=True),) \
+            + jax.grad(plain, argnums=(0, 1, 2, 3))(*ops)
+        out[(seq, blocks)] = (got, want)
+    return out
+
+
+@pytest.mark.parametrize("which", range(5), ids=[
+    "forward", "dq_nope", "dq_rope", "dkv", "dk_rope"])
+@pytest.mark.parametrize("seq,blocks", [
+    (256, (128, 128)), (384, (128, 128)), (256, (256, 128)),
+    (256, (128, 256))], ids=["2kb", "3kb", "q256_k128", "q128_k256"])
+def test_latent_flash_kernels_match_the_xla_form(latent_cases, seq, blocks,
+                                                 which):
+    got, want = latent_cases[(seq, blocks)]
+    scale = float(jnp.abs(want[which]).max())
+    assert float(jnp.abs(got[which] - want[which]).max()) <= 5e-6 * scale
+
+
+def test_dispatcher_counts_the_latent_kernels(monkeypatch):
+    """Where the kernels are usable the op takes them (``latent``); off the
+    chip it takes the XLA form (``xla``): the same result."""
+    *ops, _ = _latent_operands(1, 128)
+    before = nn_ops.attention_dispatch_stats()
+    plain = nn_ops.latent_attention.fn(*ops, num_heads=2)
+    real = pk.flash_attention_latent
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    monkeypatch.setattr(
+        pk, "flash_attention_latent",
+        lambda *a: real(*a, interpret=True))
+    flash = nn_ops.latent_attention.fn(*ops, num_heads=2)
+    after = nn_ops.attention_dispatch_stats()
+    assert after["latent"] == before["latent"] + 1
+    assert after["xla"] == before["xla"] + 1
+    assert (after["packed"], after["flash"]) == (before["packed"],
+                                                 before["flash"])
+    np.testing.assert_allclose(flash, plain, atol=5e-6)
+    # widths the kernels do not take stay on the XLA form
+    assert not pk.flash_attention_latent_usable(128, 24, 8, 16)
+    assert pk.flash_attention_latent_usable(8192, 128, 64, 128)
+
+
+# ---- the held-expert layer -------------------------------------------------
+
+def _layer_weights(seed, experts=16, d=64, f=48):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    p = {"moe_router_weight": 0.5 * jax.random.normal(ks[0], (experts, d)),
+         "moe_router_bias": 0.3 * jax.random.normal(ks[1], (experts,)),
+         "moe_expert_gate_weight": 0.1 * jax.random.normal(ks[2], (experts, d, f)),
+         "moe_expert_up_weight": 0.1 * jax.random.normal(ks[3], (experts, d, f)),
+         "moe_expert_down_weight": 0.1 * jax.random.normal(ks[4], (experts, f, d))}
+    p = {k: v.astype(jnp.float32) for k, v in p.items()}
+    x = jax.random.normal(ks[5], (96, d), jnp.float32)
+    return p, x
+
+
+def _held(p, x, first, count, top_k=4):
+    sl = slice(first, first + count)
+    return moe.held_experts_ffn(
+        x, p["moe_router_weight"], p["moe_router_bias"],
+        p["moe_expert_gate_weight"][sl], p["moe_expert_up_weight"][sl],
+        p["moe_expert_down_weight"][sl], first=first, top_k=top_k,
+        scale=2.446)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Over the 4 shares of a 16-expert layer, the routed parts summed and
+    the shared expert counted once are the uncut layer of the reference."""
+    p, x = _layer_weights(3)
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    p.update({"moe_shared_gate_weight": 0.1 * jax.random.normal(ks[0], (96, 64), jnp.float32),
+              "moe_shared_up_weight": 0.1 * jax.random.normal(ks[1], (96, 64), jnp.float32),
+              "moe_shared_down_weight": 0.1 * jax.random.normal(ks[2], (64, 96), jnp.float32)})
+    whole, rows_whole = ref.routed_part(p, "", x, TINY, 0, "float32")
+    whole = whole + ref.shared_part(p, "", x, "float32")
+    parts, rows = zip(*(_held(p, x, first, 4) for first in (0, 4, 8, 12)))
+    shared = moe.gated_ffn(x, p["moe_shared_gate_weight"],
+                           p["moe_shared_up_weight"],
+                           p["moe_shared_down_weight"])
+    np.testing.assert_allclose(sum(parts) + shared, whole, rtol=2e-5,
+                               atol=2e-6)
+    np.testing.assert_array_equal(np.concatenate(rows), rows_whole)
+    assert int(sum(r.sum() for r in rows)) == 96 * 4    # nothing dropped
+
+
+@pytest.mark.parametrize("first", [0, 4, 8, 12])
+def test_one_share_and_its_gradients_match_the_reference(first):
+    p, x = _layer_weights(5)
+    cut = dict(p)
+    for k in ("gate", "up", "down"):
+        name = "moe_expert_%s_weight" % k
+        cut[name] = p[name][first:first + 4]
+
+    def program(x, w):      # _held slices the uncut stacks itself
+        return jnp.sum(jnp.sin(_held(dict(p, **w), x, first, 4)[0]))
+
+    def reference(x, w):
+        return jnp.sum(jnp.sin(ref.routed_part(
+            dict(cut, **w), "", x, TINY, first, "float32")[0]))
+
+    trained = {k: v for k, v in p.items() if k != "moe_router_bias"}
+    cut_trained = {k: cut[k] for k in trained}
+    got = jax.grad(program, argnums=(0, 1))(x, trained)
+    want = jax.grad(reference, argnums=(0, 1))(x, cut_trained)
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-6)
+    for k in trained:
+        g = got[1][k]
+        if k != "moe_router_weight":
+            g = g[first:first + 4]
+        np.testing.assert_allclose(g, want[1][k], rtol=2e-4, atol=2e-6)
+
+
+def _steer(p, favoured):
+    """A bias that makes every token choose exactly ``favoured``."""
+    bias = np.full((16,), -10.0, np.float32)
+    bias[list(favoured)] = 10.0
+    return dict(p, moe_router_bias=jnp.asarray(bias))
+
+
+def test_dropless_every_token_to_one_held_expert():
+    p, x = _layer_weights(6)
+    q = _steer(p, (5, 0, 1, 2))             # of experts 4..7 only 5 is held
+    y, rows = _held(q, x, 4, 4)
+    np.testing.assert_array_equal(rows, [0, 96, 0, 0])
+    want, _ = ref.routed_part(
+        {k: (v[4:8] if "expert" in k else v) for k, v in q.items()}, "", x,
+        TINY, 4, "float32")
+    np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-6)
+    assert float(jnp.abs(y).min(axis=-1).max()) > 0     # no token dropped
+
+
+def test_dropless_every_choice_held_takes_the_worst_case_buffers():
+    """All four choices of every token held here: 4 x tokens rows, the
+    largest the layer can be asked for, none dropped."""
+    p, x = _layer_weights(7)
+    q = _steer(p, (4, 5, 6, 7))
+    y, rows = _held(q, x, 4, 4)
+    np.testing.assert_array_equal(rows, [96, 96, 96, 96])
+    want, _ = ref.routed_part(
+        {k: (v[4:8] if "expert" in k else v) for k, v in q.items()}, "", x,
+        TINY, 4, "float32")
+    np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-6)
+
+
+def test_tokens_routed_to_experts_not_held_leave_the_shared_part_intact():
+    net, _ = mla_moe.build_net(TINY, 11, "float32")
+    layer = net.expert_layers[0]
+    bias = np.full((16,), -10.0, np.float32)
+    bias[[0, 1, 2, 3]] = 10.0               # held: 4..7
+    layer.router_bias.set_data(mx.nd.array(bias))
+    x = mx.nd.array(np.random.default_rng(1).normal(size=(2, 8, 64))
+                    .astype(np.float32))
+    y = layer(x)
+    np.testing.assert_array_equal(layer.routed_rows.asnumpy(), [0, 0, 0, 0])
+    routed, _ = moe.held_experts_ffn(
+        x._data.reshape(16, 64), layer.router_weight.data()._data,
+        layer.router_bias.data()._data,
+        layer.expert_gate_weight.data()._data,
+        layer.expert_up_weight.data()._data,
+        layer.expert_down_weight.data()._data, first=4, top_k=4, scale=2.446)
+    assert float(jnp.abs(routed).max()) == 0.0          # exactly zero
+    shared = moe.gated_ffn(x._data, layer.shared_gate_weight.data()._data,
+                           layer.shared_up_weight.data()._data,
+                           layer.shared_down_weight.data()._data)
+    np.testing.assert_allclose(y.asnumpy(), shared, rtol=1e-6, atol=1e-7)
+
+
+def test_router_chooses_by_score_plus_bias_and_weighs_by_score():
+    p, x = _layer_weights(8)
+    chosen, weights = moe.route_top_k(
+        x, p["moe_router_weight"], p["moe_router_bias"], 4, scale=2.446)
+    scores = jax.nn.sigmoid(x @ p["moe_router_weight"].T)
+    want = np.argsort(-(scores + p["moe_router_bias"]), axis=-1)[:, :4]
+    np.testing.assert_array_equal(np.sort(chosen, -1), np.sort(want, -1))
+    picked = np.take_along_axis(np.asarray(scores), np.asarray(chosen), -1)
+    np.testing.assert_allclose(
+        weights, 2.446 * picked / picked.sum(-1, keepdims=True), rtol=1e-5)
+    by_score = np.argsort(-np.asarray(scores), axis=-1)[:, :4]
+    assert (np.sort(by_score, -1) != np.sort(want, -1)).any()   # bias matters
+
+
+@pytest.mark.parametrize("tokens, top_k, held, experts, want", [
+    (16384, 6, 8, 64, (24576, 49152, 98304)),   # twice the uniform share,
+    (32768, 6, 8, 64, (49152, 98304, 196608)),  # doubling up to the worst
+    (96, 4, 4, 16, (256, 384)),         # rounded up to the 128-row tile
+    (96, 4, 16, 16, (384,)),            # every expert held: the worst only
+    (128, 1, 2, 4, (128,)),
+])
+def test_buffer_sizes_follow_the_operands(tokens, top_k, held, experts, want):
+    assert moe._tiers(tokens, top_k, held, experts) == want
+
+
+@pytest.mark.parametrize("steered, want_size", [
+    ("uniform", 128), ("some_tokens", 256), ("one_held_choice", 512),
+    ("both_choices_held", 1024)])
+def test_every_buffer_size_gives_the_worst_case_buffers_result(
+        monkeypatch, steered, want_size):
+    """512 tokens, 2 of 32 experts held, 2 a token: buffers of 128, 256,
+    512 and 1024 rows. Whichever the routing picks, result and gradients
+    are those of the worst-case buffer alone."""
+    p, _ = _layer_weights(9, experts=32)
+    x = jax.random.normal(jax.random.PRNGKey(10), (512, 64), jnp.float32)
+    bias = np.zeros((32,), np.float32)
+    if steered == "some_tokens":        # 150 tokens pulled to expert 0
+        w0 = p["moe_router_weight"][0]
+        x = x.at[:150].add(20.0 * w0 / jnp.linalg.norm(w0))
+    elif steered == "one_held_choice":
+        bias[[0, 5]] = 10.0
+    elif steered == "both_choices_held":
+        bias[[0, 1]] = 10.0
+    p["moe_router_bias"] = jnp.asarray(bias)
+    sizes = moe._tiers(512, 2, 2, 32)
+    assert sizes == (128, 256, 512, 1024)
+
+    def loss(x, w):
+        y, rows = _held(dict(p, **w), x, 0, 2, top_k=2)
+        return jnp.sum(jnp.sin(y)), rows
+
+    trained = {k: v for k, v in p.items() if k != "moe_router_bias"}
+    (got, rows), got_grads = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(x, trained)
+    assert min(m for m in sizes if m >= int(rows.sum())) == want_size
+    monkeypatch.setattr(moe, "_tiers", lambda *a: (1024,))
+    (want, _), want_grads = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(x, trained)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(got_grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+
+
+# ---- the chunked loss ------------------------------------------------------
+
+@pytest.mark.parametrize("chunk", [16, 64, 1000])
+def test_chunked_cross_entropy_matches_the_whole(chunk):
+    ks = jax.random.split(jax.random.PRNGKey(chunk), 3)
+    h = jax.random.normal(ks[0], (2, 32, 24), jnp.float32)
+    w = jax.random.normal(ks[1], (50, 24), jnp.float32)
+    labels = jax.random.randint(ks[2], (2, 32), 0, 50).at[:, -1].set(-1)
+
+    def whole(h, w):
+        logp = jax.nn.log_softmax(h @ w.T, axis=-1)
+        ll = jnp.take_along_axis(logp, jnp.maximum(labels, 0)[..., None], -1)
+        return -jnp.sum(jnp.where(labels >= 0, ll[..., 0], 0.0)) / 62.0
+
+    def chunked(h, w):
+        return nn_ops.chunked_softmax_cross_entropy.fn(h, w, labels,
+                                                       chunk=chunk)
+
+    got = jax.value_and_grad(chunked, argnums=(0, 1))(h, w)
+    want = jax.value_and_grad(whole, argnums=(0, 1))(h, w)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-6)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+
+
+def test_rms_norm_and_rotary_ops_match_the_reference():
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 10, 4, 8), jnp.float32)
+    g = jax.random.normal(jax.random.PRNGKey(2), (8,), jnp.float32)
+    np.testing.assert_allclose(nn_ops.RMSNorm.fn(x, g, eps=1e-5),
+                               ref.rms_norm(x, g, 1e-5), rtol=1e-6)
+    got = nn_ops.rotary_embedding.fn(x, theta=800000.0)
+    want = jnp.stack([ref.rotary(x[b], 800000) for b in range(3)])
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    # a rotation: norms are kept, position 0 is left alone
+    np.testing.assert_allclose(jnp.linalg.norm(got, axis=-1),
+                               jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    np.testing.assert_array_equal(got[:, 0], x[:, 0])
+
+
+# ---- through the trainer ---------------------------------------------------
+
+def test_trainer_steps_in_bfloat16_and_keeps_the_counter_on_the_device():
+    cfg = dict(TINY, param_dtype="bfloat16")
+    cell = {"batch": 2, "seq": 32, "pool": 2}
+    system = mla_moe.build(cfg, cell, 13, jax.devices())
+    fixed = "router_bias"       # chooses and does not weigh: no gradient
+    bias = [np.asarray(v, np.float32) for p, v in zip(
+        system.trainer._params, system.trainer._values)
+        if p.name.endswith(fixed)]
+    text = system.trainer.lower_step(
+        system._data(0), mx.nd.array(system._label)).as_text(debug_info=True)
+    for scope in ("attention", "moe_router", "moe_experts", "moe_shared",
+                  "optimizer"):
+        assert "/%s/" % scope in text, scope
+    losses = [float(system.step(i).asnumpy()) for i in range(3)]
+    assert all(np.isfinite(losses)) and losses[0] == pytest.approx(
+        np.log(64), rel=0.1)
+    rows = system.routed_rows()
+    assert len(rows) == 2 and all(len(r) == 4 for r in rows)
+    assert all(0 < sum(r) <= 64 * 4 for r in rows)
+    after = [np.asarray(v, np.float32) for p, v in zip(
+        system.trainer._params, system.trainer._values)
+        if p.name.endswith(fixed)]
+    assert len(bias) == 2
+    for a, b in zip(bias, after):
+        np.testing.assert_array_equal(a, b)     # never trained
+    # against the reference's three steps, in bfloat16 storage
+    want = mla_moe.reference(cfg, cell, 13, 3)
+    np.testing.assert_allclose(losses, want["losses"], rtol=2e-2)
+    assert set(system.first_gradient_norms()) == set(want["grad_norms"])
+    assert not any(k.endswith(fixed) for k in want["grad_norms"])
+    assert any(k.endswith("router_weight") for k in want["grad_norms"])
+
+
+def test_planted_faults_move_the_reference():
+    """The two faults the calibration plants are faults: each moves the
+    first gradient of the leaves it touches."""
+    cell = {"batch": 2, "seq": 32, "pool": 2}
+    sound = mla_moe.reference(TINY, cell, 5, 1)
+    # (at this size the scores are nearly flat, so only the rotary key's own
+    # rows of the joint projection feel the rotation: the last 8 of 40)
+    for fault, leaf, rows in (
+            ("expert_dropped", "layer1_moe_expert_up_weight", slice(None)),
+            ("no_rope_on_shared_key", "layer0_attn_kva_weight",
+             slice(32, 40))):
+        broken = mla_moe.reference(TINY, cell, 5, 1, fault=fault)
+        a = broken["first_gradient"][leaf].astype(np.float32)[rows]
+        b = sound["first_gradient"][leaf].astype(np.float32)[rows]
+        assert np.linalg.norm(a - b) > 0.05 * np.linalg.norm(b), fault
