@@ -208,8 +208,8 @@ def bert_batch(rng, batch, seq, n_masks=20):
 
 def assert_flash_in(text, what):
     n = text.count("tpu_custom_call")
-    assert n >= 3, ("%s: %d Pallas custom calls in the compiled step — "
-                    "want the forward and both backward kernels"
+    assert n >= 2, ("%s: %d Pallas custom calls in the compiled step — "
+                    "want the forward and the backward kernel"
                     % (what, n))
     return n
 
@@ -302,7 +302,8 @@ def check_flash_against_xla(seed):
         lambda a: nn_ops.packed_self_attention.fn(a, mask=mask,
                                                   num_heads=H))))
     text = packed.lower(qkv).compile().as_text()
-    assert text.count("tpu_custom_call") == 3, "packed entry fell back"
+    # forward and the one fused backward (the sequence is one key block)
+    assert text.count("tpu_custom_call") == 2, "packed entry fell back"
     value, d_qkv = packed(qkv)
     want, grads = jax.jit(jax.value_and_grad(
         loss(lambda q, k, v: attend(q, k, v, mask)), argnums=(0, 1, 2)))(

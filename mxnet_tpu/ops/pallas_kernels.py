@@ -11,7 +11,8 @@ transformer.cc`), materialising the (S, S) score matrix in HBM.
 
 Forward AND backward are Pallas (MXU matmuls over VMEM-resident tiles,
 fp32 accumulators; backward recomputes score tiles from the saved
-logsumexp — the standard flash-attention-2 dq/dkdv split).
+logsumexp — the standard flash-attention-2 dq/dkdv split, and for the
+head-fused layout ONE kernel where one key block spans the sequence).
 
 Supports the full training configuration of the transformer model zoo:
   - key padding mask (B, S): BERT-style bidirectional masking;
@@ -75,19 +76,24 @@ def _pick_blocks(S, causal):
     return bq, bk
 
 
+# VMEM a head-fused program may plan for (Mosaic's scoped limit is 16 MiB)
+_BSHD_VMEM_BUDGET = 14 * 1024 * 1024
+
+
 def _pick_blocks_bshd(S, causal, HD, itemsize):
-    """Block sizes for the head-fused kernels, shrunk until the VMEM
-    footprint fits. Worst case is the dkdv backward: two FULL (S, HD)
-    operands + four block-sized operands, all double-buffered by the
-    pipeline. Deterministic in (S, causal, HD, itemsize) so the forward
-    and backward passes agree on blk_q (the saved-LSE layout depends on
-    it)."""
+    """Block sizes for the head-fused forward, dq and dkdv kernels, shrunk
+    until the VMEM footprint fits. Worst case of the three is the dkdv
+    backward: two FULL (S, HD) operands + four block-sized operands, all
+    double-buffered by the pipeline. Deterministic in (S, causal, HD,
+    itemsize) so the forward and backward passes agree on blk_q (the
+    saved-LSE layout depends on it). Where blk_k comes out as S the
+    backward is the ONE fused kernel instead, which sizes itself
+    (:func:`_fused_bwd_group`)."""
     bq, bk = _pick_blocks(S, causal)
-    budget = 14 * 1024 * 1024
 
     def fits(bq, bk):
         vmem = 2 * (2 * S + 4 * bk + bq) * HD * itemsize
-        return vmem <= budget
+        return vmem <= _BSHD_VMEM_BUDGET
 
     def shrink(b):
         b -= 128
@@ -152,14 +158,16 @@ def _flat_seed_parts(seed_ref, num_heads):
     return _seed_parts(seed_ref, jax.lax.div(pid, H), jax.lax.rem(pid, H))
 
 
-def _keep_bits(seed, bh, q0, k0, blk_q, blk_k, keep_prob):
+def _keep_bits(seed, bh, q0, k0, blk_q, blk_k, keep_prob, keys_first=False):
     """Deterministic keep-mask tile for global element (bh, q0+i, k0+j).
 
-    Identical calls from the forward and the two backward kernels
-    regenerate identical bits — the dropout mask is never materialised.
+    Identical calls from the forward and the backward kernels regenerate
+    identical bits — the dropout mask is never materialised. The tile is
+    (blk_q, blk_k), or its transpose (blk_k, blk_q) with ``keys_first``.
     """
-    qi = q0 + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-    ki = k0 + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
+    shape, q_dim = ((blk_k, blk_q), 1) if keys_first else ((blk_q, blk_k), 0)
+    qi = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    ki = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
     c = (qi.astype(_U32) * _U32(0x9E3779B9)) ^ \
         (ki.astype(_U32) * _U32(0x85EBCA6B)) ^ \
         (bh.astype(_U32) * _U32(0xC2B2AE35)) ^ seed.astype(_U32)
@@ -605,15 +613,18 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # relayout copy each way on the chip) or column blocks 0, 1, 2 of the one
 # packed (B, S, 3*H*D) projection (`flash_attention_packed`: no view, no
 # copy, one packed gradient written in place): same kernels, the block
-# specs' column index is the only difference.
+# specs' column index is the only difference. Forward: `flash_bshd_fwd`.
+# Backward (`_bshd_bwd_impl`): `flash_bshd_bwd`, one kernel, where the
+# sequence is one key block (S <= 512, causal S <= 256) and a head group
+# fits VMEM; `flash_bshd_dq` then `flash_bshd_dkv` for longer sequences.
 
 def flash_attention_bshd_usable(q_shape, head_dim):
     B, S, HD = q_shape[0], q_shape[1], int(np.prod(q_shape[2:]))
     # Each program holds two FULL (S, H*D) operands in VMEM (K+V in the
-    # forward; Q+dO in the dkdv backward) plus block-sized tiles and fp32
-    # accumulators. Bound that footprint well under the ~16 MB VMEM so
-    # long-sequence/many-head shapes fall back to the per-head BHSD path
-    # instead of failing Mosaic compilation.
+    # forward; Q+dO in the dkdv backward; the fused backward holds less)
+    # plus block-sized tiles and fp32 accumulators. Bound that footprint
+    # well under the ~16 MB VMEM so long-sequence/many-head shapes fall
+    # back to the per-head BHSD path instead of failing Mosaic compilation.
     full_operand_bytes = 2 * S * HD * 4
     return (S % BLOCK_Q == 0 and S >= BLOCK_Q and HD % 128 == 0
             and head_dim <= 256
@@ -834,14 +845,13 @@ def _bshd_bwd_dkv_packed_kernel(*refs, num_heads, head_dim, **kw):
                          num_heads=num_heads, head_dim=head_dim, **kw)
 
 
-def _bshd_bwd_impl(qf, kf, vf, packed, num_heads, kv_mask, seed, o, lse, g,
-                   causal, dropout, interpret):
-    """Backward over the forward's 3-D operands; ``o`` and ``g`` are
-    (B, S, H*D). Returns 3-D ``(dq, dk, dv)`` for separate operands and
-    ONE (B, S, 3*H*D) gradient for the packed projection: both calls
-    write their column blocks of the one buffer (dq: block 0; dkdv: blocks
-    1 and 2 of the buffer it takes over from the dq call), so no
-    concatenate, pad or copy assembles it afterwards."""
+def _bshd_bwd_split(qf, kf, vf, packed, num_heads, kv_mask, seed, o, lse, g,
+                    causal, dropout, interpret):
+    """The two-kernel backward (any number of key blocks): dq, which also
+    forms delta, then dkdv. For the packed projection both calls write
+    their column blocks of the one buffer (dq: block 0; dkdv: blocks 1 and
+    2 of the buffer it takes over from the dq call), so no concatenate,
+    pad or copy assembles it afterwards."""
     B, S = qf.shape[:2]
     H = num_heads
     HD = o.shape[2]
@@ -902,6 +912,215 @@ def _bshd_bwd_impl(qf, kf, vf, packed, num_heads, kv_mask, seed, o, lse, g,
     return dq, dk, dv
 
 
+# ---- the fused backward: one kernel where one key block spans the sequence
+# With blk_k == S the program that holds a row's K and V forms dS of every
+# query against every key, so dQ = dS K needs no sum across programs: the
+# scores, their exp and dP are computed ONCE a head and all three gradients
+# come from them: five products of S x S x D and one exp a score where the
+# two kernels make seven and two, q, k, v, dO read once, delta never in HBM.
+# The tile is TRANSPOSED, keys on the sublanes and queries on the lanes
+# (S^T = K Q^T), so dV = P^T dO and dK = dS^T Q are plain products of the
+# tile, and dQ^T = K^T dS^T leaves only (S, D)-sized transposes to Mosaic;
+# LSE and delta are laid along the lanes for it. Measured on the chip at
+# 96 x 512 x 12 heads of 64 (PR 28), a layer's backward: the two kernels
+# 4.21 ms; fused, query blocks of 256: queries on the sublanes (the two
+# kernels' tile, dV and dK transposing it) 2.33, keys on the sublanes with
+# dQ transposing the tile 2.44, with dQ^T 2.17; the whole sequence as ONE
+# tile: 2.19, 2.16, 2.02. So one tile, dQ^T.
+# The grid is (batch, groups of heads): a group is a lane-aligned run of
+# whole heads' columns (2.02 ms at 384 columns, 2.15 at 256, 2.44 at 128),
+# so a program holds (S, group) of each operand; the packed gradient's
+# (S, 3*H*D) block stays in VMEM over a row's groups and is written once.
+
+def _lane_pick(blk, h):
+    """Column ``h`` (a traced index) of a (rows, H) block as a (rows,)
+    vector: a masked sum over the lanes, exact."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    return jnp.sum(jnp.where(lane == h, blk, jnp.float32(0.0)), axis=-1)
+
+
+def _as_row(col):
+    """An (n,) vector laid along the lanes, (1, n): the transpose of its
+    broadcast over one 128-lane tile."""
+    return jnp.broadcast_to(col[:, None], (col.shape[0], 128)).T[0:1, :]
+
+
+def _bwd_tile_ds_t(q, k, v, do, lse_row, delta_row, dead_t, dropout, scale,
+                   seed, bh):
+    """:func:`_bwd_tile_ds` for a whole head with the KEYS on the sublanes:
+    ``(dS^T, Pdrop^T)``, both (keys, queries). The operands share q's
+    dtype; ``dead_t`` is the transposed invalid-position mask or None."""
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    p = jnp.exp(s * jnp.float32(scale) - lse_row)
+    if dead_t is not None:
+        p = jnp.where(dead_t, jnp.float32(0.0), p)
+    dpd = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    pd, dp = p, dpd
+    if dropout > 0.0:
+        keep = _keep_bits(seed, bh, 0, 0, q.shape[0], k.shape[0],
+                          1.0 - dropout, keys_first=True)
+        pd = jnp.where(keep, p / jnp.float32(1.0 - dropout),
+                       jnp.float32(0.0))
+        dp = jnp.where(keep, dpd / jnp.float32(1.0 - dropout),
+                       jnp.float32(0.0))
+    return p * (dp - delta_row), pd
+
+
+def _bshd_bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
+                           lse_ref, mask_ref, *out_refs, scale, causal,
+                           seq_len, dropout, has_mask, head_dim, group,
+                           packed_cols):
+    """One (batch row, head group) program: dq, dk and dv of the group's
+    heads from one pass over each head's (S, S) scores. ``out_refs`` is
+    three (1, S, group) blocks, or — ``packed_cols`` = H*D — the row's one
+    (1, S, 3*H*D) block, of which this program writes its three column
+    runs."""
+    b = pl.program_id(0)
+    gi = pl.program_id(1)
+    S, D = seq_len, head_dim
+    if packed_cols:
+        (out_ref,) = out_refs
+        dq_ref, dk_ref, dv_ref = (
+            out_ref.at[0, :, pl.ds(pl.multiple_of(c * packed_cols
+                                                  + gi * group, 128), group)]
+            for c in range(3))
+    else:
+        dq_ref, dk_ref, dv_ref = (r.at[0] for r in out_refs)
+    dead_t = None
+    if causal:
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, (S, S), 1)
+        dead_t = q_pos < k_pos
+    if has_mask:
+        # the key mask as a column: (S, 1)
+        mdead = jnp.broadcast_to(mask_ref[0, 0:1, :], (128, S)).T[:, 0:1] == 0
+        dead_t = mdead if dead_t is None else (dead_t | mdead)
+    lse_all = lse_ref[0].reshape(S, -1)          # (n_q, blk_q, H) -> (S, H)
+
+    for j in range(group // D):                  # static unroll
+        h = gi * (group // D) + j
+        cols = slice(j * D, (j + 1) * D)
+        # the matmuls run in q's dtype, as in the other kernels
+        q = q_ref[0, :, cols]
+        k = k_ref[0, :, cols].astype(q.dtype)
+        v = v_ref[0, :, cols].astype(q.dtype)
+        do = do_ref[0, :, cols]
+        seed, bh = _seed_parts(seed_ref, b, h)
+        delta = jnp.sum(do.astype(jnp.float32)
+                        * o_ref[0, :, cols].astype(jnp.float32), axis=-1)
+        do = do.astype(q.dtype)
+        ds_t, pd_t = _bwd_tile_ds_t(
+            q, k, v, do, _as_row(_lane_pick(lse_all, h)), _as_row(delta),
+            dead_t, dropout, scale, seed, bh)
+        ds_t = ds_t.astype(q.dtype)
+        dv = jax.lax.dot_general(pd_t.astype(q.dtype), do,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dk = jax.lax.dot_general(ds_t, q, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        # dQ^T = K^T dS^T: Mosaic transposes K, not the (S, S) tile
+        dq = jax.lax.dot_general(k, ds_t, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32).T
+        dq_ref[:, cols] = (dq * jnp.float32(scale)).astype(dq_ref.dtype)
+        dk_ref[:, cols] = (dk * jnp.float32(scale)).astype(dk_ref.dtype)
+        dv_ref[:, cols] = dv.astype(dv_ref.dtype)
+
+
+def _fused_bwd_group(S, HD, D, itemsize, packed):
+    """Columns of the fused backward's head groups: the widest lane-aligned
+    run of whole heads that divides H*D and whose footprint fits
+    ``_BSHD_VMEM_BUDGET``, or None where none does (the two kernels run). The footprint: q, k, v, dO, O and the gradient blocks,
+    double-buffered (packed: the row's whole (S, 3*H*D) gradient), the LSE
+    block at 128 lanes, and four float32 (S, S) tiles of the head at
+    work."""
+    unit = int(np.lcm(D, 128))
+    tiles = (4 * S * S + 2 * S * 128) * 4
+    for group in range(HD, 0, -unit):
+        if HD % group:
+            continue
+        out_cols = 3 * HD if packed else 3 * group
+        operands = 2 * (5 * group + out_cols) * S * itemsize
+        if operands + tiles <= _BSHD_VMEM_BUDGET:
+            return group
+    return None
+
+
+def _bshd_bwd_fused(qf, kf, vf, packed, num_heads, kv_mask, seed, o, lse, g,
+                    causal, dropout, interpret, group):
+    """The one-kernel backward, for a sequence that is one key block.
+    ``lse`` comes as the forward wrote it, (B, n_q, blk_q, H)."""
+    B, S = qf.shape[:2]
+    H = num_heads
+    HD = o.shape[2]
+    D = HD // H
+    n_groups = HD // group
+    cq, ck, cv = (0, n_groups, 2 * n_groups) if packed else (0, 0, 0)
+
+    def cols(c):
+        return pl.BlockSpec((1, S, group), lambda b, i: (b, 0, c + i))
+
+    grad = jax.ShapeDtypeStruct((B, S, (3 if packed else 1) * HD), qf.dtype)
+    if packed:
+        out_shape = grad
+        out_specs = pl.BlockSpec((1, S, 3 * HD), lambda b, i: (b, 0, 0))
+    else:
+        out_shape, out_specs = (grad,) * 3, (cols(0),) * 3
+    call = pl.pallas_call(
+        functools.partial(
+            _bshd_bwd_fused_kernel, scale=float(1.0 / np.sqrt(D)),
+            causal=causal, seq_len=S, dropout=float(dropout),
+            has_mask=kv_mask is not None, head_dim=D, group=group,
+            packed_cols=HD if packed else 0),
+        out_shape=out_shape,
+        grid=(B, n_groups),
+        in_specs=[pl.BlockSpec((1, 3), lambda b, i: (0, 0)),
+                  cols(cq), cols(ck), cols(cv), cols(0), cols(0),
+                  pl.BlockSpec((1,) + lse.shape[1:],
+                               lambda b, i: (b, 0, 0, 0)),
+                  pl.BlockSpec((1, 1, S), lambda b, i: (b, 0, 0))],
+        out_specs=out_specs,
+        interpret=interpret,
+        name="flash_bshd_bwd",
+    )
+    with jax.enable_x64(False):
+        return call(_seed_operand(seed, H), qf, kf, vf, g, o, lse,
+                    _bshd_mask(kv_mask, B, S))
+
+
+# Where :func:`_bshd_bwd_impl` sent each backward it traced (once a trace,
+# not once a step): "fused" = the one kernel, "split" = dq then dkdv.
+_BACKWARDS = {"fused": 0, "split": 0}
+
+
+def flash_backward_stats():
+    """Snapshot of the head-fused backward's path counts since the process
+    started: ``{"fused", "split"}``."""
+    return dict(_BACKWARDS)
+
+
+def _bshd_bwd_impl(qf, kf, vf, packed, num_heads, kv_mask, seed, o, lse, g,
+                   causal, dropout, interpret):
+    """Backward over the forward's 3-D operands; ``o`` and ``g`` are
+    (B, S, H*D). Returns 3-D ``(dq, dk, dv)`` for separate operands and
+    ONE (B, S, 3*H*D) gradient for the packed projection. Takes the fused
+    kernel by what it can see in its input: the sequence is one key block
+    as :func:`_pick_blocks_bshd` chose it (then dq needs no sum across
+    programs) and a head group fits VMEM; the two kernels otherwise."""
+    S, HD = qf.shape[1], o.shape[2]
+    itemsize = qf.dtype.itemsize
+    group = None
+    if _pick_blocks_bshd(S, causal, HD, itemsize)[1] == S:
+        group = _fused_bwd_group(S, HD, HD // num_heads, itemsize, packed)
+    _BACKWARDS["fused" if group else "split"] += 1
+    if group:
+        return _bshd_bwd_fused(qf, kf, vf, packed, num_heads, kv_mask, seed,
+                               o, lse, g, causal, dropout, interpret, group)
+    return _bshd_bwd_split(qf, kf, vf, packed, num_heads, kv_mask, seed, o,
+                           lse, g, causal, dropout, interpret)
+
+
 def _flat3(x):
     B, S, H, D = x.shape
     return x.reshape(B, S, H * D)
@@ -942,7 +1161,7 @@ def flash_attention_packed(qkv, num_heads, kv_mask=None, seed=None,
     """Self-attention straight from the packed projection: ``qkv`` is
     (B, S, 3*H*D) as the QKV Dense produced it ([q | k | v] along the last
     axis), the result (B, S, H*D) as the output projection reads it. The
-    same three kernels as :func:`flash_attention_bshd`, handed the one
+    same kernels as :func:`flash_attention_bshd`, handed the one
     array three times at column blocks 0, 1, 2; nothing has more than three
     dimensions, and the backward returns ONE (B, S, 3*H*D) gradient."""
     return _fap_fwd(qkv, num_heads, kv_mask, seed, causal, dropout,

@@ -50,7 +50,7 @@ def _no_persistent_cache():
 
 
 def _fwd_bwd(attend):
-    """Sum-of-squares loss through ``attend``: forward + dq + dkdv."""
+    """Sum-of-squares loss through ``attend``: forward + backward."""
     def loss(q, k, v, kv_mask, seed):
         return jnp.sum(attend(q, k, v, kv_mask, seed).astype(jnp.float32)
                        ** 2)
@@ -65,20 +65,53 @@ def _specs(qkv_shape, batch, seq, sharding, mask_sharding, scalar_sharding):
     return qkv, qkv, qkv, mask, seed
 
 
-@pytest.mark.parametrize("batch,seq", [(16, 512), (64, 128)],
-                         ids=["bert_s512_b16", "bert_s128_b64"])
-def test_bshd_fwd_bwd_compiles_one_chip(topo, batch, seq):
-    """BERT-base widths (12 heads x 64), padding mask, dropout 0.1."""
+_FUSED = ("flash_bshd_fwd", "flash_bshd_bwd")
+_SPLIT = ("flash_bshd_fwd", "flash_bshd_dq", "flash_bshd_dkv")
+
+
+@pytest.mark.parametrize("batch,seq,kernels", [
+    (16, 512, _FUSED), (64, 128, _FUSED), (8, 1024, _SPLIT)],
+    ids=["bert_s512_b16", "bert_s128_b64", "bert_s1024_b8"])
+def test_bshd_fwd_bwd_compiles_one_chip(topo, batch, seq, kernels):
+    """BERT-base widths (12 heads x 64), padding mask, dropout 0.1: one
+    key block spans 128 and 512 positions, so the backward is the one
+    fused kernel; 1,024 positions are two key blocks and keep dq + dkdv."""
     one = SingleDeviceSharding(topo.devices[0])
     fn = _fwd_bwd(lambda q, k, v, m, s: flash_attention_bshd(
         q, k, v, m, s, False, 0.1))
     text = fn.lower(*_specs((batch, seq, 12, 64), batch, seq, one, one,
                             one)).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkdv
+    assert text.count("tpu_custom_call") == len(kernels)
     # each kernel's custom call carries the name the program gave it
-    for kernel in ("flash_bshd_fwd", "flash_bshd_dq", "flash_bshd_dkv"):
+    for kernel in kernels:
         assert re.search(r'op_name="[^"]*\b%s\b[^"]*/pallas_call"' % kernel,
                          text), kernel
+
+
+@pytest.mark.parametrize("heads,head_dim,seq,causal", [
+    (8, 128, 512, False), (16, 64, 512, False), (12, 64, 256, True),
+    (12, 64, 384, False)],
+    ids=["h8_d128_s512", "h16_d64_s512", "causal_s256", "s384"])
+def test_fused_backward_compiles_at_other_widths(topo, heads, head_dim, seq,
+                                                 causal):
+    """The fused backward on the packed projection inside Mosaic's VMEM
+    limit where the head group is NOT BERT-base's: 128-wide heads, a
+    1,024-column projection (groups of 256 of it, nearest the budget), the
+    causal form and a sequence of three 128-blocks, mask and dropout on."""
+    from mxnet_tpu.ops.pallas_kernels import flash_attention_packed
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def loss(qkv, kv_mask, seed):
+        return jnp.sum(flash_attention_packed(
+            qkv, heads, kv_mask, seed, causal, 0.1).astype(jnp.float32) ** 2)
+    text = jax.jit(jax.grad(loss)).lower(
+        jax.ShapeDtypeStruct((8, seq, 3 * heads * head_dim), jnp.bfloat16,
+                             sharding=one),
+        jax.ShapeDtypeStruct((8, seq), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_bshd_bwd" in text
 
 
 def test_bhsd_causal_fwd_bwd_compiles_one_chip(topo):
@@ -116,10 +149,10 @@ def test_bshd_dp_sharded_compiles_four_chips(topo, monkeypatch):
     text = _fwd_bwd(attend).lower(*_specs(
         (64, 128, 12, 64), 64, 128, batch, batch,
         NamedSharding(mesh, P()))).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") == 2
     assert "all-gather" not in text
     # through the dispatcher the kernels lie under its scope, backward too
-    assert re.search(r'op_name="[^"]*\battention\b[^"]*\bflash_bshd_dkv\b',
+    assert re.search(r'op_name="[^"]*\battention\b[^"]*\bflash_bshd_bwd\b',
                      text)
 
 
@@ -158,14 +191,26 @@ def _block_specs(batch, rows, replicated):
             jax.ShapeDtypeStruct((batch, 512), jnp.int32, sharding=rows))
 
 
+_CUSTOM_CALL_RESULT = re.compile(r"= (\([^=]*\)|\S+) custom-call\(")
+
+
 def _assert_packed_block(text):
-    """Three Mosaic calls under the ``attention`` scope, and none of the
-    layout passes the split form paid: no ``pad_add`` fusion (the gradient
-    of the QKV split) and no bf16 copy of a q/k/v- or qkv-sized tensor."""
-    assert text.count("tpu_custom_call") == 3
-    for kernel in ("flash_bshd_fwd", "flash_bshd_dq", "flash_bshd_dkv"):
+    """Two Mosaic calls under the ``attention`` scope, forward and the one
+    fused backward, whose only result is the packed gradient (no ``delta``
+    array handed from one kernel to another: the forward's log-sum-exp is
+    the one float32 array a kernel writes), and none of the layout passes
+    the split form paid: no ``pad_add`` fusion (the gradient of the QKV
+    split) and no bf16 copy of a q/k/v- or qkv-sized tensor."""
+    assert text.count("tpu_custom_call") == 2
+    for kernel in _FUSED:
         assert re.search(r'op_name="[^"]*\battention\b[^"]*\b%s\b[^"]*'
                          r'/pallas_call"' % kernel, text), kernel
+    results = [_CUSTOM_CALL_RESULT.search(line).group(1)
+               for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sum(r.count("f32[") for r in results) == 1, results
+    backward = [r for r in results if "f32[" not in r]
+    assert len(backward) == 1 and re.match(r"bf16\[\d+,512,2304\]",
+                                           backward[0]), results
     assert "pad_add" not in text
     assert not _COPY_OF_QKV.search(text), _COPY_OF_QKV.search(text).group(0)
 
@@ -173,9 +218,11 @@ def _assert_packed_block(text):
 def test_packed_attention_block_compiles_without_layout_copies(topo,
                                                                monkeypatch):
     """The benchmark cell's block, b96 x s512, for one described chip."""
+    from mxnet_tpu.ops.pallas_kernels import flash_backward_stats
     monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
     one = SingleDeviceSharding(topo.devices[0])
     before = nn_ops.attention_dispatch_stats()
+    backwards = flash_backward_stats()
     text = _attention_block().lower(
         *_block_specs(96, one, one)).compile().as_text()
     _assert_packed_block(text)
@@ -183,6 +230,8 @@ def test_packed_attention_block_compiles_without_layout_copies(topo,
     assert after["packed"] == before["packed"] + 1
     assert (after["flash"], after["xla"]) == (before["flash"],
                                               before["xla"])
+    assert flash_backward_stats() == {"fused": backwards["fused"] + 1,
+                                      "split": backwards["split"]}
 
 
 def test_packed_attention_block_dp_sharded_compiles_four_chips(topo,

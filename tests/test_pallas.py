@@ -533,3 +533,182 @@ def test_sharded_packed_flash_draws_the_unsharded_dropout_mask():
     ref = jax.grad(lambda a: (unsharded(a) ** 2).sum())(qkv)
     np.testing.assert_allclose(np.asarray(grad), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
+
+
+# --------------------------- the fused head-fused backward (PR 28)
+
+def _bshd_residuals(packed, masked, dropout, causal, S, D):
+    """Operands and the forward's residuals for the two backward
+    implementations: B = 2 rows of HD = 256 columns; with ``masked`` the
+    first row keeps 5/8 of its keys and the second row NONE."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    B, H = 2, 256 // D
+    qkv = _rand((B, S, 3 * H * D), 160).astype(jnp.bfloat16)
+    g = _rand((B, S, H * D), 161).astype(jnp.bfloat16)
+    kv_mask = None
+    if masked:
+        lens = np.array([S * 5 // 8, 0])
+        kv_mask = jnp.asarray((np.arange(S)[None, :] < lens[:, None])
+                              .astype("int32"))
+    seed = jnp.asarray(29, jnp.int32) if dropout else None
+    if packed:
+        ops = (qkv, qkv, qkv)
+    else:
+        ops = tuple(qkv[:, :, i * H * D:(i + 1) * H * D] for i in range(3))
+    out, lse = pk._bshd_fwd_impl(*ops, packed, H, kv_mask, seed, causal,
+                                 dropout, True)
+    return ops + (packed, H, kv_mask, seed, out, lse, g, causal, dropout,
+                  True)
+
+
+def _fused_cases():
+    """Every shape x operand form x mask x dropout at head width 64; at
+    128 every shape x operand form under mask and dropout."""
+    shapes = [(False, 128), (False, 256), (False, 512), (True, 128),
+              (True, 256)]
+    cases = [(c, s, p, m, d, 64) for c, s in shapes for p in (True, False)
+             for m in (False, True) for d in (0.0, 0.1)]
+    cases += [(c, s, p, True, 0.1, 128) for c, s in shapes
+              for p in (True, False)]
+    return [pytest.param(*case, id="%s%d-%s-%s-%s-d%d" % (
+        "causal" if case[0] else "full", case[1],
+        "packed" if case[2] else "separate",
+        "masked" if case[3] else "nomask",
+        "drop0.1" if case[4] else "nodrop", case[5])) for case in cases]
+
+
+@pytest.mark.parametrize("causal,seq,packed,masked,dropout,head_dim",
+                         _fused_cases())
+def test_fused_backward_equals_the_two_kernel_backward(causal, seq, packed,
+                                                       masked, dropout,
+                                                       head_dim):
+    """``flash_bshd_bwd`` (one pass over the scores, keys on the sublanes,
+    two head groups of 128 columns on the grid) against ``flash_bshd_dq`` +
+    ``flash_bshd_dkv`` on the same residuals, both in interpret mode: equal
+    but for the last bfloat16 bit of a few elements (the transposed tile
+    sums the same float32 products in another order). The row whose keys
+    are all masked gets exactly zero gradients from both."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    args = _bshd_residuals(packed, masked, dropout, causal, seq, head_dim)
+    fused = pk._bshd_bwd_fused(*args, group=128)
+    split = pk._bshd_bwd_split(*args)
+    if not packed:
+        fused, split = (jnp.concatenate(d, -1) for d in (fused, split))
+    fused, split = (np.asarray(d, np.float32) for d in (fused, split))
+    assert fused.shape == (2, seq, 3 * 256)
+    assert np.isfinite(fused).all() and np.abs(split[0]).max() > 0.1
+    # one bfloat16 ulp is 2**-8 of the value
+    np.testing.assert_allclose(fused, split, rtol=2.0 ** -7,
+                               atol=2.0 ** -10 * np.abs(split).max())
+    assert np.mean(fused != split) < 2e-3
+    if masked:
+        assert not fused[1].any() and not split[1].any()
+
+
+@pytest.mark.parametrize("causal,seq,path", [
+    (False, 512, "fused"), (True, 256, "fused"), (False, 384, "fused"),
+    (False, 1024, "split"), (True, 512, "split"), (False, 640, "split")],
+    ids=["full512", "causal256", "full384", "full1024", "causal512",
+         "full640"])
+def test_backward_takes_the_fused_kernel_only_for_one_key_block(causal, seq,
+                                                                path):
+    """``_bshd_bwd_impl`` decides by what it sees: one key block as
+    ``_pick_blocks_bshd`` chose it -> ``flash_bshd_bwd``; more than one ->
+    ``flash_bshd_dq`` + ``flash_bshd_dkv`` as before. Read from
+    ``flash_backward_stats()`` and from the traced program."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    def loss(qkv, mask):
+        return jnp.sum(pk.flash_attention_packed(
+            qkv, 12, mask, None, causal, 0.0).astype(jnp.float32) ** 2)
+
+    before = pk.flash_backward_stats()
+    text = str(jax.make_jaxpr(jax.grad(loss))(
+        jax.ShapeDtypeStruct((2, seq, 2304), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, seq), jnp.int32)))
+    after = pk.flash_backward_stats()
+    other = "split" if path == "fused" else "fused"
+    assert after[path] == before[path] + 1 and after[other] == before[other]
+    names = sorted(set(line.split("=")[1] for line in text.splitlines()
+                       if line.strip().startswith("name=flash_")))
+    assert names == (["flash_bshd_bwd", "flash_bshd_fwd"] if path == "fused"
+                     else ["flash_bshd_dkv", "flash_bshd_dq",
+                           "flash_bshd_fwd"])
+
+
+def test_fused_backward_group_fits_the_vmem_budget():
+    """The head group is the widest lane-aligned run of whole heads whose
+    footprint fits; where the packed gradient's row does not fit at all
+    the two kernels run."""
+    from mxnet_tpu.ops.pallas_kernels import _fused_bwd_group
+    assert _fused_bwd_group(512, 768, 64, 2, True) == 384      # BERT-base
+    assert _fused_bwd_group(512, 768, 64, 2, False) == 384
+    assert _fused_bwd_group(128, 768, 64, 2, True) == 768
+    assert _fused_bwd_group(512, 1024, 128, 2, True) == 256
+    assert _fused_bwd_group(512, 1024, 64, 2, False) == 512
+    assert _fused_bwd_group(512, 2048, 128, 2, True) is None
+
+
+def _attention_jaxpr_hashes():
+    """sha256 of the traced forward + backward of the kernels this PR must
+    not change, at the shapes their users run."""
+    import hashlib
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    def sq(x):
+        return jnp.sum(x.astype(jnp.float32) ** 2)
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    traced = {}
+    # the Kimi cell: 4 causal rows of 8192, 16 heads, 128 + 64 against 128
+    latent = jax.value_and_grad(
+        lambda a, b, c, d: sq(pk.flash_attention_latent(a, b, c, d, 16, True)),
+        argnums=(0, 1, 2, 3))
+    traced["latent_4x8192_h16"] = jax.make_jaxpr(latent)(
+        spec(4, 8192, 2048), spec(4, 8192, 16, 64), spec(4, 8192, 4096),
+        spec(4, 8192, 64))
+    q = spec(2, 4, 512, 64)
+    mask, seed = spec(2, 512, dtype=jnp.int32), spec(dtype=jnp.int32)
+    for name, causal, drop, masked in (
+            ("bhsd_causal", True, 0.0, False),
+            ("bhsd_masked_dropout", False, 0.1, True)):
+        def bhsd(q, k, v, m, s, causal=causal, drop=drop, masked=masked):
+            return sq(pk.flash_attention(q, k, v, m if masked else None,
+                                         s if drop else None, causal, drop))
+        traced[name] = jax.make_jaxpr(jax.value_and_grad(
+            bhsd, argnums=(0, 1, 2)))(q, q, q, mask, seed)
+    # the head-fused kernels over more than one key block: the two-kernel
+    # backward as it was
+    for name, S, causal in (("packed_split_s1024", 1024, False),
+                            ("packed_split_causal_s512", 512, True)):
+        def packed(qkv, m, causal=causal):
+            return sq(pk.flash_attention_packed(qkv, 12, m, None, causal,
+                                                0.0))
+        traced[name] = jax.make_jaxpr(jax.value_and_grad(packed))(
+            spec(2, S, 2304), spec(2, S, dtype=jnp.int32))
+
+    def bshd(q, k, v, m, s):
+        return sq(pk.flash_attention_bshd(q, k, v, m, s, False, 0.1))
+    q = spec(2, 1024, 12, 64)
+    traced["bshd_split_s1024_dropout"] = jax.make_jaxpr(jax.value_and_grad(
+        bshd, argnums=(0, 1, 2)))(q, q, q, spec(2, 1024, dtype=jnp.int32),
+                                  seed)
+    return {name: hashlib.sha256(str(j).encode()).hexdigest()
+            for name, j in traced.items()}
+
+
+def test_latent_bhsd_and_split_kernels_trace_to_the_parents_jaxprs():
+    """``flash_latent_*`` at the Kimi cell's shape, ``flash_bhsd_*`` and the
+    two-kernel head-fused path trace to the programs recorded from the
+    commit before the fused backward (``tests/data/
+    attention_jaxprs_pr27.json``, written by this function there): the
+    control cell stands still, shown without the chip."""
+    import json
+    import os
+    path = os.path.join(os.path.dirname(__file__), "data",
+                        "attention_jaxprs_pr27.json")
+    with open(path) as f:
+        recorded = json.load(f)
+    assert _attention_jaxpr_hashes() == recorded

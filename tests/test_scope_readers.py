@@ -96,13 +96,15 @@ def test_traced_step_carries_the_programs_scopes(path, monkeypatch):
                          "/".join(scopes.scope_path(n)))
                for n in layers[scopes.BLOCKS])
     kernels = {k for n in attention
-               for k in ("flash_bshd_fwd", "flash_bshd_dq", "flash_bshd_dkv")
+               for k in ("flash_bshd_fwd", "flash_bshd_bwd", "flash_bshd_dq",
+                         "flash_bshd_dkv")
                if "/attention/%s/" % k in n}
-    assert kernels == ({"flash_bshd_fwd", "flash_bshd_dq", "flash_bshd_dkv"}
+    # 128 positions are one key block: forward and the one fused backward
+    assert kernels == ({"flash_bshd_fwd", "flash_bshd_bwd"}
                        if path == "flash_interpret" else set())
     # the backward rule of the kernels' custom_vjp keeps the call site's scope
     if path == "flash_interpret":
-        assert any("transpose(jvp(" in n and "/attention/flash_bshd_dkv/" in n
+        assert any("transpose(jvp(" in n and "/attention/flash_bshd_bwd/" in n
                    for n in attention)
 
 
@@ -320,6 +322,37 @@ def test_scheduler_self_time_reader():
     assert read({"kind": "serve", "spans": spans}) == pytest.approx(12.0)
     assert read({"kind": "serve", "spans": spans[1:4]}) is None
     assert read({"kind": "train", "spans": spans}) is None
+
+
+@pytest.mark.parametrize("path,expected", [
+    ("fused", 100.0), ("split", 0.0), ("both", 50.0), ("no_counter", None),
+    ("nothing_traced", None)])
+def test_attention_fused_bwd_reader(path, expected, monkeypatch):
+    """``attention_fused_bwd_pct.train`` over ``flash_backward_stats()``:
+    a tiny BERT step through the packed kernels at 128 positions (one key
+    block) reads 100; a backward over two key blocks reads 0; a program
+    without the counter (the parent), or one that traced no head-fused
+    backward (the XLA path), reads None."""
+    monkeypatch.setattr(pk, "_BACKWARDS", dict.fromkeys(pk._BACKWARDS, 0))
+    if path in ("fused", "both"):
+        _interpret_packed_kernels(monkeypatch)
+        _step_locations()
+        assert pk.flash_backward_stats() == {"fused": 2, "split": 0}
+    elif path == "nothing_traced":
+        _step_locations()                       # the composed softmax
+    if path in ("split", "both"):
+        import jax
+        import jax.numpy as jnp
+        for _ in range(2 if path == "both" else 1):
+            jax.make_jaxpr(jax.grad(lambda a: jnp.sum(
+                pk.flash_attention_packed(a, 2, None, None, False, 0.0)
+                .astype(jnp.float32))))(
+                    jax.ShapeDtypeStruct((1, 1024, 768), jnp.bfloat16))
+    if path == "no_counter":
+        monkeypatch.delattr(pk, "flash_backward_stats")
+    read = load_reader("attention_fused_bwd_pct.train", METRIC_DIR)
+    assert read(_obs()) == expected
+    assert read(_obs(kind="serve")) is None
 
 
 @pytest.mark.parametrize("path,expected", [
