@@ -22,8 +22,7 @@ must not even import ``mxnet_tpu.serving`` — the decode/serving suites
 ride this PR untouched.
 
 Writes ``ELASTIC3D.json`` (stamped via benchmark/_artifact.py).
-``--skip-recovery`` runs only the in-process placement section (what
-``bench.py``'s crash-isolated ``elastic3d`` section uses).
+``--skip-recovery`` runs only the in-process placement section.
 """
 from __future__ import annotations
 
@@ -81,10 +80,9 @@ def bench_placement(steps=12):
     # the memory-constrained config: a budget pure-dp (every expert
     # replicated on every device) cannot meet, sized off the model so
     # the bench stays meaningful if the config changes. Floored at the
-    # tightest feasible placement so a small pool (bench.py on a single
-    # real chip) still plans instead of erroring — there the comparison
-    # honestly reports beats_pure_dp=false rather than failing the
-    # section.
+    # tightest feasible placement so a small pool (a single real chip)
+    # still plans instead of erroring — there the comparison honestly
+    # reports beats_pure_dp=false rather than failing.
     budget = int(max(dp_mem * 0.6,
                      planner.min_memory_per_device(n_dev, profile) * 1.05))
     plan = planner.plan_sharding(n_dev, profile, hbm_bytes=budget)
@@ -236,7 +234,7 @@ def main():
     ap.add_argument("--devices", type=int, default=8)
     ap.add_argument("--step-slow-ms", type=float, default=150.0)
     ap.add_argument("--skip-recovery", action="store_true",
-                    help="placement comparison only (bench.py section)")
+                    help="placement comparison only")
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "ELASTIC3D.json"))
     args = ap.parse_args()

@@ -22,9 +22,6 @@ a speedup claim (``cpu_caveat`` is stamped; counters and assertions are
 the portable result).
 
 Writes ``SHARDED_SERVING.json`` (stamped via benchmark/_artifact.py).
-``bench.py``'s ``sharded_serving`` section runs this file as a
-subprocess on a forced 8-device CPU host platform and merges the
-artifact into the round.
 """
 from __future__ import annotations
 
@@ -294,8 +291,7 @@ def main():
         os.path.dirname(os.path.abspath(__file__)),
         "SHARDED_SERVING.json"))
     ap.add_argument("--json-only", action="store_true",
-                    help="print the artifact to stdout, write no file "
-                         "(bench.py section mode)")
+                    help="print the artifact to stdout, write no file")
     args = ap.parse_args()
     _force_devices(args.devices)
 

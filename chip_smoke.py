@@ -16,9 +16,10 @@ non-zero exit and no result line. Phases (full width, random weights from
 - ``train_bert``   — BERT-base pretraining step, s512 b16 bf16, padding
   mask, dropout as shipped; the compiled step must contain the Pallas
   attention kernels. Then ``dot_product_attention`` at (16, 512, 12, 64)
-  with a padding mask: the flash kernels against the XLA path of the same
-  op, on the chip, and the packed entry (value and packed gradient)
-  against the separate-operand kernels, bit for bit.
+  with a padding mask: the flash kernels against the op's XLA form
+  (``ops.nn.xla_attention``, called directly), on the chip, and the packed
+  entry (value and packed gradient) against the separate-operand kernels,
+  bit for bit.
 - ``serve_lm``     — ``transformer_lm_base`` behind ``DecodeEngine`` ->
   ``GenerationScheduler`` -> ``ModelServer`` in this process: four
   concurrent HTTP ``POST /generate`` streams + ``GET /healthz``; served
@@ -156,9 +157,8 @@ def make_bert(seq, dropout=None):
 
 
 def bert_trainer(seed, mesh, seq, dropout=None):
-    """BERT-base + pretraining loss behind ShardedTrainer (adam, bf16) —
-    the ``bert`` configuration of benchmark/bench_lm.py, with the padding
-    mask real pretraining batches carry."""
+    """BERT-base + pretraining loss behind ShardedTrainer (adam, bf16),
+    with the padding mask real pretraining batches carry."""
     import mxnet_tpu as mx
     from mxnet_tpu import parallel
     from mxnet_tpu.gluon.block import HybridBlock
@@ -243,8 +243,8 @@ def phase_train_bert(dev, seed):
 
 def check_flash_against_xla(seed):
     """The first on-device correctness check of the kernels: the op's
-    flash path (bf16, as the models call it) against its XLA path on the
-    same values in f32, the flash gate closed for that one trace."""
+    flash path (bf16, as the models call it) against its XLA form on the
+    same values in f32."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import nn as nn_ops
@@ -268,20 +268,16 @@ def check_flash_against_xla(seed):
     out = host(flash(q, k, v, mask)).astype(np.float32)
     flash_s = clock() - t0
 
-    prev = os.environ.get("MXNET_FLASH_ATTENTION")
-    os.environ["MXNET_FLASH_ATTENTION"] = "0"     # the existing gate
-    try:
-        xla = jax.jit(attend)
-        f32 = [a.astype(jnp.float32) for a in (q, k, v)]
-        with jax.default_matmul_precision("highest"):
-            assert "tpu_custom_call" not in \
-                xla.lower(*f32, mask).compile().as_text()
-            ref = host(xla(*f32, mask))
-    finally:
-        if prev is None:
-            del os.environ["MXNET_FLASH_ATTENTION"]
-        else:
-            os.environ["MXNET_FLASH_ATTENTION"] = prev
+    def heads_first(a):
+        return jnp.transpose(a, (0, 2, 1, 3))
+
+    xla = jax.jit(lambda q, k, v, mask: heads_first(nn_ops.xla_attention(
+        heads_first(q), heads_first(k), heads_first(v), mask)))
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        assert "tpu_custom_call" not in \
+            xla.lower(*f32, mask).compile().as_text()
+        ref = host(xla(*f32, mask))
     assert out.shape == ref.shape == (B, S, H, D)
     assert np.isfinite(out).all()
     err = float(np.abs(out - ref).max())

@@ -183,11 +183,6 @@ KNOBS = dict([
        "retire after first token + prefix-cache publish — the "
        "disaggregation handoff), or 'decode' (admits expect prefix-cache "
        "coverage; misses are counted as decode_lane_misses)"),
-    _k("MXNET_FLASH_ATTENTION", 1, int, "wired",
-       "dispatch _contrib_dot_product_attention to the pallas flash "
-       "kernels when the problem aligns and a TPU is present (ops/nn.py; "
-       "0 = always take the XLA softmax path — the with/without switch "
-       "benchmark/bench_lm.py records the BERT MFU delta with)"),
     _k("MXNET_HTTP_MAX_BODY", 8 * 1024 * 1024, int, "wired",
        "ModelServer POST body cap in bytes: a larger client-declared "
        "Content-Length is consumed in bounded chunks and refused with "

@@ -13,21 +13,12 @@ re-layouts internally for the TPU).
 from __future__ import annotations
 
 import math as _math
-import os as _os
+from functools import partial as _partial
 
 import numpy as _np
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-# round-5 perf-experiment gates (each a measured end-to-end loss in its
-# default-off state -- see PERF.md round-5 study)
-_POOL_EQBWD = _os.environ.get("MXTPU_MAXPOOL_EQBWD", "0") == "1"
-_CONV_S2D = _os.environ.get("MXTPU_CONV_S2D", "0") == "1"
-_BN_BARRIER = _os.environ.get("MXTPU_BN_BARRIER", "0") == "1"
-# threefry restores jax.random.bernoulli dropout masks (10x costlier on
-# the VPU than the default counter-hash; see PERF.md round-5 LM study)
-_DROPOUT_THREEFRY = _os.environ.get("MXTPU_DROPOUT_THREEFRY", "0") == "1"
 
 from ..base import dtype_np
 from ._common import _bind_key, _bind_train
@@ -60,36 +51,6 @@ def _pair(v, n=2):
     return t if t else (1,) * n
 
 
-def _conv_s2d_stride2(data, weight, padding):
-    """Stride-2 conv with few input channels, rewritten via space-to-depth.
-
-    A 7x7/s2 stem conv on 3 channels runs the MXU at ~3/128 packing — the
-    round-5 profile measured the ResNet-50 stem fwd+dw at 5.2% of step time
-    (~24 TFLOP/s vs the 54 conv ceiling). Mathematically identical rewrite:
-    block-2 space-to-depth on the (padded) input (C -> 4C channels, half
-    spatial) turns it into a ceil(k/2)^2 STRIDE-1 conv on 4C channels:
-        out[o,i,j] = sum_{c,u,v} xp[c,2i+u,2j+v] w[o,c,u,v]
-                   = sum_{c,r_u,r_v,q_u,q_v} X2[(c,ru,rv), i+qu, j+qv]
-                                             W2[o,(c,ru,rv), qu, qv]
-    with u = 2 qu + ru (kernel zero-padded k -> 2*ceil(k/2)). Same FLOPs,
-    4x the MXU contraction depth, and the gradient convs (autodiff through
-    the reshape/transpose) get the same packing win."""
-    N, C, H, W = data.shape
-    O, _, K, _ = weight.shape
-    K2 = (K + 1) // 2
-    xp = jnp.pad(data, [(0, 0), (0, 0), padding[0], padding[1]])
-    Hp, Wp = xp.shape[2], xp.shape[3]
-    x2 = xp.reshape(N, C, Hp // 2, 2, Wp // 2, 2)
-    x2 = x2.transpose(0, 1, 3, 5, 2, 4).reshape(N, C * 4, Hp // 2, Wp // 2)
-    wp = jnp.pad(weight, [(0, 0), (0, 0), (0, 2 * K2 - K), (0, 2 * K2 - K)])
-    w2 = wp.reshape(O, C, K2, 2, K2, 2)
-    w2 = w2.transpose(0, 1, 3, 5, 2, 4).reshape(O, C * 4, K2, K2)
-    dn = lax.conv_dimension_numbers(x2.shape, w2.shape,
-                                    ("NCHW", "OIHW", "NCHW"))
-    return lax.conv_general_dilated(x2, w2, (1, 1), [(0, 0), (0, 0)],
-                                    dimension_numbers=dn)
-
-
 @register("Convolution", aliases=("convolution",))
 def Convolution(data, weight, bias=None, kernel=(), stride=(), dilate=(),
                 pad=(), num_filter=0, num_group=1, no_bias=False,
@@ -102,22 +63,6 @@ def Convolution(data, weight, bias=None, kernel=(), stride=(), dilate=(),
     dilate = _pair(dilate, nd)
     pad = _pair(pad, nd) if pad else (0,) * nd
     padding = [(p, p) for p in pad]
-    if (_CONV_S2D and nd == 2 and num_group == 1 and stride == (2, 2)
-            and dilate == (1, 1)
-            and weight.ndim == 4 and weight.shape[1] * weight.shape[2] <= 32
-            and weight.shape[2] == weight.shape[3]
-            and weight.shape[2] % 2 == 1 and weight.shape[2] >= 5
-            and (data.shape[2] + 2 * pad[0]) % 2 == 0
-            and (data.shape[3] + 2 * pad[1]) % 2 == 0):
-        # OFF by default: measured on-chip (round 5, ResNet-50 b32) the
-        # space-to-depth shuffle cost exceeded the MXU-packing gain
-        # (2695 vs 2782 img/s end-to-end, barrier'd or fused) — the stem
-        # conv is latency- not depth-bound at these shapes. Kept behind
-        # MXTPU_CONV_S2D=1; the rewrite itself is oracle-exact.
-        out = _conv_s2d_stride2(data, weight, padding)
-        if not no_bias and bias is not None:
-            out = out + bias.reshape((1, -1, 1, 1))
-        return out
     dn_str = {1: ("NCH", "OIH", "NCH"),
               2: ("NCHW", "OIHW", "NCHW"),
               3: ("NCDHW", "OIDHW", "NCDHW")}[nd]
@@ -169,106 +114,6 @@ def Deconvolution(data, weight, bias=None, kernel=(), stride=(), dilate=(),
     return out
 
 
-
-
-@jax.custom_vjp
-def _fwd_barrier(x):
-    """optimization_barrier in the forward pass only; gradients flow
-    through untouched (a plain barrier transposes to a cotangent barrier,
-    which breaks backward fusions)."""
-    return lax.optimization_barrier(x)
-
-
-_fwd_barrier.defvjp(lambda x: (lax.optimization_barrier(x), None),
-                    lambda _, g: (g,))
-
-
-# -- max-pool with a TPU-friendly backward ---------------------------------
-#
-# XLA derives reduce_window's max-pool gradient as select-and-scatter, which
-# the round-2/round-5 profiles measured as the single slowest HLO in the
-# ResNet-50 step (3.8% of device time for ONE op, plus a 1.8% forward that
-# re-reads windows). This custom VJP keeps the reduce_window forward but
-# replaces the backward with an equality-spread: each input position checks
-# the <=ceil(k/s)^2 windows that cover it and accumulates g/count for every
-# window whose max it equals (count = number of tied positions, computed
-# with k^2 strided slices in output space). Tie handling differs from
-# select-and-scatter (which gives the whole gradient to the FIRST max):
-# ties SHARE the gradient — per-window gradient mass is identical, and for
-# the no-tie case (distinct window values) the two are exactly equal.
-
-def _cover_indices(in_size, out_size, k, s, p):
-    """Per input coordinate y, the <=2 output windows covering it (valid
-    for k <= 2s): index vectors (lo, hi) and hi's validity mask."""
-    yp = _np.arange(in_size) + p
-    lo = (yp - k + s) // s          # ceil((yp - k + 1) / s)
-    hi = yp // s
-    # full membership check (window i covers yp iff i*s <= yp < i*s + k):
-    # with k < s there are inter-window gaps, and a clamped/gap index must
-    # not claim coverage
-    lo_ok = (lo >= 0) & (lo <= out_size - 1) & \
-        (lo * s <= yp) & (lo * s + k > yp)
-    hi_ok = (hi >= 0) & (hi <= out_size - 1) & (hi != lo) & \
-        (hi * s <= yp) & (hi * s + k > yp)
-    return (_np.clip(lo, 0, out_size - 1), lo_ok,
-            _np.clip(hi, 0, out_size - 1), hi_ok)
-
-
-def _maxpool2d_fwd(data, kernel, stride, padding):
-    init = -jnp.inf if jnp.issubdtype(data.dtype, jnp.floating) \
-        else jnp.asarray(jnp.iinfo(data.dtype).min, data.dtype)
-    return lax.reduce_window(data, init, lax.max, (1, 1) + kernel,
-                             (1, 1) + stride, [(0, 0), (0, 0)] + padding)
-
-
-def _maxpool2d_nchw_bwd(kernel, stride, padding, res, g):
-    data, out = res
-    (kh, kw), (sh, sw) = kernel, stride
-    (ph, _), (pw, _) = padding
-    N, C, H, W = data.shape
-    OH, OW = out.shape[2], out.shape[3]
-    neg = jnp.asarray(-jnp.inf, data.dtype)
-    xp = jnp.pad(data, [(0, 0), (0, 0), padding[0], padding[1]],
-                 constant_values=neg)
-    # ties per window: k*k strided slices of the padded input, all fused
-    # into one elementwise pass in output space
-    count = None
-    for dy in range(kh):
-        for dx in range(kw):
-            sl = lax.slice(xp, (0, 0, dy, dx),
-                           (N, C, dy + sh * (OH - 1) + 1,
-                            dx + sw * (OW - 1) + 1), (1, 1, sh, sw))
-            eq = (sl == out).astype(jnp.float32)
-            count = eq if count is None else count + eq
-    gn = (g.astype(jnp.float32) / count).astype(data.dtype)
-    # spread back: for each of the <=2x2 covering windows per position,
-    # gather out/gn rows (constant index vectors -> fused gathers) and
-    # accumulate where the input equals the window max
-    ylo, ylo_ok, yhi, yhi_ok = _cover_indices(H, OH, kh, sh, ph)
-    xlo, xlo_ok, xhi, xhi_ok = _cover_indices(W, OW, kw, sw, pw)
-    gin = jnp.zeros(data.shape, data.dtype)
-    for yi, ym in ((ylo, ylo_ok), (yhi, yhi_ok)):
-        for xi, xm in ((xlo, xlo_ok), (xhi, xhi_ok)):
-            o = jnp.take(jnp.take(out, yi, axis=2), xi, axis=3)
-            gv = jnp.take(jnp.take(gn, yi, axis=2), xi, axis=3)
-            m = (ym[:, None] & xm[None, :])
-            gin = gin + jnp.where((data == o) & m, gv,
-                                  jnp.zeros((), data.dtype))
-    return (gin,)
-
-
-# kernel/stride/padding are static python values (nondiff)
-_maxpool2d_nchw = jax.custom_vjp(_maxpool2d_fwd, nondiff_argnums=(1, 2, 3))
-
-
-def _maxpool2d_res_fwd(data, kernel, stride, padding):
-    out = _maxpool2d_fwd(data, kernel, stride, padding)
-    return out, (data, out)
-
-
-_maxpool2d_nchw.defvjp(_maxpool2d_res_fwd, _maxpool2d_nchw_bwd)
-
-
 @register("Pooling", aliases=("pooling",))
 def Pooling(data, kernel=(), pool_type="max", stride=(), pad=(),
             global_pool=False, pooling_convention="valid", cudnn_off=False,
@@ -301,17 +146,6 @@ def Pooling(data, kernel=(), pool_type="max", stride=(), pad=(),
     else:
         padding = [(0, 0), (0, 0)] + [(p, p) for p in pad]
     if pool_type == "max":
-        spad = padding[2:]
-        if (_POOL_EQBWD and nd == 2
-                and jnp.issubdtype(data.dtype, jnp.floating)
-                and all(k <= 2 * s for k, s in zip(kernel, stride))
-                and all(p[0] == p[1] for p in spad)):
-            # Equality-spread backward (see _maxpool2d_nchw above). OFF by
-            # default: measured on-chip (round 5), the gather-based spread
-            # lowered to materialized layout copies and LOST ~25% end-to-end
-            # vs XLA's select-and-scatter; kept behind MXTPU_MAXPOOL_EQBWD=1
-            # for future reruns against newer XLA gather fusion.
-            return _maxpool2d_nchw(data, kernel, stride, list(spad))
         init = (-jnp.inf if jnp.issubdtype(data.dtype, jnp.floating)
                 else jnp.asarray(jnp.iinfo(data.dtype).min, data.dtype))
         return lax.reduce_window(data, init, lax.max, window, strides, padding)
@@ -466,9 +300,6 @@ def SoftmaxOutput(data, label, grad_scale=1.0, ignore_label=-1.0,
                            float(use_ignore), float(multi_output))
 
 
-from functools import partial as _partial
-
-
 @_partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
 def _softmax_output(data, label, grad_scale, ignore_label, use_ignore,
                     multi_output):
@@ -538,15 +369,6 @@ def BatchNorm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
         # formulation and precision as cuDNN/TF fused batch norm (the
         # reference's backend); fp32 accumulation bounds the cancellation
         # error at ~mean^2 * 2^-24, which the max(.., 0) clamp backstops.
-        if _BN_BARRIER:
-            # Keep the stat reductions OUT of the producing conv's fusion:
-            # measured on-chip (round 5, scan probes at ResNet stage-2/3
-            # shapes), a conv with BN-stat epilogue fused runs at 74-80
-            # TFLOP/s vs 86-96 with this barrier (+17-20%). Forward-only
-            # (identity gradient): a plain optimization_barrier transposes
-            # to a cotangent barrier that measurably breaks backward
-            # fusions (2495 vs 2772 img/s end-to-end ResNet-50).
-            data = _fwd_barrier(data)
         xf = data.astype(jnp.float32)
         mean = jnp.mean(xf, axis=reduce_axes)
         var = jnp.maximum(
@@ -665,10 +487,7 @@ def Dropout(data, p=0.5, mode="training", axes=(), cudnn_off=False,
     shape = list(data.shape)
     for ax in (axes or ()):
         shape[ax] = 1
-    if _DROPOUT_THREEFRY:
-        keep = jax.random.bernoulli(key, 1.0 - p, tuple(shape))
-    else:
-        keep = _hash_keep_mask(key, tuple(shape), 1.0 - p)
+    keep = _hash_keep_mask(key, tuple(shape), 1.0 - p)
     return jnp.where(keep, data / (1.0 - p), jnp.zeros((), dtype=data.dtype))
 
 
@@ -728,127 +547,18 @@ def UpSampling(*data, scale=1, sample_type="nearest", num_args=1,
 
 
 # ------------------------------------------------------------ attention
-
-
-def _flash_enabled():
-    """Single gate for the pallas flash-attention dispatch: the
-    registered ``MXNET_FLASH_ATTENTION`` knob (0 disables — the
-    with/without benchmark switch) plus the legacy ``MXTPU_DISABLE_FLASH``
-    escape hatch."""
-    import os
-    if os.environ.get("MXTPU_DISABLE_FLASH"):
-        return False
-    from .. import config as _config
-    return bool(_config.get("MXNET_FLASH_ATTENTION"))
-
-
-def _reduce_key_mask(mask, batch, key_len):
-    """Reduce a BERT-style broadcastable keep-mask to (B, S_k) for the
-    flash kernels. Returns (kv_mask, ok): ok=False means the mask shape
-    is unsupported by the fused path (full (B,H,Q,K) masks etc.)."""
-    if mask is None:
-        return None, True
-    nd = getattr(mask, "ndim", 0)
-    if nd == 4 and mask.shape[1] == 1 and mask.shape[2] == 1 and \
-            mask.shape[0] == batch and mask.shape[3] == key_len:
-        return mask[:, 0, 0, :], True
-    if nd == 2 and mask.shape == (batch, key_len):
-        return mask, True
-    return None, False
-
-
-def _shard_flash(call, operands, num_heads, heads_dim, mesh, batch_axes,
-                 kv_mask, seed):
-    """``call(operands, kv_mask, seed)`` under ``jax.shard_map`` over
-    ``mesh``: GSPMD cannot partition a Mosaic kernel, so each device runs
-    it on its own shard — the batch dim split over ``batch_axes``, the
-    heads dim (``heads_dim``; ``None`` = the operands have none to split,
-    the packed projection) over ``tp``, each only where the dim divides
-    (what does not divide is computed replicated). Every shard folds its
-    global batch/head offset into the dropout seed operand, so the
-    keep-mask is the unsharded call's."""
-    from jax.sharding import PartitionSpec as P
-    B, H = operands[0].shape[0], num_heads
-    b_axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)
-    n_b = _math.prod(mesh.shape[a] for a in b_axes)
-    if not b_axes or B % n_b:
-        b_axes, n_b = None, 1
-    tp = mesh.shape.get("tp", 1)
-    h_axis, n_h = ("tp", tp) if heads_dim is not None and tp > 1 \
-        and H % tp == 0 else (None, 1)
-    spec = [b_axes] + [None] * (operands[0].ndim - 1)
-    if heads_dim is not None:
-        spec[heads_dim] = h_axis
-    spec = P(*spec)
-
-    def shard(kv_mask, seed, *operands):
-        if seed is not None:
-            b_off = lax.axis_index(b_axes) * (B // n_b) if b_axes else 0
-            h_off = lax.axis_index(h_axis) * (H // n_h) if h_axis else 0
-            seed = jnp.stack([seed, jnp.int32(b_off * H + h_off),
-                              jnp.int32(H)])
-        return call(operands, kv_mask, seed)
-
-    return jax.shard_map(
-        shard, mesh=mesh,
-        in_specs=(P(b_axes, None), P()) + (spec,) * len(operands),
-        out_specs=spec, check_vma=False)(kv_mask, seed, *operands)
-
-
-def _sharded_flash(kernel, heads_dim, mesh, batch_axes, query, key, value,
-                   kv_mask, seed, causal, drop, interpret=False):
-    """A q/k/v ``kernel`` on its shard of ``mesh`` (:func:`_shard_flash`)."""
-    return _shard_flash(
-        lambda qkv, m, s: kernel(*qkv, m, s, causal, drop, interpret),
-        (query, key, value), query.shape[heads_dim], heads_dim, mesh,
-        batch_axes, kv_mask, seed)
-
-
-def _flash_seed(drop, rng_key):
-    if drop > 0.0:
-        return jax.random.randint(rng_key, (), -2**31, 2**31 - 1,
-                                  dtype=jnp.int32)
-    return None
-
-
-def _flash_call(kernel, heads_dim, query, key, value, kv_mask, rng_key,
-                causal, drop):
-    """Run a flash kernel on the devices the enclosing program spans:
-    the bare call on one device, :func:`_sharded_flash` when the trainer
-    or serving lane tracing this op made a larger mesh visible
-    (``parallel.mesh.mesh_scope``)."""
-    from ..parallel.mesh import current_scope
-    seed = _flash_seed(drop, rng_key)
-    scope = current_scope()
-    if scope is None or scope[0].size == 1:
-        return kernel(query, key, value, kv_mask, seed, causal, drop)
-    return _sharded_flash(kernel, heads_dim, scope[0], scope[1], query,
-                          key, value, kv_mask, seed, causal, drop)
-
-
-def _packed_flash_call(qkv, num_heads, kv_mask, rng_key, causal, drop):
-    """:func:`_flash_call` for the packed projection: the batch over the
-    visible mesh's batch axes, nothing over ``tp`` (the caller sends a
-    ``tp`` > 1 mesh down the split path, whose heads dim can shard)."""
-    from ..parallel.mesh import current_scope
-    from .pallas_kernels import flash_attention_packed
-    seed = _flash_seed(drop, rng_key)
-    scope = current_scope()
-    if scope is None or scope[0].size == 1:
-        return flash_attention_packed(qkv, num_heads, kv_mask, seed, causal,
-                                      drop)
-    return _shard_flash(
-        lambda ops, m, s: flash_attention_packed(ops[0], num_heads, m, s,
-                                                 causal, drop),
-        (qkv,), num_heads, None, scope[0], scope[1], kv_mask, seed)
-
+#
+# Three entries (separate q, k, v; the packed QKV projection; latent
+# attention), ONE decision (`_attention_path`), one way to run a Mosaic
+# kernel on the visible mesh (`_flash` over `_shard_flash`), and the XLA
+# forms every kernel is tested against.
 
 # Which implementation each attention call was traced into, counted where
 # the dispatcher decides (once a trace, not once a step): "packed" = the
 # flash kernels on the unsplit QKV projection, "flash" = the flash kernels
-# on separate q/k/v, "latent" = the latent-attention flash kernels (score
-# of two dot products, keys wider than values), "xla" = the composed
-# softmax.
+# on separate q/k/v (head-fused or per-head), "latent" = the
+# latent-attention flash kernels (score of two dot products, keys wider
+# than values), "xla" = the composed softmax.
 _DISPATCHED = {"packed": 0, "flash": 0, "latent": 0, "xla": 0}
 
 
@@ -860,6 +570,190 @@ def attention_dispatch_stats():
 
 def _on_accelerator():
     return any(d.platform != "cpu" for d in jax.devices())
+
+
+def _attention_path(form, shape, kv_shapes=(), mask_shape=None, scaled=True,
+                    drop=0.0, keyed=True):
+    """Name the implementation an attention call runs, from what can be
+    observed of it and nothing else: ``"packed"``, ``"bshd"``, ``"bhsd"``,
+    ``"latent"`` (the flash kernels of ``ops/pallas_kernels.py``) or
+    ``"xla"`` (the composed softmax).
+
+    =========  ===============================  ==========================
+    ``form``   ``shape``                        kernels tried, in order
+    =========  ===============================  ==========================
+    packed     the projection's (B, S, H, D)    packed, bshd, bhsd
+    BSHD       q's (B, S, H, D)                 bshd, bhsd
+    BHSD       q's (B, H, S, D)                 bhsd
+    latent     (S, nope, rope, v)               latent
+    =========  ===============================  ==========================
+
+    ``kv_shapes``: k's and v's shapes; ``mask_shape``: the keep-mask's, or
+    ``None``; ``drop``: the dropout rate in force, ``keyed`` whether a key
+    came with it. Every kernel needs an accelerator and S a multiple of
+    128. The q/k/v kernels besides need k and v of q's shape (self-
+    attention, no grouped heads), the 1/sqrt(D) scale, a mask that reduces
+    to one row of keys a batch entry ((B, 1, 1, S) or (B, S)), a key
+    wherever there is dropout, and D <= 256. The head-fused ``bshd``
+    kernels read (S, H*D) rows: H*D must be a multiple of 128 and two whole
+    operands fit 8 MiB of VMEM (else the per-head ``bhsd`` kernels, at the
+    price of a transpose each way). ``packed`` is ``bshd`` on the unsplit
+    projection, which cannot shard its heads: under a visible mesh with
+    ``tp`` > 1 the projection is split for ``bshd``. The latent kernels
+    need nope, v multiples of 128 and rope a multiple of 8 up to 128."""
+    from ..parallel.mesh import current_scope
+    from . import pallas_kernels as pk
+    if not _on_accelerator():
+        return "xla"
+    if form == "latent":
+        return "latent" if pk.flash_attention_latent_usable(*shape) else "xla"
+    if (len(shape) != 4 or any(tuple(s) != tuple(shape) for s in kv_shapes)
+            or not scaled or (drop > 0.0 and not keyed)):
+        return "xla"
+    B, S, H, D = (shape[0], shape[2], shape[1], shape[3]) if form == "BHSD" \
+        else shape
+    if mask_shape not in (None, (B, 1, 1, S), (B, S)):
+        return "xla"
+    if form != "BHSD" and pk.flash_attention_bshd_usable((B, S, H, D), D):
+        scope = current_scope()
+        tp = scope[0].shape.get("tp", 1) if scope is not None else 1
+        return "packed" if form == "packed" and tp == 1 else "bshd"
+    return "bhsd" if pk.flash_attention_usable((B, H, S, D)) else "xla"
+
+
+def _dispatch(form, shape, **observed):
+    """:func:`_attention_path` for a call being traced, counted."""
+    path = _attention_path(form, shape, **observed)
+    _DISPATCHED["flash" if path in ("bshd", "bhsd") else path] += 1
+    return path
+
+
+def _mask_shape(mask):
+    return None if mask is None else tuple(jnp.shape(mask))
+
+
+def _shard_flash(call, operands, num_heads, heads_dim, mesh, batch_axes,
+                 kv_mask, seed):
+    """``call(operands, kv_mask, seed)`` under ``jax.shard_map`` over
+    ``mesh``: GSPMD cannot partition a Mosaic kernel, so each device runs
+    it on its own shard — every operand's batch dim split over
+    ``batch_axes``, the heads dim (``heads_dim``; ``None`` = the operands
+    have none to split: the packed projection, the latent's four) over
+    ``tp``, each only where the dim divides (what does not divide is
+    computed replicated). Every shard folds its global batch/head offset
+    into the dropout seed operand, so the keep-mask is the unsharded
+    call's."""
+    from jax.sharding import PartitionSpec as P
+    B, H = operands[0].shape[0], num_heads
+    b_axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)
+    n_b = _math.prod(mesh.shape[a] for a in b_axes)
+    if not b_axes or B % n_b:
+        b_axes, n_b = None, 1
+    tp = mesh.shape.get("tp", 1)
+    h_axis, n_h = ("tp", tp) if heads_dim is not None and tp > 1 \
+        and H % tp == 0 else (None, 1)
+
+    def spec(ndim):
+        dims = [b_axes] + [None] * (ndim - 1)
+        if heads_dim is not None:
+            dims[heads_dim] = h_axis
+        return P(*dims)
+
+    specs = tuple(spec(a.ndim) for a in operands)
+
+    def shard(kv_mask, seed, *operands):
+        if seed is not None:
+            b_off = lax.axis_index(b_axes) * (B // n_b) if b_axes else 0
+            h_off = lax.axis_index(h_axis) * (H // n_h) if h_axis else 0
+            seed = jnp.stack([seed, jnp.int32(b_off * H + h_off),
+                              jnp.int32(H)])
+        return call(operands, kv_mask, seed)
+
+    return jax.shard_map(
+        shard, mesh=mesh, in_specs=(P(b_axes, None), P()) + specs,
+        out_specs=specs[0], check_vma=False)(kv_mask, seed, *operands)
+
+
+def _flash(path, operands, num_heads, mask, rng_key, causal, drop):
+    """The flash kernels of ``path`` on the devices the enclosing program
+    spans: the bare call on one device, :func:`_shard_flash` when the
+    trainer or serving lane tracing this op made a larger mesh visible
+    (``parallel.mesh.mesh_scope``). ``mask`` is in one of the two forms
+    :func:`_attention_path` lets through."""
+    from ..parallel.mesh import current_scope
+    from . import pallas_kernels as pk
+    heads_dim = {"bshd": 2, "bhsd": 1}.get(path)
+    if path == "packed":
+        def call(ops, m, s):
+            return pk.flash_attention_packed(ops[0], num_heads, m, s, causal,
+                                             drop)
+    elif path == "latent":
+        def call(ops, m, s):
+            return pk.flash_attention_latent(*ops, num_heads, causal)
+    else:
+        kernel = pk.flash_attention_bshd if path == "bshd" \
+            else pk.flash_attention
+
+        def call(ops, m, s):
+            return kernel(*ops, m, s, causal, drop)
+    kv_mask = mask[:, 0, 0, :] if mask is not None and mask.ndim == 4 \
+        else mask
+    seed = jax.random.randint(rng_key, (), -2**31, 2**31 - 1,
+                              dtype=jnp.int32) if drop > 0.0 else None
+    scope = current_scope()
+    if scope is None or scope[0].size == 1:
+        return call(operands, kv_mask, seed)
+    return _shard_flash(call, operands, num_heads, heads_dim, *scope,
+                        kv_mask, seed)
+
+
+def xla_attention(query, key, value, mask=None, dropout=0.0, scaled=True,
+                  causal=False, rng_key=None):
+    """The composed softmax over ``(..., S, D)`` operands (heads before
+    positions): what runs wherever no kernel does, and the form the kernels
+    are tested against. ``mask`` broadcasts against the (..., Q, K) scores,
+    or is a (B, K) keep-mask of the keys."""
+    d = query.shape[-1]
+    scores = jnp.einsum("...qd,...kd->...qk", query, key)
+    if scaled:
+        scores = scores / _np.sqrt(d).astype(scores.dtype)
+    if causal:
+        q, k = scores.shape[-2], scores.shape[-1]
+        cm = jnp.tril(jnp.ones((q, k), dtype=bool))
+        scores = jnp.where(cm, scores, jnp.finfo(scores.dtype).min)
+    if mask is not None:
+        m = mask
+        if getattr(m, "ndim", 0) == 2 and scores.ndim == 4 and \
+                m.shape == (scores.shape[0], scores.shape[-1]):
+            m = m[:, None, None, :]  # (B,T) key mask -> broadcast form
+        scores = jnp.where(m.astype(bool), scores, jnp.finfo(scores.dtype).min)
+    w = jax.nn.softmax(scores, axis=-1)
+    if dropout > 0.0:
+        keep = jax.random.bernoulli(rng_key, 1.0 - dropout, w.shape)
+        w = jnp.where(keep, w / (1.0 - dropout), 0.0)
+    return jnp.einsum("...qk,...kd->...qd", w, value)
+
+
+def _qkv_attention(path, bshd, query, key, value, mask, drop, scaled, causal,
+                   rng_key):
+    """Attention of separate q, k, v down ``path``. ``bshd``: the operands
+    are (B, S, H, D) views of the qkv projection, which the head-fused
+    kernels read as (B, S, H*D) with no head transpose; the per-head
+    kernels and the composed softmax take them transposed (XLA fuses these
+    transposes into the surrounding einsums)."""
+    if path == "bshd":
+        return _flash(path, (query, key, value), query.shape[2], mask,
+                      rng_key, causal, drop)
+    if bshd:
+        query, key, value = (jnp.transpose(a, (0, 2, 1, 3))
+                             for a in (query, key, value))
+    if path == "bhsd":
+        out = _flash(path, (query, key, value), query.shape[1], mask,
+                     rng_key, causal, drop)
+    else:
+        out = xla_attention(query, key, value, mask, drop, scaled, causal,
+                            rng_key)
+    return jnp.transpose(out, (0, 2, 1, 3)) if bshd else out
 
 
 @register("_contrib_dot_product_attention",
@@ -874,12 +768,19 @@ def dot_product_attention(query, key, value, mask=None, dropout=0.0,
     including BERT's padding keep-mask ((B,1,1,T) or (B,T), reduced to a
     per-key mask) and train-time attention dropout (in-kernel counter RNG,
     fwd/bwd consistent). Full (B,H,Q,K) masks and cross-attention take the
-    XLA softmax path. Whatever implements it, every op it traces (mask
-    reduction, kernels, transposes, the kernels' backward rules) carries
-    the ``attention`` scope in its metadata."""
+    XLA softmax path (:func:`_attention_path` has the whole rule).
+    Whatever implements it, every op it traces (mask reduction, kernels,
+    transposes, the kernels' backward rules) carries the ``attention``
+    scope in its metadata."""
     with jax.named_scope("attention"):
-        return _attention(query, key, value, mask, dropout, scaled, causal,
-                          layout, rng_key, train)
+        drop = float(dropout) if train else 0.0
+        bshd = layout == "BSHD" and getattr(query, "ndim", 0) == 4
+        path = _dispatch(
+            "BSHD" if bshd else "BHSD", query.shape,
+            kv_shapes=(key.shape, value.shape), mask_shape=_mask_shape(mask),
+            scaled=scaled, drop=drop, keyed=rng_key is not None)
+        return _qkv_attention(path, bshd, query, key, value, mask, drop,
+                              scaled, causal, rng_key)
 
 
 @register("_contrib_packed_self_attention",
@@ -901,105 +802,16 @@ def packed_self_attention(qkv, mask=None, num_heads=1, dropout=0.0,
     with jax.named_scope("attention"):
         B, S, C3 = qkv.shape
         H, D = int(num_heads), C3 // (3 * int(num_heads))
-        kv_mask, mask_ok = _reduce_key_mask(mask, B, S)
-        if mask_ok and _packed_flash_usable((B, S, H, D), dropout, scaled,
-                                            rng_key, train):
-            _DISPATCHED["packed"] += 1
-            return _packed_flash_call(qkv, H, kv_mask, rng_key, causal,
-                                      float(dropout) if train else 0.0)
-        split = qkv.reshape(B, S, 3, H, D)
-        out = _attention(split[:, :, 0], split[:, :, 1], split[:, :, 2],
-                         mask, dropout, scaled, causal, "BSHD", rng_key,
-                         train)
-        return out.reshape(B, S, H * D)
-
-
-def _bshd_flash_usable(q_shape, dropout, scaled, rng_key, train):
-    """Whether the head-fused flash kernels take a self-attention whose q,
-    k and v are each ``q_shape`` (B, S, H, D) and whose mask, if any,
-    reduces to (B, S): decided from shapes, the platform and the knob
-    alone."""
-    from .pallas_kernels import flash_attention_bshd_usable
-    drop = float(dropout) if train else 0.0
-    return (scaled and (drop == 0.0 or rng_key is not None)
-            and flash_attention_bshd_usable(q_shape, q_shape[-1])
-            and _flash_enabled() and _on_accelerator())
-
-
-def _packed_flash_usable(q_shape, dropout, scaled, rng_key, train):
-    """The packed form runs wherever the separate-operand kernels would,
-    except under tensor parallelism: there the heads dim shards over
-    ``tp``, which one (B, S, 3*H*D) operand cannot express."""
-    from ..parallel.mesh import current_scope
-    scope = current_scope()
-    if scope is not None and scope[0].shape.get("tp", 1) > 1:
-        return False
-    return _bshd_flash_usable(q_shape, dropout, scaled, rng_key, train)
-
-
-def _attention(query, key, value, mask, dropout, scaled, causal, layout,
-               rng_key, train):
-    if layout == "BSHD" and getattr(query, "ndim", 0) == 4:
-        # (B, S, H, D) views of the qkv projection: the head-fused kernels
-        # read them as (B, S, H*D) with no head transpose (the BHSD kernels
-        # force one on each side); the 4-D views themselves still cost a
-        # relayout each way on the chip, which the packed entry spares
-        kv_mask, mask_ok = _reduce_key_mask(mask, query.shape[0],
-                                            key.shape[1])
-        if (mask_ok and key.shape == query.shape
-                and value.shape == query.shape
-                and _bshd_flash_usable(query.shape, dropout, scaled,
-                                       rng_key, train)):
-            from .pallas_kernels import flash_attention_bshd
-            _DISPATCHED["flash"] += 1
-            return _flash_call(flash_attention_bshd, 2, query, key, value,
-                               kv_mask, rng_key, causal,
-                               float(dropout) if train else 0.0)
-        # fallback: run the BHSD path and restore the layout; XLA fuses
-        # these transposes into the surrounding einsums
-        out = _attention(
-            jnp.transpose(query, (0, 2, 1, 3)),
-            jnp.transpose(key, (0, 2, 1, 3)),
-            jnp.transpose(value, (0, 2, 1, 3)),
-            mask, dropout, scaled, causal, "BHSD", rng_key, train)
-        return jnp.transpose(out, (0, 2, 1, 3))
-
-    if query.ndim == 4 and scaled and _flash_enabled():
-        from .pallas_kernels import flash_attention, flash_attention_usable
-        # BERT-style key padding masks broadcast over q: reducible to (B,S)
-        kv_mask, mask_ok = _reduce_key_mask(mask, query.shape[0],
-                                            key.shape[2])
         drop = float(dropout) if train else 0.0
-        # kernel tiles assume self-attention layout; cross-attention with
-        # kv_len != q_len must take the XLA path
-        if (mask_ok and key.shape == query.shape
-                and value.shape == query.shape
-                and (drop == 0.0 or rng_key is not None)
-                and flash_attention_usable(query.shape, causal)
-                and _on_accelerator()):
-            _DISPATCHED["flash"] += 1
-            return _flash_call(flash_attention, 1, query, key, value,
-                               kv_mask, rng_key, causal, drop)
-    _DISPATCHED["xla"] += 1
-    d = query.shape[-1]
-    scores = jnp.einsum("...qd,...kd->...qk", query, key)
-    if scaled:
-        scores = scores / _np.sqrt(d).astype(scores.dtype)
-    if causal:
-        q, k = scores.shape[-2], scores.shape[-1]
-        cm = jnp.tril(jnp.ones((q, k), dtype=bool))
-        scores = jnp.where(cm, scores, jnp.finfo(scores.dtype).min)
-    if mask is not None:
-        m = mask
-        if getattr(m, "ndim", 0) == 2 and scores.ndim == 4 and \
-                m.shape == (scores.shape[0], scores.shape[-1]):
-            m = m[:, None, None, :]  # (B,T) key mask -> broadcast form
-        scores = jnp.where(m.astype(bool), scores, jnp.finfo(scores.dtype).min)
-    w = jax.nn.softmax(scores, axis=-1)
-    if dropout > 0.0 and train:
-        keep = jax.random.bernoulli(rng_key, 1.0 - dropout, w.shape)
-        w = jnp.where(keep, w / (1.0 - dropout), 0.0)
-    return jnp.einsum("...qk,...kd->...qd", w, value)
+        path = _dispatch("packed", (B, S, H, D), mask_shape=_mask_shape(mask),
+                         scaled=scaled, drop=drop, keyed=rng_key is not None)
+        if path == "packed":
+            return _flash(path, (qkv,), H, mask, rng_key, causal, drop)
+        split = qkv.reshape(B, S, 3, H, D)
+        out = _qkv_attention(path, True, split[:, :, 0], split[:, :, 1],
+                             split[:, :, 2], mask, drop, scaled, causal,
+                             rng_key)
+        return out.reshape(B, S, H * D)
 
 
 # ------------------------------------------------- latent attention (MLA)
@@ -1040,27 +852,29 @@ def rotary_embedding(data, theta=10000.0):
                            axis=-1).astype(data.dtype)
 
 
-def _latent_flash_call(q_nope, q_rope, kv, k_rope, num_heads, causal):
-    """The latent flash kernels on the devices the enclosing program spans:
-    the bare call on one device, each device's share of the batch under
-    ``shard_map`` where a larger mesh is visible."""
-    from jax.sharding import PartitionSpec as P
-    from ..parallel.mesh import current_scope
-    from .pallas_kernels import flash_attention_latent
-
-    def call(*operands):
-        return flash_attention_latent(*operands, num_heads, causal)
-
-    scope = current_scope()
-    if scope is None or scope[0].size == 1:
-        return call(q_nope, q_rope, kv, k_rope)
-    mesh, batch_axes = scope
-    axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)
-    if not axes or q_nope.shape[0] % _math.prod(mesh.shape[a] for a in axes):
-        axes = None
-    return jax.shard_map(call, mesh=mesh, in_specs=(P(axes),) * 4,
-                         out_specs=P(axes), check_vma=False)(
-        q_nope, q_rope, kv, k_rope)
+def xla_latent_attention(q_nope, q_rope, kv, k_rope, num_heads=1,
+                         causal=True):
+    """:func:`latent_attention` composed from XLA ops: keys concatenated
+    from their two parts, the rotary key broadcast over the heads, the
+    (B, H, S, S) scores whole in float32."""
+    B, S, _ = q_nope.shape
+    H = int(num_heads)
+    dn, dr = q_nope.shape[-1] // H, q_rope.shape[-1]
+    dv = kv.shape[-1] // H - dn
+    kv4 = kv.reshape(B, S, H, dn + dv)
+    q = jnp.concatenate([q_nope.reshape(B, S, H, dn), q_rope], axis=-1)
+    k = jnp.concatenate(
+        [kv4[..., :dn],
+         jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))], axis=-1)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / _np.float32(_np.sqrt(dn + dr))
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((S, S), dtype=bool)), scores,
+                           -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(kv.dtype)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, kv4[..., dn:])
+    return out.reshape(B, S, H * dv)
 
 
 @register("_contrib_latent_attention")
@@ -1074,35 +888,20 @@ def latent_attention(q_nope, q_rope, kv, k_rope, num_heads=1, causal=True):
     (B, S, rope)``, both already rotated; ``kv (B, S, H*(nope + v))`` as the
     up-projection of the latent made it, ``[k_nope_h | v_h]`` head by head.
     Returns ``(B, S, H*v)`` as the output projection reads it. Where the
-    latent flash kernels run (accelerator present, S a multiple of 128,
-    nope == v a multiple of 128) nothing is padded, split or broadcast in
-    memory; everywhere else the plain XLA form computes the same. Either way
-    every op carries the ``attention`` scope."""
+    latent flash kernels run (accelerator present, S, nope and v multiples
+    of 128) nothing is padded, split or broadcast in memory and each device
+    of a visible mesh attends its share of the batch; everywhere else
+    :func:`xla_latent_attention` computes the same. Either way every op
+    carries the ``attention`` scope."""
     with jax.named_scope("attention"):
-        B, S, _ = q_nope.shape
         H = int(num_heads)
-        dn, dr = q_nope.shape[-1] // H, q_rope.shape[-1]
-        dv = kv.shape[-1] // H - dn
-        from .pallas_kernels import flash_attention_latent_usable
-        if (flash_attention_latent_usable(S, dn, dr, dv) and _flash_enabled()
-                and _on_accelerator()):
-            _DISPATCHED["latent"] += 1
-            return _latent_flash_call(q_nope, q_rope, kv, k_rope, H, causal)
-        _DISPATCHED["xla"] += 1
-        kv4 = kv.reshape(B, S, H, dn + dv)
-        q = jnp.concatenate([q_nope.reshape(B, S, H, dn), q_rope], axis=-1)
-        k = jnp.concatenate(
-            [kv4[..., :dn],
-             jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))], axis=-1)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = scores / _np.float32(_np.sqrt(dn + dr))
-        if causal:
-            scores = jnp.where(jnp.tril(jnp.ones((S, S), dtype=bool)), scores,
-                               -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(kv.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, kv4[..., dn:])
-        return out.reshape(B, S, H * dv)
+        dn = q_nope.shape[-1] // H
+        path = _dispatch("latent", (q_nope.shape[1], dn, q_rope.shape[-1],
+                                    kv.shape[-1] // H - dn))
+        if path == "latent":
+            return _flash(path, (q_nope, q_rope, kv, k_rope), H, None, None,
+                          causal, 0.0)
+        return xla_latent_attention(q_nope, q_rope, kv, k_rope, H, causal)
 
 
 @register("_contrib_held_experts_ffn", n_out=2)

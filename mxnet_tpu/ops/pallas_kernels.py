@@ -41,8 +41,6 @@ __all__ = ["flash_attention", "flash_attention_bshd",
            "flash_attention_usable", "flash_attention_bshd_usable",
            "flash_attention_latent_usable"]
 
-import os as _os
-
 # 128 is the alignment unit (MXU/VPU tiling); actual blocks are chosen
 # per call by _pick_blocks: the largest 128-multiple divisor of S up to
 # the preferred size. Bigger k-blocks amortize the streaming loop's
@@ -50,8 +48,8 @@ import os as _os
 # 51 TFLOP/s, 256/512 = 74 TFLOP/s end-to-end.
 BLOCK_Q = 128
 BLOCK_K = 128
-_PREF_BLOCK_Q = int(_os.environ.get("MXTPU_FLASH_BLOCK_Q", "256"))
-_PREF_BLOCK_K = int(_os.environ.get("MXTPU_FLASH_BLOCK_K", "512"))
+_PREF_BLOCK_Q = 256
+_PREF_BLOCK_K = 512
 
 
 def _pick_blocks(S, causal):
@@ -61,9 +59,6 @@ def _pick_blocks(S, causal):
     on GLOBAL (head, q, k) coordinates, so block choice never changes
     the sampled mask."""
     def pick(pref):
-        # round env-supplied preferences down to a positive multiple of
-        # 128 first, else the divisor search below can't terminate
-        pref = max(128, (int(pref) // 128) * 128)
         b = max(128, min(pref, S))
         while b > 128 and S % b:
             b -= 128

@@ -133,6 +133,10 @@ def _restore_checkpoint(trainer, path):
     # instead of failing on a top-level key mismatch
     try:
         saved = ckptr.metadata(path)
+        # orbax >= 0.11 hands back a StepMetadata around the tree's own
+        # metadata; older versions the tree itself
+        saved = getattr(saved, "item_metadata", saved)
+        saved = getattr(saved, "tree", saved)
         saved_keys = set(saved.keys())
     except Exception:  # noqa: BLE001 — older layouts: keep strict template
         saved, saved_keys = None, set(tpl.keys())

@@ -6,8 +6,7 @@ hidden by the engine's dependency scheduler. On TPU the equivalent hole in
 the pipeline is the host->device (H2D) transfer itself: `device_put` issued
 at step time serializes staging with compute, and `step_many` pre-stages an
 entire `(n_steps, batch, ...)` tensor into HBM — bounding span length and
-delaying step 0 until the whole span has transferred (PERF.md bench_datafed
-note).
+delaying step 0 until the whole span has transferred.
 
 :class:`DeviceFeed` is the TPU-native prefetcher: a depth-K ring of batches
 *already dispatched* to sharded device buffers. A single background stager

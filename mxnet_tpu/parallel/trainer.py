@@ -506,104 +506,6 @@ class ShardedTrainer:
             (out, *_), _aux = self._pure_eval(key, self._values, x)
         return NDArray(out)
 
-    def bench_span(self, steps, data_shape, num_classes, dtype=None):
-        """Benchmarking utility: run ``steps`` training steps where each
-        step's batch is GENERATED IN-GRAPH (jax.random inside the scan)
-        instead of staged from host memory. Runs the exact same
-        ``_one_step`` program as :meth:`step_many`; only the data source
-        differs — so span length is bounded by compute, not by HBM
-        residency of a pre-staged (steps, batch, ...) tensor. Updates the
-        trainer's parameters/optimizer state like real steps. Returns the
-        per-step losses.
-
-        NOTE: when the trainer was built with ``preprocess``, data_shape
-        must be the RAW input shape the preprocess expects (e.g. NHWC for
-        an image pipeline) — uint8 batches are generated in-graph and run
-        through preprocess, matching the data-fed program exactly."""
-        import jax.numpy as jnp
-
-        dt = jnp.bfloat16 if dtype in ("bfloat16", jnp.bfloat16) \
-            else jnp.float32
-
-        def many(key, param_vals, states, t0, lr):
-            def body(carry, _):
-                key, pv, st, t = carry
-                key, kd, kl, sub = jax.random.split(key, 4)
-                if self._preprocess is not None:
-                    # match the data-fed program: raw uint8 in, preprocess
-                    # (cast/normalize/layout) inside the step
-                    x = jax.random.randint(kd, data_shape, 0, 256,
-                                           jnp.uint8)
-                else:
-                    x = jax.random.uniform(kd, data_shape, dt)
-                y = jax.random.randint(kl, (data_shape[0],), 0,
-                                       num_classes).astype(jnp.float32)
-                loss, pv2, st2, _aux = self._one_step(
-                    sub, pv, st, t, lr, (x,), y)
-                return (key, pv2, st2, t + 1), loss
-
-            (key, pv, st, t), losses = jax.lax.scan(
-                body, (key, list(param_vals), list(states), t0), None,
-                length=steps)
-            return losses, pv, st
-
-        sig = (steps, tuple(data_shape), num_classes, str(dt))
-        cache = getattr(self, "_bench_fns", None)
-        if cache is None:
-            cache = self._bench_fns = {}
-        fn = cache.get(sig)
-        if fn is None:
-            fn = cache[sig] = jax.jit(many, donate_argnums=(1, 2))
-        from .. import random as _rnd
-        # t is 1-based inside the update kernels (Adam bias correction
-        # divides by 1 - beta^t), same as step_many
-        losses, self._values, self._states = fn(
-            _rnd.next_key(), self._values, self._states, self._t + 1,
-            self._lr)
-        self._t += steps
-        from ..ndarray.ndarray import NDArray
-        return NDArray(losses)
-
-    def bench_span_fn(self, steps, make_batch, tag=None):
-        """Like :meth:`bench_span` but with a caller-supplied traced batch
-        generator — for models whose inputs aren't a single image tensor
-        (BERT token/segment/position tuples, LM token streams...).
-
-        ``make_batch(key)`` is traced inside the scan body and must return
-        ``(x_args_tuple, y)`` built from jax ops on ``key``. ``tag`` keys
-        the compile cache (pass a stable string; the callable's identity
-        is not part of the key)."""
-        def many(key, param_vals, states, t0, lr):
-            def body(carry, _):
-                key, pv, st, t = carry
-                key, kb, sub = jax.random.split(key, 3)
-                x_args, y = make_batch(kb)
-                loss, pv2, st2, _aux = self._one_step(
-                    sub, pv, st, t, lr, tuple(x_args), y)
-                return (key, pv2, st2, t + 1), loss
-
-            (key, pv, st, t), losses = jax.lax.scan(
-                body, (key, list(param_vals), list(states), t0), None,
-                length=steps)
-            return losses, pv, st
-
-        sig = ("fn", steps, tag if tag is not None else id(make_batch))
-        cache = getattr(self, "_bench_fns", None)
-        if cache is None:
-            cache = self._bench_fns = {}
-        # cache the generator too: an id()-keyed entry must keep its
-        # make_batch alive, or a recycled id would hit a stale compile
-        entry = cache.get(sig)
-        if entry is None or (tag is None and entry[1] is not make_batch):
-            entry = cache[sig] = (jax.jit(many, donate_argnums=(1, 2)),
-                                  make_batch)
-        fn = entry[0]
-        losses, self._values, self._states = fn(
-            _random.next_key(), self._values, self._states, self._t + 1,
-            self._lr)
-        self._t += steps
-        return NDArray(losses)
-
     def sync_back(self):
         """Write the trainer's (possibly sharded) values back into the
         Block's Parameters — gathers shards first, then lands each ctx copy

@@ -338,10 +338,13 @@ PyObject* handle_obj(void* h) {
 // Boot an interpreter when hosted by a non-Python program (reference
 // `src/initialize.cc` library init). extra_sys_path may be NULL; pass the
 // repo root when mxnet_tpu is not on the default sys.path.
+bool g_booted_here = false;
+
 MXTPU_API int MXTpuInit(const char* extra_sys_path) {
   bool booted_here = !Py_IsInitialized();
   if (booted_here) {
     Py_InitializeEx(0);
+    g_booted_here = true;
   }
   int rc = 0;
   {
@@ -385,8 +388,19 @@ MXTPU_API int MXGetVersion(int* out) {
 
 MXTPU_API int MXNotifyShutdown() {
   // Drain outstanding device work (reference MXNotifyShutdown waits the
-  // engine); interpreter teardown is left to the process.
-  return MXNDArrayWaitAll();
+  // engine). Where MXTpuInit booted the interpreter, finalize it too, as
+  // a Python process does before it exits: the runtime's own exit hooks
+  // release its backends and join its threads, which otherwise race the
+  // process's static destructors after main() returns (a segfault after
+  // the host's last line, one exit in four with a compile cache on disk).
+  // No entry point may be called afterwards.
+  int rc = MXNDArrayWaitAll();
+  if (g_booted_here && Py_IsInitialized()) {
+    g_booted_here = false;
+    PyGILState_Ensure();
+    if (Py_FinalizeEx() != 0 && rc == 0) rc = -1;
+  }
+  return rc;
 }
 
 MXTPU_API int MXRandomSeed(int seed) {

@@ -65,7 +65,10 @@ const char* MXGetLastError(void);
 /* version as 10000*major + 100*minor + patch (reference MXNET_VERSION) */
 int MXGetVersion(int* out);
 
-/* graceful teardown notification (reference MXNotifyShutdown) */
+/* graceful teardown (reference MXNotifyShutdown): waits for outstanding
+ * work; where MXTpuInit booted the interpreter it is finalized too, so a
+ * standalone host calls this last, before it returns from main(), and
+ * no entry point after it */
 int MXNotifyShutdown(void);
 
 int MXRandomSeed(int seed);

@@ -81,6 +81,7 @@ int main(int argc, char** argv) {
   CHECK(MXNDArrayFree(x));
   CHECK(MXNDArrayFree(outs[0]));
   CHECK(MXNDArrayFree(souts[0]));
+  CHECK(MXNotifyShutdown());
   printf("C_API_HOST_OK\n");
   return 0;
 }

@@ -111,6 +111,7 @@ int main(int argc, char** argv) {
   CHECK(MXPredFree(pred));
   free(json);
   free(params);
+  CHECK(MXNotifyShutdown());
   printf("C_API_PREDICT_OK\n");
   return 0;
 }

@@ -301,6 +301,7 @@ int main(int argc, char** argv) {
   CHECK(MXExecutorFree(exec));
   MXSymbolFree(net);
 
+  CHECK(MXNotifyShutdown());
   printf("C_API_TRAIN_OK\n");
   return 0;
 }

@@ -13,9 +13,8 @@ Acceptance criteria, on the CPU oracle:
 
 plus the satellites: classification rules, knob registration +
 enable/disable, fake-clock flight recorder, watchdog-stall dump wiring,
-checksummed profile capture (server endpoint + gateway proxy),
-``bench.py`` section crash isolation, the ``benchmark/*.json`` schema
-audit, and ``tools/trace_summary.py`` exclusive (self) time.
+checksummed profile capture (server endpoint + gateway proxy), the
+``benchmark/*.json`` schema audit, and ``tools/trace_summary.py`` exclusive (self) time.
 """
 import glob
 import importlib.util
@@ -622,83 +621,13 @@ def test_bench_diff_directions_and_round_files(tmp_path):
     # BENCH_r0x round files compare their parsed payload
     r1 = tmp_path / "r1.json"
     r2 = tmp_path / "r2.json"
-    r1.write_text(json.dumps({"n": 4, "cmd": "python bench.py", "rc": 0,
+    r1.write_text(json.dumps({"n": 4, "cmd": "python round.py", "rc": 0,
                               "tail": "...", "parsed": {
                                   "value": 100.0, "unit": "img/s"}}))
-    r2.write_text(json.dumps({"n": 6, "cmd": "python bench.py", "rc": 0,
+    r2.write_text(json.dumps({"n": 6, "cmd": "python round.py", "rc": 0,
                               "tail": "...", "parsed": {
                                   "value": 70.0, "unit": "img/s"}}))
     assert bd.main([str(r1), str(r2), "--gate", "--json-only"]) == 2
-
-
-# ---------------------------------------------------------------------------
-# bench.py section isolation
-# ---------------------------------------------------------------------------
-
-def test_bench_sections_isolate_crashes():
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    def ok_section(ctx):
-        return {"metric": "x", "value": 1.0, "unit": "img/s"}
-
-    def crashing(ctx):
-        raise RuntimeError("convert_element_type exploded")
-
-    out = bench._run_sections([("good", ok_section),
-                               ("bad", crashing),
-                               ("after", ok_section)])
-    assert out["good"]["status"] == "OK"
-    assert out["after"]["status"] == "OK"      # ran despite the crash
-    assert out["bad"]["status"] == "FAILED"
-    assert "convert_element_type" in out["bad"]["reason"]
-    assert any("RuntimeError" in line for line in out["bad"]["tail"])
-    assert all("wall_clock" in s for s in out.values())
-    # section wall-clock is bookkeeping: bench_diff must treat it as
-    # informational, never gate on it
-    bd = _bd()
-    assert bd.direction_for("sections.serving_probe.wall_clock") == \
-        bd.INFO
-    # declared section list covers the subsystems
-    names = [n for n, _ in bench.SECTIONS]
-    assert names == ["resnet50_train", "serving_probe", "elastic3d",
-                     "roofline_attribution"]
-
-
-def test_bench_refuses_other_platforms_and_fails_on_failed_section(
-        monkeypatch, capsys):
-    """bench.py measures the chip: off-TPU it exits non-zero before any
-    section, and a round with a FAILED section prints its JSON line and
-    still exits non-zero."""
-    import jax
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod2", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert jax.devices()[0].platform == "cpu"
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code not in (0, None)
-
-    class _Tpu:
-        platform, device_kind = "tpu", "TPU v5 lite"
-
-    def boom(ctx):
-        raise RuntimeError("section exploded")
-
-    monkeypatch.setattr(bench, "_require_tpu", lambda: [_Tpu()])
-    monkeypatch.setattr(bench, "SECTIONS", (("boom", boom),))
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code == 1
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["failed_sections"] == ["boom"]
-    assert bench._peak_tflops(_Tpu()) == 197.0
-    _Tpu.device_kind = "TPU v99"
-    with pytest.raises(RuntimeError, match="no published peak"):
-        bench._peak_tflops(_Tpu())
 
 
 # ---------------------------------------------------------------------------

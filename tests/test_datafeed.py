@@ -4,8 +4,8 @@ PrefetchingIter lifecycle, and CachedOp concurrent dispatch.
 
 The overlap claims are proven structurally (monkeypatched staging funnel:
 batches are staged ahead of consumption, zero consumer-side stage waits
-after warmup) — the CPU oracle can't measure real H2D/compute overlap; the
-throughput artifact comes from benchmark/datafeed_bench.py on the chip.
+after warmup) — the CPU oracle can't measure real H2D/compute overlap, and
+no benchmark cell feeds ``step_stream`` on the chip yet.
 """
 import threading
 import time

@@ -600,22 +600,6 @@ def test_bench_regression_gate_vs_pr7_artifact():
         cur["chunked_prefill"]["monolithic"]["inter_token_p99_ms"]
 
 
-def test_flash_attention_knob(monkeypatch):
-    """MXNET_FLASH_ATTENTION=0 (and the legacy MXTPU_DISABLE_FLASH)
-    disable the pallas flash dispatch — the with/without switch
-    benchmark/bench_lm.py's bertdelta records the BERT MFU delta with."""
-    from mxnet_tpu.ops.nn import _flash_enabled
-    monkeypatch.delenv("MXTPU_DISABLE_FLASH", raising=False)
-    monkeypatch.delenv("MXNET_FLASH_ATTENTION", raising=False)
-    assert _flash_enabled()                      # default on
-    monkeypatch.setenv("MXNET_FLASH_ATTENTION", "0")
-    assert not _flash_enabled()
-    monkeypatch.setenv("MXNET_FLASH_ATTENTION", "1")
-    assert _flash_enabled()
-    monkeypatch.setenv("MXTPU_DISABLE_FLASH", "1")
-    assert not _flash_enabled()                  # legacy override wins
-
-
 def test_generation_gauge_includes_prefix(tiny_lm):
     from mxnet_tpu.serving import generation as gen
     pc = PrefixCache(block=4, name="gauge.px")

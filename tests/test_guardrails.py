@@ -97,7 +97,14 @@ def test_guarded_clean_run_bitwise_equals_unguarded():
 
 
 def test_guarded_clean_run_bitwise_with_batchnorm_aux():
-    """Aux (BatchNorm running stats) rides the same guarded fold-back."""
+    """Aux (BatchNorm running stats) rides the same guarded fold-back:
+    every value the optimizer updates is bitwise the unguarded run's. The
+    running statistics are held to one float32 ULP a step and no closer:
+    the guarded step is another XLA program, and XLA:CPU is free to fuse
+    and round ``momentum * old + (1 - momentum) * batch`` differently in
+    the two (on this jax ``running_mean`` differs by one ULP from the
+    second step on, while the weights, the gradients they come from and
+    the optimizer state stay identical)."""
     batches = _batches(4, seed=5)
     ta = _make_trainer(seed=0, with_bn=True)
     for x, y in batches:
@@ -110,8 +117,17 @@ def test_guarded_clean_run_bitwise_with_batchnorm_aux():
         g.step(x, y)
     g.sync_back()
 
-    for va, vb in zip(ta._values, tb._values):
-        np.testing.assert_array_equal(np.asarray(va), np.asarray(vb))
+    trainable = set(ta._trainable_indices())
+    assert len(trainable) < len(ta._values)     # the running stats are aux
+    for i, (va, vb) in enumerate(zip(ta._values, tb._values)):
+        if i in trainable:
+            np.testing.assert_array_equal(np.asarray(va), np.asarray(vb))
+        else:
+            np.testing.assert_array_max_ulp(np.asarray(va), np.asarray(vb),
+                                            maxulp=len(batches))
+    for sa, sb in zip(ta._states, tb._states):
+        for a, b in zip(sa, sb):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------

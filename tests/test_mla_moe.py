@@ -114,12 +114,11 @@ def latent_cases():
                 *a, 2, True, blocks, True) * w)
 
         def plain(*a):
-            return jnp.sum(nn_ops.latent_attention.fn(
-                *a, num_heads=2, causal=True) * w)
+            return jnp.sum(nn_ops.xla_latent_attention(*a, 2, True) * w)
 
         got = (pk.flash_attention_latent(*ops, 2, True, blocks, True),) \
             + jax.grad(flash, argnums=(0, 1, 2, 3))(*ops)
-        want = (nn_ops.latent_attention.fn(*ops, num_heads=2, causal=True),) \
+        want = (nn_ops.xla_latent_attention(*ops, 2, True),) \
             + jax.grad(plain, argnums=(0, 1, 2, 3))(*ops)
         out[(seq, blocks)] = (got, want)
     return out
@@ -137,17 +136,13 @@ def test_latent_flash_kernels_match_the_xla_form(latent_cases, seq, blocks,
     assert float(jnp.abs(got[which] - want[which]).max()) <= 5e-6 * scale
 
 
-def test_dispatcher_counts_the_latent_kernels(monkeypatch):
+def test_dispatcher_counts_the_latent_kernels(request):
     """Where the kernels are usable the op takes them (``latent``); off the
     chip it takes the XLA form (``xla``): the same result."""
     *ops, _ = _latent_operands(1, 128)
     before = nn_ops.attention_dispatch_stats()
     plain = nn_ops.latent_attention.fn(*ops, num_heads=2)
-    real = pk.flash_attention_latent
-    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
-    monkeypatch.setattr(
-        pk, "flash_attention_latent",
-        lambda *a: real(*a, interpret=True))
+    request.getfixturevalue("chip_present_interpreted")
     flash = nn_ops.latent_attention.fn(*ops, num_heads=2)
     after = nn_ops.attention_dispatch_stats()
     assert after["latent"] == before["latent"] + 1

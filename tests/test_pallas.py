@@ -330,9 +330,10 @@ def test_sharded_flash_draws_the_unsharded_dropout_mask(mesh_axes, batch,
     seed = jnp.asarray(7, jnp.int32)
 
     def sharded(q, k, v):
-        return nn_ops._sharded_flash(flash_attention_bshd, 2, mesh, ("dp",),
-                                     q, k, v, None, seed, False, 0.5,
-                                     interpret=True)
+        return nn_ops._shard_flash(
+            lambda ops, m, s: flash_attention_bshd(*ops, m, s, False, 0.5,
+                                                   True),
+            (q, k, v), heads, 2, mesh, ("dp",), None, seed)
 
     def unsharded(q, k, v):
         return flash_attention_bshd(q, k, v, None, seed, False, 0.5, True)
@@ -351,19 +352,18 @@ def test_sharded_flash_draws_the_unsharded_dropout_mask(mesh_axes, batch,
                                    rtol=1e-4, err_msg="d%s" % n)
 
 
-def test_dispatch_shards_flash_under_a_visible_mesh(monkeypatch):
+def test_dispatch_shards_flash_under_a_visible_mesh(
+        chip_present_interpreted, monkeypatch):
     """The dispatcher takes the shard_map route exactly when the tracing
     trainer/lane made a mesh of more than one device visible."""
     from mxnet_tpu import parallel
     from mxnet_tpu.ops import nn as nn_ops
-    calls = []
-    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
-    monkeypatch.setattr(
-        nn_ops, "_sharded_flash",
-        lambda kernel, heads_dim, mesh, axes, q, *a, **kw:
-        calls.append((mesh.size, axes)) or q)
-    monkeypatch.setattr(nn_ops, "_flash_enabled", lambda: True)
     from mxnet_tpu.ops import pallas_kernels as pk
+    calls = []
+    monkeypatch.setattr(
+        nn_ops, "_shard_flash",
+        lambda call, operands, heads, heads_dim, mesh, axes, *a:
+        calls.append((mesh.size, axes)) or operands[0])
     monkeypatch.setattr(pk, "flash_attention_bshd",
                         lambda q, *a, **kw: calls.append("bare") or q)
     q = _rand((4, 128, 2, 64), 120)
@@ -426,7 +426,7 @@ def test_packed_kernels_equal_the_split_kernels_bit_for_bit(masked, causal,
             np.float32))
 
 
-def test_packed_entry_paths_and_fallbacks(monkeypatch):
+def test_packed_entry_paths_and_fallbacks(request):
     """The packed entry takes the packed kernels where the head-fused
     kernels run and no ``tp`` > 1 mesh is visible, and is otherwise today's
     split + ``dot_product_attention(layout="BSHD")`` bit for bit: on the
@@ -434,10 +434,7 @@ def test_packed_entry_paths_and_fallbacks(monkeypatch):
     dispatch counter names the path each call took."""
     from mxnet_tpu import parallel
     from mxnet_tpu.ops import nn as nn_ops
-    from mxnet_tpu.ops import pallas_kernels as pk
     B, H, D = 2, 2, 64
-    real_bshd, real_packed = pk.flash_attention_bshd, \
-        pk.flash_attention_packed
 
     def took(fn):
         before = nn_ops.attention_dispatch_stats()
@@ -466,16 +463,7 @@ def test_packed_entry_paths_and_fallbacks(monkeypatch):
                                   np.asarray(todays(qkv, mask)))
 
     # from here on a chip is "present"; the kernels run interpreted
-    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
-    monkeypatch.setattr(nn_ops, "_flash_enabled", lambda: True)
-    monkeypatch.setattr(
-        pk, "flash_attention_bshd",
-        lambda q, k, v, m, s, c, d, interpret=False:
-        real_bshd(q, k, v, m, s, c, d, True))
-    monkeypatch.setattr(
-        pk, "flash_attention_packed",
-        lambda a, h, m, s, c, d, interpret=False:
-        real_packed(a, h, m, s, c, d, True))
+    request.getfixturevalue("chip_present_interpreted")
 
     out, path = took(lambda: entry(qkv, mask))
     assert path == {"packed": 1}
@@ -533,6 +521,121 @@ def test_sharded_packed_flash_draws_the_unsharded_dropout_mask():
     ref = jax.grad(lambda a: (unsharded(a) ** 2).sum())(qkv)
     np.testing.assert_allclose(np.asarray(grad), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
+
+
+# ------------------------- the one decision and the one wrapper (PR 29)
+
+_BERT = (96, 512, 12, 64)                   # the benchmark cell's (B, S, H, D)
+_FULL_MASK = (96, 12, 512, 512)
+
+
+def _kv(shape):
+    return {"kv_shapes": (shape, shape)}
+
+
+@pytest.mark.parametrize("want,form,shape,observed,mesh,chip", [
+    # every row of the table, taken
+    ("packed", "packed", _BERT, {}, None, True),
+    ("packed", "packed", _BERT, {"mask_shape": (96, 1, 1, 512), "drop": 0.1},
+     None, True),
+    ("packed", "packed", _BERT, {"mask_shape": (96, 512)}, {"dp": 4}, True),
+    ("bshd", "BSHD", _BERT, _kv(_BERT), None, True),
+    ("bhsd", "BHSD", (32, 8, 512, 64), _kv((32, 8, 512, 64)), None, True),
+    ("latent", "latent", (8192, 128, 64, 128), {}, None, True),
+    ("latent", "latent", (8192, 128, 64, 256), {}, None, True),  # nope != v
+    # the head-fused kernels' own conditions: down to the per-head kernels
+    ("bshd", "packed", _BERT, {}, {"dp": 2, "tp": 2}, True),     # tp > 1
+    ("bhsd", "BSHD", (1, 128, 3, 20), _kv((1, 128, 3, 20)), None, True),
+    ("bhsd", "packed", (1, 128, 3, 20), {}, None, True),   # H*D = 60
+    ("bshd", "packed", (1, 1024, 12, 64), {}, {"tp": 2}, True),  # 6 MiB
+    ("bhsd", "packed", (1, 2048, 12, 64), {}, None, True),   # 12 MiB > 8
+    # every reason to leave the kernels
+    ("xla", "packed", _BERT, {}, None, False),                   # CPU only
+    ("xla", "latent", (8192, 128, 64, 128), {}, None, False),
+    ("xla", "packed", (2, 200, 2, 64), {}, None, True),     # S % 128
+    ("xla", "BHSD", (2, 2, 64, 64), _kv((2, 2, 64, 64)), None, True),
+    ("xla", "BSHD", (1, 128, 1, 384), _kv((1, 128, 1, 384)), None, True),
+    ("xla", "BHSD", (1, 1, 128, 384), _kv((1, 1, 128, 384)), None, True),
+    ("xla", "packed", _BERT, {"mask_shape": _FULL_MASK}, None, True),
+    ("xla", "BHSD", (96, 12, 512, 64),
+     dict(_kv((96, 12, 512, 64)), mask_shape=_FULL_MASK), None, True),
+    ("xla", "BSHD", (2, 128, 2, 64), _kv((2, 256, 2, 64)), None, True),
+    ("xla", "BHSD", (2, 8, 128, 64), _kv((2, 2, 128, 64)), None, True),
+    ("xla", "BHSD", (16, 128, 64), _kv((16, 128, 64)), None, True),  # 3-D
+    ("xla", "packed", _BERT, {"scaled": False}, None, True),
+    ("xla", "packed", _BERT, {"drop": 0.1, "keyed": False}, None, True),
+    ("packed", "packed", _BERT, {"drop": 0.0, "keyed": False}, None, True),
+    ("xla", "latent", (8192, 96, 64, 96), {}, None, True),   # nope % 128
+    ("xla", "latent", (8192, 128, 192, 128), {}, None, True),  # rope > 128
+    ("xla", "latent", (200, 128, 64, 128), {}, None, True),
+])
+def test_attention_path_is_decided_from_what_is_observed(
+        want, form, shape, observed, mesh, chip, monkeypatch):
+    """``_attention_path`` alone, no tracing: operand shapes and layout,
+    the mask's form, ``scaled``, dropout with or without a key, the visible
+    mesh and the platform decide, and nothing else does."""
+    import contextlib
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import nn as nn_ops
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: chip)
+    before = nn_ops.attention_dispatch_stats()
+    scope = parallel.mesh_scope(parallel.make_mesh(**mesh), ("dp",)) \
+        if mesh else contextlib.nullcontext()
+    with scope:
+        assert nn_ops._attention_path(form, shape, **observed) == want
+    assert nn_ops.attention_dispatch_stats() == before     # deciding is pure
+
+
+def _latent_call_operands(batch):
+    H, nope, rope, v = 1, 128, 8, 128
+    shapes = [(batch, 128, H * nope), (batch, 128, H, rope),
+              (batch, 128, H * (nope + v)), (batch, 128, rope)]
+    return H, [_rand(s, 170 + i) for i, s in enumerate(shapes)]
+
+
+@pytest.mark.parametrize("form", ["packed", "bshd", "bhsd", "latent"])
+def test_every_call_form_shards_through_the_one_wrapper(
+        form, chip_present_interpreted, monkeypatch):
+    """Under a visible dp=4 mesh each of the four kernel paths goes through
+    ``_shard_flash`` once, each device attending its own row, and equals
+    the same entry called with no mesh in sight."""
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import nn as nn_ops
+    B, S, H, D = 4, 128, 2, 64
+    mask = jnp.asarray((np.arange(S)[None, :] < np.array(
+        [[96], [128], [64], [128]])).astype("int32"))
+    if form == "packed":
+        operands = [_rand((B, S, 3 * H * D), 171)]
+        entry = lambda a: nn_ops.packed_self_attention.fn(
+            a, mask=mask, num_heads=H)
+    elif form == "latent":
+        heads, operands = _latent_call_operands(B)
+        entry = lambda *a: nn_ops.latent_attention.fn(*a, num_heads=heads)
+    else:
+        # 20-wide heads: H*D is no multiple of 128, so BSHD views go down
+        # to the per-head kernels too
+        shape = (B, S, H, D) if form == "bshd" else (B, S, 3, 20)
+        operands = [_rand(shape, 172 + i) for i in range(3)]
+        entry = lambda q, k, v: nn_ops.dot_product_attention.fn(
+            q, k, v, mask=mask, layout="BSHD")
+    wrapped = []
+    real = nn_ops._shard_flash
+    monkeypatch.setattr(
+        nn_ops, "_shard_flash",
+        lambda call, ops, *a: wrapped.append(len(ops)) or real(
+            call, ops, *a))
+    before = nn_ops.attention_dispatch_stats()
+    want = entry(*operands)
+    assert wrapped == []
+    with parallel.mesh_scope(parallel.make_mesh(dp=4), ("dp",)):
+        got = jax.jit(lambda *a: entry(*a))(*operands)
+    assert wrapped == [len(operands)]
+    took = {k: v - before[k]
+            for k, v in nn_ops.attention_dispatch_stats().items() if
+            v != before[k]}
+    assert took == {"flash" if form in ("bshd", "bhsd") else form: 2}
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-6)
 
 
 # --------------------------- the fused head-fused backward (PR 28)

@@ -1,81 +1,8 @@
-"""Oracle tests for the gated round-5 perf-experiment paths.
-
-These paths are OFF by default (each measured as an end-to-end loss on
-the chip — see PERF.md round-5 study) but stay in the tree behind env
-knobs for future XLA versions; these tests pin their correctness against
-the default lowerings.
-"""
+"""The default dropout mask's statistics and BERT's gather-first decode."""
 import jax
-import jax.numpy as jnp
 import numpy as np
-import pytest
-from jax import lax
 
 from mxnet_tpu.ops import nn as opsnn
-
-
-def _direct_conv(x, w, s, p):
-    dn = lax.conv_dimension_numbers(x.shape, w.shape,
-                                    ("NCHW", "OIHW", "NCHW"))
-    return lax.conv_general_dilated(x, w, (s, s), [(p, p), (p, p)],
-                                    dimension_numbers=dn)
-
-
-@pytest.mark.parametrize("C,O,K,p,H", [(3, 64, 7, 3, 224), (3, 16, 7, 3, 32),
-                                       (4, 8, 5, 2, 20), (1, 8, 5, 1, 16)])
-def test_conv_s2d_matches_direct(C, O, K, p, H):
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(2, C, H, H).astype(np.float32))
-    w = jnp.asarray(rng.randn(O, C, K, K).astype(np.float32))
-    a = opsnn._conv_s2d_stride2(x, w, [(p, p), (p, p)])
-    b = _direct_conv(x, w, 2, p)
-    assert a.shape == b.shape
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-4, atol=1e-4)
-    g = jnp.asarray(rng.randn(*np.array(a.shape)).astype(np.float32))
-    ga = jax.grad(lambda w: (opsnn._conv_s2d_stride2(
-        x, w, [(p, p), (p, p)]) * g).sum())(w)
-    gb = jax.grad(lambda w: (_direct_conv(x, w, 2, p) * g).sum())(w)
-    np.testing.assert_allclose(np.asarray(ga), np.asarray(gb),
-                               rtol=1e-4, atol=1e-4)
-
-
-def _ref_pool(x, k, s, p):
-    return lax.reduce_window(x, -jnp.inf, lax.max, (1, 1) + k, (1, 1) + s,
-                             [(0, 0), (0, 0)] + [(pp, pp) for pp in p])
-
-
-@pytest.mark.parametrize("k,s,p,H", [((3, 3), (2, 2), (1, 1), 28),
-                                     ((2, 2), (2, 2), (0, 0), 28),
-                                     ((3, 3), (2, 2), (0, 0), 27),
-                                     ((2, 2), (1, 1), (0, 0), 9),
-                                     ((3, 3), (3, 3), (1, 1), 13),
-                                     # k < s: inter-window gaps must get
-                                     # zero gradient
-                                     ((2, 2), (3, 3), (0, 0), 9),
-                                     ((1, 1), (2, 2), (1, 1), 8)])
-def test_maxpool_eqbwd_matches_select_and_scatter(k, s, p, H):
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(2, 3, H, H).astype(np.float32))
-    pd = [(p[0], p[0]), (p[1], p[1])]
-    o_new = opsnn._maxpool2d_nchw(x, k, s, pd)
-    o_ref = _ref_pool(x, k, s, p)
-    np.testing.assert_allclose(np.asarray(o_new), np.asarray(o_ref))
-    g = jnp.asarray(rng.randn(*np.array(o_ref.shape)).astype(np.float32))
-    gr_new = jax.grad(lambda x: (opsnn._maxpool2d_nchw(
-        x, k, s, pd) * g).sum())(x)
-    gr_ref = jax.grad(lambda x: (_ref_pool(x, k, s, p) * g).sum())(x)
-    # random floats: no ties, so tie-splitting == first-max exactly
-    np.testing.assert_allclose(np.asarray(gr_new), np.asarray(gr_ref),
-                               atol=1e-5)
-
-
-def test_maxpool_eqbwd_tie_mass_preserved():
-    # all-equal input: every window's gradient mass lands exactly once
-    x = jnp.zeros((1, 1, 6, 6))
-    gr = jax.grad(lambda x: opsnn._maxpool2d_nchw(
-        x, (3, 3), (2, 2), [(1, 1), (1, 1)]).sum())(x)
-    np.testing.assert_allclose(float(np.asarray(gr).sum()), 9.0, rtol=1e-6)
 
 
 def test_hash_dropout_mask_statistics():
@@ -112,11 +39,3 @@ def test_bert_gather_first_mlm_matches_full_decode():
     want = np.take_along_axis(full.asnumpy(),
                               pos.astype(int)[:, :, None], axis=1)
     np.testing.assert_allclose(picked.asnumpy(), want, rtol=1e-5, atol=1e-5)
-
-
-def test_fwd_barrier_identity_gradient():
-    x = jnp.asarray(np.random.RandomState(0).randn(4, 3).astype(np.float32))
-    y, vjp = jax.vjp(opsnn._fwd_barrier, x)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(x))
-    g = jnp.ones_like(x)
-    np.testing.assert_allclose(np.asarray(vjp(g)[0]), np.asarray(g))

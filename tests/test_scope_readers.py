@@ -67,20 +67,10 @@ def _step_locations():
     return set(re.findall(r'"(jit\(step\)/[^"]*)"', text))
 
 
-def _interpret_packed_kernels(monkeypatch):
-    """A chip is "present" and the packed kernels run interpreted: the path
-    BERT's and TransformerLM's blocks take on the chip."""
-    kernel = pk.flash_attention_packed
-    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
-    monkeypatch.setattr(
-        pk, "flash_attention_packed",
-        lambda qkv, h, m, s, c, d: kernel(qkv, h, m, s, c, d, True))
-
-
 @pytest.mark.parametrize("path", ["xla", "flash_interpret"])
-def test_traced_step_carries_the_programs_scopes(path, monkeypatch):
+def test_traced_step_carries_the_programs_scopes(path, request):
     if path == "flash_interpret":
-        _interpret_packed_kernels(monkeypatch)
+        request.getfixturevalue("chip_present_interpreted")
     names = _step_locations()
     layers = {}
     for name in names:
@@ -327,7 +317,7 @@ def test_scheduler_self_time_reader():
 @pytest.mark.parametrize("path,expected", [
     ("fused", 100.0), ("split", 0.0), ("both", 50.0), ("no_counter", None),
     ("nothing_traced", None)])
-def test_attention_fused_bwd_reader(path, expected, monkeypatch):
+def test_attention_fused_bwd_reader(path, expected, monkeypatch, request):
     """``attention_fused_bwd_pct.train`` over ``flash_backward_stats()``:
     a tiny BERT step through the packed kernels at 128 positions (one key
     block) reads 100; a backward over two key blocks reads 0; a program
@@ -335,7 +325,7 @@ def test_attention_fused_bwd_reader(path, expected, monkeypatch):
     backward (the XLA path), reads None."""
     monkeypatch.setattr(pk, "_BACKWARDS", dict.fromkeys(pk._BACKWARDS, 0))
     if path in ("fused", "both"):
-        _interpret_packed_kernels(monkeypatch)
+        request.getfixturevalue("chip_present_interpreted")
         _step_locations()
         assert pk.flash_backward_stats() == {"fused": 2, "split": 0}
     elif path == "nothing_traced":
@@ -359,7 +349,7 @@ def test_attention_fused_bwd_reader(path, expected, monkeypatch):
     ("flash_interpret", 100.0), ("xla", 0.0), ("no_counter", None),
     ("nothing_traced", None)])
 def test_attention_packed_reader_on_a_tiny_traced_step(path, expected,
-                                                       monkeypatch):
+                                                       monkeypatch, request):
     """``attention_packed_pct.train`` over the dispatcher's own counter:
     every attention of a tiny BERT step traced through the packed kernels
     reads 100, through the composed softmax 0; a program without the
@@ -367,7 +357,7 @@ def test_attention_packed_reader_on_a_tiny_traced_step(path, expected,
     monkeypatch.setattr(nn_ops, "_DISPATCHED",
                         dict.fromkeys(nn_ops._DISPATCHED, 0))
     if path == "flash_interpret":
-        _interpret_packed_kernels(monkeypatch)
+        request.getfixturevalue("chip_present_interpreted")
     if path != "nothing_traced":
         _step_locations()
         counts = nn_ops.attention_dispatch_stats()
@@ -380,8 +370,8 @@ def test_attention_packed_reader_on_a_tiny_traced_step(path, expected,
 
 
 @pytest.mark.parametrize("path", ["xla", "packed_interpret"])
-def test_prefill_through_the_packed_entry_equals_the_split(path,
-                                                           monkeypatch):
+def test_prefill_through_the_packed_entry_equals_the_split(path, monkeypatch,
+                                                           request):
     """``TransformerLM.prefill`` (``forward_kv`` in every block) hands the
     packed projection to the attention and slices k and v for the arena
     only: the same next tokens, the same k and v as the split it replaced.
@@ -413,7 +403,7 @@ def test_prefill_through_the_packed_entry_equals_the_split(path,
     monkeypatch.setattr(MultiHeadAttention, "forward_kv", packed)
     before = nn_ops.attention_dispatch_stats()
     if path == "packed_interpret":
-        _interpret_packed_kernels(monkeypatch)
+        request.getfixturevalue("chip_present_interpreted")
     logits, cache = net.prefill(tokens, lengths)
     took = {k: v - before[k]
             for k, v in nn_ops.attention_dispatch_stats().items()}

@@ -60,21 +60,3 @@ def test_checkpoint_preserves_shardings(tmp_path):
     for a, b in zip(t1._values, t2._values):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b))
         assert b.sharding.is_equivalent_to(a.sharding, a.ndim)
-
-
-def test_bench_span_runs_real_steps():
-    """bench_span must advance the same training state as step_many —
-    parameters move, step counter advances, losses finite, and repeated
-    spans reuse the compiled program (no recompile explosion)."""
-    t = _make_trainer()
-    before = [np.asarray(v).copy() for v in t._values]
-    losses = t.bench_span(4, (8, 8), 4)
-    assert losses.shape == (4,)
-    assert np.isfinite(losses.asnumpy()).all()
-    assert t._t == 4
-    moved = sum(float(np.abs(np.asarray(v) - b).sum())
-                for v, b in zip(t._values, before))
-    assert moved > 0
-    t.bench_span(4, (8, 8), 4)
-    assert t._t == 8
-    assert len(t._bench_fns) == 1  # cached, not re-jitted

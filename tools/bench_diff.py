@@ -2,8 +2,7 @@
 """Bench regression ledger: compare two bench artifacts, gate on it.
 
 Every benchmark in this repo writes a JSON artifact (``benchmark/*.json``,
-the ``BENCH_r0x.json`` round files, ``bench.py``'s sectioned output) —
-but until now nothing *compared* them, so a regression was silently
+the ``BENCH_r0x.json`` round files) — but until now nothing *compared* them, so a regression was silently
 recorded instead of caught (the ROADMAP's "rounds 4→5 have no signal"
 failure class). This tool loads two artifacts, walks every **shared**
 numeric metric (nested dicts/lists flatten to dotted paths), applies a
@@ -77,9 +76,9 @@ _SKIP_PAT = re.compile(
 def _list_segments(items):
     """Path segments for a list's elements: a list of dicts that carry
     an identity key (``metric``/``op``/``name``/``id``) is keyed by it —
-    ranked lists (bench.py's roofline table, BENCH_LM's record list)
-    reorder between rounds, and positional comparison would gate row i
-    of one round against a DIFFERENT entity's row i in the other.
+    ranked lists (a roofline table, a record list) reorder between
+    rounds, and positional comparison would gate row i of one round
+    against a DIFFERENT entity's row i in the other.
     Duplicate or missing identities fall back to the index."""
     segs = []
     seen = {}
