@@ -11,8 +11,8 @@ transformer.cc`), materialising the (S, S) score matrix in HBM.
 
 Forward AND backward are Pallas (MXU matmuls over VMEM-resident tiles,
 fp32 accumulators; backward recomputes score tiles from the saved
-logsumexp — the standard flash-attention-2 dq/dkdv split, and for the
-head-fused layout ONE kernel where one key block spans the sequence).
+logsumexp — the flash-attention-2 dq/dkdv split, or ONE kernel: head-fused
+over one key block, latent where a (row, head)'s float32 dq fits VMEM).
 
 Supports the full training configuration of the transformer model zoo:
   - key padding mask (B, S): BERT-style bidirectional masking;
@@ -1189,12 +1189,16 @@ flash_attention_packed.defvjp(_fap_fwd, _fap_bwd)
 # last dim must be a multiple of 128 or the whole dim, and the rotary op
 # writes a new array anyway); k_rope (B, S, rope) is read by every head
 # from the one array. Nothing is padded or broadcast in memory. The key
-# blocks are a GRID axis (the innermost, sequential one), so VMEM holds one
-# block of each operand and accumulators in scratch, whatever the sequence
-# length; a causal tile wholly above the diagonal is skipped and its block
-# index clamped, so nothing is fetched for it. The tile bodies are the ones
-# the BHSD and BSHD kernels run (`_fwd_tile_update`, `_bwd_tile_ds`,
-# `_tile_dead`), given the rotary pair as their second dot product.
+# blocks are a GRID axis (sequential), so VMEM holds one block of each
+# operand and accumulators in scratch; a causal tile wholly above the
+# diagonal is skipped and its block index clamped, so nothing is fetched for
+# it. The forward and the dq + dkv pair run the tile bodies of the BHSD and
+# BSHD kernels (`_fwd_tile_update`, `_bwd_tile_ds`, `_tile_dead`), given the
+# rotary pair as their second dot product. Backward (`_latent_bwd_impl`):
+# `flash_latent_bwd`, ONE kernel, where a (row, head)'s float32 dq fits VMEM
+# beside the tile (`_latent_bwd_vmem`; 6 MiB of 30 planned at 8,192, up to
+# about 120,000 positions at these widths); `flash_latent_dq` then
+# `flash_latent_dkv`, whose VMEM does not grow with the sequence, beyond.
 
 # measured on the chip at 2 x 8192 x 16 heads (PR 27), forward + backward:
 # 256/256 70.7 ms, 512/512 38.2, 1024/512 36.4, 256/1024 37.0, 256/2048
@@ -1335,6 +1339,96 @@ def _latent_dkv_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref,
         dkr_ref[0, 0] = dkr_acc[...] * jnp.float32(scale)
 
 
+# ---- the fused latent backward: one pass over each live tile's scores
+# Grid (batch, head, key block, query block), both block axes sequential.
+# dk_nope, dv and the head's share of dk_rope accumulate over the inner query
+# sweep as in the dkv kernel; dq accumulates over the OUTER key axis into a
+# float32 scratch that holds the whole sequence of one (row, head) and is
+# cast and written during the last key block's sweep (the dq blocks' index
+# stands still until then, so nothing is written back early): eight MXU
+# passes a tile (a 64-deep or 64-wide product costs a 128 one) where dq + dkv
+# make eleven, one exp and one mask sweep where they make two. The tile has
+# the KEYS on the sublanes (S^T = K Q^T), so dV = P^T dO and dK = dS^T Q are
+# plain products of it, LSE and delta are read as the lane rows they are
+# stored as, and dQ^T = K^T dS^T accumulates TRANSPOSED ((nope + rope) x S
+# float32, no lane padding of the 64-wide rotary part), turned once a query
+# block on the way out. The causal mask is applied on tiles that straddle the
+# diagonal only. Measured on the chip at 4 x 8192 x 16 heads (PR 30), a
+# layer's backward with delta and the dk_rope sum: the pair 43.1 ms; fused,
+# 1024/1024: queries on the sublanes 31.3, keys on the sublanes with dQ
+# transposing the tile 30.8, with dQ^T 29.2 (29.7 masking every live tile);
+# 512/1024 30.7, 1024/512 30.6, 512/512 32.3. Same bits as the pair.
+
+def _latent_bwd_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref,
+                       delta_ref, dqn_ref, dqr_ref, dkv_ref, dkr_ref,
+                       dqn_acc, dqr_acc, dkn_acc, dv_acc, dkr_acc, *, scale,
+                       causal, blk_q, blk_k, nope):
+    """One (batch, head, k-block, q-block) program of the one-kernel
+    backward. ``dqn_acc`` / ``dqr_acc`` are (n_q, nope | rope, blk_q):
+    dQ^T of the whole sequence, block by block."""
+    kj, qi = pl.program_id(2), pl.program_id(3)
+    k0, q0 = kj * blk_k, qi * blk_q
+    nn, tn = (((1,), (0,)), ((), ())), (((0,), (0,)), ((), ()))
+
+    @pl.when(qi == 0)
+    def _():
+        dkn_acc[...] = jnp.zeros(dkn_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+        dkr_acc[...] = jnp.zeros(dkr_acc.shape, jnp.float32)
+
+    @pl.when(kj == 0)
+    def _():
+        dqn_acc[qi] = jnp.zeros(dqn_acc.shape[1:], jnp.float32)
+        dqr_acc[qi] = jnp.zeros(dqr_acc.shape[1:], jnp.float32)
+
+    def tile(masked):
+        # the matmuls run in q's dtype, as in the other kernels
+        qn, qr = qn_ref[0], qr_ref[0, 0]
+        kn = kv_ref[0, :, :nope].astype(qn.dtype)
+        v = kv_ref[0, :, nope:].astype(qn.dtype)
+        kr = kr_ref[0].astype(qr.dtype)
+        do = do_ref[0].astype(qn.dtype)
+        p_t = jnp.exp(_scores(kn, qn, (kr, qr)) * jnp.float32(scale)
+                      - lse_ref[0])
+        if masked:
+            k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, p_t.shape, 0)
+            q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, p_t.shape, 1)
+            p_t = jnp.where(q_pos < k_pos, jnp.float32(0.0), p_t)
+        ds_t = (p_t * (_scores(v, do, None) - delta_ref[0])).astype(qn.dtype)
+        dv_acc[...] += jax.lax.dot_general(
+            p_t.astype(do.dtype), do, nn, preferred_element_type=jnp.float32)
+        dkn_acc[...] += jax.lax.dot_general(
+            ds_t, qn, nn, preferred_element_type=jnp.float32)
+        dkr_acc[...] += jax.lax.dot_general(
+            ds_t, qr, nn, preferred_element_type=jnp.float32)
+        dqn_acc[qi] += jax.lax.dot_general(
+            kn, ds_t, tn, preferred_element_type=jnp.float32)
+        dqr_acc[qi] += jax.lax.dot_general(
+            kr, ds_t, tn, preferred_element_type=jnp.float32)
+
+    if causal:
+        below = k0 + (blk_k - 1) <= q0       # no position above the diagonal
+        pl.when(below)(lambda: tile(False))
+        pl.when(jnp.logical_and(_latent_live(True, q0, k0, blk_q),
+                                jnp.logical_not(below)))(lambda: tile(True))
+    else:
+        tile(False)
+
+    @pl.when(qi == pl.num_programs(3) - 1)
+    def _():
+        dkv_ref[0, :, :nope] = (dkn_acc[...] * jnp.float32(scale)).astype(
+            dkv_ref.dtype)
+        dkv_ref[0, :, nope:] = dv_acc[...].astype(dkv_ref.dtype)
+        dkr_ref[0, 0] = dkr_acc[...] * jnp.float32(scale)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
+    def _():
+        dqn_ref[0] = (dqn_acc[qi].T * jnp.float32(scale)).astype(
+            dqn_ref.dtype)
+        dqr_ref[0, 0] = (dqr_acc[qi].T * jnp.float32(scale)).astype(
+            dqr_ref.dtype)
+
+
 def _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, q_inner):
     """Block specs of the latent kernels' operands on a (batch, head, outer
     block, inner block) grid; ``q_inner`` says which of the two block axes
@@ -1374,14 +1468,17 @@ def _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, q_inner):
 
 
 def _latent_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
-                 interpret):
+                 interpret, outer="parallel", vmem_limit=None):
+    """``outer`` is the outer block axis's semantics; ``vmem_limit`` the
+    bytes Mosaic may plan for where the default 16 MiB are too few."""
     from jax.experimental.pallas import tpu as pltpu
     return pl.pallas_call(
         kernel, out_shape=out_shape, grid=grid, in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary")),
+            "parallel", "parallel", outer, "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret, name=name)
 
 
@@ -1413,41 +1510,124 @@ def _latent_fwd_impl(q_nope, q_rope, kv, k_rope, num_heads, causal, blocks,
         return call(q_nope, q_rope, kv, k_rope)
 
 
-def _latent_bwd_impl(q_nope, q_rope, kv, k_rope, o, lse, g, num_heads,
-                     causal, blocks, interpret):
-    H = num_heads
-    B, S, nope, rope, v_dim, blk_q, blk_k = _latent_dims(
-        q_nope, q_rope, kv, H, blocks)
-    # delta_i = rowsum(dO o O) per head: one fused XLA elementwise + reduce
-    delta = jnp.sum((g.astype(jnp.float32) * o.astype(jnp.float32))
-                    .reshape(B, S, H, v_dim), axis=-1)
-    delta = jnp.transpose(delta, (0, 2, 1)).reshape(B * H, 1, S)
+def _latent_bwd_split(operands, H, causal, dims, interpret):
+    """The two-kernel backward: dq over the key blocks, then dkv over the
+    query blocks, each recomputing the tile. VMEM holds blocks only."""
+    q_nope, q_rope, kv = operands[:3]
+    B, S, nope, rope, v_dim, blk_q, blk_k = dims
     common = dict(scale=float(1.0 / np.sqrt(nope + rope)), causal=causal,
                   blk_q=blk_q, blk_k=blk_k, nope=nope)
     by_k = _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, False)
     by_q = _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, True)
 
-    def operands(s):
+    def ins(s):
         return [s["q_nope"], s["q_rope"], s["kv"], s["k_rope"], s["out"],
                 s["row"], s["row"]]
 
     dq_call = _latent_call(
         functools.partial(_latent_dq_kernel, **common), "flash_latent_dq",
-        (B, H, S // blk_q, S // blk_k), operands(by_k),
+        (B, H, S // blk_q, S // blk_k), ins(by_k),
         (by_k["q_nope"], by_k["q_rope"]),
         (jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
          jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype)),
         [(blk_q, nope), (blk_q, rope)], interpret)
     dkv_call = _latent_call(
         functools.partial(_latent_dkv_kernel, **common), "flash_latent_dkv",
-        (B, H, S // blk_k, S // blk_q), operands(by_q),
+        (B, H, S // blk_k, S // blk_q), ins(by_q),
         (by_q["kv"], by_q["dk_rope"]),
         (jax.ShapeDtypeStruct(kv.shape, kv.dtype),
          jax.ShapeDtypeStruct((B, H, S, rope), jnp.float32)),
         [(blk_k, nope), (blk_k, v_dim), (blk_k, rope)], interpret)
+    return dq_call(*operands) + dkv_call(*operands)
+
+
+# VMEM the fused latent backward may plan for: the v5e's 128 MiB less what
+# the compiler keeps for itself
+_LATENT_VMEM_BUDGET = 112 * 1024 * 1024
+
+
+def _latent_bwd_vmem(seq, nope, rope, v_dim, blk_q, blk_k, itemsize):
+    """Bytes of VMEM the one-kernel backward plans for: the blocks of its
+    seven operands and four gradients, double-buffered by the pipeline (a
+    last dim under 128 takes 128 lanes, a row vector 8 sublanes), the key
+    block's three float32 accumulators, four float32 (blk_k, blk_q) tiles,
+    and dQ^T of the whole sequence in float32, the one term that grows with
+    it. The compiler's own count at 1024 / 1024 is 15.6 MiB + dQ^T; this
+    plans 23.6."""
+    lanes = -(-rope // 128) * 128
+    blocks = (blk_q * (nope + lanes + v_dim)
+              + blk_k * (nope + v_dim + lanes)) * itemsize + 2 * 8 * blk_q * 4
+    grads = (blk_q * (nope + lanes) + blk_k * (nope + v_dim)) * itemsize \
+        + blk_k * lanes * 4
+    accs = blk_k * (nope + v_dim + lanes) * 4
+    tiles = 4 * blk_q * blk_k * 4
+    return 2 * (blocks + grads) + accs + tiles + seq * (nope + rope) * 4
+
+
+def _latent_bwd_fused(operands, H, causal, dims, interpret):
+    """The one-kernel backward (see :func:`_latent_bwd_kernel`)."""
+    q_nope, q_rope, kv = operands[:3]
+    B, S, nope, rope, v_dim, blk_q, blk_k = dims
+    n_q, n_k = S // blk_q, S // blk_k
+    spec = _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, True)
+
+    def last_sweep(block, index):
+        """A dq block: query block i during the last key block's sweep,
+        block 0 (unwritten, so never written back) before it."""
+        return pl.BlockSpec(block, lambda b, h, j, i: index(
+            b, h, jnp.where(j == n_k - 1, i, 0)))
+
+    call = _latent_call(
+        functools.partial(
+            _latent_bwd_kernel, scale=float(1.0 / np.sqrt(nope + rope)),
+            causal=causal, blk_q=blk_q, blk_k=blk_k, nope=nope),
+        "flash_latent_bwd", (B, H, n_k, n_q),
+        [spec["q_nope"], spec["q_rope"], spec["kv"], spec["k_rope"],
+         spec["out"], spec["row"], spec["row"]],
+        (last_sweep((1, blk_q, nope), lambda b, h, i: (b, i, h)),
+         last_sweep((1, 1, blk_q, rope), lambda b, h, i: (b, h, i, 0)),
+         spec["kv"], spec["dk_rope"]),
+        (jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
+         jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype),
+         jax.ShapeDtypeStruct(kv.shape, kv.dtype),
+         jax.ShapeDtypeStruct((B, H, S, rope), jnp.float32)),
+        [(n_q, nope, blk_q), (n_q, rope, blk_q), (blk_k, nope),
+         (blk_k, v_dim), (blk_k, rope)], interpret, outer="arbitrary",
+        vmem_limit=_latent_bwd_vmem(S, nope, rope, v_dim, blk_q, blk_k,
+                                    q_nope.dtype.itemsize))
+    return call(*operands)
+
+
+# Where :func:`_latent_bwd_impl` sent each backward it traced (once a trace):
+# "fused" = the one kernel, "split" = dq then dkv.
+_LATENT_BACKWARDS = {"fused": 0, "split": 0}
+
+
+def latent_backward_stats():
+    """:func:`flash_backward_stats` for the latent family."""
+    return dict(_LATENT_BACKWARDS)
+
+
+def _latent_bwd_impl(q_nope, q_rope, kv, k_rope, o, lse, g, num_heads,
+                     causal, blocks, interpret):
+    """Backward of the latent attention. Takes the one kernel by what it
+    can see in its input: dQ^T of a (row, head)'s whole sequence fits VMEM
+    beside the tile (:func:`_latent_bwd_vmem`); the dq + dkv pair
+    otherwise."""
+    H = num_heads
+    dims = _latent_dims(q_nope, q_rope, kv, H, blocks)
+    B, S, nope, rope, v_dim, blk_q, blk_k = dims
+    # delta_i = rowsum(dO o O) per head: one fused XLA elementwise + reduce
+    delta = jnp.sum((g.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(B, S, H, v_dim), axis=-1)
+    delta = jnp.transpose(delta, (0, 2, 1)).reshape(B * H, 1, S)
+    fused = _latent_bwd_vmem(S, nope, rope, v_dim, blk_q, blk_k,
+                             q_nope.dtype.itemsize) <= _LATENT_VMEM_BUDGET
+    _LATENT_BACKWARDS["fused" if fused else "split"] += 1
+    run = _latent_bwd_fused if fused else _latent_bwd_split
     with jax.enable_x64(False):
-        dqn, dqr = dq_call(q_nope, q_rope, kv, k_rope, g, lse, delta)
-        dkv, dkr = dkv_call(q_nope, q_rope, kv, k_rope, g, lse, delta)
+        dqn, dqr, dkv, dkr = run((q_nope, q_rope, kv, k_rope, g, lse, delta),
+                                 H, causal, dims, interpret)
     return dqn, dqr, dkv, jnp.sum(dkr, axis=1).astype(k_rope.dtype)
 
 

@@ -261,21 +261,30 @@ def _latent_fwd_bwd():
 
 @pytest.mark.parametrize("seq", [8192, 32768])
 def test_latent_kernels_compile_at_published_widths(topo, seq):
-    """16 heads of 128 + 64 against 128, bfloat16: forward, dq and dkv for
-    one described chip. The key blocks are a grid axis, so what fits at
-    8,192 positions (the cell's) fits at 32,768: VMEM does not grow with
-    the sequence (the per-head BHSD kernels are refused at 8,192 x 192)."""
+    """16 heads of 128 + 64 against 128, bfloat16, for one described chip:
+    two Mosaic calls, ``flash_latent_fwd`` and the ONE backward
+    ``flash_latent_bwd``, at 8,192 positions (the cell's) and at 32,768.
+    The forward's VMEM holds blocks only; the backward's grows with the
+    sequence by dQ^T of one (row, head) in float32 (6 MiB at 8,192, 24 at
+    32,768) and asks Mosaic for what ``_latent_bwd_vmem`` plans, so this is
+    where that footprint is proved without the chip. Past the budget (about
+    120,000 positions at these widths) ``flash_latent_dq`` + ``_dkv`` run
+    (the per-head BHSD kernels are refused at 8,192 x 192)."""
+    from mxnet_tpu.ops import pallas_kernels as pk
     one = SingleDeviceSharding(topo.devices[0])
     rows = 2 if seq == 8192 else 1
 
     def spec(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
 
+    backwards = pk.latent_backward_stats()
     text = _latent_fwd_bwd().lower(
         spec(rows, seq, 16 * 128), spec(rows, seq, 16, 64),
         spec(rows, seq, 16 * 256), spec(rows, seq, 64)).compile().as_text()
-    assert text.count("tpu_custom_call") == 3
-    for kernel in ("flash_latent_fwd", "flash_latent_dq", "flash_latent_dkv"):
+    assert pk.latent_backward_stats() == {"fused": backwards["fused"] + 1,
+                                          "split": backwards["split"]}
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("flash_latent_fwd", "flash_latent_bwd"):
         assert kernel in text, kernel
 
 

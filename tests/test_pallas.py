@@ -765,13 +765,16 @@ def _attention_jaxpr_hashes():
         return jax.ShapeDtypeStruct(shape, dtype)
 
     traced = {}
-    # the Kimi cell: 4 causal rows of 8192, 16 heads, 128 + 64 against 128
-    latent = jax.value_and_grad(
-        lambda a, b, c, d: sq(pk.flash_attention_latent(a, b, c, d, 16, True)),
-        argnums=(0, 1, 2, 3))
-    traced["latent_4x8192_h16"] = jax.make_jaxpr(latent)(
-        spec(4, 8192, 2048), spec(4, 8192, 16, 64), spec(4, 8192, 4096),
-        spec(4, 8192, 64))
+    # the Kimi cell: 4 causal rows of 8192, 16 heads, 128 + 64 against 128.
+    # The forward is the parent's; the backward is PR 30's one kernel.
+    latent = (spec(4, 8192, 2048), spec(4, 16, 8192, 64),
+              spec(4, 8192, 4096), spec(4, 8192, 64))
+    traced["latent_fwd_4x8192_h16"] = jax.make_jaxpr(
+        lambda *a: pk._latent_fwd_impl(*a, 16, True, None, False))(*latent)
+    traced["latent_bwd_4x8192_h16"] = jax.make_jaxpr(
+        lambda *a: pk._latent_bwd_impl(*a, 16, True, None, False))(
+        *latent, spec(4, 8192, 2048), spec(64, 1, 8192, dtype=jnp.float32),
+        spec(4, 8192, 2048))
     q = spec(2, 4, 512, 64)
     mask, seed = spec(2, 512, dtype=jnp.int32), spec(dtype=jnp.int32)
     for name, causal, drop, masked in (
@@ -803,11 +806,13 @@ def _attention_jaxpr_hashes():
 
 
 def test_latent_bhsd_and_split_kernels_trace_to_the_parents_jaxprs():
-    """``flash_latent_*`` at the Kimi cell's shape, ``flash_bhsd_*`` and the
-    two-kernel head-fused path trace to the programs recorded from the
-    commit before the fused backward (``tests/data/
-    attention_jaxprs_pr27.json``, written by this function there): the
-    control cell stands still, shown without the chip."""
+    """``flash_latent_fwd`` at the Kimi cell's shape, ``flash_bhsd_*`` and
+    the two-kernel head-fused path trace to the programs recorded from the
+    commits before the fused backwards (``tests/data/
+    attention_jaxprs_pr27.json``, written by this function there; the
+    latent forward's entry from PR 29's tree): what the BERT cells and
+    ``TransformerLM`` run stands still, shown without the chip. The latent
+    backward's entry is ``flash_latent_bwd`` as PR 30 left it."""
     import json
     import os
     path = os.path.join(os.path.dirname(__file__), "data",
@@ -815,3 +820,93 @@ def test_latent_bhsd_and_split_kernels_trace_to_the_parents_jaxprs():
     with open(path) as f:
         recorded = json.load(f)
     assert _attention_jaxpr_hashes() == recorded
+
+
+# ---- the latent family's one-kernel backward (PR 30) -----------------------
+
+def _latent_grads(attend, operands, weight, heads, causal):
+    return jax.grad(lambda *a: jnp.sum(attend(*a, heads, causal) * weight),
+                    argnums=(0, 1, 2, 3))(*operands)
+
+
+@pytest.mark.parametrize("causal,seq,blocks,v_dim", [
+    (True, 512, (256, 128), 128), (True, 512, (128, 256), 128),
+    (False, 512, (256, 128), 128), (True, 384, (128, 128), 256),
+    (False, 256, (128, 256), 256)],
+    ids=["causal_q256_k128", "causal_q128_k256", "full_q256_k128",
+         "causal_3kb_v256", "full_1kb_v256"])
+def test_latent_fused_backward_equals_the_pair_and_the_xla_form(
+        causal, seq, blocks, v_dim, monkeypatch):
+    """``flash_latent_bwd`` (dq summed in VMEM along the key axis) against
+    ``flash_latent_dq`` + ``_dkv`` (the footprint decision turned, the only
+    way to the pair at a length the CPU can run) and against
+    ``xla_latent_attention``: all four gradients, several key blocks,
+    blocks that differ and do not divide each other's multiples, values
+    wider than the keys' own part."""
+    from mxnet_tpu.ops import nn as nn_ops
+    from mxnet_tpu.ops import pallas_kernels as pk
+    heads, nope, rope = 2, 128, 64
+    shapes = [(2, seq, heads * nope), (2, seq, heads, rope),
+              (2, seq, heads * (nope + v_dim)), (2, seq, rope),
+              (2, seq, heads * v_dim)]
+    *operands, weight = (_rand(s, 300 + i) for i, s in enumerate(shapes))
+
+    def flash(*a):
+        return pk.flash_attention_latent(*a, blocks, True)
+
+    before = pk.latent_backward_stats()
+    fused = _latent_grads(flash, operands, weight, heads, causal)
+    monkeypatch.setattr(pk, "_LATENT_VMEM_BUDGET", 0)
+    pair = _latent_grads(flash, operands, weight, heads, causal)
+    assert pk.latent_backward_stats() == {"fused": before["fused"] + 1,
+                                          "split": before["split"] + 1}
+    plain = _latent_grads(nn_ops.xla_latent_attention, operands, weight,
+                          heads, causal)
+    for got, same, want in zip(fused, pair, plain):
+        scale = float(jnp.abs(want).max())
+        assert scale > 0.01
+        # the same sums in the same order; the tile is transposed
+        assert float(jnp.abs(got - same).max()) <= 2e-6 * scale
+        assert float(jnp.abs(got - want).max()) <= 5e-6 * scale
+
+
+def test_latent_backward_counts_the_traced_decision():
+    """``_latent_bwd_impl`` decides by the footprint it computes from the
+    shapes: the one kernel at the Kimi cell's widths and at 32,768, the pair
+    where dQ^T of a sequence cannot be held (131,072 at these widths).
+    Counted once a trace in ``latent_backward_stats()``; the head-fused
+    family counts in its own keys only."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    def names(seq, rows):
+        def loss(a, b, c, d):
+            return jnp.sum(pk.flash_attention_latent(a, b, c, d, 16, True)
+                           .astype(jnp.float32))
+        text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+            *(jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
+                (rows, seq, 2048), (rows, seq, 16, 64), (rows, seq, 4096),
+                (rows, seq, 64)))))
+        return sorted(set(line.split("=")[1] for line in text.splitlines()
+                          if line.strip().startswith("name=flash_")))
+
+    assert pk._latent_bwd_vmem(8192, 128, 64, 128, 1024, 1024, 2) \
+        == 31064064                                   # 29.6 MiB planned
+    assert pk._latent_bwd_vmem(32768, 128, 64, 128, 1024, 1024, 2) \
+        <= pk._LATENT_VMEM_BUDGET < pk._latent_bwd_vmem(
+            131072, 128, 64, 128, 1024, 1024, 2)
+    head_fused, before = pk.flash_backward_stats(), pk.latent_backward_stats()
+    assert names(8192, 4) == ["flash_latent_bwd", "flash_latent_fwd"]
+    assert pk.latent_backward_stats() == {"fused": before["fused"] + 1,
+                                          "split": before["split"]}
+    assert names(131072, 1) == ["flash_latent_dkv", "flash_latent_dq",
+                                "flash_latent_fwd"]
+    assert pk.latent_backward_stats() == {"fused": before["fused"] + 1,
+                                          "split": before["split"] + 1}
+    assert pk.flash_backward_stats() == head_fused
+    # a BERT-shaped backward counts in the head-fused family's keys alone
+    jax.make_jaxpr(jax.grad(lambda qkv: jnp.sum(pk.flash_attention_packed(
+        qkv, 12).astype(jnp.float32))))(
+        jax.ShapeDtypeStruct((2, 512, 2304), jnp.bfloat16))
+    assert pk.flash_backward_stats()["fused"] == head_fused["fused"] + 1
+    assert pk.latent_backward_stats() == {"fused": before["fused"] + 1,
+                                          "split": before["split"] + 1}

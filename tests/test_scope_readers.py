@@ -346,6 +346,35 @@ def test_attention_fused_bwd_reader(path, expected, monkeypatch, request):
 
 
 @pytest.mark.parametrize("path,expected", [
+    ("fused", 100.0), ("split", 0.0), ("no_counter", None),
+    ("nothing_traced", None)])
+def test_latent_fused_bwd_reader(path, expected, monkeypatch):
+    """``latent_fused_bwd_pct.train`` over ``latent_backward_stats()``: a
+    latent backward traced at widths whose dQ^T fits VMEM reads 100; the
+    dq + dkv pair alone reads 0; a program without the counter (the
+    parent), or one that traced no latent backward (the BERT cells), reads
+    None."""
+    import jax
+    import jax.numpy as jnp
+    monkeypatch.setattr(pk, "_LATENT_BACKWARDS",
+                        dict.fromkeys(pk._LATENT_BACKWARDS, 0))
+    if path == "split":
+        monkeypatch.setattr(pk, "_LATENT_VMEM_BUDGET", 0)
+    if path in ("fused", "split"):
+        jax.make_jaxpr(jax.grad(lambda a, b, c, d: jnp.sum(
+            pk.flash_attention_latent(a, b, c, d, 2, True)
+            .astype(jnp.float32))))(*(
+                jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
+                    (1, 256, 256), (1, 256, 2, 64), (1, 256, 512),
+                    (1, 256, 64))))
+    if path == "no_counter":
+        monkeypatch.delattr(pk, "latent_backward_stats")
+    read = load_reader("latent_fused_bwd_pct.train", METRIC_DIR)
+    assert read(_obs()) == expected
+    assert read(_obs(kind="serve")) is None
+
+
+@pytest.mark.parametrize("path,expected", [
     ("flash_interpret", 100.0), ("xla", 0.0), ("no_counter", None),
     ("nothing_traced", None)])
 def test_attention_packed_reader_on_a_tiny_traced_step(path, expected,
