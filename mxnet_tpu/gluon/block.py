@@ -28,7 +28,7 @@ from ..ndarray.ndarray import NDArray
 from .parameter import (DeferredInitializationError, Parameter,
                         ParameterDict, swapped_in)
 
-__all__ = ["Block", "HybridBlock", "SymbolBlock"]
+__all__ = ["Block", "HybridBlock", "SymbolBlock", "recomputed"]
 
 _naming = threading.local()
 
@@ -303,6 +303,24 @@ class Block:
 
     def forward(self, *args):
         raise NotImplementedError
+
+
+def recomputed(block, x, keep=()):
+    """``block(x)`` whose forward is run again in the backward pass of the
+    traced program that holds it (``jax.checkpoint`` around the call: only
+    ``x`` is kept for the backward, not what the block computed on the way,
+    except the arrays the ops tagged with a name in ``keep``). The block's
+    parameters ride as the closed-over tracers they are under
+    ``parallel.functionalize``; the block must write no auxiliary state.
+    Outside a traced program (an eager call, the autograd tape) it is the
+    plain call."""
+    from .. import _tape
+    if not isinstance(getattr(x, "_data", None), _Tracer) \
+            or _tape.is_recording():
+        return block(x)
+    return NDArray(jax.checkpoint(
+        lambda data: block(NDArray(data))._data,
+        policy=jax.checkpoint_policies.save_only_these_names(*keep))(x._data))
 
 
 class _HookHandle:

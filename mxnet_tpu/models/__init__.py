@@ -4,3 +4,4 @@ from .transformer import (TransformerLM, MultiHeadAttention,
                           transformer_lm_small, transformer_lm_base, tp_rules)
 from .moe_transformer import MoETransformerLM, moe_lm_tiny
 from .lstm_lm import RNNModel
+from .eva_lm import EvaDecoder, EvaAttention, EvaDecoderLayer, eva_lm_tiny

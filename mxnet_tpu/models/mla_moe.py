@@ -51,14 +51,20 @@ def _dense(units, in_units, prefix):
 
 
 class RMSNorm(HybridBlock):
-    def __init__(self, units, eps=1e-5, **kwargs):
+    """``unit_offset``: the leaf (then named ``offset``) stores the gain's
+    offset from one, zero at the start, and the gain is one plus it."""
+
+    def __init__(self, units, eps=1e-5, unit_offset=False, **kwargs):
         super().__init__(**kwargs)
-        self._eps = eps
+        self._eps, self._offset = eps, unit_offset
         with self.name_scope():
-            self.gamma = self.params.get("gamma", shape=(units,), init="ones")
+            # an initializer fills whatever is named ``gamma`` with ones
+            self.gamma = self.params.get("offset", shape=(units,),
+                                         init="zeros") if unit_offset \
+                else self.params.get("gamma", shape=(units,), init="ones")
 
     def hybrid_forward(self, F, x, gamma):
-        return F.RMSNorm(x, gamma, eps=self._eps)
+        return F.RMSNorm(x, gamma, eps=self._eps, unit_offset=self._offset)
 
 
 class LatentAttention(HybridBlock):

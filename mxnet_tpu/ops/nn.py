@@ -558,13 +558,14 @@ def UpSampling(*data, scale=1, sample_type="nearest", num_args=1,
 # flash kernels on the unsplit QKV projection, "flash" = the flash kernels
 # on separate q/k/v (head-fused or per-head), "latent" = the
 # latent-attention flash kernels (score of two dot products, keys wider
-# than values), "xla" = the composed softmax.
-_DISPATCHED = {"packed": 0, "flash": 0, "latent": 0, "xla": 0}
+# than values), "eva" = the window-plus-summaries flash kernels, "xla" = the
+# composed softmax.
+_DISPATCHED = {"packed": 0, "flash": 0, "latent": 0, "eva": 0, "xla": 0}
 
 
 def attention_dispatch_stats():
     """Snapshot of the dispatcher's path counts since the process
-    started: ``{"packed", "flash", "latent", "xla"}``."""
+    started: ``{"packed", "flash", "latent", "eva", "xla"}``."""
     return dict(_DISPATCHED)
 
 
@@ -576,8 +577,8 @@ def _attention_path(form, shape, kv_shapes=(), mask_shape=None, scaled=True,
                     drop=0.0, keyed=True):
     """Name the implementation an attention call runs, from what can be
     observed of it and nothing else: ``"packed"``, ``"bshd"``, ``"bhsd"``,
-    ``"latent"`` (the flash kernels of ``ops/pallas_kernels.py``) or
-    ``"xla"`` (the composed softmax).
+    ``"latent"``, ``"eva"`` (the flash kernels of ``ops/pallas_kernels.py``)
+    or ``"xla"`` (the composed softmax).
 
     =========  ===============================  ==========================
     ``form``   ``shape``                        kernels tried, in order
@@ -586,6 +587,7 @@ def _attention_path(form, shape, kv_shapes=(), mask_shape=None, scaled=True,
     BSHD       q's (B, S, H, D)                 bshd, bhsd
     BHSD       q's (B, H, S, D)                 bhsd
     latent     (S, nope, rope, v)               latent
+    eva        (S, D, window, chunk)            eva
     =========  ===============================  ==========================
 
     ``kv_shapes``: k's and v's shapes; ``mask_shape``: the keep-mask's, or
@@ -600,13 +602,18 @@ def _attention_path(form, shape, kv_shapes=(), mask_shape=None, scaled=True,
     price of a transpose each way). ``packed`` is ``bshd`` on the unsplit
     projection, which cannot shard its heads: under a visible mesh with
     ``tp`` > 1 the projection is split for ``bshd``. The latent kernels
-    need nope, v multiples of 128 and rope a multiple of 8 up to 128."""
+    need nope, v multiples of 128 and rope a multiple of 8 up to 128. The
+    EVA kernels need D a multiple of 128, S a multiple of the window, the
+    window a multiple of 128 and of the chunk, and the summaries (S / chunk)
+    a multiple of 128."""
     from ..parallel.mesh import current_scope
     from . import pallas_kernels as pk
     if not _on_accelerator():
         return "xla"
     if form == "latent":
         return "latent" if pk.flash_attention_latent_usable(*shape) else "xla"
+    if form == "eva":
+        return "eva" if pk.flash_attention_eva_usable(*shape) else "xla"
     if (len(shape) != 4 or any(tuple(s) != tuple(shape) for s in kv_shapes)
             or not scaled or (drop > 0.0 and not keyed)):
         return "xla"
@@ -674,7 +681,8 @@ def _shard_flash(call, operands, num_heads, heads_dim, mesh, batch_axes,
         out_specs=specs[0], check_vma=False)(kv_mask, seed, *operands)
 
 
-def _flash(path, operands, num_heads, mask, rng_key, causal, drop):
+def _flash(path, operands, num_heads, mask, rng_key, causal, drop,
+           window=None):
     """The flash kernels of ``path`` on the devices the enclosing program
     spans: the bare call on one device, :func:`_shard_flash` when the
     trainer or serving lane tracing this op made a larger mesh visible
@@ -690,6 +698,9 @@ def _flash(path, operands, num_heads, mask, rng_key, causal, drop):
     elif path == "latent":
         def call(ops, m, s):
             return pk.flash_attention_latent(*ops, num_heads, causal)
+    elif path == "eva":
+        def call(ops, m, s):
+            return pk.flash_attention_eva(*ops, num_heads, window)
     else:
         kernel = pk.flash_attention_bshd if path == "bshd" \
             else pk.flash_attention
@@ -818,12 +829,17 @@ def packed_self_attention(qkv, mask=None, num_heads=1, dropout=0.0,
 
 
 @register("RMSNorm", aliases=("rms_norm",))
-def RMSNorm(data, gamma, eps=1e-5):
+def RMSNorm(data, gamma, eps=1e-5, unit_offset=False):
     """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, computed in
-    float32 whatever the storage type (Zhang & Sennrich 2019)."""
+    float32 whatever the storage type (Zhang & Sennrich 2019). With
+    ``unit_offset`` the gain is ``1 + gamma``: the leaf stores its offset
+    from one."""
     x = data.astype(jnp.float32)
     y = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
-    return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+    gain = gamma.astype(jnp.float32)
+    if unit_offset:
+        gain = 1.0 + gain
+    return (y * gain).astype(data.dtype)
 
 
 def _rotary_tables(seq, dim, theta):
@@ -902,6 +918,110 @@ def latent_attention(q_nope, q_rope, kv, k_rope, num_heads=1, causal=True):
             return _flash(path, (q_nope, q_rope, kv, k_rope), H, None, None,
                           causal, 0.0)
         return xla_latent_attention(q_nope, q_rope, kv, k_rope, H, causal)
+
+
+# ------------------------------------- window + chunk summaries (EVA)
+
+
+def eva_chunk_summaries(k, v, mu, phi, num_heads=1, chunk=16):
+    """The learned pooling of EVA attention: for each head and each chunk c
+    of ``chunk`` consecutive positions, ``kt_c = sum_m softmax_m(s mu_h .
+    k_m) k_m`` and ``vt_c = sum_m softmax_m(s phi_h . k_m) v_m`` over the
+    chunk's positions m, s = 1/sqrt(D). ``k`` (already rotated) and ``v`` are
+    (B, S, H*D), ``mu`` and ``phi`` (H, D); returns ``(kt, vt)``, each
+    (B, S / chunk, H*D). Logits and softmaxes in float32; written under the
+    ``eva_pool`` scope."""
+    with jax.named_scope("eva_pool"):
+        B, S, HD = k.shape
+        H, C = int(num_heads), int(chunk)
+        D = HD // H
+        kc = k.reshape(B, S // C, C, H, D)
+        vc = v.reshape(B, S // C, C, H, D)
+        scale = _np.float32(1.0 / _np.sqrt(D))
+
+        def pooled(vector, what):
+            # multiply-and-reduce, not dots: sixteen positions a chunk are
+            # no matmul. On the chip at 1 x 32,768 x 32 x 128 (PR 31) XLA
+            # takes 9.2 ms forward and 20.7 with the gradient, fourteen
+            # times the reads' bound: a kernel's to win (PERF.md section 7)
+            logits = scale * jnp.sum(
+                kc.astype(jnp.float32) * vector.astype(jnp.float32), axis=-1)
+            weights = jax.nn.softmax(logits, axis=2)
+            out = jnp.sum(weights[..., None] * what.astype(jnp.float32),
+                          axis=2)
+            return out.astype(k.dtype).reshape(B, S // C, HD)
+
+        return pooled(mu, kc), pooled(phi, vc)
+
+
+def xla_eva_aggregate(q, k, v, kt, vt, num_heads, window):
+    """The aggregation of EVA attention composed from XLA ops, a window at
+    a time (no (S, S) array): the queries of window w against the positions
+    of their own window at or before them and the summaries ``kt``, ``vt``
+    of every chunk of an earlier window, under one float32 softmax."""
+    B, S, HD = q.shape
+    H, W = int(num_heads), int(window)
+    D, n_sum = HD // H, kt.shape[1]
+    per_window = n_sum // (S // W)
+    scale = _np.float32(1.0 / _np.sqrt(D))
+    kt4, vt4 = kt.reshape(B, n_sum, H, D), vt.reshape(B, n_sum, H, D)
+    below = jnp.tril(jnp.ones((W, W), dtype=bool))
+    earlier = jnp.arange(n_sum) // per_window
+
+    def one(w):
+        qw, kw, vw = (lax.dynamic_slice_in_dim(a, w * W, W, axis=1)
+                      .reshape(B, W, H, D) for a in (q, k, v))
+        exact = jnp.einsum("bqhd,bkhd->bhqk", qw, kw,
+                           preferred_element_type=jnp.float32) * scale
+        far = jnp.einsum("bqhd,bchd->bhqc", qw, kt4,
+                         preferred_element_type=jnp.float32) * scale
+        scores = jnp.concatenate(
+            [jnp.where(below, exact, -1e30),
+             jnp.where((earlier < w)[None, None, None, :], far, -1e30)], -1)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        out = jnp.einsum("bhqk,bkhd->bqhd", probs[..., :W], vw) \
+            + jnp.einsum("bhqc,bchd->bqhd", probs[..., W:], vt4)
+        return out.reshape(B, W, HD)
+
+    out = lax.map(one, jnp.arange(S // W))              # (S/W, B, W, HD)
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, HD)
+
+
+def xla_eva_attention(q, k, v, mu, phi, num_heads=1, window=2048, chunk=16):
+    """:func:`eva_attention` composed from XLA ops."""
+    kt, vt = eva_chunk_summaries(k, v, mu, phi, num_heads, chunk)
+    return xla_eva_aggregate(q, k, v, kt, vt, num_heads, window)
+
+
+@register("_contrib_eva_attention")
+def eva_attention(q, k, v, mu, phi, num_heads=1, window=2048, chunk=16):
+    """EVA attention (Zheng et al., "Efficient Attention via Control
+    Variates", ICLR 2023, as a byte-level decoder fixes it): causal softmax
+    attention in which the query at t sees the positions of ITS OWN window of
+    ``window`` positions at or before t exactly, and every EARLIER window
+    through the summaries of its chunks of ``chunk`` positions
+    (:func:`eva_chunk_summaries`: a learned pooling of the chunk's keys and
+    values by the per-head vectors ``mu`` and ``phi``), both kinds of key
+    under ONE softmax of scale 1/sqrt(D). The first window sees no summary
+    and is plain causal attention; with ``chunk`` 1 a summary is its
+    position and the whole is plain causal attention over the sequence.
+
+    ``q``, ``k`` (both already rotated) and ``v`` are (B, S, H*D) as the
+    projections made them, ``mu`` and ``phi`` (H, D); returns (B, S, H*D) as
+    the output projection reads it. Where the EVA flash kernels run
+    (:func:`_attention_path`, form ``eva``) the aggregation steps through
+    the live tiles only and each device of a visible mesh attends its share
+    of the batch; everywhere else :func:`xla_eva_aggregate` computes the
+    same. The pooling is XLA either way. Every op carries the ``attention``
+    scope, the pooling ``eva_pool`` inside it."""
+    with jax.named_scope("attention"):
+        H, W, C = int(num_heads), int(window), int(chunk)
+        path = _dispatch("eva", (q.shape[1], q.shape[-1] // H, W, C))
+        kt, vt = eva_chunk_summaries(k, v, mu, phi, H, C)
+        if path == "eva":
+            return _flash(path, (q, k, v, kt, vt), H, None, None, True, 0.0,
+                          window=W)
+        return xla_eva_aggregate(q, k, v, kt, vt, H, W)
 
 
 @register("_contrib_held_experts_ffn", n_out=2)
@@ -997,7 +1117,21 @@ def chunked_softmax_cross_entropy(hidden, weight, labels, chunk=2048):
     ``chunk`` of positions at a time: no (positions, V) array is live whole,
     forward or backward (the backward pass recomputes each chunk's logits
     from the saved log-sum-exp and adds the head's gradient up in
-    float32)."""
+    float32). ``labels (..., P)``, one axis more than ``hidden`` has before
+    its last: P targets a position, head j's rows of ``weight (P * V, d)``
+    from ``j * V`` on, and the mean over the heads of each head's mean,
+    a head at a time."""
     flat = hidden.reshape(-1, hidden.shape[-1])
-    lab = labels.reshape(-1).astype(jnp.int32)
-    return _chunked_ce(flat, weight, lab, _ce_chunks(flat.shape[0], chunk))
+    chunk = _ce_chunks(flat.shape[0], chunk)
+    if labels.ndim < hidden.ndim:
+        return _chunked_ce(flat, weight, labels.reshape(-1).astype(jnp.int32),
+                           chunk)
+    heads = labels.shape[-1]
+
+    def one(total, head):       # a scan: one head's d(hidden) live at a time
+        return total + _chunked_ce(flat, *head, chunk), None
+
+    total, _ = lax.scan(one, jnp.float32(0.0), (
+        weight.reshape(heads, -1, weight.shape[-1]),
+        labels.reshape(-1, heads).T.astype(jnp.int32)))
+    return total / heads

@@ -38,8 +38,9 @@ from jax.experimental import pallas as pl
 
 __all__ = ["flash_attention", "flash_attention_bshd",
            "flash_attention_packed", "flash_attention_latent",
-           "flash_attention_usable", "flash_attention_bshd_usable",
-           "flash_attention_latent_usable"]
+           "flash_attention_eva", "flash_attention_usable",
+           "flash_attention_bshd_usable", "flash_attention_latent_usable",
+           "flash_attention_eva_usable"]
 
 # 128 is the alignment unit (MXU/VPU tiling); actual blocks are chosen
 # per call by _pick_blocks: the largest 128-multiple divisor of S up to
@@ -1665,3 +1666,459 @@ def flash_attention_latent(q_nope, q_rope, kv, k_rope, num_heads,
                         k_rope, int(num_heads), bool(causal), blocks,
                         interpret)
     return out
+
+
+# ====================================================================== eva
+# The aggregation of EVA attention: the query at t sees the positions of ITS
+# OWN window at or before t, and the chunk summaries of every EARLIER window,
+# under one softmax. q, k, v (B, S, H*D) and the summaries kt, vt
+# (B, S / chunk, H*D) stay as the projections and the pooling made them and
+# are read as column blocks, D a multiple of 128. As in the latent family the
+# key blocks are a GRID axis (sequential) and the accumulators live in
+# scratch, so VMEM holds one block of each operand whatever the sequence;
+# unlike it the axis covers only the tiles that can be live: first the
+# summary blocks, then the blocks of the query's own window, whose index the
+# index map works out from the query block's. A summary tile of windows not
+# earlier than the query's, and a window tile above the diagonal, is skipped
+# and its block index clamped to the nearest live one (nothing is fetched for
+# it); a tile that straddles is masked. The tile bodies are the BHSD
+# kernels' (`_fwd_tile_update`, `_bwd_tile_ds`, `_tile_dead`): the summaries'
+# mask rides as their key-padding row. Backward: `flash_eva_bwd` over the
+# forward's grid with the query blocks sequential gives dq and, from ONE pass
+# over each window tile's scores, the positions' dk and dv (a window's
+# accumulators in scratch); `flash_eva_dsum` gives the summaries' gradients,
+# a summary block against the query blocks of every later window. The
+# pooling, and its gradient, are XLA's (`ops/nn.py`).
+
+# measured on the chip at 1 x 32,768 x 32 heads of 128, windows of 2,048,
+# chunks of 16 (PR 31), forward / forward + backward, ms: 1024/1024/1024
+# 19.6 / 51.9 (with `flash_eva_dq` + `_dkv` in place of the one `_bwd`:
+# 57.4), 1024/1024/512 22.9 / 53.5 (58.9), 2048/1024/1024 22.3 / 57.1,
+# 2048/1024/512 24.7 / 56.9, 1024/512/512 29.3 / 60.4, 512/512/512 32.4 /
+# 65.7, 2048/2048/1024 56.0 / 90.9, 2048/2048/512 80.7 / 112.6
+_PREF_EVA = (1024, 1024, 1024)      # queries, positions, summaries a block
+
+
+def flash_attention_eva_usable(seq, head_dim, window, chunk):
+    """Whether the EVA kernels take this problem."""
+    return (head_dim % 128 == 0 and window % 128 == 0 and window % chunk == 0
+            and seq % window == 0 and (seq // chunk) % 128 == 0)
+
+
+def _pick_blocks_eva(seq, window, chunk):
+    """(blk_q, blk_k, blk_s): the largest multiples of 128 up to the
+    preferred sizes that divide the window (queries, positions: a query
+    block lies in one window) and the number of summaries."""
+    def pick(pref, whole):
+        b = max(128, min(pref // 128 * 128, whole))
+        while whole % b:
+            b -= 128
+        return b
+    pq, pk, ps = _PREF_EVA
+    return pick(pq, window), pick(pk, window), pick(ps, seq // chunk)
+
+
+class _EvaPlan:
+    """The shapes and block arithmetic the EVA kernels share. ``n_s``
+    summary blocks of ``blk_s``, ``n_w`` position blocks of ``blk_k`` a
+    window, ``per_window`` summaries a window."""
+
+    def __init__(self, q, kt, num_heads, window, blocks):
+        self.B, self.S, HD = q.shape
+        self.H, self.W = num_heads, window
+        self.D = HD // num_heads
+        self.n_sum = kt.shape[1]
+        self.per_window = self.n_sum // (self.S // window)
+        chunk = self.S // self.n_sum
+        self.blk_q, self.blk_k, self.blk_s = \
+            blocks or _pick_blocks_eva(self.S, window, chunk)
+        self.n_q = self.S // self.blk_q
+        self.n_s, self.n_w = self.n_sum // self.blk_s, window // self.blk_k
+        self.scale = float(1.0 / np.sqrt(self.D))
+
+    # -- which tiles are live, from block indices (traced or plain ints)
+    def live_summaries(self, i):
+        """Summaries the queries of block ``i`` see: those of the windows
+        before theirs."""
+        return (i * self.blk_q) // self.W * self.per_window
+
+    def last_summary_block(self, i):
+        return jnp.maximum(-(-self.live_summaries(i) // self.blk_s) - 1, 0)
+
+    def first_position_block(self, i):
+        """Index, among all position blocks, of the first of block ``i``'s
+        window."""
+        return (i * self.blk_q) // self.W * self.n_w
+
+    def last_position_step(self, i):
+        """The last live step ``jj`` among the window's position blocks:
+        the one that holds the query block's last position."""
+        return ((i * self.blk_q) % self.W + self.blk_q - 1) // self.blk_k
+
+    def first_later_query_block(self, j):
+        """First query block that sees summary block ``j``: the first of
+        the window after the one its first summary belongs to."""
+        return ((j * self.blk_s) // self.per_window + 1) \
+            * (self.W // self.blk_q)
+
+    # -- the liveness the kernels branch on and :meth:`tiles` counts
+    def summary_step_live(self, i, j):
+        """Step ``j`` of query block ``i``'s sweep is a summary tile that
+        holds a summary the block sees."""
+        return (j < self.n_s) & (j * self.blk_s < self.live_summaries(i))
+
+    def position_step_live(self, i, j):
+        """Step ``j`` is a tile of the block's own window that does not lie
+        wholly above the diagonal."""
+        return (j >= self.n_s) & (j - self.n_s <= self.last_position_step(i))
+
+    def seen_by_query_block(self, j, i):
+        """Summary block ``j`` holds a summary that query block ``i`` sees
+        (the dsum kernel's grid: summary blocks by query blocks)."""
+        return i >= self.first_later_query_block(j)
+
+    def tiles(self):
+        """``(stepped, live)`` key tiles of one (row, head) over the three
+        kernels' grids, by the predicates the kernels branch on."""
+        sweep = [bool(self.summary_step_live(i, j))
+                 or bool(self.position_step_live(i, j))
+                 for i in range(self.n_q) for j in range(self.n_s + self.n_w)]
+        dsum = [bool(self.seen_by_query_block(j, i))
+                for j in range(self.n_s) for i in range(self.n_q)]
+        # the forward's and the backward's grids are the same sweep
+        return 2 * len(sweep) + len(dsum), 2 * sum(sweep) + sum(dsum)
+
+
+# Key tiles the grids of the EVA kernels traced so far step through, and
+# those of them that hold a live pair (counted where the planner decides,
+# once a trace, for one (row, head) of each call, forward and backward).
+_EVA_TILES = {"stepped": 0, "live": 0}
+
+
+def eva_tile_stats():
+    """:func:`flash_backward_stats` for the EVA family's grids."""
+    return dict(_EVA_TILES)
+
+
+def _summary_keep_row(s0, blk_s, seen):
+    """(1, blk_s) keep-row of a summary tile: 1 where the summary's index is
+    below ``seen``."""
+    idx = s0 + jax.lax.broadcasted_iota(jnp.int32, (1, blk_s), 1)
+    return (idx < seen).astype(jnp.int32)
+
+
+def _eva_fwd_kernel(q_ref, k_ref, v_ref, kt_ref, vt_ref, o_ref, lse_ref,
+                    acc_ref, m_ref, l_ref, *, plan):
+    """One (batch, head, q-block, key step) program: an online-softmax step
+    into the scratch accumulators over a summary tile (steps below ``n_s``)
+    or a tile of the query's own window; the last step writes the output
+    and the log-sum-exp."""
+    p = plan
+    i, j = pl.program_id(2), pl.program_id(3)
+    q0 = i * p.blk_q
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    def step(k, v, dead, k0, blk_k):
+        carry = (acc_ref[...], m_ref[0, :], l_ref[0, :])
+        _, (acc, m_i, l_i) = _fwd_tile_update(
+            q_ref[0], k, v, carry, dead, None, None, q0, k0, p.blk_q, blk_k,
+            0.0, p.scale)
+        acc_ref[...] = acc
+        m_ref[0, :] = m_i
+        l_ref[0, :] = l_i
+
+    # a live tile is either whole (no mask) or straddles the edge of what
+    # the block sees (the summaries' end, the diagonal)
+    seen, s0 = p.live_summaries(i), j * p.blk_s
+    live, whole = p.summary_step_live(i, j), s0 + p.blk_s <= seen
+
+    @pl.when(jnp.logical_and(live, whole))
+    def _():
+        step(kt_ref[0], vt_ref[0], None, s0, p.blk_s)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(whole)))
+    def _():
+        row = _summary_keep_row(s0, p.blk_s, seen)
+        step(kt_ref[0], vt_ref[0],
+             _tile_dead(False, q0, s0, p.blk_q, p.blk_s, row), s0, p.blk_s)
+
+    k0 = q0 // p.W * p.W + (j - p.n_s) * p.blk_k
+    live, whole = p.position_step_live(i, j), k0 + (p.blk_k - 1) <= q0
+
+    @pl.when(jnp.logical_and(live, whole))
+    def _():
+        step(k_ref[0], v_ref[0], None, k0, p.blk_k)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(whole)))
+    def _():
+        step(k_ref[0], v_ref[0],
+             _tile_dead(True, q0, k0, p.blk_q, p.blk_k, None), k0, p.blk_k)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        l_safe = jnp.maximum(l_ref[0, :], jnp.float32(1e-20))
+        o_ref[0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        lse_ref[0, 0, :] = m_ref[0, :] + jnp.log(l_safe)
+
+
+def _eva_bwd_kernel(q_ref, k_ref, v_ref, kt_ref, vt_ref, do_ref, lse_ref,
+                    delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                    *, plan):
+    """grads wrt Q and wrt the POSITIONS' keys and values in one pass over
+    the forward's grid, the query blocks sequential: dQ = dS K * scale over
+    the live summary tiles and the live tiles of the query's own window; a
+    window's dK and dV accumulate in scratch over the query blocks of that
+    window (its tiles' scores are computed once, five MXU passes a tile
+    where a dq and a dkv kernel make seven: 14% of a layer's backward on
+    the chip) and are written when its last query block ends. VMEM holds a window's accumulators whatever
+    the sequence."""
+    p = plan
+    i, j = pl.program_id(2), pl.program_id(3)
+    q0 = i * p.blk_q
+    per = p.W // p.blk_q                    # query blocks a window
+    last = j == pl.num_programs(3) - 1
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    @pl.when(jnp.logical_and(j == 0, i % per == 0))
+    def _():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    seen, s0 = p.live_summaries(i), j * p.blk_s
+
+    @pl.when(p.summary_step_live(i, j))
+    def _():
+        kt = kt_ref[0]
+        ds, _ = _bwd_tile_ds(
+            q_ref[0], kt, vt_ref[0], do_ref[0], lse_ref[0, 0, :],
+            delta_ref[0, 0, :], _summary_keep_row(s0, p.blk_s, seen), False,
+            0.0, p.scale, None, None, q0, s0, p.blk_q, p.blk_s)
+        dq_acc[...] += jax.lax.dot_general(
+            ds.astype(kt.dtype), kt, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    off = (j - p.n_s) * p.blk_k             # the tile's place in the window
+    k0 = q0 // p.W * p.W + off
+
+    @pl.when(p.position_step_live(i, j))
+    def _():
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        ds, pd = _bwd_tile_ds(
+            q, k, v_ref[0], do, lse_ref[0, 0, :], delta_ref[0, 0, :], None,
+            True, 0.0, p.scale, None, None, q0, k0, p.blk_q, p.blk_k)
+        ds = ds.astype(q.dtype)
+        dq_acc[...] += jax.lax.dot_general(
+            ds, k.astype(q.dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(off, p.blk_k), p.blk_k)
+        over_q = (((0,), (0,)), ((), ()))
+        dk_acc[rows, :] += jax.lax.dot_general(
+            ds, q, over_q, preferred_element_type=jnp.float32)
+        dv_acc[rows, :] += jax.lax.dot_general(
+            pd.astype(q.dtype), do.astype(q.dtype), over_q,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(last)
+    def _():
+        dq_ref[0] = (dq_acc[...] * jnp.float32(p.scale)).astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(last, i % per == per - 1))
+    def _():
+        dk_ref[0] = (dk_acc[...] * jnp.float32(p.scale)).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _eva_dsum_kernel(q_ref, kt_ref, vt_ref, do_ref, lse_ref, delta_ref,
+                     dkt_ref, dvt_ref, dk_acc, dv_acc, *, plan):
+    """grads wrt one block of chunk summaries on a (batch, head, summary
+    block, query block) grid: dV~ = P^T dO, dK~ = dS^T Q * scale, summed over
+    the query blocks of every window after the one the block's first summary
+    belongs to."""
+    p = plan
+    sj, qi = pl.program_id(2), pl.program_id(3)
+    s0, q0 = sj * p.blk_s, qi * p.blk_q
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    @pl.when(p.seen_by_query_block(sj, qi))
+    def _():
+        q, do = q_ref[0], do_ref[0]
+        ds, pd = _bwd_tile_ds(
+            q, kt_ref[0], vt_ref[0], do, lse_ref[0, 0, :], delta_ref[0, 0, :],
+            _summary_keep_row(s0, p.blk_s, p.live_summaries(qi)), False, 0.0,
+            p.scale, None, None, q0, s0, p.blk_q, p.blk_s)
+        over_q = (((0,), (0,)), ((), ()))
+        dv_acc[...] += jax.lax.dot_general(
+            pd.astype(do.dtype), do.astype(q.dtype), over_q,
+            preferred_element_type=jnp.float32)
+        dk_acc[...] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, over_q,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(qi == pl.num_programs(3) - 1)
+    def _():
+        dkt_ref[0] = (dk_acc[...] * jnp.float32(p.scale)).astype(
+            dkt_ref.dtype)
+        dvt_ref[0] = dv_acc[...].astype(dvt_ref.dtype)
+
+
+def _eva_specs(p):
+    """Block specs on the forward's (batch, head, q-block, key step) grid:
+    the summary block stands still once the steps reach the window's
+    positions, the position block while they are among the summaries, and
+    a dead step takes the index of the nearest live one."""
+    def summary_block(i, j):
+        return jnp.minimum(j, p.last_summary_block(i))
+
+    def position_block(i, j):
+        jj = jnp.clip(j - p.n_s, 0, p.last_position_step(i))
+        return p.first_position_block(i) + jj
+
+    D = p.D
+    return {
+        "q": pl.BlockSpec((1, p.blk_q, D), lambda b, h, i, j: (b, i, h)),
+        "row": pl.BlockSpec((1, 1, p.blk_q),
+                            lambda b, h, i, j: (b * p.H + h, 0, i)),
+        "k": pl.BlockSpec((1, p.blk_k, D), lambda b, h, i, j: (
+            b, position_block(i, j), h)),
+        "kt": pl.BlockSpec((1, p.blk_s, D), lambda b, h, i, j: (
+            b, summary_block(i, j), h)),
+    }
+
+
+def _eva_summary_specs(p):
+    """Block specs on :func:`_eva_dsum_kernel`'s (batch, head, summary
+    block, query block) grid; a dead step takes the first live query
+    block's index."""
+    def q_block(sj, qi):
+        return jnp.minimum(jnp.maximum(qi, p.first_later_query_block(sj)),
+                           p.n_q - 1)
+
+    return {
+        "q": pl.BlockSpec((1, p.blk_q, p.D), lambda b, h, sj, qi: (
+            b, q_block(sj, qi), h)),
+        "row": pl.BlockSpec((1, 1, p.blk_q), lambda b, h, sj, qi: (
+            b * p.H + h, 0, q_block(sj, qi))),
+        "kt": pl.BlockSpec((1, p.blk_s, p.D),
+                           lambda b, h, sj, qi: (b, sj, h)),
+    }
+
+
+# VMEM an EVA program may plan for (blocks and tiles only, whatever the
+# sequence): room for tiles past Mosaic's default 16 MiB, of the chip's 128
+_EVA_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _eva_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
+              interpret, outer="parallel"):
+    """``outer`` is the outer block axis's semantics."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        kernel, out_shape=out_shape, grid=grid, in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", outer, "arbitrary"),
+            vmem_limit_bytes=_EVA_VMEM_LIMIT),
+        interpret=interpret, name=name)
+
+
+def _eva_fwd_impl(q, k, v, kt, vt, num_heads, window, blocks, interpret):
+    p = _EvaPlan(q, kt, num_heads, window, blocks)
+    spec = _eva_specs(p)
+    call = _eva_call(
+        functools.partial(_eva_fwd_kernel, plan=p), "flash_eva_fwd",
+        (p.B, p.H, p.n_q, p.n_s + p.n_w),
+        [spec["q"], spec["k"], spec["k"], spec["kt"], spec["kt"]],
+        (spec["q"], spec["row"]),
+        (jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((p.B * p.H, 1, p.S), jnp.float32)),
+        [(p.blk_q, p.D), (1, p.blk_q), (1, p.blk_q)], interpret)
+    with jax.enable_x64(False):
+        return call(q, k, v, kt, vt)
+
+
+def _eva_bwd_impl(q, k, v, kt, vt, o, lse, g, num_heads, window, blocks,
+                  interpret):
+    """``(dq, dk, dv, dkt, dvt)``: dq and the positions' gradients over the
+    forward's grid, then the summaries' over the query blocks that see
+    them."""
+    p = _EvaPlan(q, kt, num_heads, window, blocks)
+    stepped, live = p.tiles()
+    _EVA_TILES["stepped"] += stepped
+    _EVA_TILES["live"] += live
+    # delta_i = rowsum(dO o O) per head: one fused XLA elementwise + reduce
+    delta = jnp.sum((g.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(p.B, p.S, p.H, p.D), axis=-1)
+    delta = jnp.transpose(delta, (0, 2, 1)).reshape(p.B * p.H, 1, p.S)
+    spec, by_s = _eva_specs(p), _eva_summary_specs(p)
+    like = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    window_block = pl.BlockSpec((1, p.W, p.D), lambda b, h, i, j: (
+        b, (i * p.blk_q) // p.W, h))
+    bwd_call = _eva_call(
+        functools.partial(_eva_bwd_kernel, plan=p), "flash_eva_bwd",
+        (p.B, p.H, p.n_q, p.n_s + p.n_w),
+        [spec["q"], spec["k"], spec["k"], spec["kt"], spec["kt"], spec["q"],
+         spec["row"], spec["row"]],
+        (spec["q"], window_block, window_block), (like,) * 3,
+        [(p.blk_q, p.D), (p.W, p.D), (p.W, p.D)], interpret,
+        outer="arbitrary")
+    dsum_call = _eva_call(
+        functools.partial(_eva_dsum_kernel, plan=p), "flash_eva_dsum",
+        (p.B, p.H, p.n_s, p.n_q),
+        [by_s["q"], by_s["kt"], by_s["kt"], by_s["q"], by_s["row"],
+         by_s["row"]], (by_s["kt"], by_s["kt"]),
+        (jax.ShapeDtypeStruct(kt.shape, kt.dtype),) * 2,
+        [(p.blk_s, p.D), (p.blk_s, p.D)], interpret)
+    with jax.enable_x64(False):
+        dq, dk, dv = bwd_call(q, k, v, kt, vt, g, lse, delta)
+        dkt, dvt = dsum_call(q, kt, vt, g, lse, delta)
+    return dq, dk, dv, dkt, dvt
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_eva(q, k, v, kt, vt, num_heads, window, blocks, interpret):
+    return _eva_fwd_impl(q, k, v, kt, vt, num_heads, window, blocks,
+                         interpret)[0]
+
+
+def _fe_fwd(q, k, v, kt, vt, num_heads, window, blocks, interpret):
+    from jax.ad_checkpoint import checkpoint_name
+    out, lse = _eva_fwd_impl(q, k, v, kt, vt, num_heads, window, blocks,
+                             interpret)
+    # named, so that a caller that recomputes its forward in the backward
+    # pass can keep these two and spare this kernel its second run
+    out = checkpoint_name(out, "eva_attention_out")
+    lse = checkpoint_name(lse, "eva_attention_lse")
+    return out, (q, k, v, kt, vt, out, lse)
+
+
+def _fe_bwd(num_heads, window, blocks, interpret, res, g):
+    q, k, v, kt, vt, out, lse = res
+    return _eva_bwd_impl(q, k, v, kt, vt, out, lse, g, num_heads, window,
+                         blocks, interpret)
+
+
+_flash_eva.defvjp(_fe_fwd, _fe_bwd)
+
+
+def flash_attention_eva(q, k, v, kt, vt, num_heads, window, blocks=None,
+                        interpret=False):
+    """Blockwise exact aggregation of EVA attention. ``q``, ``k``, ``v``
+    (B, S, H*D), q and k already rotated; ``kt``, ``vt`` (B, S / chunk, H*D)
+    the chunk summaries. Returns (B, S, H*D). ``blocks = (blk_q, blk_k,
+    blk_s)`` overrides the block sizes (multiples of 128; queries and
+    positions divide the window, summaries their number)."""
+    return _flash_eva(q, k, v, kt, vt, int(num_heads), int(window), blocks,
+                      interpret)

@@ -103,13 +103,14 @@ def _seed_everything():
 def chip_present_interpreted(monkeypatch):
     """A chip is "present" and the attention kernels run interpreted: the
     dispatcher in ``ops/nn.py`` decides as it does on the chip
-    (``_on_accelerator`` is its platform seam) and the four kernel entries
+    (``_on_accelerator`` is its platform seam) and the five kernel entries
     it calls are handed ``interpret=True``. Nothing else is patched."""
     import functools
     from mxnet_tpu.ops import nn as nn_ops
     from mxnet_tpu.ops import pallas_kernels as pk
     monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
     for name in ("flash_attention", "flash_attention_bshd",
-                 "flash_attention_packed", "flash_attention_latent"):
+                 "flash_attention_packed", "flash_attention_latent",
+                 "flash_attention_eva"):
         monkeypatch.setattr(pk, name, functools.partial(getattr(pk, name),
                                                         interpret=True))

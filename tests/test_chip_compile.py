@@ -302,3 +302,82 @@ def test_bhsd_kernels_are_refused_at_the_latent_cells_length(topo):
     with pytest.raises(Exception, match="vmem"):
         jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             shape, shape, shape).compile()
+
+
+# ---- the EVA kernels at the benchmark's widths ------------------------------
+
+def test_eva_kernels_compile_at_published_widths(topo):
+    """32 heads of 128, windows of 2,048, chunks of 16, one row of 32,768
+    bytes in bfloat16, for one described chip: three Mosaic calls,
+    ``flash_eva_fwd``, ``_bwd`` and ``_dsum``, whose VMEM holds blocks, tiles
+    and one window's accumulators; the pooling around them is XLA's."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    one = SingleDeviceSharding(topo.devices[0])
+    seq, heads, window, chunk = 32768, 32, 2048, 16
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v, mu, phi):
+        kt, vt = nn_ops.eva_chunk_summaries(k, v, mu, phi, heads, chunk)
+        return jnp.sum(pk.flash_attention_eva(
+            q, k, v, kt, vt, heads, window).astype(jnp.float32) ** 2)
+
+    tiles = pk.eva_tile_stats()
+    wide = spec(1, seq, heads * 128)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        wide, wide, wide, spec(heads, 128), spec(heads, 128)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("flash_eva_fwd", "flash_eva_bwd", "flash_eva_dsum"):
+        assert kernel in text, kernel
+    after = pk.eva_tile_stats()
+    # the planner at 1024 / 1024 / 1024: 320 grid steps, 228 of them live
+    assert (after["stepped"] - tiles["stepped"],
+            after["live"] - tiles["live"]) == (320, 228)
+
+
+def test_eva_decoder_step_names_the_kernels(topo, monkeypatch):
+    """One recomputed layer's training step at the published widths and
+    32,768 bytes, traced by the trainer as on the chip and compiled for one
+    described chip: the lowered step names the three EVA kernels, the forward
+    once (the attention's output and log-sum-exp are kept by name, so the
+    recomputed layer does not run it again)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import parallel
+    from mxnet_tpu.models.eva_lm import EvaDecoder
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    net = EvaDecoder(vocab_size=320, units=4096, num_layers=1, num_heads=32,
+                     hidden_size=11008, window=2048, chunk=16, pred_heads=8,
+                     rope_theta=100000.0, recompute=True)
+    net.initialize(mx.init.Zero())
+    net.cast("bfloat16")
+    trainer = parallel.ShardedTrainer(
+        net, lambda out, _label: out, "adam", {"learning_rate": 1e-4},
+        mesh=parallel.make_mesh(dp=1, devices=jax.devices()[:1]),
+        dtype="bfloat16")
+    trainer._build_step()
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    before = nn_ops.attention_dispatch_stats()
+    compiled = trainer._step_fn.lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one),
+        [spec(v) for v in trainer._values],
+        [tuple(spec(x) for x in s) for s in trainer._states], 1, 1e-4,
+        ints(1, 32768), ints(1, 32768, 8),
+        jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one)).compile()
+    after = nn_ops.attention_dispatch_stats()
+    assert after["eva"] == before["eva"] + 1 and after["xla"] == before["xla"]
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("flash_eva_fwd", "flash_eva_bwd", "flash_eva_dsum"):
+        assert len(re.findall(r"= [^\n]*custom-call\([^\n]*%s" % kernel,
+                              text)) == 1, kernel
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 8 * 2 ** 30
