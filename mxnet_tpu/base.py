@@ -26,11 +26,12 @@ class MXNetError(RuntimeError):
 # docs/observability.md): ``attention`` around the whole attention op
 # (ops/nn.py), ``optimizer`` around the trainer's update loop
 # (parallel/trainer.py), ``moe_router`` / ``moe_experts`` / ``moe_shared``
-# around the three parts of a dropless expert layer (parallel/moe.py). A
-# block of one of these names enters the scope under its name plus "_", so a
-# trace reader that meets the bare word knows the program wrote it.
+# around the three parts of a dropless expert layer and ``moe_sort`` /
+# ``moe_products`` / ``moe_combine`` inside ``moe_experts`` (parallel/moe.py).
+# A block of one of these names enters the scope under its name plus "_", so
+# a trace reader that meets the bare word knows the program wrote it.
 PROGRAM_SCOPES = ("attention", "optimizer", "moe_router", "moe_experts",
-                  "moe_shared")
+                  "moe_shared", "moe_sort", "moe_products", "moe_combine")
 
 string_types = (str,)
 numeric_types = (float, int, _np.generic)

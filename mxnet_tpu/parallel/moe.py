@@ -16,11 +16,19 @@ ALL the router's experts (sigmoid scores, choice by score + correction
 bias, weights normalised over the chosen and scaled, in float32), leaves
 out the assignments to experts it does not hold, sorts the rest by expert
 and runs a grouped matrix product over them: no capacity, no dropped token,
-no (tokens, experts, capacity) tensor, static shapes. On one chip there is
-no exchange and nothing stands in for the absent chips. It writes the
+no (tokens, experts, capacity) tensor, static shapes. Every pass between
+the router and the grouped products, and between them and a token's result,
+costs by the rows of the sorted buffer (at most twice the rows routed),
+never by tokens x ``top_k``: the buffer's rows are gathered from the tokens,
+and the combine is one gather of the buffer into token order and a sum over
+each token's adjacent rows (:func:`_sum_by_token`). On one chip there is no
+exchange and nothing stands in for the absent chips. It writes the
 ``moe_router`` and ``moe_experts`` scopes (``moe_shared`` around the shared
-experts) into the traced program and returns the rows routed to each held
-expert beside the result.
+experts) into the traced program, and inside ``moe_experts`` the scopes
+``moe_sort`` (the sort and the maps between the orders), ``moe_products``
+(the grouped products) and ``moe_combine`` (back to the tokens); what is
+left of ``moe_experts`` is the buffer's gathers and the SiLU passes. It
+returns the rows routed to each held expert beside the result.
 """
 from __future__ import annotations
 
@@ -34,7 +42,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["moe_ffn", "moe_ffn_sharded", "init_moe_params",
-           "held_experts_ffn", "gated_ffn", "route_top_k", "grouped_matmul"]
+           "held_experts_ffn", "gated_ffn", "route_top_k", "grouped_matmul",
+           "combine_stats"]
 
 
 def init_moe_params(rng, d_model, d_hidden, n_experts, dtype=np.float32):
@@ -157,17 +166,17 @@ def route_top_k(x, router_w, router_b, top_k, scale=1.0, normalize=True):
     return chosen.astype(jnp.int32), picked * scale
 
 
-def _megablox_usable(lhs, rhs):
-    """The Pallas grouped product takes the call where an accelerator is
-    present, the sizes tile (128s) and the visible mesh is one device: GSPMD
-    cannot partition a Mosaic kernel, ``lax.ragged_dot`` it can."""
+def _megablox_usable(m, k, n):
+    """The Pallas grouped products take a call of ``m`` rows, contraction
+    ``k`` and ``n`` columns where an accelerator is present, the sizes tile
+    (128s) and the visible mesh is one device: GSPMD cannot partition a
+    Mosaic kernel, ``lax.ragged_dot`` and ``segment_sum`` it can."""
     from .mesh import current_scope
     scope = current_scope()
     if scope is not None and scope[0].size > 1:
         return False
     return (any(d.platform != "cpu" for d in jax.devices())
-            and lhs.shape[0] % 128 == 0 and lhs.shape[1] % 128 == 0
-            and rhs.shape[2] % 128 == 0)
+            and m % 128 == 0 and k % 128 == 0 and n % 128 == 0)
 
 
 def _gmm_tiling(m, k, n):
@@ -226,12 +235,18 @@ _pallas_gmm.defvjp(_pallas_gmm_fwd, _pallas_gmm_bwd)
 def grouped_matmul(lhs, rhs, group_sizes):
     """``lhs[rows of group g] @ rhs[g]`` for rows sorted by group:
     ``lhs (M, K)``, ``rhs (G, K, N)``, ``group_sizes (G,)`` int32 whose sum
-    may be less than M. Rows past the last group are NOT defined (mask
-    them). The work follows the rows in the groups, not M."""
+    may be less than M. Rows past the last group are NOT defined where the
+    Pallas kernels run (zero elsewhere): nothing may sum over them. The
+    kernels themselves do not: a row's result reads that row alone, and the
+    weights' gradient (``tgmm``) selects the groups' rows before it
+    multiplies. The work follows the rows in the groups, not M."""
     group_sizes = group_sizes.astype(jnp.int32)
-    if _megablox_usable(lhs, rhs):
-        return _pallas_gmm(lhs, rhs, group_sizes)
-    return lax.ragged_dot(lhs, rhs, group_sizes)
+    with jax.named_scope("moe_products"):
+        if _megablox_usable(lhs.shape[0], lhs.shape[1], rhs.shape[2]):
+            return _pallas_gmm(lhs, rhs, group_sizes)
+        live = jnp.arange(lhs.shape[0], dtype=jnp.int32) < jnp.sum(group_sizes)
+        return jnp.where(live[:, None],
+                         lax.ragged_dot(lhs, rhs, group_sizes), 0)
 
 
 def gated_ffn(x, w_gate, w_up, w_down):
@@ -240,20 +255,65 @@ def gated_ffn(x, w_gate, w_up, w_down):
     return up @ w_down.T
 
 
-def _sorted_rows_ffn(rows, w_gate, w_up, w_down, live, sizes):
+def _sorted_rows_ffn(rows, w_gate, w_up, w_down, sizes):
     """The held experts' gated feed-forward over rows sorted by expert:
-    grouped gate/up, SiLU product, grouped down. Dead rows (past the
-    groups) are zero going in and zero coming out, in both directions."""
+    grouped gate/up, SiLU product, grouped down. Rows past the groups are
+    not defined, going in or coming out, in either direction."""
     up = jax.nn.silu(grouped_matmul(rows, w_gate, sizes).astype(jnp.float32)) \
         * grouped_matmul(rows, w_up, sizes).astype(jnp.float32)
-    up = jnp.where(live[:, None], up, 0).astype(rows.dtype)
-    return jnp.where(live[:, None], grouped_matmul(up, w_down, sizes), 0)
+    return grouped_matmul(up.astype(rows.dtype), w_down, sizes)
 
 
-def _held_rows(rows, at, held, k):
-    """Every token's ``k``-th choice's row of the sorted buffer, float32,
-    zero where that choice is not held: ``(T, d)``."""
-    return jnp.where(held[:, k, None], rows[at[:, k]], 0).astype(jnp.float32)
+# Tokens a group of the combine's grouped segment sum: one MXU tile.
+_TOKEN_BLOCK = 128
+
+# Where :func:`_sum_by_token` sent each sum it traced (once a trace):
+# "grouped" = megablox's ``tgmm`` over blocks of tokens, "xla" = segment_sum.
+_COMBINES = {"grouped": 0, "xla": 0}
+
+
+def combine_stats():
+    """How many of the combine's sums over a token's rows were traced as
+    the grouped product (``grouped``) and as ``jax.ops.segment_sum``
+    (``xla``) since import."""
+    return dict(_COMBINES)
+
+
+def _sum_by_token(rows, token, scale, held, dtype):
+    """``out[t] = sum of scale[i] * rows[i] over the i with token[i] == t``,
+    summed in float32, ``(T, d)`` of ``dtype``: ``rows (m, d)`` in token
+    order, ``token (m,)`` non-decreasing over the first ``held.sum()`` rows,
+    which are the only ones read (the others are not defined), ``scale (m,)``
+    float32 or ``None`` for ones, ``held (T, top_k)`` which assignments the
+    rows stand for.
+
+    On the chip the sum is the grouped product the weights' gradients use
+    (``tgmm``): the groups are blocks of ``_TOKEN_BLOCK`` tokens, the
+    transposed operand a one-hot of the token within its block that carries
+    the scale. A float32 operand meets the MXU in float32 (the kernel's
+    product is traced at the highest precision; Mosaic's own rounds float32
+    operands to bfloat16), so a float32 scale times a bfloat16 row is what
+    it is in XLA. Elsewhere ``jax.ops.segment_sum``."""
+    (m, d), T, block = rows.shape, held.shape[0], _TOKEN_BLOCK
+    if T % block == 0 and _megablox_usable(m, block, d):
+        _COMBINES["grouped"] += 1
+        hot = (token % block)[:, None] == jnp.arange(block, dtype=jnp.int32)
+        hot = hot.astype(rows.dtype) if scale is None else \
+            jnp.where(hot, scale[:, None], 0.0)
+        counts = jnp.sum(held.reshape(T // block, -1), axis=-1,
+                         dtype=jnp.int32)
+        exact = "highest" if hot.dtype == jnp.float32 else None
+        with jax.enable_x64(False), jax.default_matmul_precision(exact):
+            out = _megablox().tgmm(hot.swapaxes(0, 1), rows, counts, dtype,
+                                   (128, block, min(d, 2048)))
+        return out.reshape(T, d)
+    _COMBINES["xla"] += 1
+    rows = rows.astype(jnp.float32)
+    if scale is not None:
+        rows = rows * scale[:, None]
+    live = jnp.arange(m, dtype=jnp.int32) < jnp.sum(held, dtype=jnp.int32)
+    return jax.ops.segment_sum(rows, jnp.where(live, token, T), T,
+                               indices_are_sorted=True).astype(dtype)
 
 
 def _tiers(tokens, top_k, held, experts):
@@ -284,66 +344,71 @@ def _pick_tier(routed, tiers, make, *operands):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _routed_experts(x, weights, w_gate, w_up, w_down, routing, top_k, tiers):
     """``sum_k weights[t, k] * E_chosen(t, k)(x[t])`` over the held choices,
-    float32 ``(T, d)``. ``routing = (order, place, held, sizes)``: the
-    assignments sorted by held expert (not held last), each assignment's
-    place in that order, which are held, and the rows of each held expert.
+    summed in float32, ``(T, d)`` of ``x``'s type. ``routing = (order, perm,
+    rank, held, sizes)``: the assignments sorted by held expert (not held
+    last); the sorted buffer's row of the i-th HELD assignment in token
+    order; each assignment's number among the held ones in token order;
+    which are held; the rows of each held expert.
 
-    Gathers only, forward and backward (a scatter-add of rows is slow on
-    the chip): the rows of the sorted buffer are gathered from ``x``; a
-    token's result gathers its ``top_k`` rows back; the backward pass
-    gathers the other way. Nothing is kept for the backward pass but the
-    operands: it recomputes the sorted rows and their products."""
+    Gathers of the buffer's ``m`` rows only, forward and backward (a
+    scatter-add of rows is slow on the chip): the sorted buffer is gathered
+    from ``x``; a token's result gathers the buffer into token order once
+    and sums each token's adjacent rows (:func:`_sum_by_token`); the
+    backward pass gathers the other way, and a weight's gradient is a row
+    sum taken in the sorted buffer, where the result's cotangent already
+    is. No pass is sized by tokens x ``top_k`` but the scalar maps. Nothing
+    is kept for the backward pass but the operands: it recomputes the sorted
+    rows and their products."""
     return _routed_fwd(x, weights, w_gate, w_up, w_down, routing, top_k,
                        tiers)[0]
 
 
 def _routed_fwd(x, weights, w_gate, w_up, w_down, routing, top_k, tiers):
-    order, place, held, sizes = routing
-    routed = jnp.sum(sizes)
+    order, perm, _, held, sizes = routing
 
     def sized(m):
         def run(x, weights, w_gate, w_up, w_down):
-            live = jnp.arange(m, dtype=jnp.int32) < routed
-            rows = jnp.where(live[:, None], x[order[:m] // top_k], 0)
-            out = _sorted_rows_ffn(rows, w_gate, w_up, w_down, live, sizes)
-            at = jnp.minimum(place, m - 1)
-            # a choice at a time: (T, d) gathers, no (T, top_k, d) array
-            return sum(_held_rows(out, at, held, k) * weights[:, k, None]
-                       for k in range(top_k))
+            first, back = order[:m], perm[:m]
+            token = first // top_k
+            out = _sorted_rows_ffn(x[token], w_gate, w_up, w_down, sizes)
+            with jax.named_scope("moe_combine"):
+                return _sum_by_token(
+                    out[back], token[back], weights.reshape(-1)[first][back],
+                    held, x.dtype)
         return run
 
-    y = _pick_tier(routed, tiers, sized, x, weights, w_gate, w_up, w_down)
+    y = _pick_tier(jnp.sum(sizes), tiers, sized, x, weights, w_gate, w_up,
+                   w_down)
     return y, (x, weights, w_gate, w_up, w_down, routing)
 
 
 def _routed_bwd(top_k, tiers, res, dy):
     x, weights, w_gate, w_up, w_down, routing = res
-    order, place, held, sizes = routing
-    routed = jnp.sum(sizes)
+    order, perm, rank, held, sizes = routing
 
     def sized(m):
         def run(x, weights, w_gate, w_up, w_down, dy):
-            live = jnp.arange(m, dtype=jnp.int32) < routed
-            first = order[:m]
-            rows = jnp.where(live[:, None], x[first // top_k], 0)
+            first, back = order[:m], perm[:m]
+            token = first // top_k
             out, pull = jax.vjp(
-                lambda r, g, u, w: _sorted_rows_ffn(r, g, u, w, live, sizes),
-                rows, w_gate, w_up, w_down)
+                lambda r, g, u, w: _sorted_rows_ffn(r, g, u, w, sizes),
+                x[token], w_gate, w_up, w_down)
             # a sorted row's cotangent: its token's, times its weight
+            dy_rows = dy[token].astype(jnp.float32)
             gate = weights.reshape(-1)[first]
-            d_out = jnp.where(live[:, None],
-                              dy[first // top_k] * gate[:, None], 0)
-            d_rows, d_gate, d_up, d_down = pull(d_out.astype(out.dtype))
-            at = jnp.minimum(place, m - 1)
-            d_x = sum(_held_rows(d_rows, at, held, k) for k in range(top_k))
-            d_weights = jnp.stack(
-                [jnp.sum(_held_rows(out, at, held, k) * dy, axis=-1)
-                 for k in range(top_k)], axis=-1)
-            return d_x.astype(x.dtype), d_weights, d_gate, d_up, d_down
+            d_rows, d_gate, d_up, d_down = pull(
+                (dy_rows * gate[:, None]).astype(out.dtype))
+            with jax.named_scope("moe_combine"):
+                d_x = _sum_by_token(d_rows[back], token[back], None, held,
+                                    x.dtype)
+                d_sorted = jnp.sum(out.astype(jnp.float32) * dy_rows, axis=-1)
+                d_weights = jnp.where(
+                    held, d_sorted[back][jnp.minimum(rank, m - 1)], 0)
+            return d_x, d_weights, d_gate, d_up, d_down
         return run
 
-    grads = _pick_tier(routed, tiers, sized, x, weights, w_gate, w_up,
-                       w_down, dy)
+    grads = _pick_tier(jnp.sum(sizes), tiers, sized, x, weights, w_gate,
+                       w_up, w_down, dy)
     return grads + (None,)
 
 
@@ -361,23 +426,33 @@ def held_experts_ffn(x, router_w, router_b, w_gate, w_up, w_down, first=0,
 
     Every assignment is computed, however skewed the routing (dropless):
     the buffers hold the worst case, ``T * min(top_k, G)`` rows; the
-    grouped products work on the rows there are, and the gathers and
-    elementwise passes run on the smallest buffer that holds them, of twice
-    a uniform router's ``T * top_k * G / E`` rows, doubling (:func:`_tiers`)."""
+    grouped products work on the rows there are, and the gathers, the
+    combine and the elementwise passes run on the smallest buffer that
+    holds them, of twice a uniform router's ``T * top_k * G / E`` rows,
+    doubling (:func:`_tiers`). Only the sort and the scalar maps between
+    the orders cost by ``T * top_k``."""
     T, G = x.shape[0], w_gate.shape[0]
     with jax.named_scope("moe_router"):
         chosen, weights = route_top_k(x, router_w, router_b, top_k, scale,
                                       normalize)
     with jax.named_scope("moe_experts"):
-        local = chosen - first
-        held = (local >= 0) & (local < G)
-        flat = jnp.where(held, local, G).reshape(-1)            # (T*k,)
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        sizes = jnp.sum(flat[:, None] == jnp.arange(G, dtype=jnp.int32),
-                        axis=0).astype(jnp.int32)
-        place = jnp.zeros((T * top_k,), jnp.int32).at[order].set(
-            jnp.arange(T * top_k, dtype=jnp.int32)).reshape(T, top_k)
+        with jax.named_scope("moe_sort"):
+            local = chosen - first
+            held = (local >= 0) & (local < G)
+            flat = jnp.where(held, local, G).reshape(-1)        # (T*k,)
+            order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+            sizes = jnp.sum(flat[:, None] == jnp.arange(G, dtype=jnp.int32),
+                            axis=0).astype(jnp.int32)
+            # token order: the held assignments as they come, then the rest
+            # in their sorted places, so that any prefix that holds the
+            # rows routed is a permutation of itself
+            at = jnp.arange(T * top_k, dtype=jnp.int32)
+            rank = jnp.cumsum(held.reshape(-1), dtype=jnp.int32) - 1
+            perm = jnp.zeros_like(at).at[
+                jnp.where(at < jnp.sum(sizes), rank[order], at)].set(
+                    at, unique_indices=True)
         y = _routed_experts(x, jnp.where(held, weights, 0.0), w_gate, w_up,
-                            w_down, (order, place, held, sizes), top_k,
+                            w_down, (order, perm, rank.reshape(T, top_k),
+                                     held, sizes), top_k,
                             _tiers(T, top_k, G, router_w.shape[0]))
-        return y.astype(x.dtype), lax.stop_gradient(sizes.astype(jnp.float32))
+        return y, lax.stop_gradient(sizes.astype(jnp.float32))

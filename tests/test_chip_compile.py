@@ -381,3 +381,50 @@ def test_eva_decoder_step_names_the_kernels(topo, monkeypatch):
                               text)) == 1, kernel
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 8 * 2 ** 30
+
+
+# ---- the held experts' combine at the latent decoder's cell ------------------
+
+def test_held_experts_combine_runs_over_the_buffers_rows(topo, monkeypatch):
+    """``held_experts_ffn`` forward + backward at the cell's shapes (32,768
+    tokens of 2,048, 8 of 64 experts of 1,408 held, 6 a token, bfloat16)
+    for one described chip. No gather writes a (32,768, 2,048) array in any
+    of the three buffer sizes' branches (54 did while the combine ran a
+    choice at a time); every branch sums by token with ``tgmm`` (42 Mosaic
+    calls: 3 sizes x (3 + 1 forward, 3 recomputed + 3 ``d_lhs`` + 3 + 1
+    ``tgmm`` backward)); the temporaries stay under the 5.70 GB the layer
+    took before (4.16 GB when this was written)."""
+    from mxnet_tpu.parallel import moe
+    monkeypatch.setattr(moe, "_megablox_usable", lambda m, k, n: (
+        m % 128 == 0 and k % 128 == 0 and n % 128 == 0))
+    one = SingleDeviceSharding(topo.devices[0])
+    tokens, d, f, held, experts, top_k = 32768, 2048, 1408, 8, 64, 6
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    def loss(x, router_w, router_b, w_gate, w_up, w_down):
+        y, _ = moe.held_experts_ffn(x, router_w, router_b, w_gate, w_up,
+                                    w_down, first=0, top_k=top_k, scale=2.446)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    before = moe.combine_stats()
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        spec(tokens, d), spec(experts, d), spec(experts), spec(held, d, f),
+        spec(held, d, f), spec(held, f, d)).compile()
+    after = moe.combine_stats()
+    assert (after["grouped"] - before["grouped"], after["xla"]) == (
+        6, before["xla"])
+    text = compiled.as_text()
+    assert not re.findall(r"= \w+\[32768,2048\][^\n]* gather\(", text)
+    assert text.count("tpu_custom_call") == 42
+    branches = re.findall(r"conditional\([^\n]*branch_computations=\{([^}]*)\}",
+                          text)
+    assert len(branches) == 2 and all(b.count(",") == 2 for b in branches)
+    kernels = re.findall(
+        r'tpu_custom_call[^\n]*op_name="[^"]*[/(](moe_\w+)\)*/jit\((\w+)\)/', text)
+    assert sorted(set(kernels)) == [
+        ("moe_combine", "tgmm"), ("moe_products", "gmm"),
+        ("moe_products", "tgmm")]
+    assert kernels.count(("moe_combine", "tgmm")) == 3 * 2      # y and d_x
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.70e9

@@ -4,6 +4,8 @@ imports nothing of the program), at a small size on the CPU: forward, loss
 and every gradient; the latent flash kernels in interpret mode against the
 XLA form; the held-expert layer's shares adding up to the uncut layer;
 droplessness; the chunked loss; the trainer's step with its counter."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -342,6 +344,149 @@ def test_every_buffer_size_gives_the_worst_case_buffers_result(
     for a, b in zip(jax.tree_util.tree_leaves(got_grads),
                     jax.tree_util.tree_leaves(want_grads)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+
+
+# ---- the combine over the buffer's rows --------------------------------------
+
+def _a_choice_at_a_time(x, router_w, router_b, w_gate, w_up, w_down, top_k):
+    """The combine as it stood before the buffer's rows sized it, kept as
+    the oracle: a choice at a time over whole (T, d) arrays, zero where the
+    choice is not held, each token through its own expert's matrices."""
+    chosen, weights = moe.route_top_k(x, router_w, router_b, top_k, 2.446)
+    held = chosen < w_gate.shape[0]
+    y = 0.0
+    for k in range(top_k):
+        e = jnp.minimum(chosen[:, k], w_gate.shape[0] - 1)
+        up = jax.nn.silu(jnp.einsum("td,tdf->tf", x, w_gate[e])) \
+            * jnp.einsum("td,tdf->tf", x, w_up[e])
+        out = jnp.einsum("tf,tfd->td", up, w_down[e])
+        y = y + jnp.where(held[:, k, None], out, 0) * weights[:, k, None]
+    return y
+
+
+def _steered_layer(tokens, d, f, experts, held_choices):
+    """Operands of a layer that holds experts 0 and 1 of ``experts``, two
+    choices a token, whose router sends token t to held expert g exactly
+    where ``held_choices[t, g]`` (feature g of x is the switch: its score
+    is 1 or 0); ``None`` leaves the router as drawn."""
+    ks = jax.random.split(jax.random.PRNGKey(12), 5)
+    x = jax.random.normal(ks[0], (tokens, d), jnp.float32)
+    router_w = 0.1 * jax.random.normal(ks[1], (experts, d), jnp.float32)
+    if held_choices is not None:
+        switch = jnp.where(jnp.asarray(held_choices), 1.0, -1.0)
+        x = x.at[:, :2].set(switch)
+        router_w = router_w.at[:, :2].set(0.0).at[0, 0].set(40.0) \
+            .at[1, 1].set(40.0).at[:2, 2:].set(0.0)
+    w_gate, w_up, w_down = (
+        0.1 * jax.random.normal(k, shape, jnp.float32) for k, shape in zip(
+            ks[2:], [(2, d, f), (2, d, f), (2, f, d)]))
+    return x, router_w, jnp.zeros((experts,), jnp.float32), w_gate, w_up, \
+        w_down
+
+
+def _held_choices(case, tokens):
+    """Which of the two held experts each token is sent to, by case."""
+    want = np.zeros((tokens, 2), bool)
+    if case == "uniform":
+        return None
+    if case == "every_choice_held":         # the worst-case buffer
+        want[:] = True
+    elif case == "both_choices_on_a_block_boundary":
+        want[[127, 128]] = True             # token blocks are 128 tokens
+        want[np.arange(5, tokens, 37), 0] = True
+    elif case == "rows_at_a_buffer_size":
+        want[:128, 1] = True
+    elif case == "rows_one_over_a_buffer_size":
+        want[:128, 1] = True
+        want[300, 0] = True
+    else:
+        assert case == "none_held"
+    return want
+
+
+def _assert_matches_the_oracle(operands, want_rows):
+    def layer(x, router_w, w_gate, w_up, w_down):
+        y, rows = moe.held_experts_ffn(
+            x, router_w, operands[2], w_gate, w_up, w_down, first=0, top_k=2,
+            scale=2.446)
+        return jnp.sum(jnp.sin(y)), (y, rows)
+
+    def oracle(x, router_w, w_gate, w_up, w_down):
+        y = _a_choice_at_a_time(x, router_w, operands[2], w_gate, w_up,
+                                w_down, 2)
+        return jnp.sum(jnp.sin(y)), y
+
+    trained = operands[:2] + operands[3:]
+    (_, (y, rows)), grads = jax.value_and_grad(
+        layer, argnums=(0, 1, 2, 3, 4), has_aux=True)(*trained)
+    (_, y_want), grads_want = jax.value_and_grad(
+        oracle, argnums=(0, 1, 2, 3, 4), has_aux=True)(*trained)
+    if want_rows is not None:
+        assert int(rows.sum()) == want_rows
+    for got, want in zip((y,) + grads, (y_want,) + grads_want):
+        assert np.isfinite(got).all()
+        # float32 sums in another order: a few units of the largest's last place
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=4e-6 * max(1.0, float(jnp.abs(want).max())))
+    return rows
+
+
+@pytest.mark.parametrize("case, want_rows", [
+    ("uniform", None), ("every_choice_held", 1024), ("none_held", 0),
+    ("both_choices_on_a_block_boundary", 4 + 14),
+    ("rows_at_a_buffer_size", 128), ("rows_one_over_a_buffer_size", 129)])
+def test_combine_over_the_buffers_rows_matches_a_choice_at_a_time(
+        case, want_rows):
+    """Forward and all five gradients of the layer (one permutation into
+    token order, a sum over each token's adjacent rows, the weights'
+    gradient a row sum in the sorted buffer) against the per-choice combine,
+    float32. 512 tokens, 2 of 32 experts held: buffers of 128 to 1024
+    rows. Off the chip the sums are ``segment_sum``."""
+    assert moe._tiers(512, 2, 2, 32) == (128, 256, 512, 1024)
+    before = moe.combine_stats()
+    _assert_matches_the_oracle(
+        _steered_layer(512, 64, 48, 32, _held_choices(case, 512)), want_rows)
+    after = moe.combine_stats()
+    assert after["grouped"] == before["grouped"]
+    assert after["xla"] > before["xla"]
+
+
+@pytest.fixture
+def megablox_interpreted(monkeypatch):
+    """The chip's path off the chip: the Pallas grouped products take every
+    call whose sizes tile, interpreted."""
+    backend = moe._megablox()
+    monkeypatch.setattr(moe, "_megablox_usable", lambda m, k, n: (
+        m % 128 == 0 and k % 128 == 0 and n % 128 == 0))
+    for kernel in ("gmm", "tgmm"):
+        monkeypatch.setattr(backend, kernel, functools.partial(
+            getattr(backend, kernel), interpret=True))
+
+
+@pytest.mark.parametrize("case", [
+    "uniform", "both_choices_on_a_block_boundary", "undefined_rows_are_nan"])
+def test_grouped_combine_matches_a_choice_at_a_time(
+        monkeypatch, megablox_interpreted, case):
+    """The same where the sums are the grouped product over blocks of 128
+    tokens (256 tokens: two blocks; 128 wide). In the last case every row
+    past the groups of every grouped product's result is NaN, as undefined
+    memory may be on the chip: nothing sums over one."""
+    if case == "undefined_rows_are_nan":
+        real = moe._gmm_call
+
+        def planted(lhs, rhs, sizes, transpose_rhs):
+            live = jnp.arange(lhs.shape[0]) < jnp.sum(sizes)
+            return jnp.where(live[:, None],
+                             real(lhs, rhs, sizes, transpose_rhs), jnp.nan)
+        monkeypatch.setattr(moe, "_gmm_call", planted)
+    before = moe.combine_stats()
+    rows = _assert_matches_the_oracle(_steered_layer(
+        256, 128, 128, 8, None if case != "both_choices_on_a_block_boundary"
+        else _held_choices(case, 256)), None)
+    assert 0 < int(rows.sum()) < 256        # dead rows in the first buffer
+    after = moe.combine_stats()
+    assert after["xla"] == before["xla"]
+    assert after["grouped"] > before["grouped"]
 
 
 # ---- the chunked loss ------------------------------------------------------

@@ -374,6 +374,79 @@ def test_latent_fused_bwd_reader(path, expected, monkeypatch):
     assert read(_obs(kind="serve")) is None
 
 
+# the expert layer's inner scopes as the compiled step writes them: forward
+# and transposed, inside the buffer-size switch's branch and outside it
+MOE = "jit(step)/jvp(net0)/net0_layer1/moe_/moe_experts"
+MOE_T = "jit(step)/transpose(jvp(net0))/net0_layer1/moe_/moe_experts"
+MOE_TEXT = "\n".join([
+    "HloModule jit_step, is_scheduled=true",
+    "%branch_1 (a.1: bf16[8,128]) -> bf16[8,128] {",
+    _line("gather.1", "bf16[8,128]{1,0}", "gather(%a.1, %a.1)",
+          MOE + "/cond/branch_1_fun/moe_combine/gather"),
+    _line("custom-call.1", "bf16[8,128]{1,0}", "custom-call(%gather.1)",
+          MOE + "/cond/branch_1_fun/moe_combine/jit(tgmm)/pallas_call"),
+    _line("custom-call.2", "bf16[8,128]{1,0}", "custom-call(%a.1)",
+          MOE_T + "/cond/branch_1_fun/transpose(jvp(moe_products))/jit(gmm)"
+          "/pallas_call"),
+    _line("fusion.7", "bf16[8,128]{1,0}", "fusion(%a.1), kind=kLoop",
+          MOE + "/cond/branch_1_fun/mul"),
+    "}",
+    "ENTRY %main.1 (p0: bf16[8,128]) -> bf16[8,128] {",
+    _line("sort.1", "s32[8]{0}", "sort(%p0)", MOE + "/moe_sort/sort"),
+    _line("scatter.1", "s32[8]{0}", "scatter(%sort.1)",
+          MOE + "/moe_sort/scatter"),
+    _line("conditional.1", "bf16[8,128]{1,0}",
+          "conditional(%p0), branch_computations={%branch_1}",
+          MOE + "/cond"),
+    "}"])
+
+
+@pytest.mark.parametrize("metric,expected", [
+    ("moe_combine_scope_pct.train", 30.0),      # gather.1 + custom-call.1
+    ("moe_products_scope_pct.train", 20.0),     # custom-call.2
+    ("moe_sort_scope_pct.train", 15.0),         # sort.1 + scatter.1
+    ("moe_scope_pct.train", 75.0),              # the conditional holds 60
+])
+def test_expert_layer_scope_readers(metric, expected):
+    """The three scopes inside ``moe_experts``: an instruction in a branch
+    of the buffer-size switch runs inside the ``conditional``'s own event,
+    and is found by its scope there; a program that writes none of the
+    three (the parent of PR 32) reads None."""
+    events = [("sort.1", 0.0, 1.0), ("scatter.1", 1.0, 1.5),
+              ("conditional.1", 2.0, 8.0), ("gather.1", 2.0, 3.0),
+              ("custom-call.1", 3.0, 5.0), ("custom-call.2", 5.0, 7.0),
+              ("fusion.7", 7.0, 8.0)]
+    obs = _obs(step_text=MOE_TEXT)
+    obs["trace"].update(events=events, busy_s=10.0)
+    read = load_reader(metric, METRIC_DIR)
+    assert read(obs) == pytest.approx(expected)
+    assert read(_obs(kind="serve")) is None
+    assert read(_obs(trace=None)) is None
+    if metric != "moe_scope_pct.train":
+        before = re.sub(r"/(transpose\(jvp\()?moe_(combine|products|sort)\)*",
+                        "", MOE_TEXT)
+        obs["step_text"] = before
+        assert read(obs) is None
+
+
+@pytest.mark.parametrize("counts,expected", [
+    ({"grouped": 30, "xla": 0}, 100.0), ({"grouped": 0, "xla": 30}, 0.0),
+    ({"grouped": 0, "xla": 0}, None), (None, None)])
+def test_moe_combine_grouped_reader(counts, expected, monkeypatch):
+    """``moe_combine_grouped_pct.train`` over ``combine_stats()``: every sum
+    over a token's rows the grouped product reads 100, ``segment_sum`` alone
+    0; a program without the counter (the parent), or one that traced no
+    expert layer (the other cells), reads None."""
+    from mxnet_tpu.parallel import moe
+    if counts is None:
+        monkeypatch.delattr(moe, "combine_stats")
+    else:
+        monkeypatch.setattr(moe, "_COMBINES", counts)
+    read = load_reader("moe_combine_grouped_pct.train", METRIC_DIR)
+    assert read(_obs()) == expected
+    assert read(_obs(kind="serve")) is None
+
+
 @pytest.mark.parametrize("path,expected", [
     ("flash_interpret", 100.0), ("xla", 0.0), ("no_counter", None),
     ("nothing_traced", None)])
