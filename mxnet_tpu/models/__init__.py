@@ -5,3 +5,5 @@ from .transformer import (TransformerLM, MultiHeadAttention,
 from .moe_transformer import MoETransformerLM, moe_lm_tiny
 from .lstm_lm import RNNModel
 from .eva_lm import EvaDecoder, EvaAttention, EvaDecoderLayer, eva_lm_tiny
+from .hybrid_ssm import (HybridDecoder, HybridDecoderLayer, MambaMixer,
+                         GroupedAttention, hybrid_ssm_tiny)

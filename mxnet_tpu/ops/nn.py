@@ -558,15 +558,29 @@ def UpSampling(*data, scale=1, sample_type="nearest", num_args=1,
 # flash kernels on the unsplit QKV projection, "flash" = the flash kernels
 # on separate q/k/v (head-fused or per-head), "latent" = the
 # latent-attention flash kernels (score of two dot products, keys wider
-# than values), "eva" = the window-plus-summaries flash kernels, "xla" = the
+# than values), "eva" = the window-plus-summaries flash kernels, "grouped" =
+# the grouped-query flash kernels over streamed key blocks, "xla" = the
 # composed softmax.
-_DISPATCHED = {"packed": 0, "flash": 0, "latent": 0, "eva": 0, "xla": 0}
+_DISPATCHED = {"packed": 0, "flash": 0, "latent": 0, "eva": 0, "grouped": 0,
+               "xla": 0}
 
 
 def attention_dispatch_stats():
     """Snapshot of the dispatcher's path counts since the process
-    started: ``{"packed", "flash", "latent", "eva", "xla"}``."""
+    started: ``{"packed", "flash", "latent", "eva", "grouped", "xla"}``."""
     return dict(_DISPATCHED)
+
+
+# Where ``ssm_scan`` sent each scan it traced (once a trace): "kernel" = the
+# chunked-scan kernels of ``ops/pallas_kernels.py``, "xla" =
+# :func:`xla_ssm_scan`.
+_SSM_SCANS = {"kernel": 0, "xla": 0}
+
+
+def ssm_scan_stats():
+    """Snapshot of where :func:`ssm_scan` sent the scans it traced since
+    the process started: ``{"kernel", "xla"}``."""
+    return dict(_SSM_SCANS)
 
 
 def _on_accelerator():
@@ -577,8 +591,8 @@ def _attention_path(form, shape, kv_shapes=(), mask_shape=None, scaled=True,
                     drop=0.0, keyed=True):
     """Name the implementation an attention call runs, from what can be
     observed of it and nothing else: ``"packed"``, ``"bshd"``, ``"bhsd"``,
-    ``"latent"``, ``"eva"`` (the flash kernels of ``ops/pallas_kernels.py``)
-    or ``"xla"`` (the composed softmax).
+    ``"latent"``, ``"eva"``, ``"grouped"`` (the flash kernels of
+    ``ops/pallas_kernels.py``) or ``"xla"`` (the composed softmax).
 
     =========  ===============================  ==========================
     ``form``   ``shape``                        kernels tried, in order
@@ -588,6 +602,7 @@ def _attention_path(form, shape, kv_shapes=(), mask_shape=None, scaled=True,
     BHSD       q's (B, H, S, D)                 bhsd
     latent     (S, nope, rope, v)               latent
     eva        (S, D, window, chunk)            eva
+    grouped    (S, D, q heads, kv heads)        grouped
     =========  ===============================  ==========================
 
     ``kv_shapes``: k's and v's shapes; ``mask_shape``: the keep-mask's, or
@@ -605,7 +620,9 @@ def _attention_path(form, shape, kv_shapes=(), mask_shape=None, scaled=True,
     need nope, v multiples of 128 and rope a multiple of 8 up to 128. The
     EVA kernels need D a multiple of 128, S a multiple of the window, the
     window a multiple of 128 and of the chunk, and the summaries (S / chunk)
-    a multiple of 128."""
+    a multiple of 128. The grouped kernels (causal, no mask, no dropout,
+    the 1/sqrt(D) scale: the op has no other form) need the query heads a
+    multiple of the key-value heads and D a multiple of 8 up to 256."""
     from ..parallel.mesh import current_scope
     from . import pallas_kernels as pk
     if not _on_accelerator():
@@ -614,6 +631,9 @@ def _attention_path(form, shape, kv_shapes=(), mask_shape=None, scaled=True,
         return "latent" if pk.flash_attention_latent_usable(*shape) else "xla"
     if form == "eva":
         return "eva" if pk.flash_attention_eva_usable(*shape) else "xla"
+    if form == "grouped":
+        return "grouped" if pk.flash_attention_grouped_usable(*shape) \
+            else "xla"
     if (len(shape) != 4 or any(tuple(s) != tuple(shape) for s in kv_shapes)
             or not scaled or (drop > 0.0 and not keyed)):
         return "xla"
@@ -682,7 +702,7 @@ def _shard_flash(call, operands, num_heads, heads_dim, mesh, batch_axes,
 
 
 def _flash(path, operands, num_heads, mask, rng_key, causal, drop,
-           window=None):
+           window=None, kv_heads=None):
     """The flash kernels of ``path`` on the devices the enclosing program
     spans: the bare call on one device, :func:`_shard_flash` when the
     trainer or serving lane tracing this op made a larger mesh visible
@@ -701,6 +721,9 @@ def _flash(path, operands, num_heads, mask, rng_key, causal, drop,
     elif path == "eva":
         def call(ops, m, s):
             return pk.flash_attention_eva(*ops, num_heads, window)
+    elif path == "grouped":
+        def call(ops, m, s):
+            return pk.flash_attention_grouped(*ops, num_heads, kv_heads)
     else:
         kernel = pk.flash_attention_bshd if path == "bshd" \
             else pk.flash_attention
@@ -1022,6 +1045,247 @@ def eva_attention(q, k, v, mu, phi, num_heads=1, window=2048, chunk=16):
             return _flash(path, (q, k, v, kt, vt), H, None, None, True, 0.0,
                           window=W)
         return xla_eva_aggregate(q, k, v, kt, vt, H, W)
+
+
+# ------------------------------------------------ grouped-query attention
+
+
+def xla_grouped_attention(q, k, v, num_heads, num_kv_heads, block=512):
+    """:func:`grouped_attention` composed from XLA ops, a block of queries
+    at a time (no (S, S) array): query head j against key-value head
+    ``j // (num_heads / num_kv_heads)``, one float32 causal softmax."""
+    B, S, HD = q.shape
+    H, KV = int(num_heads), int(num_kv_heads)
+    D, G = HD // H, H // KV
+    k4, v4 = k.reshape(B, S, KV, D), v.reshape(B, S, KV, D)
+    scale = _np.float32(1.0 / _np.sqrt(D))
+    block = min(int(block), S)
+    if S % block:
+        block = S
+    key_pos = jnp.arange(S)
+
+    def one(i):
+        qb = lax.dynamic_slice_in_dim(q, i * block, block, axis=1).reshape(
+            B, block, KV, G, D)
+        scores = jnp.einsum("bqcgd,bkcd->bcgqk", qb, k4,
+                            preferred_element_type=jnp.float32) * scale
+        seen = (i * block + jnp.arange(block))[:, None] >= key_pos[None, :]
+        probs = jax.nn.softmax(jnp.where(seen, scores, -1e30),
+                               axis=-1).astype(v.dtype)
+        return jnp.einsum("bcgqk,bkcd->bqcgd", probs, v4).reshape(
+            B, block, HD)
+
+    out = lax.map(jax.checkpoint(one), jnp.arange(S // block))
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, HD)
+
+
+@register("_contrib_grouped_attention")
+def grouped_attention(q, k, v, num_heads=1, num_kv_heads=1):
+    """Causal grouped-query attention (Ainslie et al. 2023) without
+    positions: ``num_heads`` query heads share ``num_kv_heads`` key-value
+    heads, query head j reading key-value head ``j // (num_heads /
+    num_kv_heads)``, scores times 1/sqrt(D) under one causal softmax (a model
+    with another multiplier folds the ratio into q).
+
+    ``q (B, S, H*D)``, ``k`` and ``v (B, S, KV*D)`` as the projections made
+    them; returns ``(B, S, H*D)`` as the output projection reads it. Where
+    the grouped flash kernels run (:func:`_attention_path`, form
+    ``grouped``) the key blocks are streamed, a group's query heads are
+    stacked against their one key-value head and each device of a visible
+    mesh attends its share of the batch; everywhere else
+    :func:`xla_grouped_attention` computes the same. Either way every op
+    carries the ``attention`` scope."""
+    with jax.named_scope("attention"):
+        H, KV = int(num_heads), int(num_kv_heads)
+        path = _dispatch("grouped", (q.shape[1], q.shape[-1] // H, H, KV))
+        if path == "grouped":
+            return _flash(path, (q, k, v), H, None, None, True, 0.0,
+                          kv_heads=KV)
+        return xla_grouped_attention(q, k, v, H, KV)
+
+
+# ------------------------------- state-space mixer (Mamba-2's chunked scan)
+
+
+@register("_contrib_causal_conv1d")
+def causal_conv1d(data, weight, bias):
+    """``silu`` of the causal depthwise convolution along axis 1 of ``data
+    (B, S, C)``: ``conv[t, c] = bias[c] + sum_k weight[c, k] data[t - (K - 1)
+    + k, c]`` (tap K - 1 weighs the position itself; nothing before the
+    sequence), in float32 whatever the storage type. Written under the
+    ``ssm_conv`` scope."""
+    with jax.named_scope("ssm_conv"):
+        S, K = data.shape[1], weight.shape[1]
+        padded = jnp.pad(data, ((0, 0), (K - 1, 0), (0, 0)))
+        taps = weight.astype(jnp.float32)
+        out = bias.astype(jnp.float32)
+        for j in range(K):
+            out = out + taps[:, j] * padded[:, j:j + S].astype(jnp.float32)
+        return jax.nn.silu(out).astype(data.dtype)
+
+
+@register("_contrib_gated_rms_norm")
+def gated_rms_norm(data, gate, gamma, eps=1e-5):
+    """``RMSNorm(data * silu(gate)) * gamma`` over the last axis, in
+    float32 whatever the storage type."""
+    x = data.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    y = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+@_partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _split_columns(x, sizes):
+    """``x`` cut along its last axis into blocks of ``sizes`` columns. The
+    gradient is the blocks' gradients side by side (one concatenation in
+    x's type, where slicing's own transpose pads each to x's width and adds
+    them up)."""
+    edges = _np.cumsum((0,) + tuple(sizes))
+    return tuple(x[..., lo:hi] for lo, hi in zip(edges[:-1], edges[1:]))
+
+
+def _split_columns_fwd(x, sizes):
+    return _split_columns(x, sizes), None
+
+
+def _split_columns_bwd(sizes, _, grads):
+    return (jnp.concatenate(grads, axis=-1),)
+
+
+_split_columns.defvjp(_split_columns_fwd, _split_columns_bwd)
+
+
+def _chunk_log_decay(dt, a, chunk):
+    """``cumsum`` of ``dt * a`` over each chunk's positions: ``dt (B, S, H)``
+    float32 -> (B, S, H), where position i of a chunk holds the log of the
+    decay from the chunk's start through i."""
+    B, S, H = dt.shape
+    steps = (dt * a).reshape(B, S // chunk, chunk, H)
+    return jnp.cumsum(steps, axis=2).reshape(B, S, H)
+
+
+def xla_ssm_scan(x, dt, a, b, c, num_heads, chunk):
+    """The chunked scan composed from XLA ops, a chunk at a time (no
+    (heads, S / chunk, chunk, chunk) array): ``y`` of :func:`ssm_scan`
+    without the ``D`` skip. A chunk's step is checkpointed, so the backward
+    pass keeps one (B, H, P, N) float32 state a chunk."""
+    B, S, HP = x.shape
+    H, Q = int(num_heads), int(chunk)
+    P, N, nc = HP // H, b.shape[-1], S // Q
+    dt, a = dt.astype(jnp.float32), a.astype(jnp.float32)
+    below = jnp.tril(jnp.ones((Q, Q), dtype=bool))[None, :, :, None]
+
+    @jax.checkpoint
+    def one(state, args):
+        xc, dtc, bc, cc = args          # (B,Q,H,P) (B,Q,H) (B,Q,N) (B,Q,N)
+        cs = jnp.cumsum(dtc * a, axis=1)
+        pairs = jnp.einsum("bqn,bkn->bqk", cc, bc,
+                           preferred_element_type=jnp.float32)
+        decay = jnp.exp(jnp.where(below, cs[:, :, None] - cs[:, None], -1e30))
+        inside = (pairs[..., None] * decay * dtc[:, None]).astype(x.dtype)
+        y = jnp.einsum("bqkh,bkhp->bqhp", inside, xc,
+                       preferred_element_type=jnp.float32)
+        y = y + jnp.exp(cs)[..., None] * jnp.einsum(
+            "bqn,bhpn->bqhp", cc, state.astype(x.dtype),
+            preferred_element_type=jnp.float32)
+        to_end = jnp.exp(cs[:, -1:] - cs) * dtc
+        state = jnp.exp(cs[:, -1])[:, :, None, None] * state + jnp.einsum(
+            "bqhp,bqn->bhpn",
+            (xc.astype(jnp.float32) * to_end[..., None]).astype(x.dtype), bc,
+            preferred_element_type=jnp.float32)
+        return state, y.astype(x.dtype)
+
+    def chunks(arr):        # (B, S, ...) -> (S / Q, B, Q, ...)
+        return jnp.moveaxis(arr.reshape((B, nc, Q) + arr.shape[2:]), 1, 0)
+
+    _, y = lax.scan(one, jnp.zeros((B, H, P, N), jnp.float32), (
+        chunks(x.reshape(B, S, H, P)), chunks(dt), chunks(b), chunks(c)))
+    return jnp.moveaxis(y, 0, 1).reshape(B, S, HP)
+
+
+def _ssm_scan_path(seq, heads, head_dim, state, chunk):
+    """``"kernel"`` (the chunked-scan kernels of ``ops/pallas_kernels.py``)
+    or ``"xla"`` (:func:`xla_ssm_scan`), from shapes and platform alone:
+    the kernels need an accelerator, no visible mesh of several devices
+    (GSPMD cannot partition a Mosaic kernel and the scan has no
+    ``shard_map`` wrapper yet), heads of 64 in even number, a state that
+    is a multiple of 128 wide, a chunk that is a multiple of 128 and a
+    sequence of whole chunks."""
+    from ..parallel.mesh import current_scope
+    from . import pallas_kernels as pk
+    scope = current_scope()
+    if not _on_accelerator() or (scope is not None and scope[0].size > 1):
+        return "xla"
+    return "kernel" if pk.ssm_scan_usable(seq, heads, head_dim, state,
+                                          chunk) else "xla"
+
+
+@register("_contrib_ssm_scan")
+def ssm_scan(x, dt, a_log, b, c, d, num_heads=1, chunk=256):
+    """The selective state-space scan of Mamba-2 (Dao and Gu, "Transformers
+    are SSMs", ICML 2024) with one group of B and C: for head h with state
+    ``h (P, N)`` from nought, ``h_t = exp(dt[t, h] A[h]) h_{t-1} + dt[t, h]
+    x[t, h] b[t]^T`` and ``y[t, h] = h_t c[t] + d[h] x[t, h]``, ``A =
+    -exp(a_log)``.
+
+    ``x (B, S, H*P)``; ``dt (B, S, H)`` the step AFTER its softplus;
+    ``a_log`` and ``d (H,)``; ``b`` and ``c (B, S, N)``, shared by the heads.
+    Returns ``(B, S, H*P)``. Computed in the chunked form: inside a chunk of
+    ``chunk`` positions ``Y = (L o C B^T)(dt x)`` with ``L[i, j]`` the decay
+    from j to i, the chunk's end state from ``B^T (decay to the end o dt
+    x)``, the states passed from chunk to chunk by the chunks' total decays
+    and ``Y += (decay from the start o C) h_prev``. Decays in float32 (the
+    log-decays are summed, never the decays multiplied); products in the
+    storage type with float32 accumulation. A sequence that is not whole
+    chunks is padded with steps of nought, which neither decay nor feed the
+    state. ONE decision (:func:`_ssm_scan_path`) sends it to the kernels
+    (forward and backward, the state in float32 VMEM along the sequential
+    chunk axis) or to :func:`xla_ssm_scan`; counted once a trace in
+    :func:`ssm_scan_stats`. Written under the ``ssm_scan`` scope."""
+    from . import pallas_kernels as pk
+    with jax.named_scope("ssm_scan"):
+        B, S, HP = x.shape
+        H, Q = int(num_heads), int(chunk)
+        P, N = HP // H, b.shape[-1]
+        dt = dt.astype(jnp.float32)
+        a = -jnp.exp(a_log.astype(jnp.float32))
+        pad = -S % Q
+        if pad:
+            x, dt, b, c = (jnp.pad(arr, ((0, 0), (0, pad), (0, 0)))
+                           for arr in (x, dt, b, c))
+        path = _ssm_scan_path(S + pad, H, P, N, Q)
+        _SSM_SCANS[path] += 1
+        if path == "kernel":
+            y = pk.ssm_scan(x, dt, _chunk_log_decay(dt, a, Q), b, c, H, Q)
+        else:
+            y = xla_ssm_scan(x, dt, a, b, c, H, Q)
+        skip = d.astype(jnp.float32)[:, None] * x.astype(jnp.float32).reshape(
+            x.shape[:2] + (H, P))
+        y = (y.astype(jnp.float32) + skip.reshape(x.shape)).astype(x.dtype)
+        return y[:, :S] if pad else y
+
+
+@register("_contrib_ssm_mixer")
+def ssm_mixer(zxbcdt, conv_weight, conv_bias, dt_bias, a_log, d, gamma,
+              num_heads=1, head_dim=64, state=128, chunk=256, eps=1e-5):
+    """What a Mamba-2 mixer does between its two projections, under the
+    ``ssm`` scope: ``zxbcdt (B, S, inner + (inner + 2 state) + heads)`` is
+    the input projection ``[z | xBC | dt]``; ``xBC = silu(conv(xBC))``
+    (:func:`causal_conv1d`, scope ``ssm_conv``) splits into x, B and C;
+    ``dt = softplus(dt + dt_bias)`` in float32; :func:`ssm_scan` (scope
+    ``ssm_scan``); then ``RMSNorm(y * silu(z)) * gamma``
+    (:func:`gated_rms_norm`). Returns ``(B, S, inner)`` as the output
+    projection reads it."""
+    with jax.named_scope("ssm"):
+        H, N = int(num_heads), int(state)
+        inner = H * int(head_dim)
+        z, xbc, dt = _split_columns(zxbcdt, (inner, inner + 2 * N, H))
+        x, b, c = _split_columns(
+            causal_conv1d.fn(xbc, conv_weight, conv_bias),
+            (inner, N, N))
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + dt_bias.astype(jnp.float32))
+        y = ssm_scan.fn(x, dt, a_log, b, c, d, num_heads=H, chunk=chunk)
+        return gated_rms_norm.fn(y, z, gamma, eps=eps)
 
 
 @register("_contrib_held_experts_ffn", n_out=2)

@@ -38,9 +38,10 @@ from jax.experimental import pallas as pl
 
 __all__ = ["flash_attention", "flash_attention_bshd",
            "flash_attention_packed", "flash_attention_latent",
-           "flash_attention_eva", "flash_attention_usable",
-           "flash_attention_bshd_usable", "flash_attention_latent_usable",
-           "flash_attention_eva_usable"]
+           "flash_attention_eva", "flash_attention_grouped", "ssm_scan",
+           "flash_attention_usable", "flash_attention_bshd_usable",
+           "flash_attention_latent_usable", "flash_attention_eva_usable",
+           "flash_attention_grouped_usable", "ssm_scan_usable"]
 
 # 128 is the alignment unit (MXU/VPU tiling); actual blocks are chosen
 # per call by _pick_blocks: the largest 128-multiple divisor of S up to
@@ -238,14 +239,18 @@ def _fwd_tile_update(q, k, v, carry, dead, seed, bh, q0, k0, blk_q, blk_k,
 
 
 def _bwd_tile_ds(q, k, v, do, lse, delta, mask_row, causal, dropout,
-                 scale, seed, bh, q0, k0, blk_q, blk_k, extra=None):
+                 scale, seed, bh, q0, k0, blk_q, blk_k, extra=None,
+                 dead=None):
     """Recompute dS = P o (dP - delta) for one tile (and Pdrop for dV) —
-    the single implementation all four backward kernels run."""
+    the single implementation all four backward kernels run. ``dead``: the
+    tile's own mask of positions not seen, where neither ``causal`` nor
+    ``mask_row`` says it (the grouped kernels' stacked rows)."""
     k = k.astype(q.dtype)
     v = v.astype(q.dtype)
     do = do.astype(q.dtype)
     p, pd, keep = _recompute_tile(q, k, lse, seed, bh, q0, k0, mask_row,
-                                  causal, dropout, scale, blk_q, blk_k, extra)
+                                  causal, dropout, scale, blk_q, blk_k, extra,
+                                  dead)
     dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
     if dropout > 0.0:
@@ -305,11 +310,10 @@ def _attn_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
 # ------------------------------------------------------------ backward tiles
 
 def _recompute_tile(q, k, lse, seed, bh, q0, k0, mask_row, causal,
-                    dropout, scale, blk_q, blk_k, extra=None):
+                    dropout, scale, blk_q, blk_k, extra=None, dead=None):
     """Recompute (P, Pdrop, keep, dead) for one (q-block, k-block) tile
     from the saved logsumexp. Shared by the dq and dkdv kernels."""
     s = _scores(q, k, extra) * jnp.float32(scale)
-    dead = None
     if causal:
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
         k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
@@ -2122,3 +2126,653 @@ def flash_attention_eva(q, k, v, kt, vt, num_heads, window, blocks=None,
     positions divide the window, summaries their number)."""
     return _flash_eva(q, k, v, kt, vt, int(num_heads), int(window), blocks,
                       interpret)
+
+
+# ================================================================== grouped
+# Causal grouped-query attention over STREAMED key blocks. q (B, H, S, D) and
+# k, v (B, KV, S, D), heads before positions (the op transposes: one pass
+# each way over arrays the kernels then read many times). As in the latent
+# family the key blocks are a sequential grid axis and the accumulators live
+# in scratch, so VMEM holds one block of each operand whatever the sequence.
+# The G = H / KV query heads of a group are STACKED along the rows of one
+# tile, (G * blk_q, D) against their one (blk_k, D) key-value block: a key
+# block is fetched once a group, and dk and dv, contracted over the stacked
+# rows, come out summed over the group. A row's position is its index modulo
+# blk_q. The tile bodies are the BHSD kernels' (`_fwd_tile_update`,
+# `_bwd_tile_ds`), handed the stacked tile's own causal mask; a tile above
+# the diagonal is skipped and its block index clamped to the nearest live
+# one. Backward: `flash_grouped_dq` over the forward's grid, then
+# `flash_grouped_dkv` with the query blocks sequential.
+
+# measured on the chip at 1 x 32,768 x 32 / 8 heads of 64 (PR 33), forward /
+# forward + backward, ms, queries a head x keys a block: 256/256 338.7 /
+# 596.6, 128/512 202.8 / 426.4, 256/512 173.8 / 373.8, 512/512 158.4 / 342.0,
+# 128/1024 118.0 / 317.3, 256/1024 107.9 / 297.6, 512/1024 106.7 / 293.3,
+# 128/2048 92.1 / 282.3, 256/2048 86.7 / 274.2, 512/2048 87.2 / 272.3 (the XLA
+# form, 512 queries at a time: 627.9 / 2,041.3)
+_GROUPED_ROWS = 1024        # stacked query rows a tile (G * blk_q)
+_PREF_GROUPED_K = 2048
+_GROUPED_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def flash_attention_grouped_usable(seq, head_dim, heads, kv_heads):
+    """Whether the grouped kernels take this problem."""
+    return (seq % 128 == 0 and seq >= 128 and heads % kv_heads == 0
+            and head_dim % 8 == 0 and head_dim <= 256)
+
+
+def _pick_blocks_grouped(seq, group):
+    """(blk_q, blk_k): the largest multiples of 128 that divide ``seq``, up
+    to ``_GROUPED_ROWS / group`` queries and ``_PREF_GROUPED_K`` keys."""
+    def pick(pref):
+        b = max(128, min(pref // 128 * 128, seq))
+        while seq % b:
+            b -= 128
+        return b
+    return pick(_GROUPED_ROWS // group), pick(_PREF_GROUPED_K)
+
+
+def _grouped_dead(q0, k0, group, blk_q, blk_k):
+    """The causal mask of a stacked tile: row r holds position ``q0 + r %
+    blk_q`` of query head ``r // blk_q`` of the group."""
+    q_pos = q0 + jax.lax.broadcasted_iota(
+        jnp.int32, (group, blk_q, blk_k), 1).reshape(group * blk_q, blk_k)
+    k_pos = k0 + jax.lax.broadcasted_iota(
+        jnp.int32, (group * blk_q, blk_k), 1)
+    return q_pos < k_pos
+
+
+def _grouped_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+                        l_ref, *, scale, group, blk_q, blk_k):
+    """One (batch, kv head, q-block, k-block) program: one online-softmax
+    step of the group's stacked rows into the scratch accumulators; the last
+    k-block writes the output and the log-sum-exp."""
+    kj = pl.program_id(3)
+    q0, k0 = pl.program_id(2) * blk_q, kj * blk_k
+    rows, d = group * blk_q, q_ref.shape[-1]
+
+    @pl.when(kj == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    def step(dead):
+        carry = (acc_ref[...], m_ref[0, :], l_ref[0, :])
+        _, (acc, m_i, l_i) = _fwd_tile_update(
+            q_ref[0].reshape(rows, d), k_ref[0, 0], v_ref[0, 0], carry, dead,
+            None, None, q0, k0, rows, blk_k, 0.0, scale)
+        acc_ref[...] = acc
+        m_ref[0, :] = m_i
+        l_ref[0, :] = l_i
+
+    live, whole = _latent_live(True, q0, k0, blk_q), k0 + (blk_k - 1) <= q0
+    pl.when(jnp.logical_and(live, whole))(lambda: step(None))
+    pl.when(jnp.logical_and(live, jnp.logical_not(whole)))(
+        lambda: step(_grouped_dead(q0, k0, group, blk_q, blk_k)))
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _():
+        l_safe = jnp.maximum(l_ref[0, :], jnp.float32(1e-20))
+        o_ref[0] = (acc_ref[...] / l_safe[:, None]).reshape(
+            group, blk_q, d).astype(o_ref.dtype)
+        lse_ref[0, 0, :] = m_ref[0, :] + jnp.log(l_safe)
+
+
+def _grouped_tile_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q0, k0,
+                     scale, group, blk_q, blk_k):
+    """``(ds, p, q, do)`` of one stacked tile, through `_bwd_tile_ds`."""
+    rows, d = group * blk_q, q_ref.shape[-1]
+    q, do = q_ref[0].reshape(rows, d), do_ref[0].reshape(rows, d)
+    ds, pd = _bwd_tile_ds(
+        q, k_ref[0, 0], v_ref[0, 0], do, lse_ref[0, 0, :], delta_ref[0, 0, :],
+        None, False, 0.0, scale, None, None, q0, k0, rows, blk_k,
+        dead=_grouped_dead(q0, k0, group, blk_q, blk_k))
+    return ds, pd, q, do
+
+
+def _grouped_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dq_acc, *, scale, group, blk_q, blk_k):
+    """grad wrt the group's Q: one (batch, kv head, q-block, k-block)
+    program; dQ = dS K * scale."""
+    kj = pl.program_id(3)
+    q0, k0 = pl.program_id(2) * blk_q, kj * blk_k
+
+    @pl.when(kj == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    @pl.when(_latent_live(True, q0, k0, blk_q))
+    def _():
+        ds, _, q, _ = _grouped_tile_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q0, k0, scale,
+            group, blk_q, blk_k)
+        k = k_ref[0, 0].astype(q.dtype)
+        dq_acc[...] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * jnp.float32(scale)).reshape(
+            group, blk_q, dq_ref.shape[-1]).astype(dq_ref.dtype)
+
+
+def _grouped_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        dk_ref, dv_ref, dk_acc, dv_acc, *, scale, group,
+                        blk_q, blk_k):
+    """grads wrt one key-value head's K and V, summed over the group's query
+    heads by the contraction over the stacked rows: one (batch, kv head,
+    k-block, q-block) program. dV = P^T dO; dK = dS^T Q * scale."""
+    qi = pl.program_id(3)
+    k0, q0 = pl.program_id(2) * blk_k, qi * blk_q
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    @pl.when(_latent_live(True, q0, k0, blk_q))
+    def _():
+        ds, pd, q, do = _grouped_tile_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q0, k0, scale,
+            group, blk_q, blk_k)
+        over_q = (((0,), (0,)), ((), ()))
+        dv_acc[...] += jax.lax.dot_general(
+            pd.astype(q.dtype), do.astype(q.dtype), over_q,
+            preferred_element_type=jnp.float32)
+        dk_acc[...] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, over_q, preferred_element_type=jnp.float32)
+
+    @pl.when(qi == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[0, 0] = (dk_acc[...] * jnp.float32(scale)).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _grouped_specs(KV, group, D, n_q, blk_q, blk_k, q_inner):
+    """Block specs of the grouped kernels' operands on a (batch, kv head,
+    outer block, inner block) grid (`_latent_specs`' clamping: a skipped
+    tile takes the block index of the nearest live one). A row vector (the
+    log-sum-exp, delta) of a (batch, kv head, q-block) is the group's stacked
+    rows, (1, 1, group * blk_q)."""
+    if q_inner:
+        def qk(j, i):
+            return jnp.maximum(i, (j * blk_k) // blk_q), j
+    else:
+        def qk(i, j):
+            return i, jnp.minimum(j, (i * blk_q + blk_q - 1) // blk_k)
+
+    def q_side(spec):
+        return lambda b, g, x, y: spec(b, g, qk(x, y)[0])
+
+    def k_side(spec):
+        return lambda b, g, x, y: spec(b, g, qk(x, y)[1])
+
+    return {
+        "q": pl.BlockSpec((1, group, blk_q, D),
+                          q_side(lambda b, g, i: (b, g, i, 0))),
+        "kv": pl.BlockSpec((1, 1, blk_k, D),
+                           k_side(lambda b, g, j: (b, g, j, 0))),
+        "row": pl.BlockSpec(
+            (1, 1, group * blk_q),
+            q_side(lambda b, g, i: ((b * KV + g) * n_q + i, 0, 0))),
+    }
+
+
+def _grouped_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
+                  interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        kernel, out_shape=out_shape, grid=grid, in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_GROUPED_VMEM_LIMIT),
+        interpret=interpret, name=name)
+
+
+def _grouped_dims(q, k, blocks):
+    B, H, S, D = q.shape
+    KV = k.shape[1]
+    group = H // KV
+    blk_q, blk_k = blocks or _pick_blocks_grouped(S, group)
+    return B, H, KV, group, S, D, blk_q, blk_k
+
+
+def _grouped_fwd_impl(q, k, v, blocks, interpret):
+    B, H, KV, group, S, D, blk_q, blk_k = _grouped_dims(q, k, blocks)
+    n_q, rows = S // blk_q, group * blk_q
+    spec = _grouped_specs(KV, group, D, n_q, blk_q, blk_k, False)
+    kernel = functools.partial(
+        _grouped_fwd_kernel, scale=float(1.0 / np.sqrt(D)), group=group,
+        blk_q=blk_q, blk_k=blk_k)
+    call = _grouped_call(
+        kernel, "flash_grouped_fwd", (B, KV, n_q, S // blk_k),
+        [spec["q"], spec["kv"], spec["kv"]], (spec["q"], spec["row"]),
+        (jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((B * KV * n_q, 1, rows), jnp.float32)),
+        [(rows, D), (1, rows), (1, rows)], interpret)
+    with jax.enable_x64(False):
+        return call(q, k, v)
+
+
+def _grouped_bwd_impl(q, k, v, o, lse, g, blocks, interpret):
+    B, H, KV, group, S, D, blk_q, blk_k = _grouped_dims(q, k, blocks)
+    n_q, rows = S // blk_q, group * blk_q
+    common = dict(scale=float(1.0 / np.sqrt(D)), group=group, blk_q=blk_q,
+                  blk_k=blk_k)
+    # delta_i = rowsum(dO o O), laid out as the log-sum-exp is: a group's
+    # stacked rows a (batch, kv head, q-block)
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.transpose(delta.reshape(B, KV, group, n_q, blk_q),
+                          (0, 1, 3, 2, 4)).reshape(B * KV * n_q, 1, rows)
+    by_k = _grouped_specs(KV, group, D, n_q, blk_q, blk_k, False)
+    by_q = _grouped_specs(KV, group, D, n_q, blk_q, blk_k, True)
+
+    def ins(s):
+        return [s["q"], s["kv"], s["kv"], s["q"], s["row"], s["row"]]
+
+    dq_call = _grouped_call(
+        functools.partial(_grouped_dq_kernel, **common), "flash_grouped_dq",
+        (B, KV, n_q, S // blk_k), ins(by_k), by_k["q"],
+        jax.ShapeDtypeStruct(q.shape, q.dtype), [(rows, D)], interpret)
+    dkv_call = _grouped_call(
+        functools.partial(_grouped_dkv_kernel, **common), "flash_grouped_dkv",
+        (B, KV, S // blk_k, n_q), ins(by_q), (by_q["kv"], by_q["kv"]),
+        (jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        [(blk_k, D), (blk_k, D)], interpret)
+    operands = (q, k, v, g, lse, delta)
+    with jax.enable_x64(False):
+        return (dq_call(*operands),) + tuple(dkv_call(*operands))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_grouped(q, k, v, blocks, interpret):
+    return _grouped_fwd_impl(q, k, v, blocks, interpret)[0]
+
+
+def _fg_fwd(q, k, v, blocks, interpret):
+    from jax.ad_checkpoint import checkpoint_name
+    out, lse = _grouped_fwd_impl(q, k, v, blocks, interpret)
+    # named, so that a caller that recomputes its forward in the backward
+    # pass can keep these two and spare this kernel its second run
+    out = checkpoint_name(out, "grouped_attention_out")
+    lse = checkpoint_name(lse, "grouped_attention_lse")
+    return out, (q, k, v, out, lse)
+
+
+def _fg_bwd(blocks, interpret, res, g):
+    q, k, v, out, lse = res
+    return _grouped_bwd_impl(q, k, v, out, lse, g, blocks, interpret)
+
+
+_flash_grouped.defvjp(_fg_fwd, _fg_bwd)
+
+
+def flash_attention_grouped(q, k, v, num_heads, num_kv_heads, blocks=None,
+                            interpret=False):
+    """Blockwise exact causal grouped-query attention with the 1/sqrt(D)
+    scale. ``q (B, S, H*D)``, ``k`` and ``v (B, S, KV*D)`` as the projections
+    made them, query head j reading key-value head ``j // (H / KV)``; returns
+    ``(B, S, H*D)``. ``blocks = (blk_q, blk_k)`` overrides the block sizes
+    (multiples of 128 that divide S)."""
+    B, S, _ = q.shape
+    H, KV = int(num_heads), int(num_kv_heads)
+
+    def heads_first(a, n):
+        return jnp.transpose(a.reshape(B, S, n, -1), (0, 2, 1, 3))
+
+    out = _flash_grouped(heads_first(q, H), heads_first(k, KV),
+                         heads_first(v, KV), blocks, interpret)
+    return jnp.transpose(out, (0, 2, 1, 3)).reshape(q.shape)
+
+
+# ====================================================================== ssd
+# The chunked scan of a Mamba-2 state-space layer (Dao and Gu 2024), one
+# group of B and C, heads of 64. x (B, S, H*64) stays as the convolution made
+# it and is read as column blocks; b, c (B, S, N) are shared by the heads; dt
+# (the step after its softplus) and cs (the log-decay summed over each
+# chunk's positions, which XLA makes in float32: a product on the MXU would
+# round it) come in TWO layouts, (B, H / hb, S, hb) for a head's column over
+# the chunk's positions and (B, H, S) for its row, because a tile needs
+# both. The grid is (batch, block of hb heads, chunk) with the chunk axis
+# SEQUENTIAL: the states of the block's heads, (hb * 64, N) float32, ride in
+# VMEM scratch from chunk to chunk, as `flash_latent_bwd`'s dq rides along
+# its key axis. Inside a step: G = C B^T once (shared by the heads), then for
+# each head M = G o L o dt with L[i, j] = exp(cs_i - cs_j) for j <= i, Y = M
+# X + (exp(cs) o C) S_prev^T, and S = exp(cs_last) S_prev + X^T (exp(cs_last
+# - cs) o dt o B). Heads of 64 on 128 lanes: two heads share a 128-column
+# slab of x; each head's products run on the whole slab and a lane mask
+# picks its half (a 64-wide product costs the MXU a 128-wide one anyway), so
+# nothing is sliced off the lane tiling. Every decay, and the sums that make
+# the gradients of dt and cs, in float32; the products in x's type with
+# float32 accumulation. The forward can also write each chunk's START state,
+# (B, S / Q, H * 64, N) float32: the backward reads it and is then one
+# kernel over the chunks in REVERSE with the state's gradient in scratch.
+# KEPT, not recomputed: 268 MB a layer at 1 x 32,768 x 64 heads x 128, alive
+# from the layer's (recomputed) forward to its backward only. Measured on the
+# chip there (PR 33), forward / forward + backward, ms: 16 heads a step 2.95 /
+# 14.49, 8 heads 3.02 / 14.91, 32 heads 2.97 / 14.25; the XLA form 10.32 /
+# 23.16.
+
+_SSD_HEADS = 16             # heads a grid step
+_SSD_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def ssm_scan_usable(seq, heads, head_dim, state, chunk):
+    """Whether the chunked-scan kernels take this problem."""
+    return (head_dim == 64 and heads % 2 == 0 and state % 128 == 0
+            and chunk % 128 == 0 and seq % chunk == 0)
+
+
+def _ssd_heads_block(heads):
+    """Heads a grid step: the largest divisor of ``heads`` up to
+    ``_SSD_HEADS`` that is a multiple of 8 (the row layout's sublane tile),
+    else all of them."""
+    for hb in range(min(_SSD_HEADS, heads) // 8 * 8, 0, -8):
+        if heads % hb == 0:
+            return hb
+    return heads
+
+
+_NT = (((1,), (1,)), ((), ()))      # a b^T
+_NN = (((1,), (0,)), ((), ()))      # a b
+_TN = (((0,), (0,)), ((), ()))      # a^T b
+
+
+def _f32_dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _ssd_last(cs_r):
+    """(1, 1): the row's last entry, the log of the chunk's whole decay (a
+    masked sum: Mosaic does not broadcast a (1, 1) slice off lane Q - 1)."""
+    at = jax.lax.broadcasted_iota(jnp.int32, cs_r.shape, 1)
+    return jnp.sum(jnp.where(at == cs_r.shape[1] - 1, cs_r, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _ssd_tile(cs_c, cs_r, tri):
+    """``L[i, j] = exp(cs_i - cs_j)`` for j <= i, nought above."""
+    return jnp.exp(jnp.where(tri, cs_c - cs_r, jnp.float32(NEG_INF)))
+
+
+def _ssd_fwd_kernel(x_ref, dtc_ref, csc_ref, dtr_ref, csr_ref, b_ref, c_ref,
+                    y_ref, *rest, hb, keep_states):
+    """One (batch, head block, chunk) program of the forward; ``state`` is
+    (hb / 2, 128, N): a pair of heads' states stacked, pair by pair."""
+    s0_ref, state = rest if keep_states else (None,) + rest
+    Q = x_ref.shape[1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros(state.shape, jnp.float32)
+
+    if keep_states:
+        s0_ref[0, 0] = state[...].reshape(s0_ref.shape[2:])
+    bm, cm = b_ref[0], c_ref[0]
+    bf, cf = bm.astype(jnp.float32), cm.astype(jnp.float32)
+    pairs = _f32_dot(cm, bm, _NT)
+    tri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    low = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1) < 64
+    dtc, csc = dtc_ref[0, 0], csc_ref[0, 0]
+    for p in range(hb // 2):
+        cols = slice(p * 128, (p + 1) * 128)
+        xp = x_ref[0, :, cols]
+        prev = state[p]
+        prev_x = prev.astype(xp.dtype)
+        halves = []
+        for s in range(2):
+            h = 2 * p + s
+            cs_c, dt_c = csc[:, h:h + 1], dtc[:, h:h + 1]
+            cs_r, dt_r = csr_ref[0, h:h + 1, :], dtr_ref[0, h:h + 1, :]
+            inside = (pairs * _ssd_tile(cs_c, cs_r, tri) * dt_r).astype(
+                xp.dtype)
+            from_start = (cf * jnp.exp(cs_c)).astype(xp.dtype)
+            halves.append(_f32_dot(inside, xp, _NN)
+                          + _f32_dot(from_start, prev_x, _NT))
+            cs_last = _ssd_last(cs_r)
+            to_end = (bf * (jnp.exp(cs_last - cs_c) * dt_c)).astype(xp.dtype)
+            rows = slice(s * 64, s * 64 + 64)
+            state[p, rows, :] = jnp.exp(cs_last) * prev[rows, :] \
+                + _f32_dot(xp, to_end, _TN)[rows, :]
+        y_ref[0, :, cols] = jnp.where(low, halves[0], halves[1]).astype(
+            y_ref.dtype)
+
+
+def _ssd_bwd_kernel(x_ref, dy_ref, dtc_ref, csc_ref, dtr_ref, csr_ref, b_ref,
+                    c_ref, s0_ref, dx_ref, ddtc_ref, dcsc_ref, ddtr_ref,
+                    dcsr_ref, db_ref, dc_ref, dstate, dye, xw, *, hb):
+    """One (batch, head block, chunk) program of the backward, the chunks
+    in reverse: ``dstate`` is the gradient of the chunk's END state on the
+    way in and of its START state on the way out. Besides dx: the gradients
+    of dt and cs in both layouts (a tile's row sums are a column, its column
+    sums a row; XLA adds the two), and this head block's share of db and dc
+    (float32; XLA sums the blocks). ``dye`` = dy scaled by the decay from the
+    chunk's start and ``xw`` = x scaled by dt and the decay to its end, head
+    by head, are built in scratch so that the products that run over ALL the
+    block's heads (the states' and b's and c's) are one matmul each."""
+    Q = x_ref.shape[1]
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros(dstate.shape, f32)
+
+    bm, cm = b_ref[0], c_ref[0]
+    bf, cf = bm.astype(f32), cm.astype(f32)
+    pairs = _f32_dot(cm, bm, _NT)
+    tri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) \
+        >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    low = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1) < 64
+    last = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0) == Q - 1
+    lane_h = jax.lax.broadcasted_iota(jnp.int32, (Q, hb), 1)
+    row_h = jax.lax.broadcasted_iota(jnp.int32, (hb, Q), 0)
+    dtc, csc = dtc_ref[0, 0], csc_ref[0, 0]
+    end_grad = dstate[...].reshape(hb * 64, -1).astype(x_ref.dtype)
+    d_pairs = jnp.zeros((Q, Q), f32)
+    ddt_c = jnp.zeros((Q, hb), f32)
+    dcs_c = jnp.zeros((Q, hb), f32)
+    ddt_r = jnp.zeros((hb, Q), f32)
+    dcs_r = jnp.zeros((hb, Q), f32)
+
+    def total(a):       # (1, 1)
+        return jnp.sum(jnp.sum(a, axis=1, keepdims=True), axis=0,
+                       keepdims=True)
+
+    for p in range(hb // 2):
+        cols = slice(p * 128, (p + 1) * 128)
+        xp, dyp = x_ref[0, :, cols], dy_ref[0, :, cols]
+        xf, dyf = xp.astype(f32), dyp.astype(f32)
+        start = s0_ref[0, 0, cols, :]
+        start_x = start.astype(xp.dtype)
+        grad = dstate[p]
+        grad_x = grad.astype(xp.dtype)
+        dxs, dyes, xws = [], [], []
+        for s in range(2):
+            h = 2 * p + s
+            mine = low if s == 0 else jnp.logical_not(low)
+            rows = slice(s * 64, s * 64 + 64)
+            cs_c, dt_c = csc[:, h:h + 1], dtc[:, h:h + 1]
+            cs_r, dt_r = csr_ref[0, h:h + 1, :], dtr_ref[0, h:h + 1, :]
+            decay = _ssd_tile(cs_c, cs_r, tri)
+            inside = pairs * decay * dt_r
+            # the tile's own gradient: only this head's lanes of dy count
+            d_inside = _f32_dot(jnp.where(mine, dyp, jnp.zeros_like(dyp)),
+                                xp, _NT)
+            d_pairs = d_pairs + d_inside * decay * dt_r
+            per_dt = d_inside * pairs * decay       # d_inside o inside / dt
+            to_dt = jnp.sum(per_dt, axis=0, keepdims=True)          # (1, Q)
+            to_cs = jnp.sum(per_dt * dt_r, axis=1, keepdims=True)   # (Q, 1)
+            # the part read off the chunk's start state
+            e_c = jnp.exp(cs_c)
+            from_start = (cf * e_c).astype(xp.dtype)
+            y_start = _f32_dot(from_start, start_x, _NT)
+            to_cs = to_cs + jnp.sum(
+                jnp.where(mine, dyf * y_start, 0.0), axis=1, keepdims=True)
+            # the part that feeds the chunk's end state
+            cs_last = _ssd_last(cs_r)
+            total_decay = jnp.exp(cs_last)
+            end_decay = jnp.exp(cs_last - cs_c)
+            to_end = (bf * (end_decay * dt_c)).astype(xp.dtype)
+            fed = jnp.sum(_f32_dot(jnp.where(mine, xp, jnp.zeros_like(xp)),
+                                   grad_x, _NN) * bf, axis=1, keepdims=True)
+            weighed = fed * end_decay * dt_c
+            at_last = total(weighed) + total_decay * total(
+                grad[rows, :] * start[rows, :])
+            to_cs = to_cs - weighed + jnp.where(last, at_last, 0.0)
+            dcs_c = jnp.where(lane_h == h, to_cs, dcs_c)
+            ddt_c = jnp.where(lane_h == h, fed * end_decay, ddt_c)
+            dcs_r = jnp.where(row_h == h, -to_dt * dt_r, dcs_r)
+            ddt_r = jnp.where(row_h == h, to_dt, ddt_r)
+            dxs.append(_f32_dot(inside.astype(xp.dtype), dyp, _TN)
+                       + _f32_dot(to_end, grad_x, _NT))
+            dyes.append(dyf * e_c)
+            xws.append(xf * (end_decay * dt_c))
+            dstate[p, rows, :] = total_decay * grad[rows, :]
+        dx_ref[0, :, cols] = jnp.where(low, dxs[0], dxs[1]).astype(
+            dx_ref.dtype)
+        dye[:, cols] = jnp.where(low, dyes[0], dyes[1]).astype(dye.dtype)
+        xw[:, cols] = jnp.where(low, xws[0], xws[1]).astype(xw.dtype)
+
+    d_pairs = d_pairs.astype(bm.dtype)
+    dc_ref[0, 0] = _f32_dot(d_pairs, bm, _NN) + _f32_dot(
+        dye[...], s0_ref[0, 0].astype(dye.dtype), _NN)
+    db_ref[0, 0] = _f32_dot(d_pairs, cm, _TN) + _f32_dot(xw[...], end_grad,
+                                                         _NN)
+    dstate[...] += _f32_dot(dye[...], cm, _TN).reshape(dstate.shape)
+    ddtc_ref[0, 0], dcsc_ref[0, 0] = ddt_c, dcs_c
+    ddtr_ref[0], dcsr_ref[0] = ddt_r, dcs_r
+
+
+def _ssd_specs(hb, Q, N, nc, reverse):
+    """Block specs on the (batch, head block, chunk) grid; ``reverse``: the
+    chunk axis walks from the last chunk to the first."""
+    def at(c):
+        return nc - 1 - c if reverse else c
+
+    return {
+        "x": pl.BlockSpec((1, Q, hb * 64), lambda b, g, c: (b, at(c), g)),
+        "col": pl.BlockSpec((1, 1, Q, hb), lambda b, g, c: (b, g, at(c), 0)),
+        "row": pl.BlockSpec((1, hb, Q), lambda b, g, c: (b, g, at(c))),
+        "bc": pl.BlockSpec((1, Q, N), lambda b, g, c: (b, at(c), 0)),
+        "dbc": pl.BlockSpec((1, 1, Q, N), lambda b, g, c: (b, g, at(c), 0)),
+        "state": pl.BlockSpec((1, 1, hb * 64, N),
+                              lambda b, g, c: (b, at(c), g, 0)),
+    }
+
+
+def _ssd_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
+              interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        kernel, out_shape=out_shape, grid=grid, in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM(shape, dtype) for shape, dtype in scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_SSD_VMEM_LIMIT),
+        interpret=interpret, name=name)
+
+
+def _ssd_layouts(dt, hb):
+    """``(column layout (B, H / hb, S, hb), row layout (B, H, S))`` of a
+    (B, S, H) float32 array."""
+    B, S, H = dt.shape
+    return (jnp.transpose(dt.reshape(B, S, H // hb, hb), (0, 2, 1, 3)),
+            jnp.transpose(dt, (0, 2, 1)))
+
+
+def _ssd_fwd_impl(x, dt, cs, b, c, heads, chunk, keep_states, interpret):
+    B, S, HP = x.shape
+    N, Q, nc = b.shape[-1], chunk, S // chunk
+    hb = _ssd_heads_block(heads)
+    spec = _ssd_specs(hb, Q, N, nc, False)
+    out_specs, out_shape = [spec["x"]], [jax.ShapeDtypeStruct(x.shape,
+                                                              x.dtype)]
+    if keep_states:
+        out_specs.append(spec["state"])
+        out_shape.append(jax.ShapeDtypeStruct((B, nc, HP, N), jnp.float32))
+    call = _ssd_call(
+        functools.partial(_ssd_fwd_kernel, hb=hb, keep_states=keep_states),
+        "ssd_scan_fwd", (B, heads // hb, nc),
+        [spec["x"], spec["col"], spec["col"], spec["row"], spec["row"],
+         spec["bc"], spec["bc"]], out_specs, out_shape,
+        [((hb // 2, 128, N), jnp.float32)], interpret)
+    dt_c, dt_r = _ssd_layouts(dt, hb)
+    cs_c, cs_r = _ssd_layouts(cs, hb)
+    with jax.enable_x64(False):
+        return call(x, dt_c, cs_c, dt_r, cs_r, b, c)
+
+
+def _ssd_bwd_impl(x, dt, cs, b, c, states, g, heads, chunk, interpret):
+    B, S, HP = x.shape
+    N, Q, nc = b.shape[-1], chunk, S // chunk
+    hb = _ssd_heads_block(heads)
+    nb = heads // hb
+    spec = _ssd_specs(hb, Q, N, nc, True)
+    f32 = jnp.float32
+    call = _ssd_call(
+        functools.partial(_ssd_bwd_kernel, hb=hb), "ssd_scan_bwd",
+        (B, nb, nc),
+        [spec["x"], spec["x"], spec["col"], spec["col"], spec["row"],
+         spec["row"], spec["bc"], spec["bc"], spec["state"]],
+        [spec["x"], spec["col"], spec["col"], spec["row"], spec["row"],
+         spec["dbc"], spec["dbc"]],
+        [jax.ShapeDtypeStruct(x.shape, x.dtype),
+         jax.ShapeDtypeStruct((B, nb, S, hb), f32),
+         jax.ShapeDtypeStruct((B, nb, S, hb), f32),
+         jax.ShapeDtypeStruct((B, heads, S), f32),
+         jax.ShapeDtypeStruct((B, heads, S), f32),
+         jax.ShapeDtypeStruct((B, nb, S, N), f32),
+         jax.ShapeDtypeStruct((B, nb, S, N), f32)],
+        [((hb // 2, 128, N), f32), ((Q, hb * 64), x.dtype),
+         ((Q, hb * 64), x.dtype)], interpret)
+    dt_c, dt_r = _ssd_layouts(dt, hb)
+    cs_c, cs_r = _ssd_layouts(cs, hb)
+    with jax.enable_x64(False):
+        dx, ddt_c, dcs_c, ddt_r, dcs_r, db, dc = call(
+            x, g, dt_c, cs_c, dt_r, cs_r, b, c, states)
+
+    def whole(col, row):    # the two layouts' shares of a (B, S, H) gradient
+        return jnp.transpose(col, (0, 2, 1, 3)).reshape(B, S, heads) \
+            + jnp.transpose(row, (0, 2, 1))
+
+    return (dx, whole(ddt_c, ddt_r), whole(dcs_c, dcs_r),
+            jnp.sum(db, axis=1).astype(b.dtype),
+            jnp.sum(dc, axis=1).astype(c.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _ssd_scan(x, dt, cs, b, c, heads, chunk, interpret):
+    return _ssd_fwd_impl(x, dt, cs, b, c, heads, chunk, False, interpret)[0]
+
+
+def _ssd_vjp_fwd(x, dt, cs, b, c, heads, chunk, interpret):
+    y, states = _ssd_fwd_impl(x, dt, cs, b, c, heads, chunk, True, interpret)
+    return y, (x, dt, cs, b, c, states)
+
+
+def _ssd_vjp_bwd(heads, chunk, interpret, res, g):
+    x, dt, cs, b, c, states = res
+    return _ssd_bwd_impl(x, dt, cs, b, c, states, g, heads, chunk, interpret)
+
+
+_ssd_scan.defvjp(_ssd_vjp_fwd, _ssd_vjp_bwd)
+
+
+def ssm_scan(x, dt, cs, b, c, num_heads, chunk, interpret=False):
+    """The chunked scan as kernels, forward and backward: ``y[t, h] = h_t
+    c[t]`` of the recurrence ``h_t = exp(dt[t, h] A[h]) h_{t-1} + dt[t, h]
+    x[t, h] b[t]^T`` (no ``D`` skip). ``x (B, S, H*64)``; ``dt (B, S, H)``
+    float32, the step after its softplus; ``cs (B, S, H)`` float32, ``dt *
+    A`` summed over each chunk's positions up to and with each (the log of
+    the decay since the chunk began); ``b`` and ``c (B, S, N)``. Returns
+    ``(B, S, H*64)``; differentiable in all five."""
+    return _ssd_scan(x, dt.astype(jnp.float32), cs.astype(jnp.float32), b, c,
+                     int(num_heads), int(chunk), interpret)

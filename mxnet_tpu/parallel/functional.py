@@ -9,6 +9,7 @@ mxnet_tpu.optimizer (numerical parity with the eager Trainer path).
 """
 from __future__ import annotations
 
+import contextlib
 import re
 
 import jax
@@ -30,18 +31,29 @@ def functionalize(block, train=True):
     """Return (pure_fn, params). ``pure_fn(rng_key, param_vals, *inputs)``
     → (outputs_tuple, aux_vals_tuple); aux_vals align with ``aux_handles``
     attribute set on the function (BatchNorm moving stats etc.)."""
+    from ..context import current_context
     from ..gluon.parameter import swapped_in
     from ..ndarray.ndarray import NDArray
     params = list(block.collect_params().values())
 
     def pure(rng_key, param_vals, *input_vals):
-        nds = [NDArray(v) for v in input_vals]
+        # the caller's mesh places this program, and ``param_vals`` stand in
+        # for every copy the block holds. A traced array claims the CURRENT
+        # context, so where the block's own copy lives elsewhere (on the
+        # host, say, to leave the chip to the trainer's) the block is called
+        # under the context of ITS copy (the inputs labelled with it), and
+        # every ``p.data(x.context)`` finds the value swapped in. Otherwise
+        # nothing is labelled and nothing entered.
+        held = params[0].list_ctx() if params else []
+        away = held[0] if held and current_context() not in held else None
+        nds = [NDArray(v, ctx=away) for v in input_vals]
         _random.push_trace_key(rng_key)
         prev_rec = _tape.set_recording(False)
         prev_train = _tape.set_training(train)
         sink = _tape.push_aux_sink()
         try:
-            with swapped_in(params, param_vals):
+            with swapped_in(params, param_vals), \
+                    away or contextlib.nullcontext():
                 out = block(*nds)
         finally:
             _tape.pop_aux_sink()

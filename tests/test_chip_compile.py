@@ -428,3 +428,129 @@ def test_held_experts_combine_runs_over_the_buffers_rows(topo, monkeypatch):
         ("moe_products", "tgmm")]
     assert kernels.count(("moe_combine", "tgmm")) == 3 * 2      # y and d_x
     assert compiled.memory_analysis().temp_size_in_bytes < 5.70e9
+
+
+# ---- the hybrid state-space decoder's kernels at the benchmark's widths -----
+
+def test_scan_kernels_compile_at_published_widths(topo):
+    """64 scan heads of 64 over a state of 128 in chunks of 256, one row of
+    32,768 tokens in bfloat16, for one described chip: two Mosaic calls,
+    ``ssd_scan_fwd`` (the form that also writes the chunk-boundary states)
+    and the ONE backward ``ssd_scan_bwd``; heads of 64 ride two to a
+    128-lane slab and the state's float32 scratch holds 16 heads."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    one = SingleDeviceSharding(topo.devices[0])
+    seq, heads, state, chunk = 32768, 64, 128, 256
+    assert pk.ssm_scan_usable(seq, heads, 64, state, chunk)
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, dt, cs, b, c):
+        return jnp.sum(pk.ssm_scan(x, dt, cs, b, c, heads, chunk)
+                       .astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(
+        spec(1, seq, heads * 64), spec(1, seq, heads, dtype=jnp.float32),
+        spec(1, seq, heads, dtype=jnp.float32), spec(1, seq, state),
+        spec(1, seq, state)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("ssd_scan_fwd", "ssd_scan_bwd"):
+        assert kernel in text, kernel
+    # the kept states (268 MB), the two float32 shares of db and dc, the
+    # layouts of dt and the log-decay: no (heads, S / 256, 256, 256) array
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    assert not re.search(r"\[[0-9,]*256,256\]", text)
+
+
+def test_grouped_kernels_compile_at_published_widths(topo):
+    """32 query heads over 8 key-value heads of 64, one row of 32,768 tokens
+    in bfloat16, for one described chip: three Mosaic calls whose VMEM holds
+    one block of each operand whatever the sequence (four stacked query
+    heads of 256 rows against a 2,048-row key block), where the per-head BHSD
+    kernels, which keep a head's whole K and V in VMEM, are refused."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    one = SingleDeviceSharding(topo.devices[0])
+    seq, heads, kv_heads = 32768, 32, 8
+    assert nn_ops._attention_path("grouped", (seq, 64, heads, kv_heads)) \
+        == "xla"        # no chip here: the kernels are called directly
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        return jnp.sum(pk.flash_attention_grouped(q, k, v, heads, kv_heads)
+                       .astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec(1, seq, heads * 64), spec(1, seq, kv_heads * 64),
+        spec(1, seq, kv_heads * 64)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("flash_grouped_fwd", "flash_grouped_dq",
+                   "flash_grouped_dkv"):
+        assert kernel in text, kernel
+    assert not re.search(r"\[[0-9,]*32768,32768\]", text)
+
+    whole = spec(1, heads, seq, 64)
+
+    def per_head(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, None, True, 0.0)
+                       .astype(jnp.float32))
+    with pytest.raises(Exception, match="vmem"):
+        jax.jit(jax.grad(per_head, argnums=(0, 1, 2))).lower(
+            whole, whole, whole).compile()
+
+
+def test_hybrid_decoder_step_names_the_kernels(topo, monkeypatch):
+    """One recomputed state-space layer and the attention layer at the
+    published widths, the whole tied vocabulary and 32,768 tokens, traced by
+    the trainer as on the chip and compiled for one described chip: the
+    lowered step names the scan's forward twice (the layer's forward is run
+    again, and that run writes the states the one backward reads) and the
+    grouped forward once (its output and log-sum-exp are kept by name)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import parallel
+    from mxnet_tpu.models.hybrid_ssm import HybridDecoder
+    from mxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    net = HybridDecoder(
+        vocab_size=100352, units=2048, layer_types=("mamba", "attention"),
+        hidden_size=8192, mamba_heads=64, mamba_head_dim=64, mamba_state=128,
+        num_heads=32, num_kv_heads=8, attention_multiplier=0.015625,
+        residual_multiplier=0.22, embedding_multiplier=12.0,
+        logits_scaling=8.0, recompute=True)
+    net.initialize(mx.init.Zero())
+    net.cast("bfloat16")
+    trainer = parallel.ShardedTrainer(
+        net, lambda out, _label: out, "adam", {"learning_rate": 1e-4},
+        mesh=parallel.make_mesh(dp=1, devices=jax.devices()[:1]),
+        dtype="bfloat16")
+    trainer._build_step()
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    before, scans = nn_ops.attention_dispatch_stats(), nn_ops.ssm_scan_stats()
+    compiled = trainer._step_fn.lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one),
+        [spec(v) for v in trainer._values],
+        [tuple(spec(x) for x in s) for s in trainer._states], 1, 1e-4,
+        ints(1, 32768), ints(1, 32768),
+        jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one)).compile()
+    after = nn_ops.attention_dispatch_stats()
+    assert after == dict(before, grouped=before["grouped"] + 1)
+    assert nn_ops.ssm_scan_stats() == dict(scans, kernel=scans["kernel"] + 1)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 6
+    for kernel, times in (("ssd_scan_fwd", 2), ("ssd_scan_bwd", 1),
+                          ("flash_grouped_fwd", 1), ("flash_grouped_dq", 1),
+                          ("flash_grouped_dkv", 1)):
+        assert len(re.findall(r"= [^\n]*custom-call\([^\n]*%s" % kernel,
+                              text)) == times, kernel
+    assert not re.search(r"\[[0-9,]*(256,256|32768,32768)\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30

@@ -510,7 +510,7 @@ def test_prefill_through_the_packed_entry_equals_the_split(path, monkeypatch,
     took = {k: v - before[k]
             for k, v in nn_ops.attention_dispatch_stats().items()}
     assert took == {"packed": net.num_layers if path != "xla" else 0,
-                    "flash": 0, "latent": 0, "eva": 0,
+                    "flash": 0, "latent": 0, "eva": 0, "grouped": 0,
                     "xla": net.num_layers if path == "xla" else 0}
 
     np.testing.assert_array_equal(logits.asnumpy().argmax(-1),
