@@ -583,8 +583,29 @@ def ssm_scan_stats():
     return dict(_SSM_SCANS)
 
 
+# Where ``causal_conv1d`` sent each convolution it traced (once a trace):
+# "kernel" = the convolution kernels of ``ops/pallas_kernels.py``, "xla" =
+# :func:`xla_causal_conv1d`.
+_SSM_CONVS = {"kernel": 0, "xla": 0}
+
+
+def ssm_conv_stats():
+    """Snapshot of where :func:`causal_conv1d` sent the convolutions it
+    traced since the process started: ``{"kernel", "xla"}``."""
+    return dict(_SSM_CONVS)
+
+
 def _on_accelerator():
     return any(d.platform != "cpu" for d in jax.devices())
+
+
+def _one_device_accelerator():
+    """An accelerator and no visible mesh of several devices: where a
+    kernel that has no ``shard_map`` wrapper (the scan's, the convolution's)
+    can run."""
+    from ..parallel.mesh import current_scope
+    scope = current_scope()
+    return _on_accelerator() and (scope is None or scope[0].size == 1)
 
 
 def _attention_path(form, shape, kv_shapes=(), mask_shape=None, scaled=True,
@@ -1107,21 +1128,48 @@ def grouped_attention(q, k, v, num_heads=1, num_kv_heads=1):
 # ------------------------------- state-space mixer (Mamba-2's chunked scan)
 
 
+def xla_causal_conv1d(data, weight, bias):
+    """:func:`causal_conv1d` composed from XLA ops: the K shifted views of
+    the padded input weighed and added up in float32, then ``silu``."""
+    S, K = data.shape[1], weight.shape[1]
+    padded = jnp.pad(data, ((0, 0), (K - 1, 0), (0, 0)))
+    taps = weight.astype(jnp.float32)
+    out = bias.astype(jnp.float32)
+    for j in range(K):
+        out = out + taps[:, j] * padded[:, j:j + S].astype(jnp.float32)
+    return jax.nn.silu(out).astype(data.dtype)
+
+
+def _ssm_conv_path(seq, channels, taps):
+    """``"kernel"`` (the convolution kernels of ``ops/pallas_kernels.py``)
+    or ``"xla"`` (:func:`xla_causal_conv1d`), from shapes and platform
+    alone: the kernels need an accelerator, no visible mesh of several
+    devices (GSPMD cannot partition a Mosaic kernel and the convolution has
+    no ``shard_map`` wrapper, as the scan has none), channels a multiple of
+    128 and at most 8 taps; a sequence of any length (``seq``) is padded to
+    whole position blocks."""
+    from . import pallas_kernels as pk
+    return "kernel" if _one_device_accelerator() and pk.causal_conv_usable(
+        seq, channels, taps) else "xla"
+
+
 @register("_contrib_causal_conv1d")
 def causal_conv1d(data, weight, bias):
     """``silu`` of the causal depthwise convolution along axis 1 of ``data
     (B, S, C)``: ``conv[t, c] = bias[c] + sum_k weight[c, k] data[t - (K - 1)
     + k, c]`` (tap K - 1 weighs the position itself; nothing before the
-    sequence), in float32 whatever the storage type. Written under the
-    ``ssm_conv`` scope."""
+    sequence), in float32 whatever the storage type. ONE decision
+    (:func:`_ssm_conv_path`) sends it to the kernels (one pass forward, one
+    backward, no float32 array of the sequence's size) or to
+    :func:`xla_causal_conv1d`; counted once a trace in
+    :func:`ssm_conv_stats`. Written under the ``ssm_conv`` scope."""
+    from . import pallas_kernels as pk
     with jax.named_scope("ssm_conv"):
-        S, K = data.shape[1], weight.shape[1]
-        padded = jnp.pad(data, ((0, 0), (K - 1, 0), (0, 0)))
-        taps = weight.astype(jnp.float32)
-        out = bias.astype(jnp.float32)
-        for j in range(K):
-            out = out + taps[:, j] * padded[:, j:j + S].astype(jnp.float32)
-        return jax.nn.silu(out).astype(data.dtype)
+        path = _ssm_conv_path(data.shape[1], data.shape[2], weight.shape[1])
+        _SSM_CONVS[path] += 1
+        if path == "kernel":
+            return pk.causal_conv1d(data, weight, bias)
+        return xla_causal_conv1d(data, weight, bias)
 
 
 @register("_contrib_gated_rms_norm")
@@ -1210,13 +1258,9 @@ def _ssm_scan_path(seq, heads, head_dim, state, chunk):
     ``shard_map`` wrapper yet), heads of 64 in even number, a state that
     is a multiple of 128 wide, a chunk that is a multiple of 128 and a
     sequence of whole chunks."""
-    from ..parallel.mesh import current_scope
     from . import pallas_kernels as pk
-    scope = current_scope()
-    if not _on_accelerator() or (scope is not None and scope[0].size > 1):
-        return "xla"
-    return "kernel" if pk.ssm_scan_usable(seq, heads, head_dim, state,
-                                          chunk) else "xla"
+    return "kernel" if _one_device_accelerator() and pk.ssm_scan_usable(
+        seq, heads, head_dim, state, chunk) else "xla"
 
 
 @register("_contrib_ssm_scan")

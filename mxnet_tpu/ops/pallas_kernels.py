@@ -39,6 +39,7 @@ from jax.experimental import pallas as pl
 __all__ = ["flash_attention", "flash_attention_bshd",
            "flash_attention_packed", "flash_attention_latent",
            "flash_attention_eva", "flash_attention_grouped", "ssm_scan",
+           "causal_conv1d", "causal_conv_usable",
            "flash_attention_usable", "flash_attention_bshd_usable",
            "flash_attention_latent_usable", "flash_attention_eva_usable",
            "flash_attention_grouped_usable", "ssm_scan_usable"]
@@ -2776,3 +2777,251 @@ def ssm_scan(x, dt, cs, b, c, num_heads, chunk, interpret=False):
     ``(B, S, H*64)``; differentiable in all five."""
     return _ssd_scan(x, dt.astype(jnp.float32), cs.astype(jnp.float32), b, c,
                      int(num_heads), int(chunk), interpret)
+
+
+# ================================================================= ssm conv
+# The causal depthwise convolution of a Mamba-2 mixer with its SiLU: x (B, S,
+# C) read once and y written once forward; x and dy read once and dx written
+# once backward, with the taps' and the bias's gradients summed on the way. A
+# column only ever meets itself, so the grid is (batch, column block,
+# position block) with the position axis SEQUENTIAL (`_ssd_call`'s
+# semantics) and a block's K - 1 rows of history carried in VMEM scratch:
+# forward the last rows of x from the block before; backward, the blocks
+# walked in REVERSE, the first rows of dpre (= dy silu'(pre)) from the block
+# after, and the rows of x before the block read as a second view of x one
+# 16-row tile back. Inside a grid step an
+# inner loop walks tiles of `_CONV_TILE` positions so that a tile's whole
+# chain (float32 whatever the storage type: the shifted rows by a sublane
+# roll of the tile with the 8 rows before it, the taps in the XLA form's
+# order, SiLU, one rounding) stays in registers. No float32 array of the
+# sequence's size exists, forward or backward. Measured on the chip at 1 x
+# 32,768 x 4,352 bfloat16, 4 taps (PR 34), forward / forward + backward, ms on
+# the host's clock (an empty program's round trip reads 0.68): (2,048, 256) a
+# step 1.73 / 3.79, (1,024, 256) 1.84 / 4.14, (512, 256) 2.06 / 4.38, (1,024,
+# 128) 2.19 / 4.91, (256, 2,176) 1.94 / 5.26; tiles of 16 / 32 / 64 positions
+# 2.03 / 1.84 / 1.78 forward; the XLA form 2.86 / 12.12. On the device's
+# clock: forward 1.01 ms (its reads and writes at the memory's full rate:
+# 0.70), backward 1.63 (1.04); the XLA form 2.23 and 9.13.
+
+_CONV_BLOCK = (2048, 256)   # positions, columns a grid step
+_CONV_TILE = 32             # positions an inner step
+_CONV_UNROLL = 4            # inner steps a loop iteration (Mosaic unrolls a
+#                             ``fori_loop`` wholly or not at all)
+
+
+def causal_conv_usable(seq, channels, taps):
+    """Whether the convolution kernels take this problem (a sequence of any
+    length: it is padded to whole position blocks)."""
+    return seq >= 1 and channels % 128 == 0 and 1 <= taps <= 8
+
+
+def _conv_blocks(seq, channels, blocks):
+    """``(positions, columns)`` of a grid step: the fewest position blocks
+    of at most ``blocks[0]`` rows in whole tiles, and the widest multiple
+    of 128 up to ``blocks[1]`` that divides ``channels``."""
+    rows, cols = blocks or _CONV_BLOCK
+    count = -(-seq // rows)
+    rows = -(-seq // (count * _CONV_TILE)) * _CONV_TILE
+    cols = max(128, min(cols, channels) // 128 * 128)
+    while channels % cols:
+        cols -= 128
+    return rows, cols
+
+
+def _conv_pre(before, cur, taps, bias):
+    """``(pre, shifted)`` of a tile: ``cur (R, cb)`` float32 with the 8 rows
+    ``before`` it; ``shifted[j][t] = x[t - (K - 1) + j]`` and ``pre = bias +
+    tap_0 shifted[0] + ... + tap_{K-1} shifted[K-1]``, added in that order
+    (the XLA form's, so that the two agree to the bit)."""
+    from jax.experimental.pallas import tpu as pltpu
+    window = jnp.concatenate([before, cur], axis=0)
+    shifted = [cur if d == 0 else pltpu.roll(window, d, 0)[8:]
+               for d in range(len(taps) - 1, -1, -1)]
+    pre = bias
+    for tap, rows in zip(taps, shifted):
+        pre = pre + tap * rows
+    return pre, shifted
+
+
+def _fold8(a):
+    """(R, cb) -> (8, cb): the 8-row tiles added up (no sublane crosses)."""
+    return sum(a[r:r + 8] for r in range(8, a.shape[0], 8)) + a[:8]
+
+
+def _conv_loop(tiles, step, init):
+    """``fori_loop(0, tiles, step, init)``, ``_CONV_UNROLL`` steps an
+    iteration where that divides ``tiles``."""
+    unroll = _CONV_UNROLL if tiles % _CONV_UNROLL == 0 else 1
+
+    def group(i, carry):
+        for k in range(unroll):
+            carry = step(i * unroll + k, carry)
+        return carry
+
+    return jax.lax.fori_loop(0, tiles // unroll, group, init)
+
+
+def _conv_fwd_kernel(x_ref, w_ref, b_ref, y_ref, tail):
+    """One (batch, column block, position block) program of the forward;
+    ``tail``: the 8 rows of x before the block, in float32."""
+    f32, tile = jnp.float32, _CONV_TILE
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        tail[...] = jnp.zeros(tail.shape, f32)
+
+    taps = [w_ref[j:j + 1, :] for j in range(w_ref.shape[0])]
+    bias = b_ref[...]
+
+    def step(i, before):
+        at = pl.ds(pl.multiple_of(i * tile, tile), tile)
+        cur = x_ref[0, at, :].astype(f32)
+        pre, _ = _conv_pre(before, cur, taps, bias)
+        y_ref[0, at, :] = jax.nn.silu(pre).astype(y_ref.dtype)
+        return cur[tile - 8:]
+
+    tail[...] = _conv_loop(x_ref.shape[1] // tile, step, tail[...])
+
+
+def _conv_bwd_kernel(x_ref, xb_ref, dy_ref, w_ref, b_ref, dx_ref, dw_ref,
+                     db_ref, head, acc):
+    """One program of the backward, the position blocks (and the tiles
+    inside one) in reverse: ``head`` holds the first 8 rows of dpre of the
+    block after, ``acc (K + 1, 8, cb)`` the taps' and the bias's gradients
+    (8 partial sums a column, added up at the sequence's start); ``xb_ref``
+    the 16 rows of x before the block (the first block's are nought)."""
+    from jax.experimental.pallas import tpu as pltpu
+    f32, tile = jnp.float32, _CONV_TILE
+    K = w_ref.shape[0]
+    step_id, steps = pl.program_id(2), pl.num_programs(2)
+    tiles = x_ref.shape[1] // tile
+
+    @pl.when(step_id == 0)
+    def _():
+        head[...] = jnp.zeros(head.shape, f32)
+        acc[...] = jnp.zeros(acc.shape, f32)
+
+    taps = [w_ref[j:j + 1, :] for j in range(K)]
+    bias = b_ref[...]
+    halo = jnp.where(step_id == steps - 1, 0.0, xb_ref[0].astype(f32)[8:])
+
+    def step(k, carry):
+        after, sums = carry
+        i = tiles - 1 - k
+        row = pl.multiple_of(i * tile, tile)
+        at = pl.ds(row, tile)
+        cur = x_ref[0, at, :].astype(f32)
+        inside = x_ref[0, pl.ds(pl.multiple_of(jnp.maximum(row - 16, 0), 16),
+                                16), :].astype(f32)[8:]
+        pre, shifted = _conv_pre(jnp.where(i == 0, halo, inside), cur, taps,
+                                 bias)
+        sig = jax.nn.sigmoid(pre)
+        dpre = dy_ref[0, at, :].astype(f32) * (sig * (1.0 + pre * (1.0 - sig)))
+        window = jnp.concatenate([dpre, after], axis=0)
+        dx = taps[K - 1] * dpre
+        for j in range(K - 2, -1, -1):      # dx[t] += tap_j dpre[t + K-1-j]
+            dx = dx + taps[j] * pltpu.roll(
+                window, tile + 8 - (K - 1 - j), 0)[:tile]
+        dx_ref[0, at, :] = dx.astype(dx_ref.dtype)
+        sums = tuple(s + _fold8(dpre * rows)
+                     for s, rows in zip(sums[:K], shifted)) \
+            + (sums[K] + _fold8(dpre),)
+        return dpre[:8], sums
+
+    first, sums = _conv_loop(
+        tiles, step, (head[...], tuple(acc[j] for j in range(K + 1))))
+    head[...] = first
+    for j in range(K + 1):
+        acc[j] = sums[j]
+
+    @pl.when(step_id == steps - 1)
+    def _():
+        for j in range(K):
+            dw_ref[0, j:j + 1, :] = jnp.sum(sums[j], axis=0, keepdims=True)
+        db_ref[0] = jnp.sum(sums[K], axis=0, keepdims=True)
+
+
+def _conv_operands(w, bias):
+    """The taps as rows ``(K, C)`` and the bias as one ``(1, C)``, float32."""
+    return (jnp.transpose(w.astype(jnp.float32)),
+            bias.astype(jnp.float32)[None, :])
+
+
+# The two programs are jitted so that a step's nine mixers (each traced
+# forward, again under its checkpoint, and backward) trace and lower each
+# kernel ONCE: 27 traces of the unrolled bodies cost a warm set-up 13 s.
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _conv_fwd_impl(x, w, bias, blocks, interpret):
+    B, S, C = x.shape
+    K = w.shape[1]
+    rows, cols = _conv_blocks(S, C, blocks)
+    block = pl.BlockSpec((1, rows, cols), lambda b, c, s: (b, s, c))
+    call = _ssd_call(
+        _conv_fwd_kernel, "ssm_conv_fwd", (B, C // cols, S // rows),
+        [block, pl.BlockSpec((K, cols), lambda b, c, s: (0, c)),
+         pl.BlockSpec((1, cols), lambda b, c, s: (0, c))],
+        block, jax.ShapeDtypeStruct(x.shape, x.dtype),
+        [((8, cols), jnp.float32)], interpret)
+    with jax.enable_x64(False):
+        return call(x, *_conv_operands(w, bias))
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _conv_bwd_impl(x, w, bias, g, blocks, interpret):
+    B, S, C = x.shape
+    K = w.shape[1]
+    rows, cols = _conv_blocks(S, C, blocks)
+    steps = S // rows
+    block = pl.BlockSpec((1, rows, cols),
+                         lambda b, c, s: (b, steps - 1 - s, c))
+    before = pl.BlockSpec(
+        (1, 16, cols), lambda b, c, s: (
+            b, jnp.maximum((steps - 1 - s) * (rows // 16) - 1, 0), c))
+    f32 = jnp.float32
+    call = _ssd_call(
+        _conv_bwd_kernel, "ssm_conv_bwd", (B, C // cols, steps),
+        [block, before, block,
+         pl.BlockSpec((K, cols), lambda b, c, s: (0, c)),
+         pl.BlockSpec((1, cols), lambda b, c, s: (0, c))],
+        [block, pl.BlockSpec((1, K, cols), lambda b, c, s: (b, 0, c)),
+         pl.BlockSpec((1, 1, cols), lambda b, c, s: (b, 0, c))],
+        [jax.ShapeDtypeStruct(x.shape, x.dtype),
+         jax.ShapeDtypeStruct((B, K, C), f32),
+         jax.ShapeDtypeStruct((B, 1, C), f32)],
+        [((8, cols), f32), ((K + 1, 8, cols), f32)], interpret)
+    with jax.enable_x64(False):
+        dx, dw, db = call(x, x, g, *_conv_operands(w, bias))
+    return (dx, jnp.transpose(jnp.sum(dw, axis=0)).astype(w.dtype),
+            jnp.sum(db, axis=(0, 1)).astype(bias.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv(x, w, bias, blocks, interpret):
+    return _conv_fwd_impl(x, w, bias, blocks, interpret)
+
+
+def _conv_vjp_fwd(x, w, bias, blocks, interpret):
+    return _conv_fwd_impl(x, w, bias, blocks, interpret), (x, w, bias)
+
+
+def _conv_vjp_bwd(blocks, interpret, res, g):
+    return _conv_bwd_impl(*res, g, blocks, interpret)
+
+
+_conv.defvjp(_conv_vjp_fwd, _conv_vjp_bwd)
+
+
+def causal_conv1d(x, w, bias, blocks=None, interpret=False):
+    """``silu(bias + sum_j w[:, j] x[t - (K - 1) + j])`` along axis 1 of ``x
+    (B, S, C)`` as kernels, forward and backward (the residuals are the
+    three operands): ``w (C, K)``, ``bias (C,)``, nothing before the
+    sequence, float32 inside whatever the storage type. ``blocks``:
+    ``(positions, columns)`` of a grid step. Returns ``(B, S, C)`` in x's
+    type; differentiable in all three."""
+    S = x.shape[1]
+    rows, _ = _conv_blocks(S, x.shape[2], blocks)
+    pad = -S % rows
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+    y = _conv(x, w, bias, blocks, interpret)
+    return y[:, :S] if pad else y

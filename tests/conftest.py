@@ -103,7 +103,7 @@ def _seed_everything():
 def chip_present_interpreted(monkeypatch):
     """A chip is "present" and the attention kernels run interpreted: the
     dispatcher in ``ops/nn.py`` decides as it does on the chip
-    (``_on_accelerator`` is its platform seam) and the seven kernel entries
+    (``_on_accelerator`` is its platform seam) and the eight kernel entries
     it calls are handed ``interpret=True``. Nothing else is patched."""
     import functools
     from mxnet_tpu.ops import nn as nn_ops
@@ -112,6 +112,6 @@ def chip_present_interpreted(monkeypatch):
     for name in ("flash_attention", "flash_attention_bshd",
                  "flash_attention_packed", "flash_attention_latent",
                  "flash_attention_eva", "flash_attention_grouped",
-                 "ssm_scan"):
+                 "ssm_scan", "causal_conv1d"):
         monkeypatch.setattr(pk, name, functools.partial(getattr(pk, name),
                                                         interpret=True))

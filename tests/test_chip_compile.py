@@ -502,13 +502,47 @@ def test_grouped_kernels_compile_at_published_widths(topo):
             whole, whole, whole).compile()
 
 
+def test_conv_kernels_compile_at_published_widths(topo, monkeypatch):
+    """Granite 4.0-H Micro's convolution (4,096 scan columns + 2 x 128 of B
+    and C, 4 taps) over 32,768 positions in bfloat16, value and gradient of
+    the op as it decides on the chip: one Mosaic call each way, no float32
+    array of the sequence's size (the XLA form's gradient writes one, and
+    four scaled copies of it: 1.71 GB of temporaries) and of temporaries
+    the output's gradient alone."""
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    def value_and_gradient(x, w, bias, g):
+        y, vjp = jax.vjp(nn_ops.causal_conv1d.fn, x, w, bias)
+        return (y,) + vjp(g)
+
+    before = nn_ops.ssm_conv_stats()
+    compiled = jax.jit(value_and_gradient).lower(
+        spec(1, 32768, 4352), spec(4352, 4), spec(4352),
+        spec(1, 32768, 4352)).compile()
+    assert nn_ops.ssm_conv_stats() == dict(before,
+                                           kernel=before["kernel"] + 1)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("ssm_conv_fwd", "ssm_conv_bwd"):
+        assert len(re.findall(r"= [^\n]*custom-call\([^\n]*%s" % kernel,
+                              text)) == 1, kernel
+    assert "f32[1,32768,4352]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
 def test_hybrid_decoder_step_names_the_kernels(topo, monkeypatch):
     """One recomputed state-space layer and the attention layer at the
     published widths, the whole tied vocabulary and 32,768 tokens, traced by
     the trainer as on the chip and compiled for one described chip: the
-    lowered step names the scan's forward twice (the layer's forward is run
-    again, and that run writes the states the one backward reads) and the
-    grouped forward once (its output and log-sum-exp are kept by name)."""
+    lowered step names the scan's forward and the convolution's twice (the
+    layer's forward is run again, and that run writes the states the one
+    backward reads) and the grouped forward once (its output and log-sum-exp
+    are kept by name). Ten layers make 57 Mosaic calls: six a recomputed
+    mixer (30 before the convolution had kernels)."""
     import mxnet_tpu as mx
     from mxnet_tpu import parallel
     from mxnet_tpu.models.hybrid_ssm import HybridDecoder
@@ -536,6 +570,7 @@ def test_hybrid_decoder_step_names_the_kernels(topo, monkeypatch):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
 
     before, scans = nn_ops.attention_dispatch_stats(), nn_ops.ssm_scan_stats()
+    convs = nn_ops.ssm_conv_stats()
     compiled = trainer._step_fn.lower(
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one),
         [spec(v) for v in trainer._values],
@@ -545,9 +580,11 @@ def test_hybrid_decoder_step_names_the_kernels(topo, monkeypatch):
     after = nn_ops.attention_dispatch_stats()
     assert after == dict(before, grouped=before["grouped"] + 1)
     assert nn_ops.ssm_scan_stats() == dict(scans, kernel=scans["kernel"] + 1)
+    assert nn_ops.ssm_conv_stats() == dict(convs, kernel=convs["kernel"] + 1)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 6
+    assert text.count("tpu_custom_call") == 9
     for kernel, times in (("ssd_scan_fwd", 2), ("ssd_scan_bwd", 1),
+                          ("ssm_conv_fwd", 2), ("ssm_conv_bwd", 1),
                           ("flash_grouped_fwd", 1), ("flash_grouped_dq", 1),
                           ("flash_grouped_dkv", 1)):
         assert len(re.findall(r"= [^\n]*custom-call\([^\n]*%s" % kernel,

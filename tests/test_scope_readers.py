@@ -447,6 +447,34 @@ def test_moe_combine_grouped_reader(counts, expected, monkeypatch):
     assert read(_obs(kind="serve")) is None
 
 
+@pytest.mark.parametrize("counts,expected", [
+    ({"kernel": 27, "xla": 0}, 100.0), ({"kernel": 0, "xla": 27}, 0.0),
+    ({"kernel": 9, "xla": 3}, 75.0), ({"kernel": 0, "xla": 0}, None),
+    (None, None)])
+def test_ssm_conv_kernel_reader(counts, expected, monkeypatch):
+    """``ssm_conv_kernel_pct.train`` over ``ssm_conv_stats()``: every
+    convolution through the kernels reads 100, the XLA form alone 0; a
+    program without the counter (the parent), or one that traced no mixer
+    (the other cells), reads None. Its index entry names the mixer's layer
+    and the one cell that builds a mixer."""
+    import json
+    if counts is None:
+        monkeypatch.delattr(nn_ops, "ssm_conv_stats")
+    else:
+        monkeypatch.setattr(nn_ops, "_SSM_CONVS", counts)
+    read = load_reader("ssm_conv_kernel_pct.train", METRIC_DIR)
+    assert read(_obs()) == expected
+    assert read(_obs(kind="serve")) is None
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entries = [m for m in json.load(f)["per_layer"]
+                   if m["name"] == "ssm_conv_kernel_pct.train"]
+    assert entries == [{
+        "name": "ssm_conv_kernel_pct.train", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "state-space mixer",
+        "moves": "train_tokens_per_s",
+        "workloads": ["granite_4_0_h_micro_train_s32768"]}]
+
+
 @pytest.mark.parametrize("path,expected", [
     ("flash_interpret", 100.0), ("xla", 0.0), ("no_counter", None),
     ("nothing_traced", None)])

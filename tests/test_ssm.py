@@ -2,9 +2,9 @@
 against its plain reference (``chipbench/configs/hybrid_ssm_ref.py``, which
 imports nothing of the program), at a small size on the CPU: loss and every
 leaf's gradient; the chunked XLA form against the position-by-position
-recurrence; the scan kernels and the grouped-query kernels (interpret mode)
-against the XLA forms, forward and every gradient; the degenerate scan; the
-tied head; the dispatcher's rows and the counters; planted faults;
+recurrence; the scan kernels, the convolution kernels and the grouped-query
+kernels (interpret mode) against the XLA forms, forward and every gradient;
+the degenerate scan; the tied head; the dispatcher's rows and the counters; planted faults;
 recomputation under the trainer."""
 import jax
 import jax.numpy as jnp
@@ -274,6 +274,148 @@ def test_the_convolution_is_causal_and_starts_from_nothing():
                 want[:, t] += np.asarray(w)[:, k] * np.asarray(x)[:, t - 3 + k]
     np.testing.assert_allclose(nn_ops.causal_conv1d.fn(x, w, bias),
                                jax.nn.silu(want), rtol=1e-5, atol=1e-5)
+
+
+CONV_CASES = {     # rows, seq, channels, taps, (positions, columns) a grid step
+    "one_block": (1, 64, 128, 4, (64, 128)),
+    "history_crosses_blocks": (1, 160, 128, 4, (32, 128)),
+    "tiles_inside_blocks": (1, 192, 128, 4, (96, 128)),
+    "unrolled_tiles": (1, 512, 128, 4, (256, 128)),
+    "two_taps": (1, 96, 128, 2, (32, 128)),
+    "eight_taps": (1, 64, 128, 8, (32, 128)),
+    "two_column_blocks": (1, 64, 256, 4, (32, 128)),
+    "batch_2": (2, 64, 128, 4, (32, 128)),
+    "padded": (2, 100, 128, 4, (64, 128)),
+}
+
+
+def _conv_operands(rows, seq, channels, taps, dtype=jnp.float32, seed=8):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(rows, seq, channels)), dtype),
+            jnp.asarray(rng.uniform(-.5, .5, (channels, taps)), dtype),
+            jnp.asarray(rng.uniform(-.5, .5, (channels,)), dtype))
+
+
+@pytest.fixture(scope="module")
+def conv_kernel_and_xla():
+    """``name -> {"y", "dx", "dw", "db", "y_bf16"}``, each a (kernel, XLA
+    form) pair, both jitted (XLA:CPU contracts a fused multiply-add the same
+    way in both), worked out once a case."""
+    done = {}
+
+    def pair(name):
+        if name in done:
+            return done[name]
+        rows, seq, channels, taps, blocks = CONV_CASES[name]
+        ops = _conv_operands(rows, seq, channels, taps)
+        g = jnp.asarray(np.random.default_rng(9).normal(size=ops[0].shape),
+                        jnp.float32)
+        kernel = lambda x, w, b: pk.causal_conv1d(x, w, b, blocks=blocks,
+                                                  interpret=True)
+
+        def both(conv):
+            def run(x, w, b):
+                y, vjp = jax.vjp(conv, x, w, b)
+                return (y,) + vjp(g.astype(y.dtype))
+            return jax.jit(run)
+
+        got, want = both(kernel)(*ops), both(nn_ops.xla_causal_conv1d)(*ops)
+        half = [o.astype(jnp.bfloat16) for o in ops]
+        done[name] = dict(zip(("y", "dx", "dw", "db"), zip(got, want)))
+        done[name]["y_bf16"] = (jax.jit(kernel)(*half),
+                                jax.jit(nn_ops.xla_causal_conv1d)(*half))
+        return done[name]
+
+    return pair
+
+
+@pytest.mark.parametrize("which", ["y", "y_bf16", "dx", "dw", "db"])
+@pytest.mark.parametrize("case", sorted(CONV_CASES))
+def test_conv_kernels_match_the_xla_form(conv_kernel_and_xla, case, which):
+    """The forward to the bit in float32 (the taps are added in the XLA
+    form's order) and within one bfloat16 ulp in bfloat16; the backward
+    kernel's three gradients, float32 sums where the XLA form adds rounded
+    terms, to 1e-5 of the gradient's norm: one position block; blocks of one
+    tile, so that the rows before and the dpre after a tile come from the
+    neighbouring grid steps; several tiles a block, one loop iteration each
+    and ``_CONV_UNROLL`` an iteration; 2, 4 and 8 taps; two
+    column blocks; two rows (the carried rows start from nought in each); a
+    sequence padded to whole blocks."""
+    got, want = conv_kernel_and_xla(case)[which]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    if which == "y":
+        np.testing.assert_array_equal(got, want)
+    elif which == "y_bf16":
+        got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+        ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
+        assert np.all(np.abs(got - want) <= ulp)
+    else:
+        assert float(jnp.linalg.norm(got - want)) <= \
+            1e-5 * float(jnp.linalg.norm(want))
+
+
+@pytest.mark.parametrize("blocks", [(64, 128), (32, 128)],
+                         ids=["one_block", "two_blocks"])
+def test_the_conv_kernel_is_causal_and_starts_from_nothing(blocks):
+    """Position t of the kernel's output reads positions t - 3 .. t and
+    nothing else (a change at position 40 moves outputs 40 .. 43 only, across
+    the blocks' edge or not), and the first three positions read nought
+    before the sequence."""
+    x, w, bias = _conv_operands(1, 64, 128, 4)
+    conv = jax.jit(lambda x: pk.causal_conv1d(x, w, bias, blocks=blocks,
+                                              interpret=True))
+    moved = np.asarray(conv(x.at[:, 30].add(1.0)) != conv(x))
+    assert moved[:, 30:34].any(axis=(0, 2)).all()
+    assert not moved[:, :30].any() and not moved[:, 34:].any()
+    want = np.zeros((1, 3, 128)) + np.asarray(bias)
+    for t in range(3):
+        for k in range(3 - t, 4):
+            want[:, t] += np.asarray(w)[:, k] * np.asarray(x)[:, t - 3 + k]
+    np.testing.assert_allclose(conv(x)[:, :3], jax.nn.silu(want), rtol=1e-5,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("shape,chip,mesh,path", [
+    ((32768, 4352, 4), True, 1, "kernel"), ((32768, 4352, 4), False, 1, "xla"),
+    ((32768, 4352, 4), True, 4, "xla"), ((32768, 4352, 4), True, None,
+                                         "kernel"),
+    ((32768, 100, 4), True, 1, "xla"), ((32768, 4352, 9), True, 1, "xla"),
+    ((1000, 256, 2), True, 1, "kernel")],
+    ids=["published", "no_chip", "mesh_of_four", "no_mesh", "100_channels",
+         "9_taps", "ragged"])
+def test_what_the_conv_kernels_take(monkeypatch, shape, chip, mesh, path):
+    from mxnet_tpu import parallel
+    monkeypatch.setattr(nn_ops, "_on_accelerator", lambda: chip)
+    before = nn_ops.ssm_conv_stats()
+    if mesh is None:
+        assert nn_ops._ssm_conv_path(*shape) == path
+    else:
+        with parallel.mesh_scope(parallel.make_mesh(
+                dp=mesh, devices=jax.devices()[:mesh]), ("dp",)):
+            assert nn_ops._ssm_conv_path(*shape) == path
+    assert nn_ops.ssm_conv_stats() == before       # deciding is pure
+
+
+def test_the_convolution_is_counted_where_it_is_decided(
+        chip_present_interpreted):
+    """On the CPU the op takes the XLA form; with a chip present the
+    kernels, unless the shapes rule them out; once a trace either way, and
+    under the ``ssm_conv`` scope."""
+    x, w, bias = _conv_operands(1, 64, 128, 4)
+    before = nn_ops.ssm_conv_stats()
+    conv = jax.jit(nn_ops.causal_conv1d.fn)
+    got = conv(x, w, bias)
+    conv(x, w, bias)                                # the trace is cached
+    assert nn_ops.ssm_conv_stats() == dict(before,
+                                           kernel=before["kernel"] + 1)
+    narrow = _conv_operands(1, 64, 100, 4)
+    nn_ops.causal_conv1d.fn(*narrow)
+    assert nn_ops.ssm_conv_stats() == {"kernel": before["kernel"] + 1,
+                                       "xla": before["xla"] + 1}
+    np.testing.assert_array_equal(
+        got, jax.jit(nn_ops.xla_causal_conv1d)(x, w, bias))
+    text = conv.lower(x, w, bias).as_text(debug_info=True)
+    assert "ssm_conv" in text
 
 
 def test_the_gated_norm_is_the_norm_of_the_gated():
