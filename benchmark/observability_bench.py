@@ -1,5 +1,6 @@
-"""Tracer-overhead benchmark: serving and step_stream paths, disabled vs.
-enabled, written to ``benchmark/OBSERVABILITY.json``.
+"""Tracer-overhead benchmark: serving, step_stream, ``ShardedTrainer.step``
+and ``NDArray.asnumpy`` paths, disabled vs. enabled, written to
+``benchmark/OBSERVABILITY.json``.
 
 Two costs matter and are measured separately:
 
@@ -116,6 +117,49 @@ def _bench_stream(trainer, steps, chunk=4):
     return steps / dt, dt / steps
 
 
+def _bench_step(trainer, steps):
+    """``ShardedTrainer.step`` in a user's loop, the loss read two steps
+    behind (``NDArray.asnumpy``), as the chip benchmark's cells drive it."""
+    rng = np.random.RandomState(0)
+    x = nd.array(rng.standard_normal((32, 16)).astype("float32"))
+    y = nd.array(rng.randint(0, 4, 32).astype("float32"))
+    pending = [trainer.step(x, y), trainer.step(x, y)]  # compile, settle
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        pending.append(trainer.step(x, y))
+        float(pending.pop(0).asnumpy())
+    for p in pending:
+        float(p.asnumpy())
+    dt = time.perf_counter() - t0
+    return steps / dt, dt / steps
+
+
+def _measure_asnumpy_ns(iters):
+    """Per-call time of ``NDArray.asnumpy`` on small ready values, each
+    read once as a loop reads its losses (a second read of one array
+    returns the host copy jax keeps), and of the flag test it makes first
+    with the tracer off: one attribute chain and a compare, the empty
+    loop's time taken off."""
+    iters = min(iters, 20000)
+    arrays = [nd.array(np.full((4,), i, "float32")) for i in range(iters)]
+    nd.waitall()
+    t0 = time.perf_counter()
+    for arr in arrays:
+        arr.asnumpy()
+    call_ns = (time.perf_counter() - t0) / iters * 1e9
+    tracer = tr.tracer
+    t0 = time.perf_counter()
+    for arr in arrays:
+        if tracer._enabled:
+            raise AssertionError("the tracer is on")
+    flagged = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    for arr in arrays:
+        pass
+    empty = time.perf_counter() - t0
+    return call_ns, max(0.0, flagged - empty) / iters * 1e9
+
+
 def _tracer_calls_per_op(ops):
     """Spans+instants recorded per operation during an enabled run — the
     multiplier for the disabled-path cost model."""
@@ -179,6 +223,35 @@ def run(quick=False):
         "tracer_calls_per_step": calls_per_step,
         "disabled_overhead_pct": disabled_pct_s,
     }
+    # ---- ShardedTrainer.step + NDArray.asnumpy (PR 35) --------------------
+    # the step's three no-op span() calls and asnumpy's flag test, by the
+    # same model: calls counted from an enabled run (the wait span among
+    # them) times the disabled call's cost, over the step's measured time
+    trainer = _stream_setup()
+    sps_off, per_step_off = _bench_step(trainer, steps)
+    tr.enable()
+    tr.clear()
+    sps_on, per_step_on = _bench_step(trainer, steps)
+    calls_per_step = _tracer_calls_per_op(steps)
+    tr.disable()
+    tr.clear()
+    out["trainer_step"] = {
+        "steps": steps,
+        "steps_per_s_disabled": sps_off,
+        "steps_per_s_enabled": sps_on,
+        "enabled_overhead_pct": (per_step_on - per_step_off)
+        / per_step_off * 100.0,
+        "tracer_calls_per_step": calls_per_step,
+        "disabled_overhead_pct": (disabled_ns * 1e-9 * calls_per_step
+                                  / per_step_off * 100.0),
+    }
+    asnumpy_ns, flag_ns = _measure_asnumpy_ns(micro_iters)
+    out["asnumpy"] = {
+        "ns_per_call_disabled": asnumpy_ns,
+        "flag_test_ns": flag_ns,
+        "disabled_overhead_pct": flag_ns / asnumpy_ns * 100.0,
+    }
+
     out["note"] = ("enabled_overhead_pct is signed: negative means the "
                    "enabled run beat the disabled one, i.e. the "
                    "measurement is warmup/noise-dominated on this "
@@ -212,8 +285,8 @@ def run(quick=False):
         "attribution fast path costs %.3f%% of a serving request — "
         "over the 1%% dispatch-overhead budget" % attr_pct)
 
-    worst = max(out["serving"]["disabled_overhead_pct"],
-                out["step_stream"]["disabled_overhead_pct"])
+    worst = max(out[path]["disabled_overhead_pct"] for path in
+                ("serving", "step_stream", "trainer_step", "asnumpy"))
     out["disabled_overhead_worst_pct"] = worst
     out["pass"] = worst < 2.0 and attr_pct < 1.0
     assert worst < 2.0, (

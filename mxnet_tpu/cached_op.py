@@ -394,10 +394,14 @@ class CachedOp:
         bucket = args[0].shape[0] if args and args[0].shape else None
         compiled_now = entry is None
         if entry is None:
-            # compile outside the lock (see __init__); the span makes XLA
-            # compiles first-class timeline citizens, labeled with the
-            # shape bucket (leading dim of the first input) that triggered
-            # them — the classic "why was THIS request 2s?" answer
+            # compile outside the lock (see __init__). The span covers
+            # the forcing trace and the lowering, labeled with the shape
+            # bucket (leading dim of the first input) that triggered them;
+            # the BACKEND compile is paid by the first dispatch below, and
+            # the bridged ``jax.compile`` event (pcache.py) shows it where
+            # it really is — together the "why was THIS request 2s?"
+            # answer. (t_c0 stays a hand-kept pair: the flight recorder
+            # takes the wall with the tracer off.)
             t_c0 = time.perf_counter()
             shards = tuple(_active_sharding(a._data) for a in args)
             if not any(s is not None for s in shards):

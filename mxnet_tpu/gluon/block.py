@@ -25,12 +25,14 @@ from jax.core import Tracer as _Tracer
 from ..base import PROGRAM_SCOPES
 from ..context import current_context
 from ..ndarray.ndarray import NDArray
+from ..observability import tracer as _trace
 from .parameter import (DeferredInitializationError, Parameter,
                         ParameterDict, swapped_in)
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "recomputed"]
 
 _naming = threading.local()
+_initializing = threading.local()   # .on: inside Block.initialize
 
 
 class _BlockScope:
@@ -224,7 +226,18 @@ class Block:
         from .. import initializer as init_mod
         if init is None:
             init = init_mod.Uniform()
-        self.collect_params().initialize(init, ctx, verbose, force_reinit)
+        params = self.collect_params()
+        if not _trace.tracer._enabled or getattr(_initializing, "on", False):
+            params.initialize(init, ctx, verbose, force_reinit)
+            return
+        # one span for the outermost call: a subclass that initializes its
+        # children one by one stays one span
+        _initializing.on = True
+        try:
+            with _trace.span("block.initialize", params=len(params)):
+                params.initialize(init, ctx, verbose, force_reinit)
+        finally:
+            _initializing.on = False
 
     def hybridize(self, active=True, **kwargs):
         for child in self._children.values():

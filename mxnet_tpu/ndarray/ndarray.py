@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from ..base import dtype_np, numeric_types, integer_types
 from ..context import Context, current_context
+from ..observability import tracer as _trace
 from .. import _tape
 
 __all__ = ["NDArray", "array", "zeros", "ones", "full", "empty", "arange",
@@ -36,6 +37,17 @@ __all__ = ["NDArray", "array", "zeros", "ones", "full", "empty", "arange",
 
 def _is_tracer(v):
     return isinstance(v, jax.core.Tracer)
+
+
+def _wait_span(data):
+    """``ndarray.wait`` around a read that is about to block the host on
+    the device: only with the tracer on, for a concrete ``jax.Array`` that
+    is not ready yet. A ready value, a tracer or a host array records
+    nothing, so eager code does not flood the ring."""
+    if _trace.tracer._enabled and isinstance(data, jax.Array) \
+            and not _is_tracer(data) and not data.is_ready():
+        return _trace.span("ndarray.wait", bytes=data.nbytes)
+    return _trace._NULL_SPAN
 
 
 class NDArray:
@@ -110,6 +122,9 @@ class NDArray:
     # ---- host interop -----------------------------------------------------
     def asnumpy(self) -> _np.ndarray:
         """Blocking copy to host (reference NDArray::SyncCopyToCPU)."""
+        if _trace.tracer._enabled:      # off: this one test and no more
+            with _wait_span(self._data):
+                return _np.asarray(self._data)
         return _np.asarray(self._data)
 
     def asscalar(self):
@@ -140,7 +155,8 @@ class NDArray:
 
     def wait_to_read(self):
         if not _is_tracer(self._data):
-            jax.block_until_ready(self._data)
+            with _wait_span(self._data):
+                jax.block_until_ready(self._data)
 
     wait_to_write = wait_to_read
 
@@ -603,7 +619,9 @@ to_dlpack_for_write = to_dlpack_for_read
 
 def waitall():
     """Parity with mx.nd.waitall (Engine::WaitForAll)."""
-    (jax.device_put(0.0) + 0).block_until_ready()
+    last = jax.device_put(0.0) + 0
+    with _wait_span(last):
+        last.block_until_ready()
 
 
 # ---- serialization (reference NDArray::Save/Load, mx.nd.save/load) --------

@@ -27,6 +27,14 @@ attribute marks a step, as ``StepTraceAnnotation`` would). With no profile
 running that costs under a microsecond; :meth:`Tracer.complete` records
 after the fact and has nothing to bridge.
 
+Self time: the per-name aggregate (:meth:`Tracer.phase_stats`) keeps,
+beside the inclusive total, each name's *self* time: a span's duration
+less what its children on the same thread covered. A live span adds its
+duration to the span that encloses it on its thread when it exits; a
+``complete()``d event charges its parent where that is the span open on
+the calling thread. Self times of all names on one thread are disjoint,
+so their sum is the time that thread spent under at least one span.
+
 Knobs: ``MXNET_TRACE_ENABLE`` (record from import), ``MXNET_TRACE_BUFFER``
 (ring capacity in events, default 65536).
 """
@@ -105,7 +113,7 @@ class _Span:
     span stack; ``__exit__`` records one "X" event."""
 
     __slots__ = ("_tr", "name", "_attrs", "_parent", "_t0", "ctx",
-                 "_pushed", "_cancelled", "_ann")
+                 "_pushed", "_cancelled", "_ann", "_child_s")
 
     def __init__(self, tr, name, parent, attrs):
         self._tr = tr
@@ -117,6 +125,7 @@ class _Span:
         self._pushed = False
         self._cancelled = False
         self._ann = None
+        self._child_s = 0.0     # what recorded children on this thread took
 
     def set(self, **attrs):
         """Attach attributes after entry (e.g. a count known only later)."""
@@ -136,13 +145,13 @@ class _Span:
         stack = tr._stack()
         parent = self._parent
         if parent is None:
-            parent = stack[-1] if stack else getattr(tr._tls, "ambient",
-                                                     None)
+            parent = stack[-1].ctx if stack else getattr(
+                tr._tls, "ambient", None)
             self._parent = parent
         sid = next(tr._ids)
         self.ctx = SpanContext(parent.trace_id if parent is not None
                                else sid, sid)
-        stack.append(self.ctx)
+        stack.append(self)
         self._pushed = True
         attrs = self._attrs
         self._ann = _TraceAnnotation(
@@ -158,13 +167,13 @@ class _Span:
         if self._ann is not None:
             self._ann.__exit__(*exc)
             self._ann = None
+        stack = tr._stack()
         if self._pushed:
-            stack = tr._stack()
-            if stack and stack[-1] is self.ctx:
+            if stack and stack[-1] is self:
                 stack.pop()
             else:  # exits raced out of order (shouldn't happen; be safe)
                 try:
-                    stack.remove(self.ctx)
+                    stack.remove(self)
                 except ValueError:
                     pass
             self._pushed = False
@@ -173,13 +182,16 @@ class _Span:
         parent = self._parent
         th = threading.current_thread()
         dur = t1 - self._t0
+        if stack:   # the span that encloses this one on its thread
+            stack[-1]._child_s += dur
         tr._append(("X", self.name, self._t0, dur,
                     threading.get_ident(), th.name, self.ctx.span_id,
                     parent.span_id if parent is not None else 0,
                     self.ctx.trace_id, self._attrs or None))
         kept = tr._observe(self.name, dur, self.ctx.trace_id,
                            parent is None, self._attrs)
-        tr._phase_add(self.name, dur, trace_id=self.ctx.trace_id, kept=kept)
+        tr._phase_add(self.name, dur, max(0.0, dur - self._child_s),
+                      trace_id=self.ctx.trace_id, kept=kept)
         return False
 
 
@@ -216,7 +228,8 @@ class Tracer:
         self._ids = itertools.count(1)
         self._tls = threading.local()
         self._stat_lock = threading.Lock()
-        self._phase = {}  # name -> [count, total_s, max_s, [bucket counts]]
+        # name -> [count, total_s, max_s, [bucket counts], self_s]
+        self._phase = {}
         # name -> {bucket index: (trace_id, value_ms, kept)} — one exemplar
         # per histogram bucket, preferring traces the tail sampler KEPT so
         # the Prometheus exposition links a bad bucket to a readable trace
@@ -325,7 +338,7 @@ class Tracer:
         (or the attached ambient context), else None."""
         stack = getattr(self._tls, "stack", None)
         if stack:
-            return stack[-1]
+            return stack[-1].ctx
         return getattr(self._tls, "ambient", None)
 
     def attach(self, ctx):
@@ -342,24 +355,36 @@ class Tracer:
         return _Span(self, name, parent, attrs)
 
     def complete(self, name, t0, t1, parent=None, tid=None, tname=None,
-                 **attrs):
+                 nested=False, **attrs):
         """Record an already-elapsed span from explicit ``time.monotonic``
         timestamps — for waits measured after the fact (queue wait observed
-        by the worker that popped the request). Returns the new span's
-        context, or None when disabled."""
+        by the worker that popped the request). Its duration is its self
+        time, and is taken off ``parent``'s where that is the span open on
+        the calling thread. ``nested``: the interval lies inside another
+        completed event that counts it already (a jit traced inside
+        another's trace): recorded with ``nested=True``, no self time, and
+        nothing charged. Returns the new span's context, or None when
+        disabled."""
         if not self._enabled:
             return None
         sid = next(self._ids)
         trace_id = parent.trace_id if parent is not None else sid
+        dur = max(0.0, t1 - t0)
+        if nested:
+            attrs["nested"] = True
+        elif tid is None and parent is not None:
+            stack = getattr(self._tls, "stack", None)
+            if stack and stack[-1].ctx is parent:
+                stack[-1]._child_s += dur
         if tid is None:
             th = threading.current_thread()
             tid, tname = threading.get_ident(), th.name
-        dur = max(0.0, t1 - t0)
         self._append(("X", name, t0, dur, tid, tname or "", sid,
                       parent.span_id if parent is not None else 0,
                       trace_id, attrs or None))
         kept = self._observe(name, dur, trace_id, parent is None, attrs)
-        self._phase_add(name, dur, trace_id=trace_id, kept=kept)
+        self._phase_add(name, dur, 0.0 if nested else dur,
+                        trace_id=trace_id, kept=kept)
         return SpanContext(trace_id, sid)
 
     def instant(self, name, parent=None, **attrs):
@@ -394,14 +419,15 @@ class Tracer:
         return len(self._buf)
 
     # ---- per-phase aggregate (the /metrics histogram surface) -------------
-    def _phase_add(self, name, dur_s, trace_id=None, kept=False):
+    def _phase_add(self, name, dur_s, self_s, trace_id=None, kept=False):
         with self._stat_lock:
             ent = self._phase.get(name)
             if ent is None:
                 ent = self._phase[name] = [0, 0.0, 0.0,
-                                           [0] * (len(_BOUNDS_MS) + 1)]
+                                           [0] * (len(_BOUNDS_MS) + 1), 0.0]
             ent[0] += 1
             ent[1] += dur_s
+            ent[4] += self_s
             if dur_s > ent[2]:
                 ent[2] = dur_s
             idx = bisect.bisect_left(_BOUNDS_MS, dur_s * 1e3)
@@ -433,17 +459,21 @@ class Tracer:
 
     def phase_stats(self):
         """Per-span-name latency aggregates derived from the trace stream:
-        ``{name: {count, total_ms, mean_ms, max_ms, buckets_ms}}`` —
-        maintained incrementally as spans complete, so it reflects every
-        span ever recorded (not just those still in the ring)."""
+        ``{name: {count, total_ms, self_ms, mean_ms, max_ms, buckets_ms}}``
+        — maintained incrementally as spans complete, so it reflects every
+        span ever recorded (not just those still in the ring, and across
+        :meth:`clear`). ``total_ms`` is inclusive; ``self_ms`` leaves out
+        what a span's children on its thread covered, so it adds up over
+        nested names."""
         with self._stat_lock:
-            items = {k: (v[0], v[1], v[2], list(v[3]))
+            items = {k: (v[0], v[1], v[2], list(v[3]), v[4])
                      for k, v in self._phase.items()}
         out = {}
-        for name, (count, total_s, max_s, buckets) in items.items():
+        for name, (count, total_s, max_s, buckets, self_s) in items.items():
             out[name] = {
                 "count": count,
                 "total_ms": total_s * 1e3,
+                "self_ms": self_s * 1e3,
                 "mean_ms": (total_s / count * 1e3) if count else 0.0,
                 "max_ms": max_s * 1e3,
                 "buckets_ms": dict(zip(_BUCKET_LABELS, buckets)),
@@ -481,8 +511,9 @@ def counter(name, **values):
         tracer.counter(name, **values)
 
 
-def complete(name, t0, t1, parent=None, **attrs):
-    return tracer.complete(name, t0, t1, parent=parent, **attrs)
+def complete(name, t0, t1, parent=None, nested=False, **attrs):
+    return tracer.complete(name, t0, t1, parent=parent, nested=nested,
+                           **attrs)
 
 
 def attach(ctx):

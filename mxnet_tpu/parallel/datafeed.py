@@ -322,7 +322,8 @@ class DeviceFeed:
         try:
             item = self._ring.get_nowait()
         except queue.Empty:
-            t0 = time.perf_counter()
+            # one pair on the tracer's clock serves the wait counter
+            # (kept with the tracer off) and the span below
             wait_t0 = _trace.now()
             try:
                 item = self._ring.get(timeout=self._timeout)
@@ -330,7 +331,7 @@ class DeviceFeed:
                 raise RuntimeError(
                     "DeviceFeed(%s): stager produced nothing for %.0fs — "
                     "wedged source?" % (self.name, self._timeout))
-            waited = time.perf_counter() - t0
+            waited = _trace.now() - wait_t0
         if item is _END:
             self._finish_epoch()
             raise StopIteration

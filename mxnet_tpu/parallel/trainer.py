@@ -11,6 +11,7 @@ optimizer update, all fused, with parameter buffers donated in place.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 
 import numpy as np
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
+from .. import pcache as _pcache
 from .. import random as _random
 from ..ndarray.ndarray import NDArray
 from ..observability import tracer as _trace
@@ -38,6 +40,29 @@ def _owned_on(v, device):
     when source and target share a device, and handing out a buffer that the
     trainer's donated step also holds would let the donation delete it."""
     return jnp.array(jax.device_put(v, device), copy=True)
+
+
+def _note_bytes(sp, *arrays):
+    """The ``bytes`` of a span that placed ``arrays`` (a recording one)."""
+    if sp.ctx is not None:
+        sp.set(bytes=sum(a.nbytes for a in arrays))
+
+
+@contextlib.contextmanager
+def _launching():
+    compiled = _pcache.stats()["compile_s"]
+    with _trace.span("trainer.launch") as sp:
+        yield
+        sp.set(first=_pcache.stats()["compile_s"] != compiled)
+
+
+def _launch_span():
+    """``trainer.launch``: the call of the compiled step. ``first`` says
+    that the call compiled (jax reported a backend compile across it):
+    that one holds the trace, the lowering and the compile or cache load,
+    which the ``jax.*`` events inside it name; a steady one is the
+    runtime's enqueue."""
+    return _launching() if _trace.tracer._enabled else _trace._NULL_SPAN
 
 
 class ShardedTrainer:
@@ -79,6 +104,17 @@ class ShardedTrainer:
         checkpoints record the plan, and multi-axis placements get
         their fused-step result waits bounded by the collective
         watchdog."""
+        # the part of set-up that grows with the model and the mesh:
+        # functionalize, shard, the per-parameter copy / cast / placement
+        with _trace.span("trainer.build") as sp:
+            self._build(block, loss_fn, optimizer, optimizer_params, mesh,
+                        param_rules, batch_axes, dtype, preprocess, plan)
+            _note_bytes(sp, *self._values,
+                        *(x for state in self._states for x in state))
+            sp.set(params=len(self._params), devices=self._mesh.devices.size)
+
+    def _build(self, block, loss_fn, optimizer, optimizer_params, mesh,
+               param_rules, batch_axes, dtype, preprocess, plan):
         self._block = block
         self._loss = loss_fn
         self._preprocess = preprocess
@@ -244,7 +280,9 @@ class ShardedTrainer:
         _chaos.point("trainer.step")
         if self._step_fn is None:
             self._build_step()
-        xs, y = self._place_batch(data, label)
+        with _trace.span("trainer.place") as sp:
+            xs, y = self._place_batch(data, label)
+            _note_bytes(sp, y, *xs)
         # numerical-fault injection on the step INPUT path (chaos kind
         # "nan"): models a corrupt batch reaching the compiled step. The
         # unguarded trainer will absorb the poison into its parameters —
@@ -256,9 +294,10 @@ class ShardedTrainer:
             xs, y = poison_nonfinite(xs, y)
         self._t += 1
         key = _random.next_key()
-        loss_val, self._values, self._states, aux = self._step_fn(
-            key, self._values, self._states, self._t,
-            lr if lr is not None else self._lr, *xs, y)
+        with _launch_span():
+            loss_val, self._values, self._states, aux = self._step_fn(
+                key, self._values, self._states, self._t,
+                lr if lr is not None else self._lr, *xs, y)
         self._await_plan((loss_val, self._values, self._states))
         # functional aux-state writeback (BatchNorm moving stats)
         for h, v in zip(self._pure.aux_handles, aux):
@@ -317,10 +356,13 @@ class ShardedTrainer:
                 "models or a single (n_steps, batch, ...) array — a list "
                 "is ambiguous")
         data_list = data if isinstance(data, tuple) else (data,)
-        xs, ys = self._place_span(
-            tuple(x._data if isinstance(x, NDArray) else jnp.asarray(x)
-                  for x in data_list),
-            label._data if isinstance(label, NDArray) else jnp.asarray(label))
+        with _trace.span("trainer.place") as sp:
+            xs, ys = self._place_span(
+                tuple(x._data if isinstance(x, NDArray) else jnp.asarray(x)
+                      for x in data_list),
+                label._data if isinstance(label, NDArray)
+                else jnp.asarray(label))
+            _note_bytes(sp, ys, *xs)
         n_steps = xs[0].shape[0]
         # same input-path injection as step(): one fire poisons the whole
         # staged span (this call IS one input staging)
@@ -329,9 +371,10 @@ class ShardedTrainer:
             xs, ys = poison_nonfinite(xs, ys)
         key = _random.next_key()
         # t is 1-based inside updates (matches step(): first call sees t=1)
-        losses, self._values, self._states = self._step_many_fn(
-            key, self._values, self._states, self._t + 1,
-            lr if lr is not None else self._lr, *xs, ys)
+        with _launch_span():
+            losses, self._values, self._states = self._step_many_fn(
+                key, self._values, self._states, self._t + 1,
+                lr if lr is not None else self._lr, *xs, ys)
         # _t commits WITH the values (the dispatch already consumed the
         # donated state): a CollectiveTimeout out of the guarded wait
         # below must leave counter and params consistent for the
@@ -471,14 +514,19 @@ class ShardedTrainer:
                         ys_list.append(y)
                     n = len(xs_list)
                     sp.set(steps=n)
-                    xs, ys = self._stack_span(xs_list, ys_list)
+                    with _trace.span("trainer.place") as place:
+                        xs, ys = self._stack_span(xs_list, ys_list)
+                        _note_bytes(place, ys, *xs)
                     if _chaos.poisoned("trainer.grads"):
                         from ..resilience.guardrails import poison_nonfinite
                         xs, ys = poison_nonfinite(xs, ys)
                     key = _random.next_key()
-                    losses, self._values, self._states = self._step_many_fn(
-                        key, self._values, self._states, self._t + 1,
-                        lr if lr is not None else self._lr, *xs, ys)
+                    with _launch_span():
+                        losses, self._values, self._states = \
+                            self._step_many_fn(
+                                key, self._values, self._states,
+                                self._t + 1,
+                                lr if lr is not None else self._lr, *xs, ys)
                     # counter commits with the values (see step_many)
                     self._t += n
                     self._await_plan((losses, self._values, self._states))

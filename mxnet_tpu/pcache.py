@@ -27,7 +27,12 @@ Tunables (``config.py``):
 idempotent; it also registers a ``jax.monitoring`` listener so disk hits
 and misses are counted process-wide and exported as
 ``cachedop.pcache.*`` profiler rows and ``mxtpu_pcache_*`` Prometheus
-families. The AOT fallback counters (layer 2, ``cached_op.py`` /
+families, and a duration listener that bridges jax's own trace / lower /
+backend-compile / cache-load durations, for every program whoever
+compiled it, into the counters ``trace_s`` / ``lower_s`` / ``compile_s`` /
+``load_s``, the per-program table :func:`programs` and, where the tracer
+is on, ``jax.trace`` / ``jax.lower`` / ``jax.compile`` / ``pcache.load``
+events on its time line. The AOT fallback counters (layer 2, ``cached_op.py`` /
 ``serving/engine.py``) live here too so every cold-start surface reads
 from one ledger.
 """
@@ -38,8 +43,10 @@ import threading
 import time
 import warnings
 
+from .observability import tracer as _trace
+
 __all__ = ["init", "init_from_env", "default_dir", "enabled", "cache_dir",
-           "stats",
+           "stats", "programs",
            "reset_stats", "note_aot_load", "note_aot_fallback",
            "sweep_ttl"]
 
@@ -54,6 +61,12 @@ _counters = {
     "aot_loads": 0,        # executables installed from AOT artifacts
     "aot_fallbacks": 0,    # AOT loads refused (fingerprint/corrupt) ->
                            # normal compile path taken instead
+    # jax's own durations since process start, in seconds; a phase that ran
+    # inside another (a jit traced inside another's trace) counts once
+    "trace_s": 0.0,        # tracing functions to jaxprs
+    "lower_s": 0.0,        # jaxpr -> MLIR module
+    "compile_s": 0.0,      # backend compile, cache loads included
+    "load_s": 0.0,         # of compile_s: reading the persistent cache
 }
 _fallback_warned = False
 
@@ -62,6 +75,27 @@ _EVENT_MAP = {
     "/jax/compilation_cache/cache_misses": "disk_misses",
     "/jax/compilation_cache/compile_requests_use_cache": "requests",
 }
+
+
+# jax's phases of making a program: event -> (column of _PROGRAM_KEYS,
+# span name). jax announces a phase's start (``record_scalar``) and, at
+# its end, its duration, both with ``fun_name=``
+_PROGRAM_KEYS = ("trace_s", "lower_s", "compile_s", "load_s")
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": (0, "jax.trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": (1, "jax.lower"),
+    _COMPILE_EVENT: (2, "jax.compile"),
+}
+# a persistent-cache hit, reported without a name inside the
+# backend-compile interval of the program it loaded
+_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# a jitted jnp function traced inside another's trace reports a trace of
+# its own, tens of thousands a step program: all go to ``programs()``, the
+# time line takes the nested ones from this length on
+_NESTED_SPAN_FLOOR_S = 1e-3
+_programs = {}              # name -> [seconds x 4, events x 4]
+_open = threading.local()   # .phases: [[event, name, nested, load_s]]
 
 
 def _cfg(name):
@@ -76,11 +110,70 @@ def _on_jax_event(event, **kwargs):
             _counters[key] += 1
 
 
+def _program_name(fun_name):
+    name = str(fun_name)
+    if name.startswith("jit(") and name.endswith(")"):
+        return name[4:-1]       # lowering and compiling say jit(f)
+    return name
+
+
+def _on_jax_phase_start(event, value, fun_name=None, **kwargs):
+    if event in _PHASES:
+        phases = getattr(_open, "phases", None)
+        if phases is None:
+            phases = _open.phases = []
+        phases.append([event, _program_name(fun_name), bool(phases), 0.0])
+
+
+def _on_jax_duration(event, duration, fun_name=None, **kwargs):
+    """A phase ended ``duration`` seconds after it began: count it and,
+    where the tracer is on, put it on the time line under whatever span
+    is open. A phase that began inside another (a jit traced inside
+    another's trace) is ``nested``: it adds to :func:`programs` alone, so
+    the flat counters, like the spans' self times, are the union."""
+    phases = getattr(_open, "phases", None)
+    if event == _LOAD_EVENT:
+        if phases and phases[-1][0] == _COMPILE_EVENT:
+            # the open compile's: it counts these seconds when it ends
+            phases[-1][3] += duration
+            _to_time_line("pcache.load", duration, phases[-1][1], True)
+        return
+    if event not in _PHASES:
+        return
+    i, span = _PHASES[event]
+    name, nested, loaded = _program_name(fun_name), False, 0.0
+    if phases and phases[-1][0] == event:
+        _, name, nested, loaded = phases.pop()
+    with _lock:
+        if not nested:
+            _counters[_PROGRAM_KEYS[i]] += duration
+            _counters["load_s"] += loaded
+        row = _programs.get(name)
+        if row is None:
+            row = _programs[name] = [0.0] * 4 + [0] * 4
+        row[i] += duration
+        row[4 + i] += 1
+        if loaded:
+            row[3] += loaded
+            row[7] += 1
+    _to_time_line(span, duration, name, nested)
+
+
+def _to_time_line(span, duration, name, nested):
+    if _trace.tracer._enabled and (
+            not nested or duration >= _NESTED_SPAN_FLOOR_S):
+        t1 = _trace.now()   # the listener runs as the phase ends
+        _trace.complete(span, t1 - duration, t1, parent=_trace.current(),
+                        nested=nested, fun=name)
+
+
 def _register_listener():
     if _state["listener_registered"]:
         return
     import jax.monitoring
     jax.monitoring.register_event_listener(_on_jax_event)
+    jax.monitoring.register_scalar_listener(_on_jax_phase_start)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
     _state["listener_registered"] = True
 
 
@@ -251,7 +344,9 @@ def note_aot_fallback(reason, where="aot", warn=True):
 
 def stats():
     """Snapshot: ``{"enabled", "dir", "disk_hits", "disk_misses",
-    "requests", "ttl_evictions", "aot_loads", "aot_fallbacks"}``."""
+    "requests", "ttl_evictions", "aot_loads", "aot_fallbacks", "trace_s",
+    "lower_s", "compile_s", "load_s"}``: a flat dict of numbers beside
+    the two of the state."""
     with _lock:
         out = dict(_counters)
     out["enabled"] = _state["enabled"]
@@ -259,12 +354,28 @@ def stats():
     return out
 
 
+def programs():
+    """``{program: {"trace_s", "lower_s", "compile_s", "load_s",
+    "count"}}`` by jax's own name of the function (``step``, not
+    ``jit(step)``), since process start: the seconds each spent being
+    traced, lowered, compiled (loads included) and loaded, nested traces
+    included, and ``count``, the most events one of its phases reported
+    (a trace that jax answers from its own cache reports an event of 0 s
+    too, so a step whose program is asked for twice reads 2)."""
+    with _lock:
+        rows = {name: list(row) for name, row in _programs.items()}
+    return {name: dict(zip(_PROGRAM_KEYS, row[:4]), count=max(row[4:]))
+            for name, row in rows.items()}
+
+
 def reset_stats():
-    """Zero the counters (tests); the enabled/dir state is untouched."""
+    """Zero the counters and the per-program table (tests); the
+    enabled/dir state is untouched."""
     global _fallback_warned
     with _lock:
-        for k in _counters:
-            _counters[k] = 0
+        for k, v in _counters.items():
+            _counters[k] = type(v)()
+        _programs.clear()
         _fallback_warned = False
 
 

@@ -39,6 +39,11 @@ COMPUTE_PREFIXES = ("trainer.",)
 STAGE_WAIT_NAMES = ("datafeed.consumer_wait",)
 QUEUE_WAIT_NAMES = ("serving.queue_wait",)
 COMPILE_NAMES = ("cachedop.compile",)
+# jax's own phases, bridged by pcache.py: name -> column of the set-up block
+JAX_PHASES = {"jax.trace": "trace_ms", "jax.lower": "lower_ms",
+              "jax.compile": "compile_ms", "pcache.load": "load_ms"}
+BUILD_NAMES = ("trainer.build", "block.initialize")
+HOST_WAIT = "ndarray.wait"
 SERVING_ROOT = "serving.http"
 SCHEDULER_ITERATION = "generation.iteration"
 SCHEDULER_EMIT = "generation.emit"
@@ -108,7 +113,9 @@ def exclusive_durations(spans):
     for ev in spans:
         args = ev.get("args") or {}
         parent = args.get("parent_id")
-        if not parent or parent not in by_span_id:
+        # a nested event (a jit traced inside another's trace, a cache
+        # load inside its compile) lies inside a sibling that counts it
+        if not parent or parent not in by_span_id or args.get("nested"):
             continue
         par = by_span_id[parent]
         # clamp the child's contribution to the parent's interval:
@@ -120,10 +127,35 @@ def exclusive_durations(spans):
         child_us[parent] += overlap
     out = {}
     for ev in spans:
-        sid = (ev.get("args") or {}).get("span_id")
+        args = ev.get("args") or {}
+        sid = args.get("span_id")
         covered = child_us.get(sid, 0.0) if sid is not None else 0.0
-        out[id(ev)] = max(0.0, ev["dur"] - covered)
+        out[id(ev)] = 0.0 if args.get("nested") \
+            else max(0.0, ev["dur"] - covered)
     return out
+
+
+def setup_block(spans):
+    """What jax spent making programs, by program, from the bridged
+    ``jax.*`` events (``args.fun``): ``{"programs": {fun: {trace_ms,
+    lower_ms, compile_ms, load_ms}}, "totals": {...}}``. A trace nested in
+    another's adds to its program's row and not to the totals; a load lies
+    inside its compile, so the totals' ``compile_ms`` leaves it out."""
+    programs = defaultdict(lambda: dict.fromkeys(JAX_PHASES.values(), 0.0))
+    totals = dict.fromkeys(JAX_PHASES.values(), 0.0)
+    for ev in spans:
+        column = JAX_PHASES.get(ev["name"])
+        if column is None:
+            continue
+        args = ev.get("args") or {}
+        ms = ev["dur"] / 1e3
+        programs[args.get("fun", "?")][column] += ms
+        if column == "load_ms":
+            totals["load_ms"] += ms
+            totals["compile_ms"] -= ms
+        elif not args.get("nested"):
+            totals[column] += ms
+    return {"programs": dict(programs), "totals": totals}
 
 
 def summarize(events, top=10, kept=None):
@@ -186,8 +218,14 @@ def summarize(events, top=10, kept=None):
     # compute sum already has the wait subtracted out — dividing by it
     # would double-penalize the wait and clamp efficiency to 0 whenever
     # waits exceed half the chunk)
-    compute_incl_ms = total_ms(lambda n: n.startswith(COMPUTE_PREFIXES),
-                               exclusive=False)
+    # ... counted once: trainer.place / .launch nest inside trainer.step,
+    # so only the trainer spans that no other trainer span holds add up
+    trainer_ids = {(ev.get("args") or {}).get("span_id") for ev in spans
+                   if ev["name"].startswith(COMPUTE_PREFIXES)} - {None}
+    compute_incl_ms = sum(
+        ev["dur"] for ev in spans
+        if ev["name"].startswith(COMPUTE_PREFIXES)
+        and (ev.get("args") or {}).get("parent_id") not in trainer_ids) / 1e3
     if compute_incl_ms > 0:
         overlap_efficiency = max(0.0,
                                  1.0 - stage_wait_ms / compute_incl_ms)
@@ -226,6 +264,11 @@ def summarize(events, top=10, kept=None):
     for ev in instants:
         instant_counts[ev["name"]] += 1
 
+    setup = setup_block(spans)
+    setup["build_ms"] = {n: names[n]["total_ms"] for n in BUILD_NAMES
+                         if n in names}
+    wait = names.get(HOST_WAIT)
+
     return {
         "spans": len(spans),
         "instants": len(instants),
@@ -244,6 +287,10 @@ def summarize(events, top=10, kept=None):
             "basis": "exclusive",
         },
         "overlap_efficiency": overlap_efficiency,
+        "setup": setup,
+        "waits": {"count": wait["count"] if wait else 0,
+                  "total_ms": wait["total_ms"] if wait else 0.0,
+                  "max_ms": wait["max_ms"] if wait else 0.0},
         "by_name": names,
         "instant_counts": dict(instant_counts),
         "top_spans": top_spans,
@@ -282,6 +329,32 @@ def format_summary(summary):
     if summary["overlap_efficiency"] is not None:
         lines.append("  staging overlap efficiency: %.1f%%"
                      % (summary["overlap_efficiency"] * 100.0))
+    setup = summary.get("setup")
+    if setup and (setup["programs"] or setup["build_ms"]):
+        tot = setup["totals"]
+        lines.append("")
+        lines.append("Set-up (jax's own phases; compile leaves the cache "
+                     "loads out):")
+        lines.append("  %-28s trace %.1f  lower %.1f  compile %.1f  "
+                     "load %.1f ms" % ("all programs", tot["trace_ms"],
+                                       tot["lower_ms"], tot["compile_ms"],
+                                       tot["load_ms"]))
+        rows = sorted(setup["programs"].items(),
+                      key=lambda kv: -sum(kv[1].values()))
+        for fun, row in rows[:10]:
+            lines.append("  %-28s trace %.1f  lower %.1f  compile %.1f  "
+                         "load %.1f ms"
+                         % (fun[:28], row["trace_ms"], row["lower_ms"],
+                            row["compile_ms"] - row["load_ms"],
+                            row["load_ms"]))
+        for name, ms in setup["build_ms"].items():
+            lines.append("  %-28s %12.2f ms" % (name, ms))
+    waits = summary.get("waits")
+    if waits and waits["count"]:
+        lines.append("")
+        lines.append("Waits: host blocked on the device (ndarray.wait) "
+                     "%.2f ms in %d, longest %.2f ms"
+                     % (waits["total_ms"], waits["count"], waits["max_ms"]))
     lines.append("")
     lines.append("Per-span aggregates (self = exclusive of children):")
     lines.append("  %-32s %8s %12s %12s %10s %10s"
