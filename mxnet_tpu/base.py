@@ -29,12 +29,13 @@ class MXNetError(RuntimeError):
 # around the three parts of a dropless expert layer and ``moe_sort`` /
 # ``moe_products`` / ``moe_combine`` inside ``moe_experts`` (parallel/moe.py),
 # ``ssm`` around what a state-space mixer does between its two projections
-# and ``ssm_conv`` / ``ssm_scan`` inside it (ops/nn.py).
+# and ``ssm_conv`` / ``ssm_scan`` inside it, ``loss_head`` around the chunked
+# cross-entropy head (ops/nn.py).
 # A block of one of these names enters the scope under its name plus "_", so
 # a trace reader that meets the bare word knows the program wrote it.
 PROGRAM_SCOPES = ("attention", "optimizer", "moe_router", "moe_experts",
                   "moe_shared", "moe_sort", "moe_products", "moe_combine",
-                  "ssm", "ssm_conv", "ssm_scan")
+                  "ssm", "ssm_conv", "ssm_scan", "loss_head")
 
 string_types = (str,)
 numeric_types = (float, int, _np.generic)

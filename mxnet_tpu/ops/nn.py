@@ -1365,54 +1365,66 @@ def _ce_chunks(tokens, chunk):
     return chunk if tokens % chunk == 0 else tokens
 
 
+def _ce_scan(hidden, weight, labels, chunk, grads):
+    """The head's one loop over chunks of ``hidden (T, d)`` against
+    ``weight (P * V, d)`` and ``labels (T, P)``: ``(loss, dh, dw)`` with
+    ``grads``, the loss alone without. A chunk's ``(chunk, P * V)`` logits
+    come from one product and are split into the P heads' ``V`` for the
+    log-sum-exp; with ``grads`` the same logits give ``d`` (the loss's
+    gradient by them at a cotangent of 1, rounded to ``hidden.dtype``), and
+    ``d`` the chunk's ``dh = d W`` and its term of ``dw = sum d^T h``
+    (float32 across the chunks)."""
+    T, heads = labels.shape
+    valid = labels >= 0
+    count = jnp.maximum(jnp.sum(valid, axis=0), 1).astype(jnp.float32)
+    ids = jnp.arange(weight.shape[0] // heads, dtype=labels.dtype)
+
+    def one(dw, args):
+        h, lab = args
+        logits = lax.dot_general(
+            h, weight, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).reshape(chunk, heads, -1)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logits, jnp.maximum(lab, 0)[..., None], axis=-1)[..., 0]
+        nll = jnp.where(lab >= 0, lse - picked, 0.0)
+        if not grads:
+            return dw, (nll, None)
+        d = jnp.where((lab >= 0)[..., None],
+                      jnp.exp(logits - lse[..., None])
+                      - (ids == lab[..., None]), 0.0)
+        d = (d * ((1.0 / heads) / count)[:, None]).astype(hidden.dtype)
+        # written once: left to itself XLA computes ``d`` from the float32
+        # logits inside each of the two products that read it, and both
+        # slow down by more than the one pass over the logits costs
+        d = lax.optimization_barrier(d.reshape(chunk, -1))
+        dw = dw + lax.dot_general(d, h, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        return dw, (nll, jnp.matmul(d, weight))
+
+    dw, (nll, dh) = lax.scan(
+        one, jnp.zeros(weight.shape, jnp.float32) if grads else None,
+        (hidden.reshape(T // chunk, chunk, -1),
+         labels.reshape(T // chunk, chunk, heads)))
+    loss = jnp.sum(jnp.sum(nll.reshape(T, heads), axis=0) / count) / heads
+    if not grads:
+        return loss
+    return loss, dh.reshape(hidden.shape), dw.astype(weight.dtype)
+
+
 @_partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _chunked_ce(hidden, weight, labels, chunk):
-    return _chunked_ce_fwd(hidden, weight, labels, chunk)[0]
-
-
-def _chunk_logits(h, weight):
-    return lax.dot_general(h, weight, (((1,), (1,)), ((), ())),
-                           preferred_element_type=jnp.float32)
+    return _ce_scan(hidden, weight, labels, chunk, grads=False)
 
 
 def _chunked_ce_fwd(hidden, weight, labels, chunk):
-    T = hidden.shape[0]
-    valid = labels >= 0
-    count = jnp.maximum(jnp.sum(valid), 1).astype(jnp.float32)
-
-    def one(_, args):
-        h, lab = args
-        logits = _chunk_logits(h, weight)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(
-            logits, jnp.maximum(lab, 0)[:, None], axis=-1)[:, 0]
-        return None, (lse, jnp.where(lab >= 0, lse - picked, 0.0))
-
-    _, (lse, nll) = lax.scan(one, None, (
-        hidden.reshape(T // chunk, chunk, -1), labels.reshape(-1, chunk)))
-    return jnp.sum(nll) / count, (hidden, weight, labels, lse.reshape(T),
-                                  count)
+    loss, dh, dw = _ce_scan(hidden, weight, labels, chunk, grads=True)
+    return loss, (dh, dw)
 
 
 def _chunked_ce_bwd(chunk, res, g):
-    hidden, weight, labels, lse, count = res
-    T = hidden.shape[0]
-    ids = jnp.arange(weight.shape[0], dtype=labels.dtype)
-
-    def one(dw, args):
-        h, lab, l = args
-        probs = jnp.exp(_chunk_logits(h, weight) - l[:, None])
-        d = jnp.where((lab >= 0)[:, None],
-                      probs - (ids[None, :] == lab[:, None]), 0.0)
-        d = (d * (g / count)).astype(hidden.dtype)
-        dw = dw + lax.dot_general(d, h, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return dw, jnp.matmul(d, weight)
-
-    dw, dh = lax.scan(one, jnp.zeros(weight.shape, jnp.float32), (
-        hidden.reshape(T // chunk, chunk, -1), labels.reshape(-1, chunk),
-        lse.reshape(-1, chunk)))
-    return dh.reshape(hidden.shape), dw.astype(weight.dtype), None
+    dh, dw = res
+    return (dh * g).astype(dh.dtype), (dw * g).astype(dw.dtype), None
 
 
 _chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
@@ -1422,24 +1434,22 @@ _chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
 def chunked_softmax_cross_entropy(hidden, weight, labels, chunk=2048):
     """Mean cross-entropy of ``hidden (..., d) @ weight (V, d).T`` against
     ``labels (...)`` over the positions whose label is not negative, a
-    ``chunk`` of positions at a time: no (positions, V) array is live whole,
-    forward or backward (the backward pass recomputes each chunk's logits
-    from the saved log-sum-exp and adds the head's gradient up in
-    float32). ``labels (..., P)``, one axis more than ``hidden`` has before
-    its last: P targets a position, head j's rows of ``weight (P * V, d)``
-    from ``j * V`` on, and the mean over the heads of each head's mean,
-    a head at a time."""
+    ``chunk`` of positions at a time: no (positions, V) array is live whole.
+    ``labels (..., P)``, one axis more than ``hidden`` has before its last:
+    P targets a position, head j's rows of ``weight (P * V, d)`` from
+    ``j * V`` on, and the mean over the heads of each head's mean.
+
+    Differentiated, the loop that computes the loss computes the head's
+    gradient from the logits it holds (three products a chunk: the logits,
+    ``d W`` and ``d^T h``): live in a chunk's step are its float32 logits
+    ``(chunk, P * V)``, ``d`` in ``hidden``'s dtype and the float32 sum of
+    ``d(weight)``; kept for the backward pass are ``d(hidden)`` and
+    ``d(weight)`` at a cotangent of 1, which it multiplies by the
+    cotangent, and neither ``hidden`` nor ``weight``. Outside a gradient
+    the loop is the logits' product and the loss alone."""
     flat = hidden.reshape(-1, hidden.shape[-1])
-    chunk = _ce_chunks(flat.shape[0], chunk)
-    if labels.ndim < hidden.ndim:
-        return _chunked_ce(flat, weight, labels.reshape(-1).astype(jnp.int32),
-                           chunk)
-    heads = labels.shape[-1]
-
-    def one(total, head):       # a scan: one head's d(hidden) live at a time
-        return total + _chunked_ce(flat, *head, chunk), None
-
-    total, _ = lax.scan(one, jnp.float32(0.0), (
-        weight.reshape(heads, -1, weight.shape[-1]),
-        labels.reshape(-1, heads).T.astype(jnp.int32)))
-    return total / heads
+    heads = 1 if labels.ndim < hidden.ndim else labels.shape[-1]
+    with jax.named_scope("loss_head"):
+        return _chunked_ce(flat, weight,
+                           labels.reshape(-1, heads).astype(jnp.int32),
+                           _ce_chunks(flat.shape[0], chunk))

@@ -534,6 +534,40 @@ def test_conv_kernels_compile_at_published_widths(topo, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
+@pytest.mark.parametrize("width,rows,targets,limit", [
+    (2048, 100352, 1, 2.6e9), (4096, 320, 8, 0.1e9)],
+    ids=["granite_tied_vocabulary", "evabyte_eight_heads"])
+def test_loss_head_compiles_to_one_loop_of_three_products(topo, width, rows,
+                                                          targets, limit):
+    """The chunked loss head over 32,768 positions in chunks of 2,048,
+    value and both gradients, at Granite 4.0-H Micro's widths (2,048 against
+    the whole tied vocabulary) and at EvaByte's (4,096 against eight heads
+    of 320 byte values): ONE ``while`` and three ``convolution``s (the
+    logits, ``d W`` and ``d^T h``; the two-pass rule compiled to two loops
+    and four, four loops for the eight heads), no ``(targets, positions,
+    width)`` array, and of temporaries the chunk's float32 logits and the
+    float32 sum of ``d(weight)``: 2.47 GB by ``memory_analysis`` at
+    Granite's (the two-pass rule read 0.82 GB: the logits' and the sum's
+    turns came one after the other) and 0.04 GB at EvaByte's (0.27 GB: it
+    kept a head's ``d(hidden)``)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    labels = (1, 32768) + ((targets,) if targets > 1 else ())
+    compiled = jax.jit(jax.value_and_grad(
+        lambda h, w, lab: nn_ops.chunked_softmax_cross_entropy.fn(
+            h, w, lab, chunk=2048), (0, 1))).lower(
+        jax.ShapeDtypeStruct((1, 32768, width), jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct((targets * rows, width), jnp.bfloat16,
+                             sharding=one),
+        jax.ShapeDtypeStruct(labels, jnp.int32, sharding=one)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"= [^\n]* while\(", text)) == 1
+    assert len(re.findall(r"= [^\n]* convolution\(", text)) == 3
+    if targets > 1:
+        assert "[%d,32768,%d]" % (targets, width) not in text
+    assert "loss_head" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
 def test_hybrid_decoder_step_names_the_kernels(topo, monkeypatch):
     """One recomputed state-space layer and the attention layer at the
     published widths, the whole tied vocabulary and 32,768 tokens, traced by

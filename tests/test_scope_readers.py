@@ -475,6 +475,56 @@ def test_ssm_conv_kernel_reader(counts, expected, monkeypatch):
         "workloads": ["granite_4_0_h_micro_train_s32768"]}]
 
 
+HEAD = "jit(step)/jvp(net0)/loss_head"
+HEAD_TEXT = "\n".join([
+    "HloModule jit_step, is_scheduled=true",
+    "%body.1 (a.1: bf16[8,128]) -> bf16[8,128] {",
+    _line("fusion.4", "f32[8,128]{1,0}", "fusion(%a.1), kind=kOutput",
+          HEAD + "/while/body/closed_call/dot_general"),
+    _line("convolution_add_fusion.1", "f32[128,128]{1,0}",
+          "fusion(%fusion.4), kind=kOutput",
+          HEAD + "/while/body/closed_call/add"),
+    "}",
+    "ENTRY %main.1 (p0: bf16[8,128]) -> bf16[8,128] {",
+    _line("fusion.1", "bf16[8,128]{1,0}", "fusion(%p0), kind=kOutput",
+          NET + "/net0_layer0_ffn/dot_general"),
+    _line("while.1", "(bf16[8,128])", "while(%fusion.1), body=%body.1",
+          HEAD + "/while"),
+    _line("fusion.9", "bf16[8,128]{1,0}", "fusion(%while.1), kind=kLoop",
+          "jit(step)/transpose(jvp(net0))/loss_head/mul"),
+    "}"])
+
+
+def test_loss_head_scope_reader():
+    """``head_scope_pct.train``: the head's loop with the instructions that
+    run inside its event counted once, and the backward rule's scaling;
+    a program from before the scope (the parent of PR 36) reads None. Its
+    index entry names the three cells whose loss is the chunked head."""
+    import json
+    events = [("fusion.1", 0.0, 5.0), ("while.1", 5.0, 8.0),
+              ("fusion.4", 5.0, 6.0), ("convolution_add_fusion.1", 6.0, 8.0),
+              ("fusion.9", 8.0, 8.5)]
+    obs = _obs(step_text=HEAD_TEXT)
+    obs["trace"].update(events=events, busy_s=10.0)
+    read = load_reader("head_scope_pct.train", METRIC_DIR)
+    assert read(obs) == pytest.approx(35.0)
+    assert read(_obs(kind="serve")) is None
+    assert read(_obs(trace=None)) is None
+    assert read(_obs()) is None
+    obs["step_text"] = HEAD_TEXT.replace("/loss_head", "").replace(
+        "loss_head)", ")")
+    assert read(obs) is None
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entries = [m for m in json.load(f)["per_layer"]
+                   if m["name"] == "head_scope_pct.train"]
+    assert entries == [{
+        "name": "head_scope_pct.train", "unit": "%", "better": "lower",
+        "source": "device_trace", "layer": "loss head",
+        "moves": "train_tokens_per_s",
+        "workloads": ["kimi_vl_a3b_train_s8192", "evabyte_train_s32768",
+                      "granite_4_0_h_micro_train_s32768"]}]
+
+
 @pytest.mark.parametrize("path,expected", [
     ("flash_interpret", 100.0), ("xla", 0.0), ("no_counter", None),
     ("nothing_traced", None)])

@@ -60,7 +60,8 @@ def model_and_reference():
         lambda p: ref.loss(p, toks, TINY))(weights)
     return {"loss": float(value), "grads": dict(zip(names, grads)),
             "ref_loss": float(ref_value), "ref_grads": ref_grads,
-            "weights": weights, "tokens": toks}
+            "weights": weights, "tokens": toks,
+            "program": lambda v: loss(v, tokens, labels), "values": values}
 
 
 def test_loss_matches_the_reference(model_and_reference):
@@ -113,6 +114,23 @@ def test_the_tied_heads_gradient_is_the_lookups_plus_the_heads(
     got = m["grads"]["embed_weight"]
     assert float(jnp.linalg.norm(got - want)) <= \
         2e-5 * float(jnp.linalg.norm(want))
+
+
+def test_the_one_pass_heads_gradient_follows_the_cotangent_through_the_tie(
+        model_and_reference):
+    """The head's gradient is formed in the forward's loop at a cotangent
+    of 1 and scaled by the backward rule: half the loss gives half of every
+    leaf's gradient, the tied embedding's sum of two among them, to the bit;
+    and the loss outside a gradient (the loss-only loop) is the
+    differentiated one (the layers before it are compiled otherwise there:
+    the head alone to the bit in ``tests/test_loss_head.py``)."""
+    m = model_and_reference
+    loss, values = m["program"], m["values"]
+    half = jax.jit(jax.grad(lambda v: 0.5 * loss(v)))(values)
+    for (name, want), got in zip(m["grads"].items(), half):
+        np.testing.assert_array_equal(np.asarray(got),
+                                      0.5 * np.asarray(want), name)
+    assert float(jax.jit(loss)(values)) == pytest.approx(m["loss"], rel=1e-6)
 
 
 # ---- the scan ---------------------------------------------------------------
