@@ -1198,9 +1198,10 @@ flash_attention_packed.defvjp(_fap_fwd, _fap_bwd)
 # blocks are a GRID axis (sequential), so VMEM holds one block of each
 # operand and accumulators in scratch; a causal tile wholly above the
 # diagonal is skipped and its block index clamped, so nothing is fetched for
-# it. The forward and the dq + dkv pair run the tile bodies of the BHSD and
-# BSHD kernels (`_fwd_tile_update`, `_bwd_tile_ds`, `_tile_dead`), given the
-# rotary pair as their second dot product. Backward (`_latent_bwd_impl`):
+# it. The dq + dkv pair run the tile bodies of the BHSD and BSHD kernels
+# (`_bwd_tile_ds`, `_tile_dead`), given the rotary pair as their second dot
+# product; the forward and the fused backward have their own. Backward
+# (`_latent_bwd_impl`):
 # `flash_latent_bwd`, ONE kernel, where a (row, head)'s float32 dq fits VMEM
 # beside the tile (`_latent_bwd_vmem`; 6 MiB of 30 planned at 8,192, up to
 # about 120,000 positions at these widths); `flash_latent_dq` then
@@ -1209,6 +1210,15 @@ flash_attention_packed.defvjp(_fap_fwd, _fap_bwd)
 # measured on the chip at 2 x 8192 x 16 heads (PR 27), forward + backward:
 # 256/256 70.7 ms, 512/512 38.2, 1024/512 36.4, 256/1024 37.0, 256/2048
 # 35.4, 512/1024 32.6, 1024/1024 30.8 (512/2048: dkv refused, VMEM)
+# The forward alone on the chip at 4 x 8192 x 16 heads, ms a call (host
+# clock, median of six; the kernel's device time in brackets): the body that
+# shared the BHSD tile update, queries on the sublanes and every live tile
+# masked, 1024/1024 20.2 [18.85]. Keys on the sublanes, the diagonal's tiles
+# masked: 1024/1024 16.0 [14.69], 2048/1024 16.2, 2048/512 16.6, 1024/2048
+# 16.9, 1024/512 17.2, 512/1024 18.2, 512/512 18.6, 512/2048 18.7; with exp2
+# [13.97]. Queries on the sublanes, the statistics lane-replicated: 1024/1024
+# 15.3 [13.89], 1024/512 16.6, 512/1024 16.9 (2048/1024: VMEM); with exp2
+# 14.4 [13.08], with the scores one product 14.1 [12.63].
 _PREF_LATENT_Q = _PREF_LATENT_K = 1024
 
 
@@ -1235,14 +1245,43 @@ def _latent_live(causal, q0, k0, blk_q):
     return (k0 <= q0 + (blk_q - 1)) if causal else True
 
 
+# ---- the latent forward's own tile body
+# The queries on the sublanes, S = Q K^T (queries, keys), so that both
+# products stream a 1,024-row operand through the MXU against few weight
+# tiles, the keys' and V's (with the keys on the sublanes, as the fused
+# backward has them, P^T would be the weights of P V: measured slower, see
+# the sizes above); the nope and rope columns of the scores are ONE product
+# over their concatenation. The running maximum and sum live in scratch
+# replicated over 128 lanes, (blk_q, 128), so reading, rescaling and
+# broadcasting them moves nothing between lanes and sublanes; the
+# log-sum-exp is turned into its lane row once a query block. The maximum is
+# taken on the unscaled scores, and the scale and log2(e) are one multiplier
+# in the one exp2 sweep (the maximum and the log-sum-exp carry them). The
+# causal mask is applied on the tiles that straddle the diagonal only. A
+# masked score is -1e30 and its exp2 exactly 0: key block 0 is live and
+# visited first for every query, so no row's maximum is still -1e30 when a
+# later tile masks it whole.
+
+def _latent_below(q0, k0, blk_k):
+    """Whether causal tile (q0, k0) has no position above the diagonal."""
+    return k0 + (blk_k - 1) <= q0
+
+
+def _lanes(x, n):
+    """A (rows, 128) block of lane-replicated values as (rows, n)."""
+    return x if n == 128 else jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _latent_fwd_kernel(qn_ref, qr_ref, kv_ref, kr_ref, o_ref, lse_ref,
                        acc_ref, m_ref, l_ref, *, scale, causal, blk_q, blk_k,
                        nope):
     """One (batch, head, q-block, k-block) program: one online-softmax
-    step into the scratch accumulators; the last k-block writes the output
-    and the log-sum-exp."""
+    step into the scratch accumulators (``m_ref`` and ``l_ref`` (blk_q,
+    128), in base 2); the last k-block writes the output and the
+    log-sum-exp."""
     kj = pl.program_id(3)
     q0, k0 = pl.program_id(2) * blk_q, kj * blk_k
+    log2e = jnp.float32(scale * np.log2(np.e))
 
     @pl.when(kj == 0)
     def _():
@@ -1250,23 +1289,68 @@ def _latent_fwd_kernel(qn_ref, qr_ref, kv_ref, kr_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
 
-    @pl.when(_latent_live(causal, q0, k0, blk_q))
-    def _():
-        dead = _tile_dead(causal, q0, k0, blk_q, blk_k, None)
-        carry = (acc_ref[...], m_ref[0, :], l_ref[0, :])
-        _, (acc, m_i, l_i) = _fwd_tile_update(
-            qn_ref[0], kv_ref[0, :, :nope], kv_ref[0, :, nope:], carry, dead,
-            None, None, q0, k0, blk_q, blk_k, 0.0, scale,
-            extra=(qr_ref[0, 0], kr_ref[0]))
-        acc_ref[...] = acc
-        m_ref[0, :] = m_i
-        l_ref[0, :] = l_i
+    def tile(masked):
+        # ONE product over the keys' nope + rope columns, in the wider of
+        # the two query parts' dtypes, accumulated in float32
+        dt = jnp.promote_types(qn_ref.dtype, qr_ref.dtype)
+        q = jnp.concatenate([qn_ref[0].astype(dt), qr_ref[0, 0].astype(dt)], 1)
+        k = jnp.concatenate([kv_ref[0, :, :nope].astype(dt),
+                             kr_ref[0].astype(dt)], 1)
+        v = kv_ref[0, :, nope:].astype(qn_ref.dtype)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if masked:
+            q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(q_pos < k_pos, jnp.float32(NEG_INF), s)
+        m_i = m_ref[...]
+        m_new = jnp.maximum(m_i, jnp.max(s, axis=1, keepdims=True) * log2e)
+        p = jnp.exp2(s * log2e - _lanes(m_new, blk_k))
+        corr = jnp.exp2(m_i - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _lanes(corr, acc_ref.shape[1]) + \
+            jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    if causal:
+        below = _latent_below(q0, k0, blk_k)
+        pl.when(below)(lambda: tile(False))
+        pl.when(jnp.logical_and(_latent_live(True, q0, k0, blk_q),
+                                jnp.logical_not(below)))(lambda: tile(True))
+    else:
+        tile(False)
 
     @pl.when(kj == pl.num_programs(3) - 1)
     def _():
-        l_safe = jnp.maximum(l_ref[0, :], jnp.float32(1e-20))
-        o_ref[0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = m_ref[0, :] + jnp.log(l_safe)
+        l_safe = jnp.maximum(l_ref[...], jnp.float32(1e-20))
+        o_ref[0] = (acc_ref[...] / _lanes(l_safe, acc_ref.shape[1])).astype(
+            o_ref.dtype)
+        lse = m_ref[...] * jnp.float32(np.log(2.0)) + jnp.log(l_safe)
+        lse_ref[0] = lse.T[0:1, :]
+
+
+# Tiles of one (row, head) the latent forwards traced so far compute, and
+# those of them that straddle the diagonal and are masked (counted where
+# :func:`_latent_fwd_impl` builds its call, once a trace, by the predicates
+# the kernel's ``pl.when``s branch on).
+_LATENT_FWD_TILES = {"live": 0, "masked": 0}
+
+
+def latent_forward_stats():
+    """:func:`flash_backward_stats` for the latent forward's tiles."""
+    return dict(_LATENT_FWD_TILES)
+
+
+def _latent_fwd_tiles(seq, blk_q, blk_k, causal):
+    """(live, masked) tiles of one (row, head) of the latent forward."""
+    tiles = [(q0, k0) for q0 in range(0, seq, blk_q)
+             for k0 in range(0, seq, blk_k)]
+    if not causal:
+        return len(tiles), 0
+    live = [t for t in tiles if _latent_live(True, *t, blk_q)]
+    return len(live), sum(not _latent_below(q0, k0, blk_k)
+                          for q0, k0 in live)
 
 
 def _latent_dq_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref,
@@ -1502,6 +1586,9 @@ def _latent_fwd_impl(q_nope, q_rope, kv, k_rope, num_heads, causal, blocks,
     B, S, nope, rope, v_dim, blk_q, blk_k = _latent_dims(
         q_nope, q_rope, kv, H, blocks)
     spec = _latent_specs(H, nope, rope, v_dim, blk_q, blk_k, causal, False)
+    live, masked = _latent_fwd_tiles(S, blk_q, blk_k, causal)
+    _LATENT_FWD_TILES["live"] += live
+    _LATENT_FWD_TILES["masked"] += masked
     kernel = functools.partial(
         _latent_fwd_kernel, scale=float(1.0 / np.sqrt(nope + rope)),
         causal=causal, blk_q=blk_q, blk_k=blk_k, nope=nope)
@@ -1511,7 +1598,7 @@ def _latent_fwd_impl(q_nope, q_rope, kv, k_rope, num_heads, causal, blocks,
         (spec["out"], spec["row"]),
         (jax.ShapeDtypeStruct((B, S, H * v_dim), q_nope.dtype),
          jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)),
-        [(blk_q, v_dim), (1, blk_q), (1, blk_q)], interpret)
+        [(blk_q, v_dim), (blk_q, 128), (blk_q, 128)], interpret)
     with jax.enable_x64(False):
         return call(q_nope, q_rope, kv, k_rope)
 

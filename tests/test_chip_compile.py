@@ -264,12 +264,13 @@ def test_latent_kernels_compile_at_published_widths(topo, seq):
     """16 heads of 128 + 64 against 128, bfloat16, for one described chip:
     two Mosaic calls, ``flash_latent_fwd`` and the ONE backward
     ``flash_latent_bwd``, at 8,192 positions (the cell's) and at 32,768.
-    The forward's VMEM holds blocks only; the backward's grows with the
-    sequence by dQ^T of one (row, head) in float32 (6 MiB at 8,192, 24 at
-    32,768) and asks Mosaic for what ``_latent_bwd_vmem`` plans, so this is
-    where that footprint is proved without the chip. Past the budget (about
-    120,000 positions at these widths) ``flash_latent_dq`` + ``_dkv`` run
-    (the per-head BHSD kernels are refused at 8,192 x 192)."""
+    The forward's VMEM holds blocks only (asserted against what Mosaic
+    planned for it); the backward's grows with the sequence by dQ^T of one
+    (row, head) in float32 (6 MiB at 8,192, 24 at 32,768) and asks Mosaic
+    for what ``_latent_bwd_vmem`` plans, so this is where that footprint
+    is proved without the chip. Past the budget (about 120,000 positions at
+    these widths) ``flash_latent_dq`` + ``_dkv`` run (the per-head BHSD
+    kernels are refused at 8,192 x 192)."""
     from mxnet_tpu.ops import pallas_kernels as pk
     one = SingleDeviceSharding(topo.devices[0])
     rows = 2 if seq == 8192 else 1
@@ -286,6 +287,21 @@ def test_latent_kernels_compile_at_published_widths(topo, seq):
     assert text.count("tpu_custom_call") == 2
     for kernel in ("flash_latent_fwd", "flash_latent_bwd"):
         assert kernel in text, kernel
+    # the forward's VMEM: what Mosaic planned for it, against its blocks (the
+    # four operands' and two results' double-buffered, a last dim under 128
+    # taking 128 lanes and the log-sum-exp row 8 sublanes), its scratch and
+    # two float32 (blk_k, blk_q) tiles, the scores and their exponentials; a
+    # body that kept anything of the whole sequence would pass it at 32,768
+    blk_q, blk_k = pk._pick_blocks_latent(seq)
+    blocks = 2 * 2 * (blk_q * (128 + 128 + 128) + blk_k * (256 + 128)) \
+        + 2 * 8 * blk_q * 4
+    scratch = (128 + 2 * 8) * blk_q * 4
+    used = [int(n) for line in text.splitlines()
+            if "flash_latent_fwd" in line and "tpu_custom_call" in line
+            for n in re.findall(
+                r'used_scoped_memory_configs":\[[^]]*"size":"(\d+)"', line)]
+    assert len(used) == 1
+    assert used[0] <= blocks + scratch + 2 * blk_q * blk_k * 4, used
 
 
 def test_bhsd_kernels_are_refused_at_the_latent_cells_length(topo):

@@ -104,38 +104,90 @@ def _latent_operands(batch, seq, heads=2, nope=128, rope=64, v_dim=128):
     return [jax.random.normal(k, s, jnp.float32) for k, s in zip(ks, shapes)]
 
 
+# (seq, blocks, causal): blocks equal, queries fewer and more than keys; three
+# and four blocks, so that some live tiles straddle the diagonal and others lie
+# wholly below it; "4kb_q128_k256": two query blocks whose first key block is
+# their only live one
+_LATENT_CASES = {
+    "2kb": (256, (128, 128), True), "3kb": (384, (128, 128), True),
+    "q256_k128": (256, (256, 128), True), "q128_k256": (256, (128, 256), True),
+    "4kb_q256_k128": (512, (256, 128), True),
+    "4kb_q128_k256": (512, (128, 256), True),
+    "3kb_full": (384, (128, 128), False),
+    "q256_k128_full": (256, (256, 128), False),
+    "q128_k256_full": (256, (128, 256), False)}
+
+
+def _latent_lse(q_nope, q_rope, kv, k_rope, heads, causal, nope=128):
+    """Log-sum-exp of each (row, head)'s scaled scores, (B * H, 1, S)."""
+    B, S, _ = q_nope.shape
+    qn = q_nope.reshape(B, S, heads, nope)
+    kn = kv.reshape(B, S, heads, -1)[..., :nope]
+    s = (jnp.einsum("bqhd,bkhd->bhqk", qn, kn, precision="highest")
+         + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope, precision="highest"))
+    s = s / np.sqrt(nope + q_rope.shape[-1])
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1).reshape(B * heads, 1, S)
+
+
 @pytest.fixture(scope="module")
 def latent_cases():
     out = {}
-    for seq, blocks in ((256, (128, 128)), (384, (128, 128)),
-                        (256, (256, 128)), (256, (128, 256))):
+    for seq, blocks, causal in _LATENT_CASES.values():
         *ops, w = _latent_operands(2, seq)
 
         def flash(*a):
             return jnp.sum(pk.flash_attention_latent(
-                *a, 2, True, blocks, True) * w)
+                *a, 2, causal, blocks, True) * w)
 
         def plain(*a):
-            return jnp.sum(nn_ops.xla_latent_attention(*a, 2, True) * w)
+            return jnp.sum(nn_ops.xla_latent_attention(*a, 2, causal) * w)
 
-        got = (pk.flash_attention_latent(*ops, 2, True, blocks, True),) \
-            + jax.grad(flash, argnums=(0, 1, 2, 3))(*ops)
-        want = (nn_ops.xla_latent_attention(*ops, 2, True),) \
-            + jax.grad(plain, argnums=(0, 1, 2, 3))(*ops)
-        out[(seq, blocks)] = (got, want)
+        lse = pk._latent_fwd_impl(ops[0], jnp.transpose(ops[1], (0, 2, 1, 3)),
+                                  ops[2], ops[3], 2, causal, blocks, True)[1]
+        got = (pk.flash_attention_latent(*ops, 2, causal, blocks, True),) \
+            + jax.grad(flash, argnums=(0, 1, 2, 3))(*ops) + (lse,)
+        want = (nn_ops.xla_latent_attention(*ops, 2, causal),) \
+            + jax.grad(plain, argnums=(0, 1, 2, 3))(*ops) \
+            + (_latent_lse(*ops, 2, causal),)
+        out[(seq, blocks, causal)] = (got, want)
     return out
 
 
-@pytest.mark.parametrize("which", range(5), ids=[
-    "forward", "dq_nope", "dq_rope", "dkv", "dk_rope"])
-@pytest.mark.parametrize("seq,blocks", [
-    (256, (128, 128)), (384, (128, 128)), (256, (256, 128)),
-    (256, (128, 256))], ids=["2kb", "3kb", "q256_k128", "q128_k256"])
+@pytest.mark.parametrize("which", range(6), ids=[
+    "forward", "dq_nope", "dq_rope", "dkv", "dk_rope", "lse"])
+@pytest.mark.parametrize("seq,blocks,causal", list(_LATENT_CASES.values()),
+                         ids=list(_LATENT_CASES))
 def test_latent_flash_kernels_match_the_xla_form(latent_cases, seq, blocks,
-                                                 which):
-    got, want = latent_cases[(seq, blocks)]
+                                                 causal, which):
+    got, want = latent_cases[(seq, blocks, causal)]
     scale = float(jnp.abs(want[which]).max())
     assert float(jnp.abs(got[which] - want[which]).max()) <= 5e-6 * scale
+
+
+@pytest.mark.parametrize("seq,blocks,causal,want", [
+    (8192, (1024, 1024), True, (36, 8)), (512, (256, 128), True, (6, 4)),
+    (512, (128, 256), True, (6, 4)), (512, (256, 128), False, (8, 0))],
+    ids=["cell", "q256_k128", "q128_k256", "q256_k128_full"])
+def test_latent_forward_counts_its_tiles(seq, blocks, causal, want):
+    """``latent_forward_stats()``: the live tiles of one (row, head) and
+    those of them masked (straddling the diagonal), once a trace. At the
+    cell's 8,192 / 1,024 / 1,024 the call is traced only (36 of 64 tiles
+    live, the 8 on the diagonal masked); the small sizes run in interpret
+    mode."""
+    *ops, _ = _latent_operands(1, seq)
+    before = pk.latent_forward_stats()
+    if seq > 1024:
+        shapes = [jax.ShapeDtypeStruct(x.shape, jnp.bfloat16) for x in ops]
+        jax.eval_shape(functools.partial(
+            pk.flash_attention_latent, num_heads=2, causal=causal,
+            blocks=blocks), *shapes)
+    else:
+        pk.flash_attention_latent(*ops, 2, causal, blocks, True)
+    after = pk.latent_forward_stats()
+    assert (after["live"] - before["live"],
+            after["masked"] - before["masked"]) == want
 
 
 def test_dispatcher_counts_the_latent_kernels(request):

@@ -765,8 +765,8 @@ def _attention_jaxpr_hashes():
         return jax.ShapeDtypeStruct(shape, dtype)
 
     traced = {}
-    # the Kimi cell: 4 causal rows of 8192, 16 heads, 128 + 64 against 128.
-    # The forward is the parent's; the backward is PR 30's one kernel.
+    # the Kimi cell: 4 causal rows of 8192, 16 heads, 128 + 64 against 128;
+    # the forward and the one-kernel backward, each with its own tile body
     latent = (spec(4, 8192, 2048), spec(4, 16, 8192, 64),
               spec(4, 8192, 4096), spec(4, 8192, 64))
     traced["latent_fwd_4x8192_h16"] = jax.make_jaxpr(
@@ -807,12 +807,12 @@ def _attention_jaxpr_hashes():
 
 def test_latent_bhsd_and_split_kernels_trace_to_the_parents_jaxprs():
     """``flash_latent_fwd`` at the Kimi cell's shape, ``flash_bhsd_*`` and
-    the two-kernel head-fused path trace to the programs recorded from the
-    commits before the fused backwards (``tests/data/
-    attention_jaxprs_pr27.json``, written by this function there; the
-    latent forward's entry from PR 29's tree): what the BERT cells and
-    ``TransformerLM`` run stands still, shown without the chip. The latent
-    backward's entry is ``flash_latent_bwd`` as PR 30 left it."""
+    the two-kernel head-fused path trace to the programs recorded in
+    ``tests/data/attention_jaxprs_pr27.json`` (written by this function):
+    what the BERT cells and ``TransformerLM`` run stands still, shown
+    without the chip. The latent entries are the one-kernel backward
+    ``flash_latent_bwd`` and the forward with its own tile body, queries on
+    the sublanes and the statistics lane-replicated."""
     import json
     import os
     path = os.path.join(os.path.dirname(__file__), "data",

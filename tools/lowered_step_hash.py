@@ -4,7 +4,10 @@ small size, for the TPU platform, without the chip (PR 29's recipe): build the
 cell's ``TrainSystem`` on the CPU with the platform seam patched, lower the
 trainer's jitted step for ``tpu``, replace every Mosaic kernel body (base64
 MLIR bytecode, which carries source lines) by the hash of its assembly without
-debug info, hash the text. Run this ONE script from the root of each tree
+debug info, hash the text. Beside that hash it prints the "frame" (the text
+with every body left out) and each kernel name's bodies hashed, so that a
+change to one kernel shows as that name alone. Run this ONE script from the
+root of each tree
 (``git archive <parent>`` into a git-ignored directory, and the change) and
 compare; the hash depends on the size chosen, so only two runs of one script
 compare. Keys after the cell override its workload file:
@@ -44,7 +47,10 @@ lowered = tr._step_fn.trace(jax.random.PRNGKey(0), tr._values, tr._states,
     lowering_platforms=("tpu",))
 text = lowered.as_text()
 from jax._src.lib.mlir import ir
+BODY = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
 bodies = [0]
+
+
 def strip(m):
     raw = base64.b64decode(m.group(1))
     with ir.Context() as ctx:
@@ -52,7 +58,22 @@ def strip(m):
         asm = ir.Module.parse(raw).operation.get_asm(enable_debug_info=False)
     bodies[0] += 1
     return '\\22body\\22: \\22' + hashlib.sha256(asm.encode()).hexdigest() + '\\22'
-text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', strip, text)
+
+
+def short(x):
+    return hashlib.sha256(x.encode()).hexdigest()[:16]
+
+
+text = BODY.sub(strip, text)
+kernels = {}
+for line in text.splitlines():
+    name = re.search(r'kernel_name = "([^"]+)"', line)
+    for body in BODY.findall(line):
+        kernels.setdefault(name.group(1) if name else "?", set()).add(body)
+# "hash": the whole text; "frame": the text with every kernel body left out;
+# "kernels": each kernel name's distinct bodies, hashed together
 print(json.dumps({"cell": cell_name, "over": over, "kernel_bodies": bodies[0],
-                  "chars": len(text),
-                  "hash": hashlib.sha256(text.encode()).hexdigest()[:16]}))
+                  "chars": len(text), "hash": short(text),
+                  "frame": short(BODY.sub(lambda m: "", text)),
+                  "kernels": {k: short(" ".join(sorted(v)))
+                              for k, v in sorted(kernels.items())}}))
